@@ -180,8 +180,11 @@ def apply_precision(mf, precision: str):
     # Dynamic attributes the serving path reads off loader-built MFs
     # must survive the wrap (single_stream keeps whole-mesh programs
     # off the per-batch rotation; params_sharded drives the residency
-    # manager's per-chip sizing; vocab_size rides text entries).
-    for attr in ("single_stream", "params_sharded", "vocab_size", "mesh"):
+    # manager's per-chip sizing; vocab_size and the attention a text
+    # model was built with ride text entries).
+    for attr in (
+        "single_stream", "params_sharded", "vocab_size", "mesh", "attention"
+    ):
         if hasattr(mf, attr):
             setattr(wrapped, attr, getattr(mf, attr))
     wrapped.precision = precision

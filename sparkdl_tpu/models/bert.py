@@ -20,7 +20,6 @@ into this module and comparing outputs.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
@@ -28,8 +27,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from sparkdl_tpu.runtime import knobs
 
 
 @dataclass(frozen=True)
@@ -121,6 +118,9 @@ def dense_attention(q, k, v, mask, dtype):
         scores = scores + mask
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+dense_attention.kind = "dense"
 
 
 class BertSelfAttention(nn.Module):
@@ -217,6 +217,25 @@ class BertEncoder(nn.Module):
 _SIZES = {"base": bert_base, "tiny": bert_tiny, "long": bert_long}
 
 
+def encoder_model_function(module: "BertEncoder", fn, params, name: str):
+    """The embed ModelFunction over ``fn`` (a closure on ``module.apply``),
+    carrying what the module knows — the one place every text builder
+    gets these from. ``vocab_size`` lets tokenizers bound their id space
+    (out-of-vocab ids would be out-of-bounds embedding gathers);
+    ``attention`` is the ``kind`` of the attention the module was built
+    with ('flash' | 'dense' | 'ring' | 'ulysses'; 'custom' for a
+    caller's own function), which ``/v1/models`` and ``chip_smoke.py``
+    report."""
+    from sparkdl_tpu.graph.function import ModelFunction
+
+    mf = ModelFunction(fn, params, input_dtype=jnp.int32, name=name)
+    mf.vocab_size = module.config.vocab_size
+    mf.attention = getattr(
+        module.attention_fn or dense_attention, "kind", "custom"
+    )
+    return mf
+
+
 def bert_model_function(
     size: str = "base",
     dtype=jnp.float32,
@@ -231,8 +250,6 @@ def bert_model_function(
     the size ladder with an explicit :class:`BertConfig` (its dtype is
     replaced by ``dtype``) — the long-context registry entries and the
     smokes' scaled-down geometries build through this."""
-    from sparkdl_tpu.graph.function import ModelFunction
-
     if config is not None:
         from dataclasses import replace
 
@@ -252,49 +269,22 @@ def bert_model_function(
             f"position table ({module.config.max_position_embeddings})"
         )
     if attention_fn is None:
-        # Default to the Pallas flash kernel; it self-selects per backend
-        # AT TRACE TIME (compiled kernel on TPU, dense einsum elsewhere),
-        # so the same ModelFunction works on CPU meshes and real chips.
-        # Pass attention_fn=dense_attention to force the einsum path.
+        # The Pallas flash kernel on TPU, the dense einsum elsewhere —
+        # chosen once, here (see make_flash_attention_fn). Pass
+        # attention_fn=dense_attention to force the einsum path.
         from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
 
         attention_fn = make_flash_attention_fn()
     module = BertEncoder(module.config, attention_fn=attention_fn)
     if params is None:
         ids0 = jnp.zeros((1, min(max_length, 16)), jnp.int32)
-        if knobs.get_str("SPARKDL_BERT_INIT") == "host":
-            # Wedge-bisect knob: run the init program (whose biggest
-            # output is the ~94 MB vocab embedding) on the host CPU
-            # backend instead of the accelerator; params then transfer
-            # leaf-by-leaf at first model call. jax RNG is threefry —
-            # backend-independent — so values are identical either way.
-            # (The flash wrapper detects the cpu default-device scope and
-            # traces the dense path during init — see _on_tpu.)
-            try:
-                cpu_dev = jax.devices("cpu")[0]
-            except RuntimeError as e:
-                raise RuntimeError(
-                    "SPARKDL_BERT_INIT=host needs the cpu platform "
-                    "registered alongside the accelerator (jax_platforms "
-                    "must include 'cpu'; bench.py child processes add it "
-                    "when the knob is set)"
-                ) from e
-            with jax.default_device(cpu_dev):
-                params = module.init(jax.random.PRNGKey(seed), ids0)
-        else:
-            params = module.init(jax.random.PRNGKey(seed), ids0)
+        params = module.init(jax.random.PRNGKey(seed), ids0)
 
     def fn(p, x):
         ids, mask = x if isinstance(x, (tuple, list)) else (x, None)
         return module.apply(p, ids, mask, pooled=True)
 
-    mf = ModelFunction(
-        fn, params, input_dtype=jnp.int32, name=f"bert_{size}[embed]"
-    )
-    # Advertised so tokenizers can bound their id space (out-of-vocab ids
-    # would be out-of-bounds embedding gathers).
-    mf.vocab_size = module.config.vocab_size
-    return mf
+    return encoder_model_function(module, fn, params, f"bert_{size}[embed]")
 
 
 def bert_model_function_sequence_parallel(
@@ -325,12 +315,6 @@ def bert_model_function_sequence_parallel(
     apply (transformers/execution honors the flag).
     """
     from jax.sharding import PartitionSpec as P
-
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
-    from sparkdl_tpu.graph.function import ModelFunction
 
     if mesh is None:
         from sparkdl_tpu.parallel import make_mesh
@@ -391,7 +375,7 @@ def bert_model_function_sequence_parallel(
         count = jax.lax.psum(jnp.sum(m, axis=1), axis)
         return total / jnp.maximum(count, 1.0)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(None, axis), P(None, axis)),
@@ -410,11 +394,9 @@ def bert_model_function_sequence_parallel(
             )
         return sharded(p, ids, jnp.asarray(mask, jnp.int32))
 
-    mf = ModelFunction(
-        fn, params, input_dtype=jnp.int32,
-        name=f"bert_{size}[embed,{strategy}/{axis}x{n}]",
+    mf = encoder_model_function(
+        module, fn, params, f"bert_{size}[embed,{strategy}/{axis}x{n}]"
     )
-    mf.vocab_size = module.config.vocab_size
     mf.single_stream = True  # whole-mesh per batch; no device round-robin
     return mf
 

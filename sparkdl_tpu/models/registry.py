@@ -241,7 +241,8 @@ class NamedTextModel:
 
 def _bert_text_builder(size: str, attention: str = "flash"):
     """Builder over models/bert.py presets. ``attention``: 'flash' (the
-    Pallas kernel, self-selecting the dense einsum off-TPU) or 'dense'.
+    Pallas kernel on TPU, the dense einsum off it — chosen at build
+    time and recorded as ``mf.attention``) or 'dense'.
     The returned ModelFunction takes a bare ids batch and derives its
     mask on device — serving payloads are one int array, not a tuple."""
 
@@ -291,14 +292,9 @@ def _bert_text_builder(size: str, attention: str = "flash"):
                 mask = (ids != 0).astype(jnp.int32)
             return module.apply(p, ids, mask, pooled=True)
 
-        mf = ModelFunction(
-            fn,
-            variables,
-            input_dtype=jnp.int32,
-            name=f"{spec.name}[{mode}]",
+        return bert_mod.encoder_model_function(
+            module, fn, variables, f"{spec.name}[{mode}]"
         )
-        mf.vocab_size = module.config.vocab_size
-        return mf
 
     return build
 

@@ -83,10 +83,9 @@ class ResNet(nn.Module):
     ``scan_blocks``: compile each stage's run of identical identity blocks
     as ONE ``lax.scan`` over stacked params instead of unrolled HLO. Same
     math, much smaller executable (ResNet50: 16 block bodies -> 8), which
-    cuts compile time and the program-load footprint — that matters on
-    remote-tunneled TPU runtimes where program size taxes every subsequent
-    host<->device RPC. Param layout differs (identity blocks stacked on a
-    leading axis), so keep it off when loading per-block weight files.
+    cuts compile time and the program-load footprint. Param layout differs
+    (identity blocks stacked on a leading axis), so keep it off when
+    loading per-block weight files.
     """
 
     stage_sizes: Sequence[int]

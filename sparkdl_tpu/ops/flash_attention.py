@@ -11,9 +11,11 @@ memory instead of O(L²).
 
 Composes with ring attention (ops/ring_attention.py): the ring rotates K/V
 shards over the mesh's 'sp' axis while this kernel computes each local
-block product. On non-TPU backends the public entry points fall back to
-the dense einsum path (numerically identical up to fp accumulation order);
-``interpret=True`` runs the actual kernel on CPU for tests.
+block product. :func:`flash_attention` always runs the kernel — compiled,
+or under ``interpret=True`` (the CPU tests) — and a Mosaic compile error
+is an error. :func:`make_flash_attention_fn` picks the attention a model
+is BUILT with, once, from the process's default backend, and the choice
+is recorded on the returned function (``.kind``).
 """
 
 from __future__ import annotations
@@ -66,7 +68,7 @@ def _flash_kernel(
         )
         * scale
     )  # [bq, bk]
-    s = s + mask_ref[0][None, :].astype(jnp.float32)
+    s = s + mask_ref[0]  # [1, bk] broadcasts over the q rows
 
     # lanes of m_ref/l_ref all hold the same per-row value; max() reads it
     # back without a sub-128 lane slice.
@@ -152,6 +154,11 @@ def flash_attention(
     qf = q.reshape(B * H, Lq_p, Dh_p)
     kf = k.reshape(B * H, Lk_p, Dh_p)
     vf = v.reshape(B * H, Lk_p, Dh_p)
+    # [B, 1, Lk]: the mask block's last two dims are then (1, block_k),
+    # and a second-minor block dim of 1 is legal only where it equals
+    # the array's own — blocking a [B, Lk] mask as (1, block_k) breaks
+    # the (8, 128) tiling rule for every B > 1.
+    mask3d = mask2d[:, None, :]
 
     nq = Lq_p // block_q
     nk = Lk_p // block_k
@@ -165,7 +172,7 @@ def flash_attention(
             pl.BlockSpec((1, block_k, Dh_p), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, Dh_p), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec(
-                (1, block_k), lambda bh, qi, ki, H=H: (bh // H, ki)
+                (1, 1, block_k), lambda bh, qi, ki, H=H: (bh // H, 0, ki)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -181,40 +188,30 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qf, kf, vf, mask2d)
+    )(qf, kf, vf, mask3d)
 
     out = out.reshape(B, H, Lq_p, Dh_p)
     return out[:, :, :L, :Dh]
 
 
-def _on_tpu() -> bool:
-    try:
-        # An explicit jax.default_device(cpu) scope (e.g. the
-        # SPARKDL_BERT_INIT=host init path) traces for the CPU even when
-        # the process default backend is the TPU — the compiled kernel
-        # must not be selected there.
-        dd = jax.config.jax_default_device
-        if dd is not None and getattr(dd, "platform", None) == "cpu":
-            return False
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def make_flash_attention_fn(
-    block_q: int = 128, block_k: int = 128, interpret: Optional[bool] = None
+    block_q: int = 128, block_k: int = 128, interpret: bool = False
 ):
     """Returns an attention fn with the ``dense_attention`` signature
     (q, k, v, mask, dtype) — drop-in for BertEncoder(attention_fn=...).
-    Uses the Pallas kernel on TPU (or interpreted when forced); falls back
-    to the dense einsum path elsewhere so CPU meshes keep working."""
+
+    The choice is made HERE, at build time, from the process's default
+    backend: the Pallas kernel on TPU (or interpreted when asked — never
+    derived from the backend), ``dense_attention`` itself elsewhere so
+    CPU meshes keep working. Either way the returned function's
+    ``.kind`` ('flash' | 'dense') says which, and nothing downstream
+    re-decides: a kernel that fails to compile raises."""
+    if not interpret and jax.default_backend() != "tpu":
+        from sparkdl_tpu.models.bert import dense_attention
+
+        return dense_attention
 
     def attention(q, k, v, mask, dtype):
-        use_interpret = interpret
-        if use_interpret is None and not _on_tpu():
-            from sparkdl_tpu.models.bert import dense_attention
-
-            return dense_attention(q, k, v, mask, dtype)
         out = flash_attention(
             q,
             k,
@@ -222,8 +219,9 @@ def make_flash_attention_fn(
             mask,
             block_q=block_q,
             block_k=block_k,
-            interpret=bool(use_interpret),
+            interpret=interpret,
         )
         return out.astype(dtype)
 
+    attention.kind = "flash"
     return attention

@@ -58,9 +58,7 @@ def make_ring_attention(axis_name: str = "sp"):
     via BertEncoder(attention_fn=...)."""
 
     def ring_attention(q, k, v, mask, dtype):
-        from sparkdl_tpu.runtime.compat import axis_size
-
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         scale = 1.0 / np.sqrt(q.shape[-1])
         perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -90,6 +88,7 @@ def make_ring_attention(axis_name: str = "sp"):
         )
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(dtype)
 
+    ring_attention.kind = "ring"
     return ring_attention
 
 
@@ -101,16 +100,12 @@ def sharded_attention(attn, q, k, v, mask, mesh, axis, dtype=jnp.float32):
     run on the local shards."""
     from jax.sharding import PartitionSpec as P
 
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     def local(q_, k_, v_, mask_):
         return attn(q_, k_, v_, mask_, dtype)
 
     spec_qkv = P(None, None, axis, None)
     spec_mask = P(None, None, None, axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask),

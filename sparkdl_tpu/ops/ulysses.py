@@ -40,9 +40,7 @@ def make_ulysses_attention(
     ``make_flash_attention_fn()`` for the Pallas kernel on TPU)."""
 
     def ulysses_attention(q, k, v, mask, dtype):
-        from sparkdl_tpu.runtime.compat import axis_size
-
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         nheads = q.shape[1]
         if nheads % n != 0:
             raise ValueError(
@@ -74,6 +72,7 @@ def make_ulysses_attention(
             out, axis_name, split_axis=2, concat_axis=1, tiled=True
         )
 
+    ulysses_attention.kind = "ulysses"
     return ulysses_attention
 
 

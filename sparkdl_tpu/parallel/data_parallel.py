@@ -154,10 +154,6 @@ def make_data_parallel_step(
     mesh.shard_batch / jax.device_put with a dp sharding; plain host
     arrays also work — jit will shard them per the in_shardings).
     """
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     replicated_spec = P()
     batch_spec = P(axis)
 
@@ -184,7 +180,7 @@ def make_data_parallel_step(
         )
         return new_state, {"loss": loss, "grad_norm": optax.global_norm(grads)}
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device_step,
         mesh=mesh,
         in_specs=(replicated_spec, batch_spec),
@@ -326,10 +322,6 @@ def make_zero1_data_parallel_step(
     probe (see :func:`_assert_elementwise_optimizer`) for optimizers the
     caller has verified independently.
     """
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     if validate_elementwise:
         _assert_elementwise_optimizer(optimizer)
     n_shards = int(mesh.shape[axis])
@@ -407,7 +399,7 @@ def make_zero1_data_parallel_step(
         )
 
     state_specs = TrainState(step=P(), params=P(), opt_state=P(axis))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device_step,
         mesh=mesh,
         in_specs=(state_specs, P(axis)),
@@ -475,17 +467,13 @@ def make_eval_step(
     metric_fn: Callable[[Any, Any], Any], mesh: Mesh, axis: str = "dp"
 ):
     """Jitted SPMD eval step: per-shard metrics psum-averaged over the mesh."""
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     def per_device(params, batch):
         m = metric_fn(params, batch)
         return jax.tree_util.tree_map(
             lambda v: jax.lax.pmean(v, axis_name=axis), m
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(), P(axis)),

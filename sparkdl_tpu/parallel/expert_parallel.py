@@ -110,10 +110,6 @@ def moe_apply(
 
     Returns [T, D]. Dropped tokens (capacity overflow) produce zeros.
     """
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     E = router_w.shape[-1]
     n = mesh.shape[axis]
     if E % n:
@@ -137,7 +133,7 @@ def moe_apply(
     if capacity is None:
         capacity = max(1, math.ceil((T // n) / E * capacity_factor))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local_moe(expert_fn, axis, E, capacity),
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),

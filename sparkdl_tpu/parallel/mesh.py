@@ -54,22 +54,18 @@ def make_mesh(
             f"Mesh axes {dict(zip(names, sizes))} need {total} devices, "
             f"have {n}"
         )
-    if devices is None and n > 1:
+    if devices is None and n > 1 and devs[0].platform == "tpu":
         # Topology-aware device assignment: on real TPU slices the flat
         # jax.devices() order does not put ICI neighbors adjacent under a
         # plain reshape; mesh_utils permutes devices so the innermost
-        # (heaviest-communication) axes land on physical neighbors. Falls
-        # back to the reshape on platforms it cannot model (CPU meshes).
-        try:
-            from jax.experimental import mesh_utils
+        # (heaviest-communication) axes land on physical neighbors. A
+        # topology it cannot lay out is an error, not a silent reshape.
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(
-                tuple(sizes), devices=devs
-            )
-        except Exception:
-            dev_array = np.asarray(devs).reshape(sizes)
+        dev_array = mesh_utils.create_device_mesh(tuple(sizes), devices=devs)
     else:
-        # explicit device lists keep the caller's order
+        # CPU meshes have no topology to respect, and explicit device
+        # lists keep the caller's order
         dev_array = np.asarray(devs).reshape(sizes)
     return Mesh(dev_array, axis_names=tuple(names))
 

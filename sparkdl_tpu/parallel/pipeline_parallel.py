@@ -55,9 +55,7 @@ def _local_pipeline(stage_fn, axis_name):
 
     def run(stacked, x):
         idx = jax.lax.axis_index(axis_name)
-        from sparkdl_tpu.runtime.compat import axis_size
-
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         my_params = jax.tree_util.tree_map(lambda a: a[0], stacked)
         n_micro = x.shape[0]
         ticks = n_micro + n - 1
@@ -123,10 +121,6 @@ def pipeline_apply(
     Differentiable: take ``jax.grad`` of a loss over this call for
     pipeline-parallel training.
     """
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     n = mesh.shape[axis]
     n_micro = n if n_microbatches is None else n_microbatches
     B = x.shape[0]
@@ -148,7 +142,7 @@ def pipeline_apply(
     xm = x.reshape(n_micro, B // n_micro, *x.shape[1:])
 
     spec_x = P(None, dp_axis) if dp_axis is not None else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         _local_pipeline(stage_fn, axis),
         mesh=mesh,
         in_specs=(P(axis), spec_x),

@@ -73,10 +73,6 @@ def tp_block_sharded(
     second mesh axis (2-D dp×tp). For repeated calls (a training loop),
     wrap the surrounding step in ``jax.jit`` so the traced program is
     compiled once and cached."""
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     n = mesh.shape[axis]
     if w1.shape[1] != w2.shape[0]:
         raise ValueError(
@@ -109,7 +105,7 @@ def tp_block_sharded(
         b2_ = next(bs) if b2 is not None else None
         return tp_mlp(x_, w1_, w2_, axis, activation, b1_, b2_)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),

@@ -35,6 +35,7 @@ generations. CLI: ``python -m sparkdl_tpu.resilience supervise``.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import shlex
@@ -44,7 +45,7 @@ import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 from sparkdl_tpu.resilience.policy import RetryPolicy, policy_from_env
 from sparkdl_tpu.utils.metrics import metrics
@@ -56,6 +57,59 @@ GENERATION_ENV = "SPARKDL_GANG_GENERATION"
 #: set to "1" for generations > 0: workers skip partitions whose output
 #: already published and verifies (see worker.py resume plumbing).
 RESUME_ENV = "SPARKDL_GANG_RESUME"
+
+
+def local_chip_count() -> int:
+    """TPU chips this host can open, counted by their device nodes —
+    ``/dev/accel<N>`` up to v4, ``/dev/vfio/<N>`` from v5e on — which is
+    what libtpu itself enumerates. (The PCI bus can list chips the
+    machine was not given.) Asking jax instead would open every chip in
+    THIS process, and a chip belongs to one process at a time. A
+    ``/dev/vfio/<N>`` node is an IOMMU group, so a host that binds other
+    devices to vfio-pci over-counts: there the operator names the chips
+    in ``TPU_VISIBLE_CHIPS`` (:func:`visible_chips`)."""
+    return len(glob.glob("/dev/accel[0-9]*")) + sum(
+        os.path.basename(p).isdigit() for p in glob.glob("/dev/vfio/*")
+    )
+
+
+def visible_chips(env: Dict[str, str]) -> List[str]:
+    """The TPU chips a process started with ``env`` would open, as
+    libtpu's chip indices: none when ``JAX_PLATFORMS`` names other
+    platforms only; the operator's ``TPU_VISIBLE_CHIPS`` list where it is
+    set; else every chip of the host."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    listed = [c.strip() for c in env.get("TPU_VISIBLE_CHIPS", "").split(",")]
+    if any(listed):
+        return [c for c in listed if c]
+    return [str(i) for i in range(local_chip_count())]
+
+
+def chip_env(rank: int, num_ranks: int, chips: Sequence[str]) -> Dict[str, str]:
+    """The variables that make libtpu (0.0.34) in rank ``rank`` open the
+    ``rank``-th of ``chips`` and no other, as a one-chip slice of its own
+    — a pure function of its arguments. Handing every rank the parent's
+    environment unchanged makes every rank open every chip, so the
+    launchers put this on top of it for a gang of several ranks (a lone
+    rank keeps the parent's view: one process over all chips). No chips
+    divides nothing; more ranks than chips is refused, because the ranks
+    that share a chip would fail or hang at backend start-up."""
+    if not chips:
+        return {}
+    if num_ranks > len(chips):
+        raise ValueError(
+            f"{num_ranks} ranks need {num_ranks} TPU chips (one process "
+            f"per chip) and this host has {len(chips)}: lower the rank "
+            "count, or run the gang on the CPU with JAX_PLATFORMS=cpu"
+        )
+    return {
+        # an index into the chips the host can open, not a device path
+        "TPU_VISIBLE_CHIPS": chips[rank],
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
 
 
 class GangFailedError(RuntimeError):
@@ -500,6 +554,21 @@ def worker_launcher(
             **(extra_env or {}),
             GENERATION_ENV: str(generation),
         }
+        if platform:
+            env["JAX_PLATFORMS"] = platform
+        chip = (
+            chip_env(rank, num_ranks, visible_chips(env))
+            if num_ranks > 1
+            else {}
+        )
+        if distributed and chip:
+            raise ValueError(
+                "a jax.distributed gang over the chips of one TPU host "
+                "has not been brought up: each rank is a one-chip slice "
+                "of its own here. Use distributed=False (independent "
+                "ranks), platform='cpu', or one process over all chips"
+            )
+        env.update(chip)
         if generation > 0:
             env.setdefault(RESUME_ENV, "1")
         return subprocess.Popen(argv, env=env, stdout=stdout, stderr=stderr)

@@ -1,15 +1,26 @@
-"""Persistent XLA compilation cache wiring + build ledger.
+"""Persistent XLA compilation cache placement + build ledger.
 
 Every cold start of the engine — a fresh serving process, a bench
 warmup, a relaunched gang rank — re-traces and re-compiles the same
 programs: converter ∘ model ∘ flattener at the same batch geometry, on
-the same jaxlib. ``SPARKDL_COMPILE_CACHE_DIR=<dir>`` turns on jax's
-persistent compilation cache (``jax.config.jax_compilation_cache_dir``,
-the ``jax.experimental.compilation_cache`` machinery underneath) so the
-serialized executable is reused across processes instead of recompiled;
-the thresholds are dropped to cache-everything because the programs this
-engine rebuilds most often (CPU parity tests, small serving rungs) are
-exactly the ones the default 1s-compile-time floor would skip.
+the same jaxlib. jax's persistent compilation cache reuses the
+serialized executable across processes instead; :func:`place` decides
+WHERE it lives, once, at package import — before the first compile of
+the process, whichever code path that compile comes from
+(``module.init``, the train step, the sharded outer jit, the
+generation programs and every ``ModelFunction`` build alike):
+
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the
+  cache: jax reads the variable itself and this module sets no other;
+- where it is not, the cache is :data:`DEFAULT_DIR`, one fixed
+  git-ignored directory inside the checkout. The path never varies by
+  process, pid or time: a cache that moves never hits. The variable is
+  exported too, so every child process (gateway workers, supervised
+  ranks) lands in the same directory.
+
+The thresholds are dropped to cache-everything because the programs
+this engine rebuilds most often (CPU parity tests, small serving rungs)
+are exactly the ones the default 1s-compile-time floor would skip.
 
 jax's own cache keys on the HLO fingerprint and does not report whether
 a given build hit. The **ledger** here gives the framework its own
@@ -21,9 +32,6 @@ process (a rebuilt transformer) or a later one (serving cold start,
 second bench run) — counts ``compile.cache_hits``. ``obs report``
 prints the pair next to the ``compile.warmup`` timer, so "how much
 warmup is the cache saving" is one report line, not a profiler session.
-
-With the env var unset nothing is wired and :func:`note_build` returns
-None — zero cost on the default path.
 """
 
 from __future__ import annotations
@@ -31,19 +39,37 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import sys
 from typing import Optional
 
-from sparkdl_tpu.runtime import knobs, locksmith
+from sparkdl_tpu.runtime import locksmith
 from sparkdl_tpu.utils.metrics import metrics
 
-_wire_lock = locksmith.lock(
-    "sparkdl_tpu/runtime/compile_cache.py::_wire_lock"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: The cache directory when the environment names none.
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
 )
-_wired_dir: Optional[str] = None
+
+#: Cache EVERYTHING: jax's default floors (1 s compile time, a
+#: filesystem-chosen entry size) skip exactly the small programs the
+#: CPU tests and serving rungs rebuild most often. (jax option, value.)
+_THRESHOLDS = (
+    ("jax_persistent_cache_min_entry_size_bytes", -1),
+    ("jax_persistent_cache_min_compile_time_secs", 0),
+)
+
+_stats_lock = locksmith.lock(
+    "sparkdl_tpu/runtime/compile_cache.py::_stats_lock"
+)
 #: Process-lifetime tally, independent of the metrics registry: bench.py
 #: resets the registry after its warmup — exactly when the builds (and
 #: their ledger hits) happen — so the record reads this instead.
-#: Mutated only under _wire_lock: concurrent first builds (the serving
+#: Mutated only under _stats_lock: concurrent first builds (the serving
 #: completion pool warming several rungs at once) must not lose
 #: increments to a racing read-modify-write.
 _stats = {"cache_hits": 0, "cache_misses": 0}
@@ -51,70 +77,56 @@ _stats = {"cache_hits": 0, "cache_misses": 0}
 
 def stats() -> dict:
     """Ledger hits/misses since process start (reset-immune)."""
-    with _wire_lock:
+    with _stats_lock:
         return dict(_stats)
 
 
+def place() -> None:
+    """Put the persistent cache where the module docstring says. Runs
+    at ``import sparkdl_tpu``; never imports jax itself (the gateway
+    parent must stay off it) and touches no backend.
+
+    Settings travel by environment variable, which a later ``import
+    jax`` — here or in a child process — reads as its defaults. A jax
+    that was imported first has already taken its defaults, so the same
+    values are written to its config as well; the cache directory is
+    the exception the contract demands: it is written only when the
+    variable was NOT set from outside."""
+    jax = sys.modules.get("jax")
+    if not os.environ.get(ENV_VAR):
+        os.environ[ENV_VAR] = DEFAULT_DIR
+        if jax is not None:
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    for option, value in _THRESHOLDS:
+        if option.upper() not in os.environ:
+            os.environ[option.upper()] = str(value)
+            if jax is not None:
+                jax.config.update(option, value)
+
+
 def cache_dir() -> Optional[str]:
-    """SPARKDL_COMPILE_CACHE_DIR, or None when persistence is off."""
-    return knobs.get_str("SPARKDL_COMPILE_CACHE_DIR") or None
+    """The cache directory in force, as jax itself resolved it; None
+    where the process switched the persistent cache off
+    (``jax_enable_compilation_cache``, as the test suite does)."""
+    import jax
 
-
-def ensure_compile_cache() -> bool:
-    """Idempotently point jax's persistent compilation cache at the
-    configured directory; True when engaged. Safe to call per build —
-    re-wires only when the env var changes (tests point successive runs
-    at different tmp dirs)."""
-    global _wired_dir
-    d = cache_dir()
-    if not d:
-        return False
-    with _wire_lock:
-        if _wired_dir == d:
-            return True
-        import jax
-
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        # jax latches "no cache" at the FIRST compile of the process; any
-        # tiny op (a jnp.ones during model build) before this wiring
-        # would leave persistence permanently off — reset so the next
-        # compile re-reads the configured dir.
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — older jax: cache may still engage
-            pass
-        # Cache EVERYTHING: the default floors (1s compile time, nonzero
-        # entry size) skip exactly the small programs the CPU tests and
-        # serving rungs rebuild most often.
-        for knob, value in (
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ("jax_persistent_cache_min_compile_time_secs", 0),
-        ):
-            try:
-                jax.config.update(knob, value)
-            except (AttributeError, ValueError):
-                pass  # older jaxlib without the knob: defaults apply
-        _wired_dir = d
-        return True
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir
 
 
 def note_build(kind: str, model: str, key: tuple) -> Optional[str]:
     """Record one program build against the ledger.
 
     Returns ``"hit"`` / ``"miss"`` (incrementing
-    ``compile.cache_hits`` / ``compile.cache_misses``) when the
-    persistent cache is engaged, None otherwise. A hit means this
-    (model, geometry, arms) key was built before under the same cache
-    dir — jax's persistent cache will serve the executable, so the
-    build's warmup pays deserialization, not compilation."""
-    if not ensure_compile_cache():
-        return None
+    ``compile.cache_hits`` / ``compile.cache_misses``), or None when no
+    cache directory is in force. A hit means this (model, geometry,
+    arms) key was built before under the same cache dir — jax's
+    persistent cache will serve the executable, so the build's warmup
+    pays deserialization, not compilation."""
     d = cache_dir()
+    if not d:
+        return None
     digest = hashlib.sha256(
         repr((kind, model, key)).encode("utf-8")
     ).hexdigest()[:32]
@@ -122,7 +134,7 @@ def note_build(kind: str, model: str, key: tuple) -> Optional[str]:
     path = os.path.join(ledger, f"{digest}.json")
     if os.path.exists(path):
         metrics.inc("compile.cache_hits")
-        with _wire_lock:
+        with _stats_lock:
             _stats["cache_hits"] += 1
         return "hit"
     try:
@@ -137,6 +149,6 @@ def note_build(kind: str, model: str, key: tuple) -> Optional[str]:
     except OSError:
         pass  # unwritable dir: jax's own cache may still work; no ledger
     metrics.inc("compile.cache_misses")
-    with _wire_lock:
+    with _stats_lock:
         _stats["cache_misses"] += 1
     return "miss"

@@ -9,16 +9,18 @@ batch assembly, bound via ctypes (no pybind11 in the environment).
 
 Every entry point has a pure-Python/PIL fallback; ``available()`` says
 whether the fast path is active. The library is built on demand with
-``make -C native`` and cached; set ``SPARKDL_TPU_NO_NATIVE=1`` to force the
-fallback (used by parity tests).
+``make -C native`` and cached; a build that fails warns with the
+compiler's message before the fallback takes over. Set
+``SPARKDL_TPU_NO_NATIVE=1`` to force the fallback (used by parity tests).
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
-import threading
+import warnings
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -36,21 +38,24 @@ _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _build() -> bool:
-    """Build the shared library with make; returns success. Quiet unless it
-    fails (then the loader records failure and the PIL path takes over)."""
-    if not os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
-        return False
-    try:
-        subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
-            check=True,
-            capture_output=True,
-            timeout=300,
+def build(clean: bool = False) -> None:
+    """Build the shared library from ``native/imagebridge.cc`` with make;
+    raises with the compiler's output when it fails. ``clean=True``
+    removes ``native/build/`` first, so what loads afterwards was
+    compiled from the source on disk (``chip_smoke.py``)."""
+    if clean:
+        shutil.rmtree(os.path.dirname(_SO_PATH), ignore_errors=True)
+    r = subprocess.run(
+        ["make", "-C", _NATIVE_DIR],
+        capture_output=True,
+        text=True,
+        timeout=300,
+    )
+    if r.returncode != 0 or not os.path.exists(_SO_PATH):
+        raise RuntimeError(
+            f"make -C {_NATIVE_DIR} failed (rc={r.returncode}): "
+            + (r.stderr or r.stdout).strip()[-2000:]
         )
-        return os.path.exists(_SO_PATH)
-    except (subprocess.SubprocessError, OSError):
-        return False
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -68,9 +73,17 @@ def _load() -> Optional[ctypes.CDLL]:
             os.path.exists(src)
             and os.path.getmtime(src) > os.path.getmtime(_SO_PATH)
         )
-        if needs_build and not _build():
-            _load_failed = True
-            return None
+        if needs_build:
+            try:
+                build()
+            except (RuntimeError, subprocess.SubprocessError, OSError) as e:
+                # a host that lands on the slower PIL path must be able
+                # to see why
+                warnings.warn(
+                    f"native image bridge unavailable, using PIL: {e}"
+                )
+                _load_failed = True
+                return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError:

@@ -1,13 +1,10 @@
 """Asynchronous D2H readback: overlap result copy-back with dispatch.
 
-The banked TPU numbers (BENCH_r05.json ``banked_tpu``) put the
-end-to-end featurizer at 139.7 img/s against a device-resident ceiling
-of 12,704 img/s, with ``device_wait`` dominating the stage attribution
-(1525 ms vs 5.8 ms host in the latest record). H2D has been pipelined
-since the chunked-feed work (PRs 2-3), but the RETURN direction still
-ran synchronously: the dispatch loop blocked in ``np.asarray(y_dev)``
-and nothing else moved while a result streamed back over the link. The
-TensorFlow dataflow design and the CUDA-aware-MPI characterization work
+H2D has been pipelined since the chunked-feed work (PRs 2-3), but the
+RETURN direction used to run synchronously: the dispatch loop blocked in
+``np.asarray(y_dev)`` and nothing else moved while a result streamed
+back over the link. What the overlap is worth on the attached chip is
+not measured (PERF.md). The TensorFlow dataflow design and the CUDA-aware-MPI characterization work
 (PAPERS.md) both make the same point — transfers must overlap compute
 in *both* directions.
 

@@ -187,14 +187,18 @@ def _gateway_main(args) -> int:
     from sparkdl_tpu.serving.server import configured_port
 
     port = args.port if args.port is not None else (configured_port() or 8000)
-    gw = ServingGateway(
-        num_workers=args.workers,
-        port=port,
-        gang_dir=args.gang_dir,
-        loader_spec=args.loader,
-        budget_mb=args.budget_mb,
-        max_batch=args.max_batch,
-    ).start()
+    try:
+        gw = ServingGateway(
+            num_workers=args.workers,
+            port=port,
+            gang_dir=args.gang_dir,
+            loader_spec=args.loader,
+            budget_mb=args.budget_mb,
+            max_batch=args.max_batch,
+        ).start()
+    except ValueError as e:  # more workers than chips
+        print(f"gateway: {e}", file=sys.stderr)
+        return 2
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *_: stop.set())
     print(

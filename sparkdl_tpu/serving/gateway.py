@@ -114,6 +114,8 @@ from sparkdl_tpu.resilience.supervisor import (
     GENERATION_ENV,
     GangFailedError,
     GangSupervisor,
+    chip_env,
+    visible_chips,
 )
 from sparkdl_tpu.runtime import knobs, locksmith
 from sparkdl_tpu.serving.request import PRIORITY_CLASSES
@@ -360,6 +362,11 @@ class ServingGateway:
         drain_wait_s: Optional[float] = None,
     ):
         self.num_workers = num_workers or gateway_workers()
+        #: fixed here, because the gang is resized while its ranks run:
+        #: started with several workers, each owns one chip for good;
+        #: started with one, that worker owns every chip the gateway can
+        #: see (mesh serving) and the gang cannot grow on a TPU host
+        self._chip_per_worker = self.num_workers > 1
         self._port_arg = int(port)
         self.gang_dir = gang_dir or tempfile.mkdtemp(prefix="sparkdl_gang_")
         self.loader_spec = loader_spec
@@ -424,6 +431,7 @@ class ServingGateway:
     def start(self) -> "ServingGateway":
         if self._started:
             return self
+        self._check_chips(self.num_workers)
         self._started = True
         os.makedirs(self.gang_dir, exist_ok=True)
         self._sup_thread = threading.Thread(
@@ -530,6 +538,20 @@ class ServingGateway:
             argv += ["--max-batch", str(self.max_batch)]
         return argv
 
+    def _check_chips(self, num_workers: int) -> None:
+        """Refuse more TPU workers than chips up front, with the message
+        (ValueError), rather than as a launch failure inside the
+        supervisor thread."""
+        chips = visible_chips({**os.environ, **self.extra_env})
+        if self._chip_per_worker:
+            chip_env(0, num_workers, chips)
+        elif chips and num_workers > 1:
+            raise ValueError(
+                "this gateway started with one worker, which holds every "
+                f"TPU chip it can see ({len(chips)}): start it with "
+                "--workers 2 or more to give each worker one chip"
+            )
+
     def _launch_worker(self, rank: int, generation: int) -> subprocess.Popen:
         env = {
             **os.environ,
@@ -537,6 +559,8 @@ class ServingGateway:
             GENERATION_ENV: str(generation),
             "SPARKDL_OBS_RANK": str(rank),
         }
+        if self._chip_per_worker:
+            env.update(chip_env(rank, self.num_workers, visible_chips(env)))
         # per-rank log, appended across generations: the post-mortem for
         # a crash loop is one file per worker, not a lost DEVNULL
         log = open(
@@ -764,6 +788,7 @@ class ServingGateway:
         if n == old:
             return {"from": old, "to": n, "generation": generation}
         if n > old:
+            self._check_chips(n)
             with self._states_cv:
                 generation = self._generation
                 for rank in range(old, n):

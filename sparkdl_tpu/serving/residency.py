@@ -256,6 +256,13 @@ class ResidencyManager:
                     "loads": m.loads,
                     "requests": m.requests,
                     "idle_s": round(now - m.last_used, 3),
+                    # text models: 'flash' (the compiled Pallas kernel)
+                    # or 'dense', as decided when the function was built
+                    **(
+                        {"attention": m.model_function.attention}
+                        if hasattr(m.model_function, "attention")
+                        else {}
+                    ),
                 }
                 for m in self._models.values()
             ]

@@ -279,10 +279,8 @@ def registerImageUDF(
         )
         # Flat channel-major feed, same as DeepImageFeaturizer: a plain
         # 4-D NHWC uint8 transfer lane-pads the 3-wide minor dim on
-        # device (the round-1 ~150 img/s cliff); the flat chw buffer
-        # keeps every transfer allocation ~1x the batch bytes. Explains
-        # the round-3 campaign's udf (108.8 img/s, plain feed) trailing
-        # the featurizer (139.7, flat feed) on a 10x-cheaper model.
+        # device; the flat chw buffer keeps every transfer allocation
+        # ~1x the batch bytes.
         pipeline_mf = converter.and_then(mf).and_then(build_flattener())
         device_fn = flat_device_fn(
             pipeline_mf, (batch_size, height, width, 3)

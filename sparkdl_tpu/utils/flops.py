@@ -82,17 +82,14 @@ def bert_size_flops_per_example(size: str, seq_len: int) -> float:
     return bert_flops_per_example(seq_len)
 
 
-def local_device_kind() -> Optional[str]:
+def local_device_kind() -> str:
     """``jax.devices()[0].device_kind`` without paying backend init at
-    import time (and surviving jax-less callers) — the shared probe
-    behind the live-MFU gauge and the bench's device tagging. None when
-    no backend resolves: "unknown", not an error."""
-    try:
-        import jax
+    import time — the shared probe behind the live-MFU gauge and the
+    bench's device tagging. A backend that fails to come up raises: an
+    accelerator that is not there is an error, not an unknown kind."""
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no backend is "unknown"
-        return None
+    return jax.devices()[0].device_kind
 
 
 def device_peak_flops(device_kind: str) -> Optional[float]:

@@ -3,8 +3,8 @@
 Reference analogue: none in-tree — the reference exposed progress only
 through the Spark UI's stage/task counters (SURVEY.md §6). Here metrics
 are first-class: transformers and estimators record counters/timers into a
-process-global registry, and the throughput numbers that BASELINE.md
-tracks (images/sec/chip, step time) are computed from these.
+process-global registry, and the throughput numbers that bench.py
+reports (images/sec/chip, step time) are computed from these.
 
 Thread-safe: executor partition threads and the batch-producer threads all
 record concurrently.

@@ -561,9 +561,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument(
         "--platform",
         default=None,
-        help="force a jax backend (e.g. 'cpu'). Applied via jax.config "
-        "before backend init, which overrides env-level platform presets "
-        "(a JAX_PLATFORMS env var alone can be overridden by site hooks).",
+        help="force a jax backend (e.g. 'cpu'); the same as exporting "
+        "JAX_PLATFORMS, applied before backend init.",
     )
     args = ap.parse_args(argv)
     if args.platform:

@@ -19,17 +19,6 @@ from sparkdl_tpu.ops import (
 )
 from sparkdl_tpu.parallel import make_mesh
 
-from sparkdl_tpu.runtime.compat import has_shard_map
-
-# the whole family runs through shard_map-backed helpers: on a jax
-# build with neither jax.shard_map nor the experimental fallback the
-# capability is absent and the family SKIPS instead of erroring
-pytestmark = pytest.mark.skipif(
-    not has_shard_map(),
-    reason="this jax build cannot shard_map (no top-level or "
-    "experimental spelling)",
-)
-
 
 def _qkv(rng, B, H, L, D):
     return tuple(

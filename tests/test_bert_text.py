@@ -110,12 +110,6 @@ def test_bert_sequence_parallel_matches_dense():
     global position offsets) == single-device dense run."""
     from jax.sharding import PartitionSpec as P
 
-    from sparkdl_tpu.runtime.compat import get_shard_map, has_shard_map
-
-    if not has_shard_map():
-        pytest.skip("this jax build cannot shard_map")
-    shard_map = get_shard_map()
-
     m_dense = bert_tiny()
     ids = jnp.asarray(
         np.random.default_rng(1).integers(4, 1000, (2, 32)), jnp.int32
@@ -133,7 +127,7 @@ def test_bert_sequence_parallel_matches_dense():
         offset = jax.lax.axis_index("sp") * L_local
         return m_ring.apply(p, ids_shard, position_offset=offset)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_run,
         mesh=mesh,
         in_specs=(P(), P(None, "sp")),
@@ -196,6 +190,8 @@ def _sp_vs_dense_embedder(strategy, mesh):
         params=mf_dense.params,
     )
     assert mf_sp.single_stream
+    # every text builder records its attention through the same helper
+    assert mf_sp.attention == strategy and mf_dense.attention == "dense"
 
     texts = [
         "sequence parallelism makes long context first class",

@@ -32,7 +32,6 @@ def test_example_runs(script):
         **os.environ,
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        "SPARKDL_TPU_PREMAPPED": "0",
         "PYTHONPATH": _ROOT,
     }
     # runpy keeps __file__ set (exec of source would not), so examples can

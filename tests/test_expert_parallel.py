@@ -10,17 +10,6 @@ import jax.numpy as jnp
 from sparkdl_tpu.parallel import make_mesh
 from sparkdl_tpu.parallel.expert_parallel import moe_apply, switch_route
 
-from sparkdl_tpu.runtime.compat import has_shard_map
-
-# the whole family runs through shard_map-backed helpers: on a jax
-# build with neither jax.shard_map nor the experimental fallback the
-# capability is absent and the family SKIPS instead of erroring
-pytestmark = pytest.mark.skipif(
-    not has_shard_map(),
-    reason="this jax build cannot shard_map (no top-level or "
-    "experimental spelling)",
-)
-
 D, E, T = 8, 8, 64
 
 

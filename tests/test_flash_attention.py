@@ -108,3 +108,39 @@ def test_attention_fn_plugs_into_bert(rng):
     np.testing.assert_allclose(
         np.asarray(out_dense), np.asarray(out_flash), atol=1e-4, rtol=1e-4
     )
+
+
+def test_batched_mask_at_production_blocks(rng):
+    """B > 1 with a different mask per row, at the (128, 128) blocks and
+    Dh=64 lane padding the compiled kernel uses, masked the way
+    BertEncoder masks (finfo.min): the mask's [B, 1, Lk] blocking must
+    hand each (batch, head) program its own batch row's keys."""
+    B, H, L, Dh = 3, 2, 256, 64
+    q, k, v = _qkv(rng, B=B, H=H, L=L, Dh=Dh)
+    mask = np.zeros((B, L), np.float32)
+    for b, n in enumerate([256, 130, 7]):
+        mask[b, n:] = np.finfo(np.float32).min
+    mask_j = jnp.asarray(mask)[:, None, None, :]
+    ours = flash_attention(q, k, v, mask_j, interpret=True)
+    ref = dense_attention(q, k, v, mask_j, jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(ours), np.asarray(ref), atol=2e-5, rtol=2e-5
+    )
+
+
+def test_attention_choice_is_made_at_build_and_recorded():
+    """Off-TPU the default builder hands back dense_attention itself
+    (kind 'dense'); interpret mode is only ever asked for, and a text
+    model function says which attention it was built with."""
+    from sparkdl_tpu.models.bert import bert_model_function
+
+    assert make_flash_attention_fn() is dense_attention
+    assert dense_attention.kind == "dense"
+    assert make_flash_attention_fn(interpret=True).kind == "flash"
+    assert bert_model_function(size="tiny", max_length=16).attention == "dense"
+    forced = bert_model_function(
+        size="tiny",
+        max_length=16,
+        attention_fn=make_flash_attention_fn(interpret=True),
+    )
+    assert forced.attention == "flash"

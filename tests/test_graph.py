@@ -209,15 +209,14 @@ class TestReferenceCompatAliases:
 
 
 @pytest.fixture()
-def _reset_compile_cache():
-    """Unwire the persistent cache after a test so the session's later
-    compiles don't chase a deleted tmp dir."""
-    yield
+def ledger_dir(tmp_path, monkeypatch):
+    """The build ledger lives under the compile cache directory in force;
+    the suite runs with the persistent cache off (conftest), so point the
+    ledger at a directory of this test's own."""
     from sparkdl_tpu.runtime import compile_cache
 
-    with compile_cache._wire_lock:
-        compile_cache._wired_dir = None
-    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setattr(compile_cache, "cache_dir", lambda: str(tmp_path))
+    return tmp_path
 
 
 def test_donation_gate_and_backend_support(monkeypatch):
@@ -297,13 +296,12 @@ def test_donation_arms_get_distinct_cache_entries(monkeypatch):
     assert mf.jitted_flat((2, 4)) is f_plain
 
 
-def test_compile_cache_ledger_hits_and_misses(tmp_path, monkeypatch, _reset_compile_cache):
+def test_compile_cache_ledger_hits_and_misses(ledger_dir):
     """Second identical jitted_flat build (a FRESH ModelFunction, so no
     object-level cache short-circuits) records a compile-cache hit; the
     first records the miss. Different geometry is a different key."""
     from sparkdl_tpu.utils.metrics import metrics
 
-    monkeypatch.setenv("SPARKDL_COMPILE_CACHE_DIR", str(tmp_path))
     h0 = metrics.counter("compile.cache_hits")
     m0 = metrics.counter("compile.cache_misses")
     _linear_mf().jitted_flat((2, 4))
@@ -313,34 +311,21 @@ def test_compile_cache_ledger_hits_and_misses(tmp_path, monkeypatch, _reset_comp
     assert metrics.counter("compile.cache_hits") - h0 == 1
     _linear_mf().jitted_flat((4, 4))  # new geometry -> miss, not hit
     assert metrics.counter("compile.cache_misses") - m0 == 2
-    ledger = tmp_path / "ledger"
-    assert len(list(ledger.glob("*.json"))) == 2
+    assert len(list((ledger_dir / "ledger").glob("*.json"))) == 2
 
 
-def test_compile_cache_off_records_nothing(monkeypatch):
+def test_compile_cache_off_records_nothing():
+    """With jax's persistent cache switched off (as conftest does) no
+    directory is in force and the ledger stays silent."""
+    from sparkdl_tpu.runtime import compile_cache
     from sparkdl_tpu.utils.metrics import metrics
 
-    monkeypatch.delenv("SPARKDL_COMPILE_CACHE_DIR", raising=False)
+    assert compile_cache.cache_dir() is None
     h0 = metrics.counter("compile.cache_hits")
     m0 = metrics.counter("compile.cache_misses")
     _linear_mf().jitted_flat((2, 4))
     assert metrics.counter("compile.cache_hits") == h0
     assert metrics.counter("compile.cache_misses") == m0
-
-
-def test_compile_cache_persists_executable(tmp_path, monkeypatch, _reset_compile_cache):
-    """jax's persistent cache actually writes the serialized executable
-    under the configured dir (the reuse a second process cold-starts
-    from), alongside the framework's ledger marker."""
-    monkeypatch.setenv("SPARKDL_COMPILE_CACHE_DIR", str(tmp_path))
-    f = _linear_mf().jitted_flat((2, 4))
-    np.asarray(f(np.ones(8, np.float32)))
-    cache_files = [
-        p
-        for p in tmp_path.iterdir()
-        if p.is_file() and p.name.endswith("-cache")
-    ]
-    assert cache_files, "no serialized executable persisted"
 
 
 def test_device_preproc_piece_identity_and_resize():

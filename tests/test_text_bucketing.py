@@ -400,10 +400,6 @@ def test_single_stream_model_keeps_fixed_geometry():
     """Whole-mesh sequence-parallel fns must NOT bucket: their sharding
     was built for exactly max_length (execution honors single_stream,
     and the TextEmbedder bucketing gate must too)."""
-    from sparkdl_tpu.runtime.compat import has_shard_map
-
-    if not has_shard_map():
-        pytest.skip("this jax build cannot shard_map")
     from sparkdl_tpu.models.bert import (
         bert_model_function_sequence_parallel,
     )

@@ -13,17 +13,6 @@ from sparkdl_tpu.ops import (
     ulysses_attention_sharded,
 )
 from sparkdl_tpu.parallel import make_mesh
-from sparkdl_tpu.runtime.compat import has_shard_map
-
-# the whole family runs through shard_map-backed helpers: on a jax
-# build with neither jax.shard_map nor the experimental fallback the
-# capability is absent and the family SKIPS instead of erroring
-pytestmark = pytest.mark.skipif(
-    not has_shard_map(),
-    reason="this jax build cannot shard_map (no top-level or "
-    "experimental spelling)",
-)
-
 
 def _qkv(rng, B, H, L, D):
     return tuple(
@@ -92,10 +81,6 @@ def test_bert_ulysses_sequence_parallel_matches_dense():
     attention computed via all_to_all head swaps == dense oracle."""
     from jax.sharding import PartitionSpec as P
 
-    from sparkdl_tpu.runtime.compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     cfg = BertConfig(
         vocab_size=1000,
         hidden_size=128,
@@ -119,7 +104,7 @@ def test_bert_ulysses_sequence_parallel_matches_dense():
         offset = jax.lax.axis_index("sp") * L_local
         return m_uly.apply(p, ids_shard, position_offset=offset)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_run,
         mesh=mesh,
         in_specs=(P(), P(None, "sp")),

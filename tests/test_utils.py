@@ -189,59 +189,3 @@ def test_fetch_http_offline_raises(tmp_path, monkeypatch):
         fetcher.fetch(
             "http://192.0.2.1/model.npz"  # TEST-NET-1: guaranteed no route
         )
-
-
-# -- jax capability shims (runtime/compat.py) --------------------------------
-
-
-def test_compat_shard_map_resolution_consistent():
-    """has_shard_map and get_shard_map agree: either the capability is
-    present and the callable works inside a 1-device mesh, or both
-    report absence (get_shard_map raises a crisp NotImplementedError)."""
-    from sparkdl_tpu.runtime import compat
-
-    if not compat.has_shard_map():
-        with pytest.raises(NotImplementedError, match="shard_map"):
-            compat.get_shard_map()
-        return
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    shard_map = compat.get_shard_map()
-    mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    # the modern kwarg surface must be accepted regardless of which
-    # spelling the build provides (the adapter translates check_vma)
-    fn = shard_map(
-        lambda v: v * 2.0,
-        mesh=mesh,
-        in_specs=P("x"),
-        out_specs=P("x"),
-        check_vma=False,
-    )
-    np.testing.assert_allclose(
-        np.asarray(fn(jnp.ones((4,)))), np.full((4,), 2.0)
-    )
-
-
-def test_compat_axis_size_inside_shard_map():
-    from sparkdl_tpu.runtime import compat
-
-    if not compat.has_shard_map():
-        pytest.skip("this jax build cannot shard_map")
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    shard_map = compat.get_shard_map()
-    mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    fn = shard_map(
-        lambda v: v * compat.axis_size("x"),
-        mesh=mesh,
-        in_specs=P("x"),
-        out_specs=P("x"),
-        check_vma=False,
-    )
-    np.testing.assert_allclose(
-        np.asarray(fn(jnp.ones((2,)))), np.ones((2,))
-    )

@@ -97,7 +97,6 @@ def test_two_worker_subprocesses_match_single_process(job_fixture):
         env = {
             **os.environ,
             "JAX_PLATFORMS": "cpu",
-            "SPARKDL_TPU_PREMAPPED": "0",
         }
         from _gang import run_gang
 
@@ -220,7 +219,6 @@ def test_two_worker_subprocesses_with_rendezvous(job_fixture):
             **os.environ,
             "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-            "SPARKDL_TPU_PREMAPPED": "0",
         }
         run_gang(
             lambda pid: [

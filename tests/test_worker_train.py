@@ -112,7 +112,6 @@ def _gang_cmd(train_fixture, job, n_proc=2):
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
         "PYTHONPATH": f"{train_fixture['dir']}:{REPO}",
-        "SPARKDL_TPU_PREMAPPED": "0",
     }
     argv = lambda i: [
         sys.executable, "-m", "sparkdl_tpu.worker",

@@ -93,9 +93,8 @@ os.environ["SPARKDL_FLEET_SCRAPE_TIMEOUT_S"] = "2"
 os.environ["SPARKDL_FLEET_STALE_S"] = "1.5"
 os.environ["SPARKDL_FLEET_RECOMMEND_S"] = "0.5"
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 from _chaos_models import ROW  # noqa: E402
 
@@ -277,7 +276,6 @@ def _gateway(num_workers, gang_dir, jsonl, fault_root=None):
         "JAX_PLATFORMS": "cpu",
         "SPARKDL_INFERENCE_MODE": "roundrobin",
         "SPARKDL_INFERENCE_DEVICES": "1",
-        "SPARKDL_TPU_PREMAPPED": "0",
         "SPARKDL_OBS_JSONL": jsonl,
     }
     if fault_root:

@@ -33,9 +33,8 @@ import tempfile
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 import numpy as np  # noqa: E402
 
@@ -145,7 +144,6 @@ def _chaos_run(root: str, job_spec: dict, tag: str):
                 "SPARKDL_FAULT_SEED": "0",
                 "SPARKDL_OBS_JSONL": jsonl,
                 "JAX_PLATFORMS": "cpu",
-                "SPARKDL_TPU_PREMAPPED": "0",
             },
         )
         sup = GangSupervisor(

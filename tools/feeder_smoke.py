@@ -24,7 +24,7 @@ imply (``tools/lint/lockorder_check.py``).
 
 Exit 0 and a one-line JSON verdict on success; exit 1 naming what failed.
 
-Usage (also callable from the bench campaign scripts as a preflight)::
+Usage (a CPU drill; tools/preflight.sh runs it too)::
 
     JAX_PLATFORMS=cpu python tools/feeder_smoke.py
 """
@@ -46,9 +46,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 # loaded 1-core CI box where partition threads start staggered.
 os.environ.setdefault("SPARKDL_FEEDER_LINGER_MS", "200")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 N_PARTITIONS = 16
 ROWS_PER_PARTITION = 100

@@ -81,9 +81,8 @@ os.environ["SPARKDL_FLEET_SCRAPE_TIMEOUT_S"] = "2"
 os.environ["SPARKDL_FLEET_STALE_S"] = "1.5"
 os.environ["SPARKDL_FLEET_RECOMMEND_S"] = "0.5"
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 from _chaos_models import ROW  # noqa: E402
 
@@ -452,7 +451,6 @@ def main(argv=None) -> int:
             "JAX_PLATFORMS": "cpu",
             "SPARKDL_INFERENCE_MODE": "roundrobin",
             "SPARKDL_INFERENCE_DEVICES": "1",
-            "SPARKDL_TPU_PREMAPPED": "0",
             # exactly the first N_SLOW interactive requests are slow,
             # fleet-wide (the O_EXCL claim dir carries the cap across
             # workers and generations)

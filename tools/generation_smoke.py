@@ -48,9 +48,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 # rides into the worker env through the gateway launch.
 os.environ.setdefault("SPARKDL_GEN_MAX_SEQS", "2")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 MODEL = "bert-tiny"
 N_SEQS = 6

@@ -44,9 +44,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_MODE", "roundrobin")
 os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 os.environ.setdefault("SPARKDL_FEEDER_IDLE_S", "0")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 from _chaos_models import ROW  # noqa: E402
 

@@ -55,9 +55,8 @@ if "xla_force_host_platform_device_count" not in _flags:
 # requests where the queue is empty, so the window never engages).
 os.environ.setdefault("SPARKDL_FEEDER_IDLE_S", "0")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 ROW = 8
 MAX_BATCH = 32

@@ -10,7 +10,7 @@ name is readback-arm dependent (``drain_wait`` under the async default,
 ``device_wait`` when ``SPARKDL_ASYNC_READBACK=0``). Exit 0 and the
 rendered table on success; exit 1 naming the missing stages otherwise.
 
-Usage (also callable from the bench campaign scripts as a preflight)::
+Usage (a CPU drill; tools/preflight.sh runs it too)::
 
     JAX_PLATFORMS=cpu python tools/obs_smoke.py [--out-dir DIR]
 
@@ -30,9 +30,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("SPARKDL_INFERENCE_MODE", "roundrobin")
 os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 #: The drain stage records as drain_wait (async-readback arm, default)
 #: or device_wait (legacy synchronous arm) — either satisfies the smoke.

@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# One-command CPU preflight for the campaign scripts: proves the flight
+# One-command CPU preflight (every drill here is a CPU drill): proves the flight
 # recorder (obs_smoke), the shared device feeder (feeder_smoke, incl.
 # the async-readback arm A/B + thread-leak check), the SQL optimizer
 # arm (sql_smoke: mixed query flood with cross-partition coalesced UDF
@@ -63,7 +63,7 @@
 # --no-append), proving the gate machinery + history consistency without
 # running a benchmark. Each step prints a one-line JSON verdict; this
 # wrapper runs them all under timeouts and exits nonzero if ANY failed,
-# so a campaign script can gate on a single command:
+# so a caller can gate on a single command:
 #
 #   tools/preflight.sh || { echo "preflight failed"; exit 1; }
 #

@@ -14,17 +14,16 @@ actually engaged and agree:
   (``SPARKDL_DEVICE_STAGE=0``) and device-preproc vs host-preproc
   (``SPARKDL_DEVICE_PREPROC``, at identity geometry where the arms are
   bit-identical) all produce row-identical outputs, Nones included;
-- **compile-cache attribution**: with ``SPARKDL_COMPILE_CACHE_DIR``
-  set, rebuilding the identical pipeline records ≥1
-  ``compile.cache_hits`` (the ledger that says the persistent cache
-  will serve this executable on the next cold start);
+- **compile-cache attribution**: rebuilding the identical pipeline
+  records ≥1 ``compile.cache_hits`` (the ledger that says the
+  persistent cache will serve this executable on the next cold start);
 - **no leaked threads**: after ``shutdown_feeders()`` no feeder owner,
   drainer, or H2D copy-pool thread survives.
 
 Exit 0 and a one-line JSON verdict on success; exit 1 naming what
 failed.
 
-Usage (also callable from the bench campaign scripts as a preflight)::
+Usage (a CPU drill; tools/preflight.sh runs it too)::
 
     JAX_PLATFORMS=cpu python tools/resident_smoke.py
 """
@@ -33,7 +32,6 @@ import argparse
 import json
 import os
 import sys
-import tempfile
 import threading
 import time
 
@@ -44,9 +42,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_MODE", "roundrobin")
 os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 os.environ.setdefault("SPARKDL_FEEDER_LINGER_MS", "200")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 N_PARTITIONS = 6
 ROWS_PER_PARTITION = 40
@@ -139,20 +136,16 @@ def _parity(label, a_rows, b_rows, problems):
 
 def _compile_cache_hits() -> int:
     """Build the identical pipeline twice (fresh transformer objects, so
-    nothing short-circuits in an object-level cache) under a persistent
-    cache dir: the second build must record a ledger hit."""
+    nothing short-circuits in an object-level cache): whatever the first
+    build was, the second must record a ledger hit under the compile
+    cache directory in force (runtime/compile_cache.py)."""
     from sparkdl_tpu.utils.metrics import metrics
 
-    with tempfile.TemporaryDirectory() as d:
-        os.environ["SPARKDL_COMPILE_CACHE_DIR"] = d
-        try:
-            before = metrics.counter("compile.cache_hits")
-            for _ in range(2):
-                xf = _transformer()
-                xf._build_device_fn((BATCH_SIZE, GEOM, GEOM, 3))
-            return int(metrics.counter("compile.cache_hits") - before)
-        finally:
-            del os.environ["SPARKDL_COMPILE_CACHE_DIR"]
+    before = metrics.counter("compile.cache_hits")
+    for _ in range(2):
+        xf = _transformer()
+        xf._build_device_fn((BATCH_SIZE, GEOM, GEOM, 3))
+    return int(metrics.counter("compile.cache_hits") - before)
 
 
 def main(argv=None) -> int:

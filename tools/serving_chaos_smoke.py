@@ -57,9 +57,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_MODE", "roundrobin")
 os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 os.environ.setdefault("SPARKDL_FEEDER_IDLE_S", "0")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 from _chaos_models import ROW, loader  # noqa: E402
 
@@ -326,7 +325,6 @@ def main(argv=None) -> int:
             "JAX_PLATFORMS": "cpu",
             "SPARKDL_INFERENCE_MODE": "roundrobin",
             "SPARKDL_INFERENCE_DEVICES": "1",
-            "SPARKDL_TPU_PREMAPPED": "0",
             # canary rollout: 25% of 'prim' traffic -> 'prim_v2'
             "SPARKDL_SERVE_CANARY_MODEL": "prim",
             "SPARKDL_SERVE_CANARY_VERSION": "prim_v2",

@@ -47,9 +47,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 # not idle-exit between request bursts.
 os.environ.setdefault("SPARKDL_FEEDER_IDLE_S", "0")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 ROW = 8
 MAX_BATCH = 32

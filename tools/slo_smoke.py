@@ -65,9 +65,8 @@ os.environ["SPARKDL_SLO_MIN_REQUESTS"] = "3"
 os.environ["SPARKDL_SLO_AVAIL"] = "0.99"
 os.environ["SPARKDL_SLO_P95_MS_INTERACTIVE"] = str(P95_TARGET_MS)
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 from _chaos_models import ROW  # noqa: E402
 

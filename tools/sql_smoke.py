@@ -47,9 +47,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 os.environ.setdefault("SPARKDL_FEEDER_LINGER_MS", "200")
 os.environ.setdefault("SPARKDL_SQL_VECTORIZE", "1")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 N_PARTITIONS = 8
 ROWS_PER_PARTITION = 8

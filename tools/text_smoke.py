@@ -54,9 +54,8 @@ os.environ.setdefault("SPARKDL_FEEDER_IDLE_S", "0")
 # bucket); keep them out of LRU churn, like the serve CLI does.
 os.environ.setdefault("SPARKDL_MAX_FEEDERS", "32")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 MAX_LEN = 512
 BATCH = 8

@@ -57,9 +57,8 @@ os.environ.setdefault("SPARKDL_INFERENCE_DEVICES", "1")
 os.environ.setdefault("SPARKDL_FEEDER_IDLE_S", "0")
 os.environ.setdefault("SPARKDL_TRACE_SAMPLE", "1")
 
-import _common  # noqa: E402  (sys.path + platform handling)
+import _common  # noqa: E402,F401  (puts the repo root on sys.path)
 
-_common.apply_env_platform()
 
 from _chaos_models import ROW  # noqa: E402
 
@@ -339,7 +338,6 @@ def _phase_gang(root, problems, verdict):
             "JAX_PLATFORMS": "cpu",
             "SPARKDL_INFERENCE_MODE": "roundrobin",
             "SPARKDL_INFERENCE_DEVICES": "1",
-            "SPARKDL_TPU_PREMAPPED": "0",
             "SPARKDL_TRACE_SAMPLE": "1",
             "SPARKDL_FAULT_PLAN": FAULT_PLAN,
             "SPARKDL_FAULT_STATE": os.path.join(root, "faults"),
