@@ -45,6 +45,7 @@ from sparkdl_tpu.dataframe.columns import (
     from_arrow_array,
     to_arrow_array,
 )
+from sparkdl_tpu.obs import span
 from sparkdl_tpu.runtime import knobs
 from sparkdl_tpu.runtime.executor import default_executor
 
@@ -2932,10 +2933,13 @@ class DataFrame:
 
     def collect(self) -> List[Row]:
         rows: List[Row] = []
-        for part in self._execute():
-            n = _part_num_rows(part)
-            for i in range(n):
-                rows.append(Row({c: part[c][i] for c in part}))
+        parts = self._execute()
+        with span("collect.box") as sp:
+            for part in parts:
+                n = _part_num_rows(part)
+                for i in range(n):
+                    rows.append(Row({c: part[c][i] for c in part}))
+            sp.add(rows=len(rows))
         return rows
 
     def collectColumns(self) -> Dict[str, list]:

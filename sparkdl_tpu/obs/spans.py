@@ -7,8 +7,12 @@ to the device stream), ``device_wait`` (blocking on a device result),
 gang-owned partition) — with free-form attributes (rows, bytes, chunk
 mode, partition index). Spans nest per thread: each thread carries its
 own stack, so the executor's partition threads and the batch-producer
-thread trace independently and a child span's ``parent_id`` always names
-the innermost open span *of its own thread*.
+thread trace independently and a child span's ``parent_id`` names the
+innermost open span *of its own thread*. Only a thread with no open span
+takes a parent named explicitly (``span(name, parent_id=...)``): the
+executor hands each partition task the id of its
+``executor.map_partitions`` span, so the spans of one job form one tree
+across the hand-off to the pool.
 
 Recording costs one lock acquisition and two ``perf_counter`` reads per
 span; the ring buffer bounds memory (``SPARKDL_OBS_RING`` spans, default
@@ -16,6 +20,14 @@ span; the ring buffer bounds memory (``SPARKDL_OBS_RING`` spans, default
 recording into a shared no-op context manager for zero-overhead runs;
 the cheap aggregate timers in :mod:`sparkdl_tpu.utils.metrics` keep
 flowing either way because call sites record them directly.
+
+One clock with the device: every span also opens a profiler annotation
+``sparkdl:<name>`` (:func:`sparkdl_tpu.utils.profiler.annotate`, with
+the scalar attributes known at open) on its own thread. While a
+``jax.profiler`` trace runs, the span is an event on the ``/host:CPU``
+plane, on the clock of the device's operations, so a device idle gap can
+be laid against what the host was doing; while none runs, the annotation
+is a check of one flag.
 
 Wall-clock anchoring: durations come from ``perf_counter`` (monotonic);
 start timestamps are anchored once per process to ``time.time`` so
@@ -34,6 +46,12 @@ from typing import Any, Dict, List, Optional
 
 from sparkdl_tpu.runtime import knobs
 from sparkdl_tpu.utils.metrics import metrics
+from sparkdl_tpu.utils.profiler import annotate
+
+#: prefix of every span's event in a jax.profiler trace
+ANNOTATION_PREFIX = "sparkdl:"
+#: attribute types a profiler annotation carries as event stats
+_SCALARS = (bool, int, float, str)
 
 # Process-wide anchor: wall time of the perf_counter epoch, fixed at
 # import so every span's start_unix is consistent within the process.
@@ -104,13 +122,20 @@ class SpanRecorder:
             stack = self._local.stack = []
         return stack
 
-    def open(self, name: str, attrs: Dict[str, Any]) -> SpanRecord:
+    def open(
+        self,
+        name: str,
+        attrs: Dict[str, Any],
+        parent_id: Optional[int] = None,
+    ) -> SpanRecord:
+        """``parent_id`` names a parent on ANOTHER thread (a task handed
+        to a pool); an open span of this thread takes precedence."""
         t = threading.current_thread()
         stack = self._stack()
         rec = SpanRecord(
             name=name,
             span_id=next(self._ids),
-            parent_id=stack[-1].span_id if stack else None,
+            parent_id=stack[-1].span_id if stack else parent_id,
             thread_id=t.ident or 0,
             thread_name=t.name,
             start_pc=time.perf_counter(),
@@ -184,13 +209,28 @@ class _Span:
     """Context manager for one recorded span. ``attrs`` may be extended
     mid-span via :meth:`add` (e.g. row counts known only after batching)."""
 
-    __slots__ = ("_name", "_attrs", "_rec", "_recorder")
+    __slots__ = (
+        "_name", "_attrs", "_parent_id", "_rec", "_recorder", "_note"
+    )
 
-    def __init__(self, name: str, attrs: Dict[str, Any]):
+    def __init__(
+        self,
+        name: str,
+        attrs: Dict[str, Any],
+        parent_id: Optional[int] = None,
+    ):
         self._name = name
         self._attrs = attrs
+        self._parent_id = parent_id
         self._rec: Optional[SpanRecord] = None
         self._recorder: Optional[SpanRecorder] = None
+        self._note = None
+
+    @property
+    def span_id(self) -> Optional[int]:
+        """This span's id once open — what a task handed to another
+        thread passes as ``parent_id``."""
+        return self._rec.span_id if self._rec is not None else None
 
     def add(self, **attrs) -> "_Span":
         if self._rec is not None:
@@ -205,11 +245,23 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self._recorder = get_recorder()
-        self._rec = self._recorder.open(self._name, self._attrs)
+        self._rec = self._recorder.open(
+            self._name, self._attrs, self._parent_id
+        )
+        self._note = annotate(
+            ANNOTATION_PREFIX + self._name,
+            **{
+                k: v
+                for k, v in self._attrs.items()
+                if isinstance(v, _SCALARS)
+            },
+        )
+        self._note.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
         if self._rec is not None:
+            self._note.__exit__(*exc)
             if exc and exc[0] is not None and "error" not in self._rec.attrs:
                 # same atomic-swap discipline as add()
                 self._rec.attrs = {
@@ -223,6 +275,7 @@ class _NoopSpan:
     """Shared do-nothing span for SPARKDL_OBS=0 paths."""
 
     __slots__ = ()
+    span_id = None
 
     def add(self, **attrs) -> "_NoopSpan":
         return self
@@ -237,8 +290,10 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-def span(name: str, **attrs):
-    """Open a span named ``name`` with initial attributes.
+def span(name: str, *, parent_id: Optional[int] = None, **attrs):
+    """Open a span named ``name`` with initial attributes. ``parent_id``
+    is the ``span_id`` of a span open on the thread that handed this one
+    its work; it is used where this thread has no open span of its own.
 
     Usage::
 
@@ -248,7 +303,7 @@ def span(name: str, **attrs):
     """
     if not obs_enabled():
         return _NOOP
-    return _Span(name, attrs)
+    return _Span(name, attrs, parent_id)
 
 
 def active_spans(recorder: Optional[SpanRecorder] = None) -> List[dict]:

@@ -188,6 +188,8 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        # a stable name for the kernel's events in a profiler trace
+        name="flash_attention",
     )(qf, kf, vf, mask3d)
 
     out = out.reshape(B, H, Lq_p, Dh_p)

@@ -40,11 +40,16 @@ class TaskContext:
     feeder keys off ``concurrency`` (coalescing only pays when >1
     partitions run AT ONCE — a sequential executor would add linger
     latency for legacy-identical padding) and labels its streams with
-    ``partition_index`` so ordered per-partition results are preserved."""
+    ``partition_index`` so ordered per-partition results are preserved.
+    ``parent_span_id`` carries the ``executor.map_partitions`` span across
+    the hand-off to a pool thread, so the task's ``executor.partition``
+    span hangs under it and one job's spans form one tree; it is tracing
+    metadata and no part of what makes two contexts equal."""
 
     partition_index: int
     num_partitions: int
     concurrency: int = 1
+    parent_span_id: Optional[int] = field(default=None, compare=False)
 
 
 _task_local = threading.local()
@@ -178,19 +183,20 @@ class Executor:
             1 if sequential else min(self.max_workers, len(partitions))
         )
 
-        def run_one(i: int, part: Any) -> Any:
+        def run_one(i: int, part: Any, parent_span_id: Optional[int]) -> Any:
             prev_ctx = getattr(_task_local, "ctx", None)
-            _task_local.ctx = TaskContext(
+            _task_local.ctx = ctx = TaskContext(
                 partition_index=i,
                 num_partitions=len(partitions),
                 concurrency=concurrency,
+                parent_span_id=parent_span_id,
             )
             try:
-                return _run_one_in_ctx(i, part)
+                return _run_one_in_ctx(i, part, ctx)
             finally:
                 _task_local.ctx = prev_ctx
 
-        def _run_one_in_ctx(i: int, part: Any) -> Any:
+        def _run_one_in_ctx(i: int, part: Any, ctx: TaskContext) -> Any:
             policy = self.retry_policy
             last_err: Optional[BaseException] = None
             attempt = 0
@@ -199,7 +205,10 @@ class Executor:
                 pt0 = time.perf_counter()
                 try:
                     with span(
-                        "executor.partition", partition=i, attempt=attempt
+                        "executor.partition",
+                        parent_id=ctx.parent_span_id,
+                        partition=i,
+                        attempt=attempt,
                     ) as sp:
                         maybe_fault(
                             "executor.partition", partition=i, attempt=attempt
@@ -250,15 +259,17 @@ class Executor:
             dump_on_failure("partition_task_error")
             raise err
 
-        with span("executor.map_partitions", partitions=len(partitions)):
+        with span(
+            "executor.map_partitions", partitions=len(partitions)
+        ) as job_sp:
             if sequential:
                 for i, part in enumerate(partitions):
-                    results[i] = run_one(i, part)
+                    results[i] = run_one(i, part, job_sp.span_id)
             else:
                 pool, private = self._acquire_pool()
                 try:
                     futs = {
-                        pool.submit(run_one, i, part): i
+                        pool.submit(run_one, i, part, job_sp.span_id): i
                         for i, part in enumerate(partitions)
                     }
                     try:

@@ -1116,6 +1116,9 @@ def run_shared(
                 h.feeder.finish(h)
             except RuntimeError:
                 pass  # feeder closed underneath us; handles already failed
-    for h in handles.values():
-        h.wait()
+    # every row is submitted: what is left is the wait for the device
+    # (and the other partitions sharing its batches) to hand them back
+    with span("result_wait", partition=partition, feeder=True):
+        for h in handles.values():
+            h.wait()
     return out

@@ -33,10 +33,10 @@ negligible pad savings.
 
 Instrumentation (all consumed by ``obs report``'s text line and the
 ``BENCH_MODE=text`` record): ``text.bucket_rows.<bucket>`` counts rows
-routed per elected edge, ``text.tokens`` / ``text.pad_tokens`` split
+routed per elected edge, and ``text.tokens`` / ``text.pad_tokens`` split
 dispatched tokens into real vs bucket-edge padding (the row-tail batch
-padding below them rides the existing ``feeder.pad_rows``), and the
-``text.pad_ratio`` gauge publishes the last run's pad fraction.
+padding below them rides the existing ``feeder.pad_rows``). The
+``tokenize`` span times the routing loop, once per partition call.
 """
 
 from __future__ import annotations
@@ -45,6 +45,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from sparkdl_tpu.obs import span
 from sparkdl_tpu.runtime import knobs
 from sparkdl_tpu.utils.metrics import metrics
 
@@ -188,17 +189,26 @@ def run_bucketed(
     ladder = tuple(ladder) if ladder is not None else bucket_ladder(max_length)
     # route: bucket edge -> ([original row index], [token id list])
     routed: dict = {}
-    for i, text in enumerate(cells):
-        if text is None:
-            continue
-        try:
-            ids = tokenize(text)
-        except Exception:
-            continue
-        b = bucket_for(len(ids), ladder)
-        idxs, rows = routed.setdefault(b, ([], []))
-        idxs.append(i)
-        rows.append(ids)
+    # the device has nothing of this partition until the loop ends: one
+    # span around it, never one per row
+    with span("tokenize") as sp:
+        for i, text in enumerate(cells):
+            if text is None:
+                continue
+            try:
+                ids = tokenize(text)
+            except Exception:
+                continue
+            b = bucket_for(len(ids), ladder)
+            idxs, rows = routed.setdefault(b, ([], []))
+            idxs.append(i)
+            rows.append(ids)
+        sp.add(
+            rows=sum(len(rows) for _, rows in routed.values()),
+            tokens=sum(
+                len(ids) for _, rows in routed.values() for ids in rows
+            ),
+        )
     if not routed:
         return out
     real_tokens = 0
@@ -224,9 +234,6 @@ def run_bucketed(
             out[i] = y
     metrics.inc("text.tokens", real_tokens)
     metrics.inc("text.pad_tokens", pad_tokens)
-    dispatched = real_tokens + pad_tokens
-    if dispatched:
-        metrics.gauge("text.pad_ratio", pad_tokens / dispatched)
     return out
 
 

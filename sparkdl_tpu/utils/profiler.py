@@ -120,14 +120,17 @@ class _NullAnnotation:
         return fn
 
 
-def annotate(name: str):
+def annotate(name: str, **attrs):
     """Named region inside a trace (TraceAnnotation); usable as decorator
-    or context manager. Degrades to a no-op — like :func:`profile_trace`
-    already does — on CPU test meshes and jax-less callers, instead of
-    raising."""
+    or context manager. ``attrs`` (scalars) become the event's stats.
+    While no trace runs, entering it checks one flag. Degrades to a
+    no-op — like :func:`profile_trace` already does — on CPU test meshes
+    and jax-less callers, instead of raising. Its caller in the program
+    is :mod:`sparkdl_tpu.obs.spans`: every span is a ``sparkdl:<name>``
+    annotation."""
     try:
         import jax
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **attrs)
     except Exception:
         return _NullAnnotation()

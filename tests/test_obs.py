@@ -1,9 +1,12 @@
 """Flight-recorder units: span nesting (including across threads), ring
 bounds, snapshot/Chrome-trace export schema, dump-on-failure, heartbeat
-obs payloads, the report CLI round-trip, and the CPU end-to-end
+obs payloads, the report CLI round-trip, the CPU end-to-end
 acceptance path (ingest/h2d/dispatch/device_wait spans from the real
-batched engine)."""
+batched engine), and the spans as `sparkdl:` events of a jax.profiler
+trace."""
 
+import contextlib
+import glob
 import json
 import os
 import threading
@@ -429,3 +432,155 @@ def test_report_renders_async_readback_line(fresh_recorder):
     rendered = report.render_report(snap)
     assert "async readback: 3 copies complete at drain" in rendered
     assert "75.0% of drains fully overlapped" in rendered
+
+
+# -- one clock: spans as events of the profiler's trace ----------------------
+
+
+@contextlib.contextmanager
+def _profiled(trace_dir):
+    """Runs the block under a jax.profiler trace on the CPU; afterwards
+    the dict it yielded holds, by name, (duration_ns, stats) of every
+    `sparkdl:` event on the host plane."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    events = {}
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(trace_dir), "**", "*.xplane.pb"), recursive=True
+    )
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("sparkdl:"):
+                    events.setdefault(ev.name, []).append(
+                        (ev.duration_ns, dict(ev.stats))
+                    )
+
+
+def _bucketed_job():
+    """Two partitions at once through `run_bucketed` over a stub device
+    fn: the shared-feeder path of the text engine."""
+    from sparkdl_tpu.runtime.executor import Executor
+    from sparkdl_tpu.runtime.feeder import shutdown_feeders
+    from sparkdl_tpu.text.bucketing import run_bucketed
+
+    def tokenize(text):
+        return [1] + [5 + len(w) for w in text.split()] + [2]
+
+    def device_fn(batch):
+        return np.asarray(batch, np.float32).sum(axis=1, keepdims=True)
+
+    parts = [["a bb ccc", None, "dd e"] * 3, ["ff g", "h i j k"] * 4]
+    try:
+        return Executor(max_workers=2).map_partitions(
+            lambda i, cells: run_bucketed(
+                cells, tokenize, device_fn, batch_size=4, max_length=16
+            ),
+            parts,
+        )
+    finally:
+        shutdown_feeders()
+
+
+@pytest.mark.parametrize("obs_on", [True, False], ids=["on", "SPARKDL_OBS=0"])
+def test_text_path_spans_are_on_the_profilers_clock(
+    fresh_recorder, tmp_path, monkeypatch, obs_on
+):
+    monkeypatch.setenv("SPARKDL_OBS", "1" if obs_on else "0")
+    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+    with _profiled(tmp_path) as events:
+        out = _bucketed_job()
+    assert out[0][1] is None and float(out[0][0][0]) == 1 + 6 + 7 + 8 + 2
+    if not obs_on:
+        assert events == {} and fresh_recorder.spans() == []
+        return
+    for name in ("tokenize", "ingest", "dispatch", "result_wait"):
+        found = events.get("sparkdl:" + name)
+        assert found, f"no sparkdl:{name} event; the trace has {sorted(events)}"
+        assert all(dur > 0 for dur, _ in found), name
+    # once per partition call, never per row; attributes known at open
+    # are the event's stats, those added later only the ring's
+    assert len(events["sparkdl:tokenize"]) == 2
+    assert {st["partition"] for _, st in events["sparkdl:result_wait"]} == {0, 1}
+    by_name = {}
+    for rec in fresh_recorder.spans():
+        by_name.setdefault(rec.name, []).append(rec)
+    assert sorted(r.attrs["rows"] for r in by_name["tokenize"]) == [6, 8]
+    assert sum(r.attrs["tokens"] for r in by_name["tokenize"]) == 3 * 9 + 4 * 10
+    # the annotation lies inside the span it belongs to
+    assert max(d for d, _ in events["sparkdl:tokenize"]) <= 1e9 * max(
+        r.dur_s for r in by_name["tokenize"]
+    )
+
+
+def test_collect_box_span_and_event(fresh_recorder, tmp_path):
+    from sparkdl_tpu.dataframe import DataFrame
+
+    df = DataFrame.fromColumns({"x": list(range(10))}, numPartitions=2)
+    with _profiled(tmp_path) as events:
+        rows = df.collect()
+    assert [r["x"] for r in rows] == list(range(10))
+    ((dur, _),) = events["sparkdl:collect.box"]
+    assert dur > 0
+    (rec,) = [r for r in fresh_recorder.spans() if r.name == "collect.box"]
+    assert rec.attrs == {"rows": 10}
+
+
+def test_annotation_carries_scalar_attrs_only(fresh_recorder, tmp_path):
+    with _profiled(tmp_path) as events:
+        with span("stage.y", partition=3, mode="flat", shape=(2, 2), gone=None):
+            pass
+    ((_, stats),) = events["sparkdl:stage.y"]
+    assert stats == {"partition": 3, "mode": "flat"}
+    (rec,) = fresh_recorder.spans()
+    assert rec.attrs["shape"] == (2, 2)  # the ring keeps every attribute
+
+
+def test_pool_thread_partition_span_hangs_under_map_partitions(
+    fresh_recorder,
+):
+    from sparkdl_tpu.runtime.executor import Executor, current_task_context
+
+    seen = {}
+
+    def fn(i, part):
+        seen[i] = (threading.get_ident(), current_task_context())
+        with span("inner.work"):
+            return part
+
+    Executor(max_workers=3).map_partitions(fn, ["a", "b", "c"])
+    spans = fresh_recorder.spans()
+    (job,) = [s for s in spans if s.name == "executor.map_partitions"]
+    parts = [s for s in spans if s.name == "executor.partition"]
+    assert len(parts) == 3
+    assert {tid for tid, _ in seen.values()}.isdisjoint({job.thread_id})
+    for rec in parts:
+        assert rec.parent_id == job.span_id
+        assert rec.thread_id != job.thread_id
+    assert {ctx.parent_span_id for _, ctx in seen.values()} == {job.span_id}
+    # inside the task the thread's own stack still decides
+    by_id = {s.span_id: s for s in spans}
+    for rec in (s for s in spans if s.name == "inner.work"):
+        assert by_id[rec.parent_id].name == "executor.partition"
+
+
+def test_explicit_parent_yields_to_the_threads_own_stack(fresh_recorder):
+    with span("outer") as outer:
+        with span("child", parent_id=10**6):
+            pass
+    with span("handed", parent_id=outer.span_id):
+        pass
+    by_name = {s.name: s for s in fresh_recorder.spans()}
+    assert by_name["child"].parent_id == by_name["outer"].span_id
+    assert by_name["handed"].parent_id == by_name["outer"].span_id
+    assert "parent_id" not in by_name["handed"].attrs
