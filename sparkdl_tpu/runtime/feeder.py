@@ -100,6 +100,7 @@ Env knobs (all read per event, so tests can flip them live):
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -1035,6 +1036,40 @@ def close_feeders_for(device_fn) -> int:
 # -- the partition-side entry point ------------------------------------------
 
 
+@contextlib.contextmanager
+def ingest_span(start: int, partition):
+    """The partition thread's host stage of one chunk: the ``ingest`` span
+    and the ``transform.host_batch`` timer around whatever turns cells
+    into device rows. Yields the span for ``rows`` and ``bytes``."""
+    t0 = time.perf_counter()
+    with span(
+        "ingest", batch_start=start, partition=partition, feeder=True
+    ) as sp:
+        yield sp
+    metrics.record_time("transform.host_batch", time.perf_counter() - t0)
+
+
+def _to_batch_chunks(cells, to_batch, dispatch_rows, partition):
+    """``run_shared``'s own host stage: ``to_batch`` over one dispatched
+    batch's cells at a time, each chunk compressed to its valid rows with
+    vectorized masked indexing."""
+    for start in range(0, len(cells), dispatch_rows):
+        chunk = list(cells[start : start + dispatch_rows])
+        with ingest_span(start, partition) as sp:
+            batch, mask = to_batch(chunk)
+            valid = np.flatnonzero(mask)
+            sp.add(
+                rows=int(len(valid)),
+                bytes=int(getattr(batch, "nbytes", 0)),
+            )
+        if not len(valid):
+            continue  # every cell null/undecodable: no device rows
+        yield (
+            start + valid,
+            batch if len(valid) == len(chunk) else batch[valid],
+        )
+
+
 def run_shared(
     device_fn: Callable,
     cells: Sequence,
@@ -1042,6 +1077,7 @@ def run_shared(
     batch_size: int,
     prefetch: Optional[int] = None,
     partition=None,
+    stream: Optional[Callable] = None,
 ) -> List[Optional[np.ndarray]]:
     """Shared-feeder equivalent of ``run_batched``: same signature shape,
     same per-cell output contract (ndarray rows, None where masked out).
@@ -1052,7 +1088,16 @@ def run_shared(
     to its valid rows with vectorized masked indexing, and streams them
     into the feeder keyed by the observed row shape — so workloads whose
     row shape varies between chunks (legal on the legacy path, which
-    recompiles per shape) transparently use one feeder per shape."""
+    recompiles per shape) transparently use one feeder per shape.
+
+    A host stage that is no ``to_batch`` — its rows leave a chunk in
+    several shapes, or in chunks of its own size: the text engine's
+    length buckets — passes ``stream`` in its place.
+    ``stream(dispatch_rows)`` yields ``(dest_idx, rows)`` as the
+    partition thread produces them, ``rows[k]``'s result landing in cell
+    ``dest_idx[k]``. Every handle stays open until the stage is
+    exhausted: the owners pad and flush a part-filled batch only once no
+    producer is open, so handing rows over early costs no padding."""
     from sparkdl_tpu.transformers.execution import default_prefetch
 
     dispatch_rows = batch_size * getattr(device_fn, "batch_multiplier", 1)
@@ -1062,26 +1107,14 @@ def run_shared(
     out: List[Optional[np.ndarray]] = [None] * n
     if n == 0:
         return out
+    chunks = (
+        _to_batch_chunks(cells, to_batch, dispatch_rows, partition)
+        if stream is None
+        else stream(dispatch_rows)
+    )
     handles: dict = {}
     try:
-        for start in range(0, n, dispatch_rows):
-            chunk = list(cells[start : start + dispatch_rows])
-            t0 = time.perf_counter()
-            with span(
-                "ingest", batch_start=start, partition=partition, feeder=True
-            ) as sp:
-                batch, mask = to_batch(chunk)
-                valid = np.flatnonzero(mask)
-                sp.add(
-                    rows=int(len(valid)),
-                    bytes=int(getattr(batch, "nbytes", 0)),
-                )
-            metrics.record_time(
-                "transform.host_batch", time.perf_counter() - t0
-            )
-            if not len(valid):
-                continue  # every cell null/undecodable: no device rows
-            rows = batch if len(valid) == len(chunk) else batch[valid]
+        for dest_idx, rows in chunks:
             key = (tuple(rows.shape[1:]), str(rows.dtype))
             handle = handles.get(key)
             if handle is None:
@@ -1105,7 +1138,7 @@ def run_shared(
                         "repeatedly closed under us)"
                     ) from e
                 handles[key] = handle
-            handle.feeder.submit_rows(handle, start + valid, rows)
+            handle.feeder.submit_rows(handle, dest_idx, rows)
     except BaseException as e:
         for h in handles.values():
             h.fail(e)  # wake anything; owner drops our queued rows

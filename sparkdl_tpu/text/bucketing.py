@@ -36,7 +36,8 @@ Instrumentation (all consumed by ``obs report``'s text line and the
 routed per elected edge, and ``text.tokens`` / ``text.pad_tokens`` split
 dispatched tokens into real vs bucket-edge padding (the row-tail batch
 padding below them rides the existing ``feeder.pad_rows``). The
-``tokenize`` span times the routing loop, once per partition call.
+``tokenize`` span times the routing loop of one chunk of a partition,
+and ``text.tokenize_chunks`` counts the chunks.
 """
 
 from __future__ import annotations
@@ -158,41 +159,31 @@ def next_bucket(length: int) -> int:
     return mid if length <= mid and mid >= min_bucket() else e
 
 
-def run_bucketed(
+#: Floor of a tokenize chunk: under it a tiny ``batchSize`` would make a
+#: span, a counter update and a queue item of every few rows.
+_MIN_CHUNK_ROWS = 32
+#: Chunks in which a partition hands over its share of one dispatched
+#: batch. The feeder's queue holds four items: with four chunks a share
+#: the partitions block about one share ahead of the owner, who needs
+#: the interpreter lock they tokenize under to pack and dispatch.
+_CHUNKS_PER_SHARE = 4
+
+
+def _route_chunk(
     cells: Sequence,
+    start: int,
+    stop: int,
     tokenize: Callable[[str], Sequence[int]],
-    device_fn: Callable,
-    batch_size: int,
-    max_length: int,
-    prefetch: Optional[int] = None,
-    ladder: Optional[Sequence[int]] = None,
-) -> List[Optional[np.ndarray]]:
-    """Length-aware equivalent of the pad-to-``max_length`` text loop:
-    same per-cell output contract as ``run_batched`` (ndarray rows,
-    None where the cell was null or tokenization failed).
-
-    Tokenization runs ONCE on the partition thread (it must — lengths
-    decide routing before any batch can form); rows then stream
-    per-bucket through ``run_batched_shared``, so concurrent partitions
-    coalesce into the same (device_fn, bucket) feeder streams and the
-    device fn compiles one program per bucket it actually sees. Buckets
-    run largest-first: the longest sequences are the slowest programs,
-    so their streams fill while the cheap buckets drain behind them.
-    """
-    from sparkdl_tpu.transformers.execution import run_batched_shared
-    from sparkdl_tpu.transformers.text import pad_or_truncate
-
-    n = len(cells)
-    out: List[Optional[np.ndarray]] = [None] * n
-    if n == 0:
-        return out
-    ladder = tuple(ladder) if ladder is not None else bucket_ladder(max_length)
-    # route: bucket edge -> ([original row index], [token id list])
+    ladder: Sequence[int],
+) -> dict:
+    """Tokenize ``cells[start:stop]`` and route each row by its length:
+    bucket edge -> ([original row index], [token id list]); null cells
+    and cells the tokenizer raised on are left out. One ``tokenize``
+    span a chunk, never one a row, and the chunk's share of the text
+    counters."""
     routed: dict = {}
-    # the device has nothing of this partition until the loop ends: one
-    # span around it, never one per row
     with span("tokenize") as sp:
-        for i, text in enumerate(cells):
+        for i, text in enumerate(cells[start:stop], start):
             if text is None:
                 continue
             try:
@@ -209,32 +200,106 @@ def run_bucketed(
                 len(ids) for _, rows in routed.values() for ids in rows
             ),
         )
+    metrics.inc("text.tokenize_chunks")
     if not routed:
-        return out
+        return routed
     real_tokens = 0
     pad_tokens = 0
-    for b in sorted(routed, reverse=True):
-        idxs, rows = routed[b]
+    for b, (idxs, rows) in routed.items():
         metrics.inc(f"text.bucket_rows.{b}", len(idxs))
         for ids in rows:
             k = min(len(ids), b)
             real_tokens += k
             pad_tokens += b - k
-
-        def to_batch(chunk, _b=b):
-            batch = np.zeros((len(chunk), _b), np.int32)
-            for j, ids in enumerate(chunk):
-                batch[j] = pad_or_truncate(ids, _b)
-            return batch, np.ones((len(chunk),), bool)
-
-        results = run_batched_shared(
-            rows, to_batch, device_fn, batch_size, prefetch=prefetch
-        )
-        for i, y in zip(idxs, results):
-            out[i] = y
     metrics.inc("text.tokens", real_tokens)
     metrics.inc("text.pad_tokens", pad_tokens)
-    return out
+    return routed
+
+
+def _pack(rows: Sequence[Sequence[int]], edge: int) -> np.ndarray:
+    from sparkdl_tpu.transformers.text import pad_or_truncate
+
+    batch = np.zeros((len(rows), edge), np.int32)
+    for j, ids in enumerate(rows):
+        batch[j] = pad_or_truncate(ids, edge)
+    return batch
+
+
+def run_bucketed(
+    cells: Sequence,
+    tokenize: Callable[[str], Sequence[int]],
+    device_fn: Callable,
+    batch_size: int,
+    max_length: int,
+    prefetch: Optional[int] = None,
+    ladder: Optional[Sequence[int]] = None,
+) -> List[Optional[np.ndarray]]:
+    """Length-aware equivalent of the pad-to-``max_length`` text loop:
+    same per-cell output contract as ``run_batched`` (ndarray rows,
+    None where the cell was null or tokenization failed).
+
+    Tokenization runs on the partition thread, and a row's length
+    decides its routing — a row's, not the partition's. On the
+    shared-feeder path (``shared_feeder_context``) the partition is
+    tokenized in chunks, and each chunk's rows go to their (device_fn,
+    bucket) feeder streams as soon as the chunk is routed, largest edge
+    first: the device starts on the first batch that the concurrent
+    partitions fill TOGETHER while they tokenize the rest, so a chunk is
+    a quarter of one partition's share of a dispatched batch: its rows
+    over the observed concurrency, in ``_CHUNKS_PER_SHARE``. Every
+    bucket's stream stays open until the partition's last chunk is in
+    (``feeder.run_shared``'s ``stream``), so no part-filled batch is
+    flushed while rows are still to come, and the device fn compiles one
+    program per bucket it actually sees.
+
+    The legacy engine (one partition at a time, ``SPARKDL_SHARED_FEEDER=0``)
+    takes the whole partition as one chunk and runs its buckets one
+    after another, largest first: the longest sequences are the slowest
+    programs.
+    """
+    from sparkdl_tpu.runtime.feeder import ingest_span
+    from sparkdl_tpu.transformers import execution
+
+    n = len(cells)
+    if n == 0:
+        return []
+    ladder = tuple(ladder) if ladder is not None else bucket_ladder(max_length)
+    ctx = execution.shared_feeder_context(device_fn)
+    if ctx is None:
+        out: List[Optional[np.ndarray]] = [None] * n
+        routed = _route_chunk(cells, 0, n, tokenize, ladder)
+        for b in sorted(routed, reverse=True):
+            idxs, rows = routed[b]
+
+            def to_batch(chunk, _b=b):
+                return _pack(chunk, _b), np.ones((len(chunk),), bool)
+
+            results = execution.run_batched_shared(
+                rows, to_batch, device_fn, batch_size, prefetch=prefetch
+            )
+            for i, y in zip(idxs, results):
+                out[i] = y
+        return out
+
+    def stream(dispatch_rows):
+        chunk_rows = max(
+            _MIN_CHUNK_ROWS,
+            dispatch_rows // (ctx.concurrency * _CHUNKS_PER_SHARE),
+        )
+        for start in range(0, n, chunk_rows):
+            routed = _route_chunk(
+                cells, start, start + chunk_rows, tokenize, ladder
+            )
+            for b in sorted(routed, reverse=True):
+                idxs, rows = routed[b]
+                with ingest_span(start, ctx.partition_index) as sp:
+                    batch = _pack(rows, b)
+                    sp.add(rows=len(rows), bytes=int(batch.nbytes))
+                yield np.asarray(idxs), batch
+
+    return execution.run_batched_shared(
+        cells, None, device_fn, batch_size, prefetch=prefetch, stream=stream
+    )
 
 
 __all__ = [

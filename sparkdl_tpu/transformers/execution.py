@@ -624,12 +624,30 @@ def device_preproc_enabled() -> bool:
     return knobs.get_flag("SPARKDL_DEVICE_PREPROC")
 
 
+def shared_feeder_context(device_fn: Callable):
+    """The TaskContext of this partition call where its rows go through
+    the shared DeviceFeeder, None where the legacy per-partition engine
+    runs them: the one choice between the two engines."""
+    from sparkdl_tpu.runtime.executor import current_task_context
+
+    ctx = current_task_context()
+    if (
+        not shared_feeder_enabled()
+        or ctx is None
+        or getattr(ctx, "concurrency", ctx.num_partitions) <= 1
+        or getattr(device_fn, "single_stream", False)
+    ):
+        return None
+    return ctx
+
+
 def run_batched_shared(
     cells: Sequence,
-    to_batch: Callable[[Sequence], Tuple[np.ndarray, np.ndarray]],
+    to_batch: Optional[Callable[[Sequence], Tuple[np.ndarray, np.ndarray]]],
     device_fn: Callable[[np.ndarray], np.ndarray],
     batch_size: int,
     prefetch: Optional[int] = None,
+    stream: Optional[Callable] = None,
 ) -> List[Optional[np.ndarray]]:
     """``run_batched`` that coalesces across concurrent partitions.
 
@@ -641,16 +659,14 @@ def run_batched_shared(
     quiet-period flush is ever padded, instead of every partition's tail.
     Whole-mesh ``single_stream`` fns and single-partition runs keep the
     legacy per-partition pipeline; so does ``SPARKDL_SHARED_FEEDER=0``.
-    Output contract is identical to :func:`run_batched`."""
-    from sparkdl_tpu.runtime.executor import current_task_context
+    Output contract is identical to :func:`run_batched`.
 
-    ctx = current_task_context()
-    if (
-        not shared_feeder_enabled()
-        or ctx is None
-        or getattr(ctx, "concurrency", ctx.num_partitions) <= 1
-        or getattr(device_fn, "single_stream", False)
-    ):
+    ``stream`` is ``run_shared``'s: the host stage of a caller whose rows
+    leave in several shapes, in place of ``to_batch`` (then None). The
+    legacy engine has no such stage, so that caller asks
+    :func:`shared_feeder_context` first."""
+    ctx = shared_feeder_context(device_fn)
+    if ctx is None:
         return run_batched(cells, to_batch, device_fn, batch_size, prefetch)
     from sparkdl_tpu.runtime.feeder import run_shared
 
@@ -661,6 +677,7 @@ def run_batched_shared(
         batch_size,
         prefetch=prefetch,
         partition=ctx.partition_index,
+        stream=stream,
     )
 
 

@@ -480,7 +480,8 @@ def _bucketed_job():
     def device_fn(batch):
         return np.asarray(batch, np.float32).sum(axis=1, keepdims=True)
 
-    parts = [["a bb ccc", None, "dd e"] * 3, ["ff g", "h i j k"] * 4]
+    # 45 and 40 cells: two chunks of the 32-row floor a partition
+    parts = [["a bb ccc", None, "dd e"] * 15, ["ff g", "h i j k"] * 20]
     try:
         return Executor(max_workers=2).map_partitions(
             lambda i, cells: run_bucketed(
@@ -508,18 +509,30 @@ def test_text_path_spans_are_on_the_profilers_clock(
         found = events.get("sparkdl:" + name)
         assert found, f"no sparkdl:{name} event; the trace has {sorted(events)}"
         assert all(dur > 0 for dur, _ in found), name
-    # once per partition call, never per row; attributes known at open
-    # are the event's stats, those added later only the ring's
-    assert len(events["sparkdl:tokenize"]) == 2
+    # once per chunk of a partition, never per row; attributes known at
+    # open are the event's stats, those added later only the ring's
+    assert len(events["sparkdl:tokenize"]) == 4
     assert {st["partition"] for _, st in events["sparkdl:result_wait"]} == {0, 1}
     by_name = {}
     for rec in fresh_recorder.spans():
         by_name.setdefault(rec.name, []).append(rec)
-    assert sorted(r.attrs["rows"] for r in by_name["tokenize"]) == [6, 8]
-    assert sum(r.attrs["tokens"] for r in by_name["tokenize"]) == 3 * 9 + 4 * 10
+    tokenized = by_name["tokenize"]
+    assert len(tokenized) == 4 and len(by_name["executor.partition"]) == 2
+    assert sum(r.attrs["rows"] for r in tokenized) == 30 + 40
+    assert sum(r.attrs["tokens"] for r in tokenized) == 15 * 9 + 20 * 10
+    # every chunk's span lies inside its partition's `executor.partition`
+    partitions = {r.span_id: r for r in by_name["executor.partition"]}
+    rows_of = {0: 0, 1: 0}
+    for r in tokenized:
+        part = partitions[r.parent_id]
+        assert part.thread_id == r.thread_id
+        assert part.start_pc <= r.start_pc
+        assert r.start_pc + r.dur_s <= part.start_pc + part.dur_s
+        rows_of[part.attrs["partition"]] += r.attrs["rows"]
+    assert rows_of == {0: 30, 1: 40}
     # the annotation lies inside the span it belongs to
     assert max(d for d, _ in events["sparkdl:tokenize"]) <= 1e9 * max(
-        r.dur_s for r in by_name["tokenize"]
+        r.dur_s for r in tokenized
     )
 
 

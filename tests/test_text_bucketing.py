@@ -417,3 +417,232 @@ def test_single_stream_model_keeps_fixed_geometry():
     for a, b in zip(d, sp):
         if a is not None:
             np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+# -- a partition streams into the feeder chunk by chunk -----------------------
+
+_LADDER = (8, 16, 32)
+
+
+def _cell(length, salt):
+    """A cell the stub tokenizer turns into `length` ids that no other
+    row of the job shares: a misrouted answer cannot pass for its own."""
+    return f"{length}:{salt}"
+
+
+def _stub_tokenize(text):
+    if text == "bad":
+        raise ValueError("tokenizer refused the row")
+    length, salt = map(int, text.split(":"))
+    return [2 + (salt + j) % 97 for j in range(length)]
+
+
+def _stub_device(batch):
+    """Per row: the ids' sum, how many there are, and the bucket edge it
+    was dispatched at."""
+    b = np.asarray(batch)
+    return np.stack(
+        [b.sum(1), (b != 0).sum(1), np.full(len(b), b.shape[1])], axis=1
+    ).astype(np.float32)
+
+
+def _text_counters():
+    counters = metrics.scalar_snapshot()["counters"]
+    return {
+        k: v
+        for k, v in counters.items()
+        if k.startswith("text.") or k == "feeder.pad_rows"
+    }
+
+
+def _bucketed_job(
+    parts, batch_size, tokenize=_stub_tokenize, device=_stub_device
+):
+    """One job over `parts`, all partitions at once; the outputs by
+    partition and what the job added to each text counter."""
+    from sparkdl_tpu.runtime.executor import Executor
+    from sparkdl_tpu.runtime.feeder import shutdown_feeders
+
+    before = _text_counters()
+    ex = Executor(max_workers=len(parts), max_failures=1)
+    try:
+        out = ex.map_partitions(
+            lambda i, cells: run_bucketed(
+                cells, tokenize, device, batch_size, 32, ladder=_LADDER
+            ),
+            parts,
+        )
+    finally:
+        shutdown_feeders()
+        ex.close()
+    after = _text_counters()
+    return out, {
+        k: after[k] - before.get(k, 0)
+        for k in after
+        if after[k] != before.get(k, 0)
+    }
+
+
+def _partitions(n_parts, rows, length_of):
+    """`length_of(r)` is row r's token length, None for a null cell, or
+    the cell itself where it is a string."""
+
+    def cell(p, r):
+        length = length_of(r)
+        if length is None or isinstance(length, str):
+            return length
+        return _cell(length, 1000 * p + r)
+
+    return [[cell(p, r) for r in range(rows)] for p in range(n_parts)]
+
+
+_MIXED = (3, 12, 30, 7, 16, 20)
+
+#: name -> (rows a partition, batchSize, row number -> token length, None
+#: for a null cell, or the cell itself)
+_STREAM_CASES = {
+    "one_bucket": (100, 64, lambda r: 9 + r % 8),
+    "three_buckets": (100, 64, lambda r: _MIXED[r % 6]),
+    "null_rows": (100, 64, lambda r: None if r % 7 == 3 else _MIXED[r % 6]),
+    "tokenizer_raises": (
+        100, 64, lambda r: "bad" if r % 5 == 2 else _MIXED[r % 6],
+    ),
+    "longer_than_top_edge": (100, 64, lambda r: 40 if r % 9 == 4 else 25),
+    "smaller_than_one_chunk": (20, 64, lambda r: _MIXED[r % 6]),
+    "no_multiple_of_the_chunk": (75, 64, lambda r: _MIXED[r % 6]),
+    "batch_size_4": (50, 4, lambda r: _MIXED[r % 6]),
+    "chunk_over_the_floor": (300, 1024, lambda r: _MIXED[r % 6]),
+}
+
+
+@pytest.mark.parametrize("n_parts", [2, 4])
+@pytest.mark.parametrize("case", sorted(_STREAM_CASES))
+def test_chunked_streaming_matches_whole_partition(monkeypatch, case, n_parts):
+    """Handing a partition over chunk by chunk changes the order in
+    which the feeder sees rows and nothing else: the same answer in
+    every cell, the same text counters, no more padded rows."""
+    from sparkdl_tpu.text import bucketing
+
+    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+    rows, batch_size, length_of = _STREAM_CASES[case]
+    parts = _partitions(n_parts, rows, length_of)
+    chunk = max(32, batch_size // (4 * n_parts))
+    assert bucketing._MIN_CHUNK_ROWS == 32
+
+    chunked, counted = _bucketed_job(parts, batch_size)
+    # the whole partition as one chunk: what the engine did before
+    monkeypatch.setattr(bucketing, "_MIN_CHUNK_ROWS", 10**9)
+    whole, counted_whole = _bucketed_job(parts, batch_size)
+
+    assert counted.pop("text.tokenize_chunks") == n_parts * -(-rows // chunk)
+    assert counted_whole.pop("text.tokenize_chunks") == n_parts
+    assert counted.pop("feeder.pad_rows", 0) <= counted_whole.pop(
+        "feeder.pad_rows", 0
+    )
+    assert counted == counted_whole
+    assert counted["text.tokens"] > 0 and counted["text.pad_tokens"] > 0
+    assert (counted.get("text.truncated_rows", 0) > 0) == (
+        case == "longer_than_top_edge"
+    )
+    assert sum(
+        v for k, v in counted.items() if k.startswith("text.bucket_rows.")
+    ) == sum(c not in (None, "bad") for part in parts for c in part)
+    for part, got, want in zip(parts, chunked, whole):
+        assert len(got) == len(want) == len(part)
+        for cell, a, b in zip(part, got, want):
+            if cell in (None, "bad"):
+                assert a is None and b is None
+                continue
+            ids = _stub_tokenize(cell)
+            edge = bucket_for(len(ids), _LADDER)
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(
+                a, [sum(ids[:edge]), min(len(ids), edge), edge]
+            )
+
+
+def test_device_starts_before_the_partition_is_tokenized(monkeypatch):
+    """The mechanism engages: the first batch is dispatched while every
+    partition still has rows to tokenize. Each partition's last row
+    waits for the device's first call, which on an engine that
+    tokenizes the whole partition first never comes."""
+    import threading
+
+    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+    dispatched = threading.Event()
+    seen_at_last_row = []
+
+    def tokenize(text):
+        if text.endswith(":last"):
+            seen_at_last_row.append(dispatched.wait(timeout=20))
+            text = text[: -len(":last")]
+        return _stub_tokenize(text)
+
+    def device(batch):
+        dispatched.set()
+        return _stub_device(batch)
+
+    # batchSize 512 over 2 partitions: chunks of 512 / (4 * 2) = 64 rows
+    parts = _partitions(2, 400, lambda r: 12)
+    parts[1] = parts[1][:330]
+    for part in parts:
+        part[-1] += ":last"
+    out, counted = _bucketed_job(parts, 512, tokenize, device)
+    assert seen_at_last_row == [True, True]
+    assert counted["text.tokenize_chunks"] == 7 + 6
+    assert counted["text.bucket_rows.16"] == 730
+    assert all(y is not None for part in out for y in part)
+
+
+@pytest.mark.parametrize("fault", ["tokenizers_caller", "device_function"])
+def test_streaming_failure_leaves_no_handle_open(monkeypatch, fault):
+    """A partition that fails mid-stream, with chunks already handed
+    over in three buckets, fails every handle it holds and ends them: a
+    device error reaches every partition, an error of run_bucketed's
+    own only its partition, and either way the feeders go idle."""
+    import time
+
+    from sparkdl_tpu.runtime import feeder as feeder_mod
+    from sparkdl_tpu.runtime.executor import Executor
+
+    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+    parts = _partitions(4, 100, lambda r: _MIXED[r % 6])
+
+    def tokenize(text):
+        if text == _cell(_MIXED[70 % 6], 2070) and fault != "device_function":
+            return 7  # no sequence: `len` raises in run_bucketed itself
+        return _stub_tokenize(text)
+
+    def device(batch):
+        if fault == "device_function":
+            raise RuntimeError("device fell over")
+        return _stub_device(batch)
+
+    def partition(i, cells):
+        try:
+            return run_bucketed(cells, tokenize, device, 16, 32, ladder=_LADDER)
+        except Exception as e:
+            return e
+
+    ex = Executor(max_workers=4, max_failures=1)
+    try:
+        out = ex.map_partitions(partition, parts)
+        if fault == "device_function":
+            assert all(
+                isinstance(o, RuntimeError) and "fell over" in str(o)
+                for o in out
+            )
+        else:
+            assert isinstance(out[2], TypeError)
+            for p in (0, 1, 3):
+                assert all(y is not None for y in out[p])
+        deadline = time.monotonic() + 10
+        feeders = list(feeder_mod._feeders.values())
+        assert len(feeders) == 3  # one stream a bucket
+        while not all(f.idle() for f in feeders):
+            assert time.monotonic() < deadline, "a handle was left open"
+            time.sleep(0.02)
+        assert all(f._open == 0 for f in feeders)
+    finally:
+        feeder_mod.shutdown_feeders()
+        ex.close()
