@@ -18,7 +18,8 @@ out against the repo's own references:
            generate on bert-tiny; /v1/models and /v1/memory
   trainer  DataParallelEstimator, ResNet50 at 224x224, 32 rows per
            device, two epochs of 4 steps
-  kernel   the compiled Pallas flash kernel against dense attention
+  kernel   the compiled Pallas flash kernel against dense attention, and
+           the hybrid family's: selective scan, causal shared-head flash
 
 Each phase prints one JSON line: wall seconds, the seconds jax spent
 compiling (or fetching from the persistent cache) and tracing, and the
@@ -636,6 +637,69 @@ def phase_kernel(sizes: Sizes, interpret: bool) -> dict:
         "compiled": not interpret,
         "rel_err_vs_dense": errs,
         "run_s": round(run_s, 3),
+        **_hybrid_kernels(sizes, interpret),
+    }
+
+
+def _hybrid_kernels(sizes: Sizes, interpret: bool) -> dict:
+    """The kernels of the hybrid text family (models/jamba.py): the
+    selective scan against the plain chunked scan, causal flash over one
+    shared key/value head against dense; and which of each a model built
+    here gets."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparkdl_tpu.models import get_model
+    from sparkdl_tpu.ops.flash_attention import (
+        dense_causal_attention,
+        flash_attention,
+    )
+    from sparkdl_tpu.ops.selective_scan import chunked_scan, selective_scan
+
+    rng = np.random.default_rng(4)
+    rows, d_inner, n = 2, 256, 16
+    errs = {}
+    for length in sizes.kernel_lengths:
+        wide = (rows, length, d_inner)
+        h, z = (jnp.asarray(rng.normal(size=wide), jnp.bfloat16) for _ in range(2))
+        dt = jnp.asarray(np.exp(rng.uniform(-6.9, -2.3, size=wide)), jnp.float32)
+        b, c = (
+            jnp.asarray(rng.normal(size=(rows, length, n)), jnp.float32)
+            for _ in range(2)
+        )
+        a = -jnp.broadcast_to(jnp.arange(1.0, n + 1), (d_inner, n))
+        d = jnp.ones((d_inner,), jnp.float32)
+        got = jax.jit(
+            lambda *t: selective_scan(*t, interpret=interpret, out_dtype=jnp.float32)
+        )(h, dt, b, c, z, a, d)
+        want = jax.jit(
+            lambda *t: chunked_scan(*t, out_dtype=jnp.float32)
+        )(h, dt, b, c, z, a, d)
+        errs[f"scan/L{length}"] = _check_close(f"scan L{length}", got, want)
+        q = jnp.asarray(rng.normal(size=(rows, 4, length, 128)), jnp.bfloat16)
+        k, v = (
+            jnp.asarray(rng.normal(size=(rows, 1, length, 128)), jnp.bfloat16)
+            for _ in range(2)
+        )
+        got = jax.jit(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=interpret, causal=True
+            )
+        )(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(
+                lambda q, k, v: dense_causal_attention(
+                    *(t.astype(jnp.float32) for t in (q, k, v)), None, jnp.float32
+                )
+            )(q, k, v)
+        errs[f"causal_flash/L{length}"] = _check_close(
+            f"causal flash L{length}", got, want
+        )
+    mf = get_model("jamba-tiny").model_function(mode="embed")
+    return {
+        "hybrid_rel_err": errs,
+        "jamba_built_with": {"attention": mf.attention, "scan": mf.scan},
     }
 
 

@@ -144,6 +144,13 @@ class ModelFunction:
     input_dtype: Any = None
     name: str = "model_fn"
     _jitted: Any = field(default=None, repr=False, compare=False)
+    #: Set by a model's builder (as ``attention`` and ``vocab_size`` are):
+    #: ``jitted()`` compiles ``fn`` itself and hands it the parameter
+    #: tree at every call, placed once on each execution device, instead
+    #: of closing over it. A compiled shape then holds no copy of the
+    #: weights: the only way to run a model whose weights are a large
+    #: share of the device's memory at more than one shape.
+    weights_as_arguments: bool = field(default=False, repr=False, compare=False)
 
     # -- execution ------------------------------------------------------------
 
@@ -201,16 +208,71 @@ class ModelFunction:
         """Jit with params captured as constants — the 'frozen' form. The
         params pytree is closed over (transferred to each execution device
         once, when that device's executable is built); every batch
-        thereafter only ships the batch."""
+        thereafter only ships the batch. A model marked
+        ``weights_as_arguments`` gets :meth:`_jitted_with_arguments`
+        instead. Either way one callable per model and placement
+        environment: the shared feeder keys its streams by it."""
         cache = self.__dict__.setdefault("_jitted_cache", {})
         key = self._placement_key()
         if key not in cache:
             from ..runtime import compile_cache
 
             compile_cache.note_build("jitted", self.name, key)
-            fn, params = self.fn, self._capture_params()
-            cache[key] = jax.jit(lambda x: fn(params, x))
+            if self.weights_as_arguments:
+                cache[key] = self._jitted_with_arguments()
+            else:
+                fn, params = self.fn, self._capture_params()
+                cache[key] = jax.jit(lambda x: fn(params, x))
         return cache[key]
+
+    def _jitted_with_arguments(self) -> Callable[[Any], Any]:
+        """``call(x)`` = ``jit(fn)(placed params, x)``. The tree is placed
+        on a device the first time ``call.place(device)`` names it (the
+        ``param_place`` span; ``model_device_fn`` names every device it
+        will dispatch to, before the first batch) and never donated. A
+        batch runs on the device its arrays are committed to, else on
+        the first device placed."""
+        import threading
+
+        from ..obs import span
+        from ..runtime.transfer import put_pytree_chunked
+
+        program = jax.jit(self.fn)
+        placed: dict = {}
+        lock = threading.Lock()
+
+        def place(device):
+            with lock:
+                if device not in placed:
+                    leaves = jax.tree_util.tree_leaves(self.params)
+                    chunk = knobs.get_int("SPARKDL_H2D_CHUNK_MB") << 20
+                    with span(
+                        "param_place",
+                        model=self.name,
+                        device=str(device),
+                        bytes=sum(int(a.nbytes) for a in leaves),
+                    ):
+                        tree = (
+                            put_pytree_chunked(self.params, device, chunk)
+                            if chunk > 0
+                            else jax.device_put(self.params, device)
+                        )
+                        placed[device] = jax.block_until_ready(tree)
+                return placed[device]
+
+        def call(x):
+            device = None
+            for leaf in jax.tree_util.tree_leaves(x):
+                if isinstance(leaf, jax.Array) and leaf.committed:
+                    (device,) = leaf.devices()
+                    break
+            if device is None:
+                device = next(iter(placed), None) or jax.devices()[0]
+            return program(place(device), x)
+
+        call.place = place
+        call.program = program  # the one jit, for tests that count shapes
+        return call
 
     def frozen(self) -> Callable[[Any], Any]:
         fn, params = self.fn, self.params

@@ -609,6 +609,45 @@ _register(
 )
 
 
+def _jamba_text_builder(size: str):
+    """Builder over models/jamba.py presets: a causal hybrid of Mamba-1
+    and attention layers whose ``embed`` is the final state of a row's
+    last real token. The ModelFunction is marked ``weights_as_arguments``
+    and reports ``attention`` ('flash' | 'dense') and ``scan``
+    ('pallas' | 'jnp'), both chosen at build time."""
+
+    def build(
+        spec: NamedTextModel, mode: str, dtype, weights_file, seed
+    ) -> ModelFunction:
+        from sparkdl_tpu.models import jamba as jamba_mod
+
+        return jamba_mod.jamba_model_function(
+            size,
+            dtype=dtype,
+            seed=seed,
+            weights_file=weights_file,
+            name=f"{spec.name}[{mode}]",
+        )
+
+    return build
+
+
+# AI21-Jamba2-3B at its published widths (no position table: the length
+# is the model's declared context), and the same family at test size.
+_register(
+    NamedTextModel(
+        "jamba2-3b", 262144, 2560, "jax", _jamba_text_builder("jamba2-3b"),
+        vocab_size=65536,
+    )
+)
+_register(
+    NamedTextModel(
+        "jamba-tiny", 4096, 64, "jax", _jamba_text_builder("jamba-tiny"),
+        vocab_size=512,
+    )
+)
+
+
 def get_model(name: str):
     """The registered spec for ``name`` — a :class:`NamedImageModel` or
     :class:`NamedTextModel`; both expose ``model_function(mode=...)``

@@ -41,13 +41,17 @@ def _flash_kernel(
     m_ref,
     l_ref,
     acc_ref,
+    causal: bool = False,
 ):
     """Grid = (B*H, num_q_blocks, num_k_blocks); the k dimension is
     sequential ('arbitrary'), so VMEM scratch carries the online softmax
-    state across k-steps for each (bh, qi) tile."""
+    state across k-steps for each (bh, qi) tile. ``causal`` (square
+    blocks): a key block above the diagonal is skipped, not masked after
+    the product, and the diagonal block is masked inside."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(2)
+    qi = pl.program_id(1) if causal else None
 
     @pl.when(ki == 0)
     def _init():
@@ -55,35 +59,46 @@ def _flash_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # [bq, dh]
-    k = k_ref[0].astype(jnp.float32)  # [bk, dh]
-    v = v_ref[0].astype(jnp.float32)  # [bk, dh]
+    def _block():
+        q = q_ref[0].astype(jnp.float32)  # [bq, dh]
+        k = k_ref[0].astype(jnp.float32)  # [bk, dh]
+        v = v_ref[0].astype(jnp.float32)  # [bk, dh]
 
-    s = (
-        jax.lax.dot_general(
-            q,
-            k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        s = (
+            jax.lax.dot_general(
+                q,
+                k,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            * scale
+        )  # [bq, bk]
+        s = s + mask_ref[0]  # [1, bk] broadcasts over the q rows
+        if causal:
+            bq, bk = s.shape
+            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col <= row, s, NEG_INF)
+
+        # lanes of m_ref/l_ref all hold the same per-row value; max() reads it
+        # back without a sub-128 lane slice.
+        m_prev = jnp.max(m_ref[:], axis=-1, keepdims=True)  # [bq, 1]
+        l_prev = jnp.max(l_ref[:], axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)  # [bq, bk]
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        * scale
-    )  # [bq, bk]
-    s = s + mask_ref[0]  # [1, bk] broadcasts over the q rows
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    # lanes of m_ref/l_ref all hold the same per-row value; max() reads it
-    # back without a sub-128 lane slice.
-    m_prev = jnp.max(m_ref[:], axis=-1, keepdims=True)  # [bq, 1]
-    l_prev = jnp.max(l_ref[:], axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)  # [bq, bk]
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    acc_ref[:] = acc_ref[:] * alpha + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    if causal:
+        pl.when(ki <= qi)(_block)
+    else:
+        _block()
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -106,15 +121,23 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    causal: bool = False,
 ):
     """Blockwise-online softmax attention.
 
     Args:
-        q, k, v: [B, H, L, Dh].
+        q: [B, H, L, Dh]. k, v: [B, Hkv, Lk, Dh], Hkv a divisor of H:
+            consecutive groups of H // Hkv query heads share one
+            key/value head, found by the blocks' index maps and never
+            repeated in memory.
         mask: additive key mask, [B, L] or [B, 1, 1, L] float (0 for keep,
             large-negative for drop). Applied to keys, as in BERT padding.
         block_q/block_k: VMEM tile sizes (128 matches the lane width).
         interpret: run the Pallas interpreter (CPU tests).
+        causal: a query sees the keys at or before its own position
+            (Lk == L, square blocks). Key blocks above the diagonal are
+            skipped, and their index clamps to the diagonal's, so they
+            are not fetched either.
 
     Returns [B, H, L, Dh] in q's dtype.
     """
@@ -122,7 +145,14 @@ def flash_attention(
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, L, Dh = q.shape
-    Lk = k.shape[2]
+    Hkv, Lk = k.shape[1], k.shape[2]
+    if H % Hkv:
+        raise ValueError(f"{H} query heads over {Hkv} key/value heads")
+    if causal and (Lk != L or block_q != block_k):
+        raise ValueError(
+            "causal attention wants Lk == L and square blocks, got "
+            f"L={L}, Lk={Lk}, blocks {block_q}x{block_k}"
+        )
     if mask is None:
         mask2d = jnp.zeros((B, Lk), jnp.float32)
     else:
@@ -152,8 +182,8 @@ def flash_attention(
     Lq_p, Lk_p = L + pq, Lk + pk
 
     qf = q.reshape(B * H, Lq_p, Dh_p)
-    kf = k.reshape(B * H, Lk_p, Dh_p)
-    vf = v.reshape(B * H, Lk_p, Dh_p)
+    kf = k.reshape(B * Hkv, Lk_p, Dh_p)
+    vf = v.reshape(B * Hkv, Lk_p, Dh_p)
     # [B, 1, Lk]: the mask block's last two dims are then (1, block_k),
     # and a second-minor block dim of 1 is legal only where it equals
     # the array's own — blocking a [B, Lk] mask as (1, block_k) breaks
@@ -164,16 +194,30 @@ def flash_attention(
     nk = Lk_p // block_k
 
     kernel = functools.partial(_flash_kernel, nk, scale)
+    kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
+    mask_block = lambda bh, qi, ki, H=H: (bh // H, 0, ki)  # noqa: E731
+    if causal or Hkv != H:
+        # with neither, the lowered kernel is to the byte what it was
+        # before either existed
+        kernel = functools.partial(kernel, causal=causal)
+        group = H // Hkv
+
+        def kv_head(bh):
+            return (bh // H) * Hkv + (bh % H) // group
+
+        def key_block(qi, ki):
+            return jnp.minimum(ki, qi) if causal else ki
+
+        kv_block = lambda bh, qi, ki: (kv_head(bh), key_block(qi, ki), 0)  # noqa: E731
+        mask_block = lambda bh, qi, ki: (bh // H, 0, key_block(qi, ki))  # noqa: E731
     out = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, Dh_p), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, Dh_p), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, Dh_p), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k), lambda bh, qi, ki, H=H: (bh // H, 0, ki)
-            ),
+            pl.BlockSpec((1, block_k, Dh_p), kv_block),
+            pl.BlockSpec((1, block_k, Dh_p), kv_block),
+            pl.BlockSpec((1, 1, block_k), mask_block),
         ],
         out_specs=pl.BlockSpec(
             (1, block_q, Dh_p), lambda bh, qi, ki: (bh, qi, 0)
@@ -196,19 +240,49 @@ def flash_attention(
     return out[:, :, :L, :Dh]
 
 
+def dense_causal_attention(q, k, v, mask, dtype):
+    """What ``flash_attention(causal=True)`` computes, as dense einsums:
+    q [B, H, L, Dh] over k, v [B, Hkv, L, Dh], float32 scores and
+    softmax, an optional additive key mask. The build-time fallback off
+    the TPU and the kernel's test oracle."""
+    B, H, L, Dh = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(B, Hkv, H // Hkv, L, Dh)
+    s = jnp.einsum(
+        "bhgqd,bhkd->bhgqk", qg, k, preferred_element_type=jnp.float32
+    ) / np.sqrt(Dh)
+    if mask is not None:
+        s = s + mask.reshape(B, 1, 1, 1, L).astype(jnp.float32)
+    s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, NEG_INF)
+    p = jax.nn.softmax(s, -1).astype(dtype)
+    o = jnp.einsum(
+        "bhgqk,bhkd->bhgqd", p, v, preferred_element_type=jnp.float32
+    )
+    return o.reshape(B, H, L, Dh).astype(dtype)
+
+
+dense_causal_attention.kind = "dense"
+
+
 def make_flash_attention_fn(
-    block_q: int = 128, block_k: int = 128, interpret: bool = False
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: bool = False,
+    causal: bool = False,
 ):
     """Returns an attention fn with the ``dense_attention`` signature
     (q, k, v, mask, dtype) — drop-in for BertEncoder(attention_fn=...).
 
     The choice is made HERE, at build time, from the process's default
     backend: the Pallas kernel on TPU (or interpreted when asked — never
-    derived from the backend), ``dense_attention`` itself elsewhere so
-    CPU meshes keep working. Either way the returned function's
-    ``.kind`` ('flash' | 'dense') says which, and nothing downstream
-    re-decides: a kernel that fails to compile raises."""
+    derived from the backend), ``dense_attention`` itself elsewhere
+    (``dense_causal_attention`` for ``causal``) so CPU meshes keep
+    working. Either way the returned function's ``.kind`` ('flash' |
+    'dense') says which, and nothing downstream re-decides: a kernel
+    that fails to compile raises."""
     if not interpret and jax.default_backend() != "tpu":
+        if causal:
+            return dense_causal_attention
         from sparkdl_tpu.models.bert import dense_attention
 
         return dense_attention
@@ -222,6 +296,7 @@ def make_flash_attention_fn(
             block_q=block_q,
             block_k=block_k,
             interpret=interpret,
+            causal=causal,
         )
         return out.astype(dtype)
 

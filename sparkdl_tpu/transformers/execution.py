@@ -224,6 +224,17 @@ def model_device_fn(model_function, jitted=None, mesh_width=None):
     byte-identical single-device fallback); ``None`` keeps the
     mode-based legacy behavior."""
     fn = jitted if jitted is not None else model_function.jitted()
+    if hasattr(fn, "place"):
+        # weights are arguments (ModelFunction.weights_as_arguments): one
+        # program a device with the tree placed there before the first
+        # batch, in either inference mode. A sharded wrapper would trace
+        # the call and fold the placed tree back into its constants.
+        devs = inference_devices()
+        if mesh_width is not None:
+            devs = devs[: max(1, int(mesh_width))]
+        for dev in devs:
+            fn.place(dev)
+        return data_parallel_device_fn(fn, devices=devs)
     if getattr(model_function, "single_stream", False):
         # jit objects don't take attributes; a closure carries n_devices
         def single(batch, _inner=fn):

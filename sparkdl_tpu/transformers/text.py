@@ -121,8 +121,16 @@ class TextEmbedder(
         cache = self.__dict__.setdefault("_jit_cache", {})
         if key not in cache or cache[key][0] is not mf:
             fn = model_device_fn(mf)
+            # a model with state-space layers says how many: every
+            # dispatched token (pad rows and pad tokens too) is scanned
+            # once by each
+            scan_layers = getattr(mf, "scan_layers", 0)
 
             def device_call(ids_batch, _fn=fn):
+                if scan_layers:
+                    metrics.inc(
+                        "ssm.scan_tokens", int(ids_batch.size) * scan_layers
+                    )
                 attn = (ids_batch != 0).astype(np.int32)
                 return _fn((ids_batch, attn))
 
