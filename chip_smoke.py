@@ -18,7 +18,8 @@ out against the repo's own references:
            generate on bert-tiny; /v1/models and /v1/memory
   trainer  DataParallelEstimator, ResNet50 at 224x224, 32 rows per
            device, two epochs of 4 steps
-  kernel   the compiled Pallas flash kernel against dense attention, and
+  kernel   the compiled Pallas flash kernel, blocked and packed (heads
+           under the lane width, side by side), against dense attention, and
            the hybrid family's: selective scan, causal shared-head flash
 
 Each phase prints one JSON line: wall seconds, the seconds jax spent
@@ -374,8 +375,10 @@ def phase_online(sizes: Sizes, on_tpu: bool) -> dict:
         spec = get_model(name)
         mf = spec.model_function(mode="embed")
         if on_tpu:
-            if mf.attention != "flash":
-                raise AssertionError(f"{name} built with {mf.attention}")
+            # both presets' heads are narrower than a lane tile (64, 32)
+            built = (mf.attention, mf.attention_layout)
+            if built != ("flash", "packed"):
+                raise AssertionError(f"{name} built with {built}")
             # params as an argument: as constants they would be
             # printed into the module text
             ids0 = np.ones((2, length), np.int32)
@@ -595,11 +598,28 @@ def phase_kernel(sizes: Sizes, interpret: bool) -> dict:
     import numpy as np
 
     from sparkdl_tpu.models.bert import dense_attention
-    from sparkdl_tpu.ops.flash_attention import flash_attention
+    from sparkdl_tpu.ops.flash_attention import (
+        flash_attention,
+        flash_attention_packed,
+        packs,
+    )
 
     flash = jax.jit(
         lambda q, k, v, m: flash_attention(q, k, v, m, interpret=interpret)
     )
+
+    @jax.jit
+    def packed(q, k, v, m):
+        # the same heads side by side, [B, L, H*Dh], as a projection
+        # writes them; the answer back in the blocked kernel's layout
+        B, H, L, dh = q.shape
+        q, k, v = (
+            t.transpose(0, 2, 1, 3).reshape(B, L, H * dh) for t in (q, k, v)
+        )
+        out = flash_attention_packed(
+            q, k, v, m, num_heads=H, interpret=interpret
+        )
+        return out.reshape(B, L, H, dh).transpose(0, 2, 1, 3)
 
     @jax.jit
     def dense(q, k, v, m):
@@ -633,6 +653,10 @@ def phase_kernel(sizes: Sizes, interpret: bool) -> dict:
                     want = dense(q, k, v, mask)
                 tag = f"{np.dtype(dtype).name}/dh{dh}/L{length}"
                 errs[tag] = _check_close(f"flash {tag}", got, want)
+                if packs(H, dh):
+                    errs[f"{tag}/packed"] = _check_close(
+                        f"packed flash {tag}", packed(q, k, v, mask), want
+                    )
     return {
         "compiled": not interpret,
         "rel_err_vs_dense": errs,

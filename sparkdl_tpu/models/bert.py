@@ -123,6 +123,14 @@ def dense_attention(q, k, v, mask, dtype):
 dense_attention.kind = "dense"
 
 
+def attention_layout(attention_fn) -> str:
+    """The layout an attention function takes and returns, said beside
+    ``.kind``: 'heads' ([B, H, L, Dh], also what a function that says
+    nothing takes) or 'packed' ([B, L, H*Dh], the projections' own
+    output, with the head count as ``num_heads=``)."""
+    return getattr(attention_fn or dense_attention, "layout", "heads")
+
+
 class BertSelfAttention(nn.Module):
     config: BertConfig
     attention_fn: Optional[Callable] = None
@@ -133,19 +141,19 @@ class BertSelfAttention(nn.Module):
         h, dh = c.num_heads, c.hidden_size // c.num_heads
 
         def proj(name):
-            return nn.Dense(c.hidden_size, dtype=c.dtype, name=name)
+            return nn.Dense(c.hidden_size, dtype=c.dtype, name=name)(x)
 
-        def split(t):  # [B, L, D] -> [B, H, L, Dh]
-            return t.reshape(*t.shape[:2], h, dh).transpose(0, 2, 1, 3)
-
-        q, k, v = (
-            split(proj("query")(x)),
-            split(proj("key")(x)),
-            split(proj("value")(x)),
-        )
+        q, k, v = proj("query"), proj("key"), proj("value")
         attn = self.attention_fn or dense_attention
-        out = attn(q, k, v, mask, c.dtype)
-        out = out.transpose(0, 2, 1, 3).reshape(*x.shape[:2], c.hidden_size)
+        if attention_layout(attn) == "packed":
+            out = attn(q, k, v, mask, c.dtype, num_heads=h)
+        else:
+
+            def split(t):  # [B, L, D] -> [B, H, L, Dh]
+                return t.reshape(*t.shape[:2], h, dh).transpose(0, 2, 1, 3)
+
+            out = attn(split(q), split(k), split(v), mask, c.dtype)
+            out = out.transpose(0, 2, 1, 3).reshape(*x.shape[:2], c.hidden_size)
         out = nn.Dense(c.hidden_size, dtype=c.dtype, name="output")(out)
         return out
 
@@ -217,6 +225,17 @@ class BertEncoder(nn.Module):
 _SIZES = {"base": bert_base, "tiny": bert_tiny, "long": bert_long}
 
 
+def flash_attention_for(config: BertConfig):
+    """``make_flash_attention_fn`` told the heads' shape, which decides
+    the kernel and the layout it reads (see there)."""
+    from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
+
+    return make_flash_attention_fn(
+        num_heads=config.num_heads,
+        head_dim=config.hidden_size // config.num_heads,
+    )
+
+
 def encoder_model_function(module: "BertEncoder", fn, params, name: str):
     """The embed ModelFunction over ``fn`` (a closure on ``module.apply``),
     carrying what the module knows — the one place every text builder
@@ -225,7 +244,8 @@ def encoder_model_function(module: "BertEncoder", fn, params, name: str):
     ``attention`` is the ``kind`` of the attention the module was built
     with ('flash' | 'dense' | 'ring' | 'ulysses'; 'custom' for a
     caller's own function), which ``/v1/models`` and ``chip_smoke.py``
-    report."""
+    report, with ``attention_layout`` beside it ('packed' where the
+    function reads the projections' own output, else 'heads')."""
     from sparkdl_tpu.graph.function import ModelFunction
 
     mf = ModelFunction(fn, params, input_dtype=jnp.int32, name=name)
@@ -233,6 +253,7 @@ def encoder_model_function(module: "BertEncoder", fn, params, name: str):
     mf.attention = getattr(
         module.attention_fn or dense_attention, "kind", "custom"
     )
+    mf.attention_layout = attention_layout(module.attention_fn)
     return mf
 
 
@@ -272,9 +293,7 @@ def bert_model_function(
         # The Pallas flash kernel on TPU, the dense einsum elsewhere —
         # chosen once, here (see make_flash_attention_fn). Pass
         # attention_fn=dense_attention to force the einsum path.
-        from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
-
-        attention_fn = make_flash_attention_fn()
+        attention_fn = flash_attention_for(module.config)
     module = BertEncoder(module.config, attention_fn=attention_fn)
     if params is None:
         ids0 = jnp.zeros((1, min(max_length, 16)), jnp.int32)

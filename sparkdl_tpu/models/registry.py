@@ -251,18 +251,12 @@ def _bert_text_builder(size: str, attention: str = "flash"):
     ) -> ModelFunction:
         from sparkdl_tpu.models import bert as bert_mod
 
+        config = bert_mod._SIZES[size](dtype=dtype).config
         if attention == "dense":
             attention_fn = bert_mod.dense_attention
         else:
-            from sparkdl_tpu.ops.flash_attention import (
-                make_flash_attention_fn,
-            )
-
-            attention_fn = make_flash_attention_fn()
-        module = bert_mod.BertEncoder(
-            bert_mod._SIZES[size](dtype=dtype).config,
-            attention_fn=attention_fn,
-        )
+            attention_fn = bert_mod.flash_attention_for(config)
+        module = bert_mod.BertEncoder(config, attention_fn=attention_fn)
         if weights_file:
             variables = _load_flax_weights(weights_file)
         else:

@@ -9,13 +9,23 @@ float32 accumulation, and the softmax runs online (running max/sum in VMEM
 scratch) so the [L, L] score matrix never materializes in HBM — O(L)
 memory instead of O(L²).
 
+Two tilings of that one algorithm, chosen by the heads' shape.
+:func:`flash_attention` takes [B, H, L, Dh] and gives one (row, head)
+pair to a grid step: right where a head fills the 128 lanes (Jamba's
+128), and what causal and shared-key/value attention run. A head
+narrower than the lanes (BERT's 64) would be padded to 128 in HBM and
+transposed in and out of that layout, so :func:`flash_attention_packed`
+reads [B, L, H*Dh], the array each projection wrote, and a grid step
+handles every head of a block of rows (:func:`packs` is the condition).
+
 Composes with ring attention (ops/ring_attention.py): the ring rotates K/V
 shards over the mesh's 'sp' axis while this kernel computes each local
-block product. :func:`flash_attention` always runs the kernel — compiled,
-or under ``interpret=True`` (the CPU tests) — and a Mosaic compile error
-is an error. :func:`make_flash_attention_fn` picks the attention a model
-is BUILT with, once, from the process's default backend, and the choice
-is recorded on the returned function (``.kind``).
+block product. Both functions always run the kernel — compiled, or under
+``interpret=True`` (the CPU tests) — and a Mosaic compile error is an
+error. :func:`make_flash_attention_fn` picks the attention a model is
+BUILT with, once, from the process's default backend and the heads'
+shape, and the choice is recorded on the returned function (``.kind``,
+``.layout``).
 """
 
 from __future__ import annotations
@@ -112,6 +122,13 @@ def _pad_len(n: int, block: int) -> int:
     return (block - n % block) % block
 
 
+def _key_mask(mask, batch: int, keys: int):
+    """The additive key mask as float32 [B, Lk]; zeros for none."""
+    if mask is None:
+        return jnp.zeros((batch, keys), jnp.float32)
+    return mask.reshape(batch, keys).astype(jnp.float32)
+
+
 def flash_attention(
     q,
     k,
@@ -153,10 +170,7 @@ def flash_attention(
             "causal attention wants Lk == L and square blocks, got "
             f"L={L}, Lk={Lk}, blocks {block_q}x{block_k}"
         )
-    if mask is None:
-        mask2d = jnp.zeros((B, Lk), jnp.float32)
-    else:
-        mask2d = mask.reshape(B, Lk).astype(jnp.float32)
+    mask2d = _key_mask(mask, B, Lk)
 
     # Head dims below the 128-lane tile (BERT-base: Dh=64) are zero-padded
     # up to the lane width: zero q/k columns leave the scores unchanged
@@ -240,6 +254,221 @@ def flash_attention(
     return out[:, :, :L, :Dh]
 
 
+LANES = 128  # the lane width: the last dim of a VMEM tile
+
+
+def packs(num_heads: Optional[int], head_dim: Optional[int]) -> bool:
+    """Whether heads of this shape lie whole in lane tiles of the
+    projections' [B, L, H*Dh] output: a head size under the lane width
+    that divides it, and whole tiles. The packed kernel's condition."""
+    return bool(
+        num_heads
+        and head_dim
+        and head_dim < LANES
+        and LANES % head_dim == 0
+        and (num_heads * head_dim) % LANES == 0
+    )
+
+
+def _packed_kernel(nk: int, scale: float, group: int, *refs):
+    """Grid = (B // r, num_q_blocks, num_k_blocks): one step is every
+    head of ``r`` rows. The refs' last dim is H*Dh, heads side by side
+    as the projections wrote them, ``group`` = 128 // Dh of them to a
+    lane tile. A tile's heads are separated without a lane shuffle: the
+    tile's q is stacked ``group`` times on the rows, copy j keeping head
+    j's lanes and zeros elsewhere (exact: the zeros add nothing to the
+    contraction over the tile's 128 lanes), so one product with the
+    tile's k gives every head's scores, [group*bq, bk], and the softmax
+    is the blocked kernel's, row by row. ``p·v`` against the whole tile
+    then holds head j's answer in rows j*bq.. at head j's lanes, which
+    the last key step selects.
+
+    With more than one key step the scratch carries the online softmax
+    for each (row, tile): running max and sum (lane broadcast) and the
+    stacked accumulator. With one (``nk == 1``, a length within a
+    block) there is no scratch: the softmax starts from the same
+    NEG_INF floor and its answer goes straight to the output block."""
+    from jax.experimental import pallas as pl
+
+    q_ref, k_ref, v_ref, mask_ref, o_ref, *scratch = refs
+    rows, bq, width = q_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
+    # head j's lanes of a tile, [bq, 128]
+    of_head = [lane // (LANES // group) == j for j in range(group)]
+
+    def tiles():
+        for t in range(width // LANES):
+            yield t, slice(t * LANES, (t + 1) * LANES)
+
+    def write(i, lanes, acc, l_sum):
+        out = acc / jnp.maximum(l_sum, 1e-30)  # [group*bq, 128]
+        merged = out[:bq]
+        for j in range(1, group):
+            merged = jnp.where(of_head[j], out[j * bq : (j + 1) * bq], merged)
+        o_ref[i, :, lanes] = merged.astype(o_ref.dtype)
+
+    def block(i, carry):
+        mask = mask_ref[i]  # [1, bk] broadcasts over the stacked q rows
+        for t, lanes in tiles():
+            q = q_ref[i, :, lanes].astype(jnp.float32)  # [bq, 128]
+            k = k_ref[i, :, lanes].astype(jnp.float32)  # [bk, 128]
+            v = v_ref[i, :, lanes].astype(jnp.float32)  # [bk, 128]
+            q = jnp.concatenate(
+                [jnp.where(lanes_j, q, 0.0) for lanes_j in of_head]
+            )  # [group*bq, 128]
+            s = (
+                jax.lax.dot_general(
+                    q,
+                    k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                * scale
+            )  # [group*bq, bk]
+            s = s + mask
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            if scratch:
+                # lanes of m_ref/l_ref all hold the same per-row value
+                m_prev = jnp.max(m_ref[i, t], axis=-1, keepdims=True)
+                l_prev = jnp.max(l_ref[i, t], axis=-1, keepdims=True)
+                m_new = jnp.maximum(m_prev, m_cur)
+                alpha = jnp.exp(m_prev - m_new)
+            else:
+                m_new = jnp.maximum(m_cur, NEG_INF)
+            p = jnp.exp(s - m_new)
+            l_cur = jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p,
+                v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [group*bq, 128]
+            if scratch:
+                acc_ref[i, t] = acc_ref[i, t] * alpha + pv
+                m_ref[i, t] = jnp.broadcast_to(m_new, m_ref.shape[2:])
+                l_ref[i, t] = jnp.broadcast_to(
+                    l_prev * alpha + l_cur, l_ref.shape[2:]
+                )
+            else:
+                write(i, lanes, pv, l_cur)
+        return carry
+
+    if scratch:
+        m_ref, l_ref, acc_ref = scratch
+        ki = pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    jax.lax.fori_loop(0, rows, block, None)
+
+    if scratch:
+
+        @pl.when(ki == nk - 1)
+        def _finalize():
+            def row(i, carry):
+                for t, lanes in tiles():
+                    write(
+                        i,
+                        lanes,
+                        acc_ref[i, t],
+                        jnp.max(l_ref[i, t], axis=-1, keepdims=True),
+                    )
+                return carry
+
+            jax.lax.fori_loop(0, rows, row, None)
+
+
+def _packed_rows(batch: int, nk: int) -> int:
+    """Rows of the batch a grid step of the packed kernel takes, as the
+    chip chose (PERF.md, PR 29: at [2048, 128, 768] float32 two rows a
+    step beat one by 1.7% and four gain 0.7% more but overflow the
+    default scoped VMEM once there is scratch; with the scratch of two
+    or four key blocks one row beat two by 3-7%): two where there is one
+    key block and the batch is even, else one."""
+    return 2 if nk == 1 and batch % 2 == 0 else 1
+
+
+def flash_attention_packed(
+    q,
+    k,
+    v,
+    mask: Optional[jax.Array] = None,
+    *,
+    num_heads: int,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: bool = False,
+):
+    """:func:`flash_attention` for heads narrower than the lane width,
+    read where the projections wrote them.
+
+    Args:
+        q: [B, L, H*Dh]. k, v: [B, Lk, H*Dh], the same H heads of Dh
+            side by side on the last axis, with ``packs(H, Dh)``.
+        mask: additive key mask, as :func:`flash_attention` takes it.
+
+    Returns [B, L, H*Dh] in q's dtype. The same products at the same
+    precision as the blocked kernel; nothing is padded or transposed in
+    HBM (a length off the block size is padded as there)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, L, D = q.shape
+    Lk = k.shape[1]
+    head_dim = D // num_heads
+    if D % num_heads or not packs(num_heads, head_dim):
+        raise ValueError(
+            f"{num_heads} heads over a width of {D} do not pack into "
+            f"{LANES}-lane tiles: use flash_attention"
+        )
+    if k.shape != (B, Lk, D) or v.shape != k.shape:
+        raise ValueError(
+            f"packed q {q.shape} wants k and v [B, Lk, {D}], got "
+            f"{k.shape} and {v.shape}"
+        )
+    mask2d = _key_mask(mask, B, Lk)
+    pq, pk = _pad_len(L, block_q), _pad_len(Lk, block_k)
+    if pq:
+        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0)))
+    if pk:
+        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0)))
+        mask2d = jnp.pad(mask2d, ((0, 0), (0, pk)), constant_values=NEG_INF)
+    nq, nk = (L + pq) // block_q, (Lk + pk) // block_k
+    rows = _packed_rows(B, nk)
+    group = LANES // head_dim
+    stacked = (rows, D // LANES, group * block_q, LANES)
+    out = pl.pallas_call(
+        functools.partial(
+            _packed_kernel, nk, 1.0 / np.sqrt(head_dim), group
+        ),
+        grid=(B // rows, nq, nk),
+        in_specs=[
+            pl.BlockSpec((rows, block_q, D), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((rows, block_k, D), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((rows, block_k, D), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((rows, 1, block_k), lambda b, qi, ki: (b, 0, ki)),
+        ],
+        out_specs=pl.BlockSpec(
+            (rows, block_q, D), lambda b, qi, ki: (b, qi, 0)
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, L + pq, D), q.dtype),
+        # running max, running sum, output accumulator
+        scratch_shapes=[pltpu.VMEM(stacked, jnp.float32)] * 3 * (nk > 1),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        # the blocked kernel's name: one kernel to the trace's readers
+        name="flash_attention",
+    )(q, k, v, mask2d[:, None, :])
+    return out[:, :L]
+
+
 def dense_causal_attention(q, k, v, mask, dtype):
     """What ``flash_attention(causal=True)`` computes, as dense einsums:
     q [B, H, L, Dh] over k, v [B, Hkv, L, Dh], float32 scores and
@@ -269,6 +498,8 @@ def make_flash_attention_fn(
     block_k: int = 128,
     interpret: bool = False,
     causal: bool = False,
+    num_heads: Optional[int] = None,
+    head_dim: Optional[int] = None,
 ):
     """Returns an attention fn with the ``dense_attention`` signature
     (q, k, v, mask, dtype) — drop-in for BertEncoder(attention_fn=...).
@@ -279,13 +510,41 @@ def make_flash_attention_fn(
     (``dense_causal_attention`` for ``causal``) so CPU meshes keep
     working. Either way the returned function's ``.kind`` ('flash' |
     'dense') says which, and nothing downstream re-decides: a kernel
-    that fails to compile raises."""
+    that fails to compile raises.
+
+    Which kernel is chosen here too, from the shape a builder knows:
+    where ``packs(num_heads, head_dim)`` and not ``causal``, the
+    function takes q, k, v as the projections wrote them, [B, L, H*Dh],
+    and the head count (``num_heads=``), runs
+    :func:`flash_attention_packed` and returns [B, L, H*Dh]; its
+    ``.layout`` is 'packed'. Every other shape, and a caller that names
+    none, gets the blocked kernel over [B, H, L, Dh] ('heads', what a
+    function without ``.layout`` takes)."""
     if not interpret and jax.default_backend() != "tpu":
         if causal:
             return dense_causal_attention
         from sparkdl_tpu.models.bert import dense_attention
 
         return dense_attention
+
+    if not causal and packs(num_heads, head_dim):
+
+        def packed_attention(q, k, v, mask, dtype, *, num_heads):
+            out = flash_attention_packed(
+                q,
+                k,
+                v,
+                mask,
+                num_heads=num_heads,
+                block_q=block_q,
+                block_k=block_k,
+                interpret=interpret,
+            )
+            return out.astype(dtype)
+
+        packed_attention.kind = "flash"
+        packed_attention.layout = "packed"
+        return packed_attention
 
     def attention(q, k, v, mask, dtype):
         out = flash_attention(

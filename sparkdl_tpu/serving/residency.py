@@ -257,12 +257,14 @@ class ResidencyManager:
                     "requests": m.requests,
                     "idle_s": round(now - m.last_used, 3),
                     # text models: 'flash' (the compiled Pallas kernel)
-                    # or 'dense', as decided when the function was built
-                    **(
-                        {"attention": m.model_function.attention}
-                        if hasattr(m.model_function, "attention")
-                        else {}
-                    ),
+                    # or 'dense', and the layout it reads ('packed': the
+                    # projections' own), as decided when the function
+                    # was built
+                    **{
+                        key: getattr(m.model_function, key)
+                        for key in ("attention", "attention_layout")
+                        if hasattr(m.model_function, key)
+                    },
                 }
                 for m in self._models.values()
             ]

@@ -63,7 +63,6 @@ _SLOW_FILES = {
     "test_pipeline_parallel.py",
     "test_expert_parallel.py",
     "test_tensor_parallel.py",
-    "test_flash_attention.py",
     "test_zoo_ingest_corpus.py",
     "test_transformers.py",
     "test_keras_image_fused.py",

@@ -332,6 +332,7 @@ def test_served_text_model_names_its_attention():
         assert np.asarray(out).shape == (1, 128)
         rows = {m["name"]: m for m in router.residency.models()}
         assert rows["bert-tiny"]["attention"] == "dense"
+        assert rows["bert-tiny"]["attention_layout"] == "heads"
     finally:
         router.close()
 

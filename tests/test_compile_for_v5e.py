@@ -1,5 +1,5 @@
-"""The kernels of the Jamba cell compiled at the published widths for a
-v5e that is described and not attached: what the chip's compiler would
+"""The kernels of the benchmark's cells compiled at the published widths
+for a v5e that is described and not attached: what the chip's compiler would
 refuse (a slice off the tiling, too much fast memory) it refuses here, at
 no chip time. Nothing runs, so nothing here says a result or a time.
 
@@ -14,7 +14,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from sparkdl_tpu.ops.flash_attention import flash_attention
+from sparkdl_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_packed,
+)
 from sparkdl_tpu.ops.selective_scan import selective_scan
 
 ROWS, D_INNER, D_STATE = 8, 5120, 16  # a dispatch of the cell; Jamba2-3B
@@ -78,3 +81,24 @@ def test_causal_flash_with_one_shared_head_compiles(shape):
     # the key/value head is found by the index map: nothing of the size of
     # 20 copies of it is made on the way in
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * ROWS * 2048 * HEAD_DIM * 2
+
+
+@pytest.mark.parametrize("rows,length", [(2048, 128), (1024, 256), (512, 512)])
+def test_packed_flash_compiles_at_bert_base_widths(shape, rows, length):
+    """A dispatch of `bert-base-embed` (the 128 bucket) and as many
+    tokens at the 256 and 512 buckets (two and four key blocks: the
+    online softmax's scratch), float32, twelve heads of 64 side by side."""
+    f32 = jnp.float32
+    x = shape((rows, length, 768), f32)
+    compiled = (
+        jax.jit(functools.partial(flash_attention_packed, num_heads=12))
+        .lower(x, x, x, shape((rows, length), f32))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the name the trace's readers look for
+    assert "%flash_attention" in text
+    # no padded, transposed or sliced copy on the way in or out: nothing
+    # of the size of q is made beside the output
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * length * 768 * 4
