@@ -1480,16 +1480,12 @@ def _measure(mode: str, platform: str) -> dict:
     # measured run (the ring+registry were reset with the warmup), so
     # BENCH_HISTORY can attribute throughput deltas to padding-waste
     # elimination vs program speed. Recorded by ENGAGEMENT: the counters
-    # only exist when the feeder actually coalesced batches; the env
-    # gate alone is also recorded so an A/B arm is always identifiable.
+    # only exist when the feeder actually coalesced batches.
     from sparkdl_tpu.graph.function import input_donation_engaged
     from sparkdl_tpu.obs.report import feeder_summary as _feeder_summary
     from sparkdl_tpu.runtime.readback import async_readback_enabled
     from sparkdl_tpu.runtime.transfer import device_stage_enabled
-    from sparkdl_tpu.transformers.execution import (
-        device_preproc_enabled,
-        shared_feeder_enabled,
-    )
+    from sparkdl_tpu.transformers.execution import device_preproc_enabled
 
     feeder = _feeder_summary(obs_snap)
     # Compile-cache attribution comes from the module's reset-immune
@@ -1499,9 +1495,8 @@ def _measure(mode: str, platform: str) -> dict:
 
     cstats = _compile_cache.stats()
     compiled = cstats if any(cstats.values()) else None
-    # Staging overlap attribution rides the record even when the shared
-    # feeder stood down (sequential executors stage through run_batched):
-    # stage_hits proves copies were in flight BEFORE dispatch needed them.
+    # Staging overlap attribution: stage_hits proves copies were in
+    # flight BEFORE dispatch needed them.
     _counters = (obs_snap.get("metrics") or {}).get("counters") or {}
     staging = {
         k.split(".")[-1]: int(_counters.get(k, 0))
@@ -1511,7 +1506,6 @@ def _measure(mode: str, platform: str) -> dict:
         staging = {}  # both keys or neither, matching feeder_summary
     extras = {
         **extras,
-        "shared_feeder": shared_feeder_enabled(),
         # The feed-path A/B arms ride every record (the feeder block —
         # when present — additionally carries the async-readback and
         # device-staging hit/miss counters), so tools/bench_gate.py can

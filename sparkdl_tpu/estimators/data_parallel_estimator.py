@@ -118,10 +118,8 @@ class DataParallelModel(Model):
                 )
             else:
                 to_batch = arrays_to_batch
-            # Shared-feeder engine (same routing as every other
-            # transformer): concurrent partitions coalesce into one
-            # continuous-batching stream; single-partition runs and
-            # SPARKDL_SHARED_FEEDER=0 fall back to the legacy pipeline.
+            # Concurrent partitions coalesce into one continuous-batching
+            # stream of the shared feeder.
             outputs = run_batched_shared(
                 cells, to_batch=to_batch, device_fn=device_fn,
                 batch_size=self._batch_size,

@@ -33,7 +33,10 @@ from sparkdl_tpu.params import (
     keyword_only,
 )
 from sparkdl_tpu.pipeline import Estimator, Model
-from sparkdl_tpu.transformers.execution import arrays_to_batch, run_batched
+from sparkdl_tpu.transformers.execution import (
+    arrays_to_batch,
+    run_batched_shared,
+)
 
 
 class LogisticRegressionModel(Model):
@@ -91,7 +94,7 @@ class LogisticRegressionModel(Model):
         prob_col = self._probability_col
 
         def op(part):
-            probs = run_batched(
+            probs = run_batched_shared(
                 part[f_col],
                 to_batch=arrays_to_batch,
                 device_fn=self._jit,

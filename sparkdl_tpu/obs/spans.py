@@ -6,7 +6,7 @@ to the device stream), ``device_wait`` (blocking on a device result),
 ``executor.partition`` (one partition task), ``worker.partition`` (one
 gang-owned partition) — with free-form attributes (rows, bytes, chunk
 mode, partition index). Spans nest per thread: each thread carries its
-own stack, so the executor's partition threads and the batch-producer
+own stack, so the executor's partition threads and the feeder's owner
 thread trace independently and a child span's ``parent_id`` names the
 innermost open span *of its own thread*. Only a thread with no open span
 takes a parent named explicitly (``span(name, parent_id=...)``): the

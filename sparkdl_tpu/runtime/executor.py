@@ -13,8 +13,9 @@ in-tree"). This framework replaces that with a small in-tree runtime:
 - ``TaskMetrics`` — per-partition timing/row counts, aggregated into
   throughput numbers (images/sec — the BASELINE metric).
 
-Device-side batching/prefetch lives in sparkdl_tpu.transformers.execution
-(the pipelined ``run_batched`` engine).
+Device-side batching/prefetch lives in sparkdl_tpu.runtime.feeder (the
+shared ``DeviceFeeder``), entered through
+sparkdl_tpu.transformers.execution.run_batched_shared.
 """
 
 from __future__ import annotations
@@ -36,11 +37,12 @@ from sparkdl_tpu.utils.metrics import metrics as global_metrics
 @dataclass(frozen=True)
 class TaskContext:
     """What a partition task knows about the run it belongs to, published
-    thread-locally for the duration of ``fn(i, part)``. The shared device
-    feeder keys off ``concurrency`` (coalescing only pays when >1
-    partitions run AT ONCE — a sequential executor would add linger
-    latency for legacy-identical padding) and labels its streams with
-    ``partition_index`` so ordered per-partition results are preserved.
+    thread-locally for the duration of ``fn(i, part)``. The batch engine
+    reads ``concurrency`` (a task of a sequential executor is alone on the
+    shared device feeder, so its tail batch is flushed without the linger
+    in which a concurrent partition could still join; the text engine
+    sizes its tokenize chunks by it) and labels its streams with
+    ``partition_index``.
     ``parent_span_id`` carries the ``executor.map_partitions`` span across
     the hand-off to a pool thread, so the task's ``executor.partition``
     span hangs under it and one job's spans form one tree; it is tracing

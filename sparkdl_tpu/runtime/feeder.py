@@ -1,16 +1,16 @@
-"""Cross-partition continuous batching: the shared device feeder.
+"""The batch engine: one shared device feeder for every offline row.
 
-The engine's distribution strategy is embarrassingly-parallel inference
-over partitions, and until this module existed every partition paid for
-that independently: N concurrent ``Executor.map_partitions`` tasks each
-ran their own ``run_batched`` pipeline, so one device pool was fed by N
-competing dispatch loops and every partition's tail batch was zero-padded
-up to ``batch_size`` — with 64 partitions of ~100 rows at batch 32, >20%
-of dispatched device rows were padding. The TensorFlow paper's input
-pipelines decouple producers from a single coalesced device stream, and
-Horovod's tensor fusion shows that batching many small submissions into
-fewer large ones is where distributed throughput lives; this module is
-that serving-shaped pattern for the batched inference path.
+Every offline row reaches the device through a :class:`DeviceFeeder`
+(``transformers/execution.run_batched_shared`` -> :func:`run_shared`).
+The TensorFlow paper's input pipelines decouple producers from a single
+coalesced device stream, and Horovod's tensor fusion shows that batching
+many small submissions into fewer large ones is where distributed
+throughput lives; this module is that serving-shaped pattern for the
+batched inference path. N concurrent ``Executor.map_partitions`` tasks
+feed ONE dispatch loop — with 64 partitions of ~100 rows at batch 32, a
+dispatch loop per partition would pad >20% of the dispatched device
+rows — and a single partition, a direct call and a whole-mesh program
+are one-producer streams of the same loop.
 
 A :class:`DeviceFeeder` is shared per ``(device_fn, dispatch size, row
 shape, dtype)``. Partition threads stay the *host* stage — they run
@@ -19,19 +19,24 @@ of each chunk (null/undecodable cells never occupy device rows here).
 One owner thread per feeder assembles those row-chunks into full batches
 **across partition boundaries**, using a small ring of reusable
 pre-allocated buffers (no per-batch ``np.zeros``/``np.concatenate``
-churn), dispatches through the device fn's existing feed-plan/chunked-H2D
-path with the same ``prefetch`` in-flight window as the legacy engine,
-and scatters results back to each partition's output list via vectorized
-masked indexing. Only the final flush batch — emitted after a short
-linger once every producer has finished — is ever padded, so padding
-waste drops from one tail per partition to one tail per quiet period.
+churn), dispatches through the device fn's feed-plan/chunked-H2D path
+with a ``prefetch``-deep in-flight window, and scatters results back to
+each partition's output list via vectorized masked indexing. Only the
+final flush batch — emitted after a short linger once every producer
+has finished — is ever padded, so padding waste is one tail per quiet
+period. A producer that knows it is alone (no ``TaskContext``, or a
+sequential executor) says so when it opens its handle (``alone``), and
+the owner pads and flushes its tail the moment its stream ends: nobody
+could have joined it, so it waits out neither the poll nor the linger.
 
 Buffer-reuse safety: a dispatched batch may alias its ring buffer (the
 flat relayout is a view, and jax's CPU client can transfer numpy buffers
 zero-copy), so a buffer only returns to the free ring after its batch's
 result has been read back — never while the program might still be
 consuming it. The ring holds ``prefetch + 2`` buffers: one being filled,
-``prefetch`` in flight, one spare.
+``prefetch`` in flight, one spare. A device fn that hands its input back
+(an identity, a view) would leave the caller's answers aliasing that
+buffer, so the drain copies such a result before it scatters it.
 
 Asynchronous readback (the D2H half of the pipeline): the owner used to
 block in ``np.asarray(y_dev)`` inside its own dispatch loop — no new
@@ -78,8 +83,6 @@ applies as usual) and the feeder resets for subsequent work.
 
 Env knobs (all read per event, so tests can flip them live):
 
-- ``SPARKDL_SHARED_FEEDER`` (read by ``execution.run_batched_shared``):
-  default on; ``0`` restores the per-partition legacy path for A/B.
 - ``SPARKDL_FEEDER_LINGER_MS`` (default 20): how long the owner waits
   with a partial batch after the last producer ends before padding and
   flushing it — the window in which a newly-arriving partition can still
@@ -140,6 +143,20 @@ open_handle_policy = RetryPolicy(
 )
 
 
+def prefetch_per_device() -> int:
+    """In-flight device batches per device. The default (2) covers
+    host/device overlap when dispatch is cheap; a deeper window keeps
+    more transfers in flight — tune with SPARKDL_PREFETCH_PER_DEVICE.
+    More in-flight batches hold more input+output buffers (HBM
+    pressure), so the default stays 2."""
+    return knobs.get_int("SPARKDL_PREFETCH_PER_DEVICE")
+
+
+def default_prefetch(device_fn=None) -> int:
+    """In-flight window: prefetch_per_device() per participating device."""
+    return prefetch_per_device() * max(1, getattr(device_fn, "n_devices", 1))
+
+
 def _linger_s() -> float:
     return max(0.0, knobs.get_float("SPARKDL_FEEDER_LINGER_MS")) / 1e3
 
@@ -162,17 +179,21 @@ class _Handle:
     submitted and falls as their results scatter back; the event fires
     when the producer has ended its stream and every submitted row is
     accounted for. ``fail`` is sticky — the first error wins and wakes
-    the waiting partition immediately."""
+    the waiting partition immediately. ``alone`` is the producer's word
+    that no other stream can join its batches (see ``run_shared``)."""
 
     __slots__ = (
-        "feeder", "out", "partition", "_lock", "_event", "_pending",
-        "_ended", "error", "segments",
+        "feeder", "out", "partition", "alone", "_lock", "_event",
+        "_pending", "_ended", "error", "segments",
     )
 
-    def __init__(self, feeder: "DeviceFeeder", out: list, partition=None):
+    def __init__(
+        self, feeder: "DeviceFeeder", out: list, partition=None, alone=False
+    ):
         self.feeder = feeder
         self.out = out
         self.partition = partition
+        self.alone = alone
         self._lock = locksmith.lock(
             "sparkdl_tpu/runtime/feeder.py::_Handle._lock"
         )
@@ -303,8 +324,8 @@ class DeviceFeeder:
 
     # -- producer side ------------------------------------------------------
 
-    def open_handle(self, out: list, partition=None) -> _Handle:
-        h = _Handle(self, out, partition)
+    def open_handle(self, out: list, partition=None, alone=False) -> _Handle:
+        h = _Handle(self, out, partition, alone)
         with self._lock:
             if self._closed:
                 raise RuntimeError("DeviceFeeder is closed")
@@ -420,17 +441,7 @@ class DeviceFeeder:
                     if flush_at is None:
                         flush_at = now + _linger_s()
                     if now >= flush_at:
-                        try:
-                            if self._fill:
-                                # Tail-flush accounting lives HERE, at the
-                                # call site, so a tail that happens to be
-                                # exactly full (pad == 0) still counts —
-                                # _flush's pad branch only owns pad_rows.
-                                metrics.inc("feeder.flushes")
-                                self._flush()
-                            self._settle_inflight()
-                        except BaseException as e:  # noqa: BLE001
-                            self._fail_all(e)
+                        self._flush_tail()
                         flush_at = None
                         last_work = time.monotonic()
                 elif open_producers == 0:
@@ -477,10 +488,20 @@ class DeviceFeeder:
             if kind == "end":
                 with self._lock:
                     self._open -= 1
+                    quiet = self._open == 0
                     self._handles = {
                         h for h in self._handles if not h._event.is_set()
                     }
                     metrics.gauge("feeder.open_producers", self._open)
+                if (
+                    quiet
+                    and item[1].alone
+                    and all(h.alone for h, _, _ in self._segs)
+                ):
+                    # The last producer was alone and said so: no other
+                    # stream can join its tail, so the poll and the
+                    # linger would buy nothing but latency.
+                    self._flush_tail()
                 continue
             _, handle, dest_idx, rows = item
             if handle.failed:
@@ -489,6 +510,20 @@ class DeviceFeeder:
                 self._append_rows(handle, dest_idx, rows)
             except BaseException as e:  # noqa: BLE001
                 self._fail_all(e)
+
+    def _flush_tail(self) -> None:
+        """Quiet period: pad and flush the ONE part-filled tail batch,
+        then see every dispatched batch's result home."""
+        try:
+            if self._fill:
+                # Tail-flush accounting lives HERE, not in _flush, so a
+                # tail that happens to be exactly full (pad == 0) still
+                # counts — _flush's pad branch only owns pad_rows.
+                metrics.inc("feeder.flushes")
+                self._flush()
+            self._settle_inflight()
+        except BaseException as e:  # noqa: BLE001
+            self._fail_all(e)
 
     def _append_rows(self, handle: _Handle, dest_idx: np.ndarray, rows: np.ndarray) -> None:
         if self._cur is None:  # a failed flush left no current buffer
@@ -762,6 +797,10 @@ class DeviceFeeder:
                 "drain_wait" if arm else "device_wait", rows=fill, feeder=True
             ):
                 y = readback.to_host(y_dev)
+                if np.may_share_memory(y, buf):
+                    # the device fn handed its input back: the rows would
+                    # alias a buffer that returns to the ring below
+                    y = y.copy()
             dt = time.perf_counter() - t0
             metrics.record_time("transform.device_wait", dt)
             if dt > 0:
@@ -1078,17 +1117,31 @@ def run_shared(
     prefetch: Optional[int] = None,
     partition=None,
     stream: Optional[Callable] = None,
+    alone: bool = False,
 ) -> List[Optional[np.ndarray]]:
-    """Shared-feeder equivalent of ``run_batched``: same signature shape,
-    same per-cell output contract (ndarray rows, None where masked out).
+    """Map ``device_fn`` over ``cells`` in fixed-size batches: one output
+    per cell, ndarray rows, None where masked out.
+
+    Args:
+        cells: partition column values (may contain None).
+        to_batch: host stage: list of cells -> (batch array, bool mask).
+        device_fn: jitted fn over one full batch (static shape).
+        batch_size: device batch size (times the device fn's
+            ``batch_multiplier``); only a tail batch is zero-padded to it.
+        prefetch: max batches in flight on the device ahead of readback;
+            defaults to 2 per participating device.
+        partition: the caller's partition index, for its spans.
+        alone: the caller knows no other producer runs beside it (a
+            direct call, a sequential executor), so its tail is flushed
+            when its stream ends, without the linger.
 
     The calling partition thread stays the host stage: it runs
     ``to_batch`` chunk by chunk (decode/tokenize overlapped across
     partitions by the executor's worker threads), compresses each chunk
     to its valid rows with vectorized masked indexing, and streams them
     into the feeder keyed by the observed row shape — so workloads whose
-    row shape varies between chunks (legal on the legacy path, which
-    recompiles per shape) transparently use one feeder per shape.
+    row shape varies between chunks transparently use one feeder per
+    shape.
 
     A host stage that is no ``to_batch`` — its rows leave a chunk in
     several shapes, or in chunks of its own size: the text engine's
@@ -1098,8 +1151,6 @@ def run_shared(
     ``dest_idx[k]``. Every handle stays open until the stage is
     exhausted: the owners pad and flush a part-filled batch only once no
     producer is open, so handing rows over early costs no padding."""
-    from sparkdl_tpu.transformers.execution import default_prefetch
-
     dispatch_rows = batch_size * getattr(device_fn, "batch_multiplier", 1)
     if prefetch is None:
         prefetch = default_prefetch(device_fn)
@@ -1128,7 +1179,9 @@ def run_shared(
                         device_fn, dispatch_rows, rows.shape[1:],
                         rows.dtype, prefetch,
                     )
-                    return feeder.open_handle(out, partition=partition)
+                    return feeder.open_handle(
+                        out, partition=partition, alone=alone
+                    )
 
                 try:
                     handle = open_handle_policy.call(_open)

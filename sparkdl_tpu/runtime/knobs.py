@@ -273,7 +273,7 @@ declare(
     "SPARKDL_PREFETCH_PER_DEVICE", "int", "2",
     "in-flight batches per device in the batched engine (more overlap, "
     "more HBM held by input+output buffers)",
-    "transformers/execution.py",
+    "runtime/feeder.py",
 )
 declare(
     "SPARKDL_INFERENCE_DEVICES", "int", None,
@@ -286,12 +286,6 @@ declare(
     "batch spread over the local pool: one mesh-sharded SPMD program "
     "('shard_map') or per-device round-robin dispatch ('roundrobin')",
     "transformers/execution.py", choices=("roundrobin", "shard_map"),
-)
-declare(
-    "SPARKDL_SHARED_FEEDER", "flag", "1",
-    "cross-partition continuous batching via the shared DeviceFeeder; "
-    "0/off restores the per-partition legacy run_batched path (A/B arm)",
-    "transformers/execution.py",
 )
 declare(
     "SPARKDL_DEVICE_PREPROC", "flag", "0",

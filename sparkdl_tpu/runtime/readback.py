@@ -8,9 +8,9 @@ not measured (PERF.md). The TensorFlow dataflow design and the CUDA-aware-MPI ch
 (PAPERS.md) both make the same point — transfers must overlap compute
 in *both* directions.
 
-This module is the one shared place both dispatch paths
-(``transformers/execution.run_batched`` and the shared
-``runtime/feeder.DeviceFeeder``) get that overlap from:
+This module is where the dispatch paths (the offline engine's and the
+serving router's ``runtime/feeder.DeviceFeeder`` streams) get that
+overlap from:
 
 - :func:`start_copy` — issue the device array's ``copy_to_host_async()``
   at DISPATCH time, so the D2H transfer rides under the device's compute
@@ -25,7 +25,7 @@ This module is the one shared place both dispatch paths
   output list: one C-level slice assignment when the destination indices
   are one contiguous run (the common no-nulls case), a native-int loop
   over pre-unpacked row views otherwise — replacing the per-row Python
-  ``out[d] = rows[k]`` loop in both drain paths.
+  ``out[d] = rows[k]`` loop in the drain.
 
 Env knob: ``SPARKDL_ASYNC_READBACK`` (default on; ``0``/``off`` restores
 the fully synchronous legacy drain — the A/B arm and escape hatch, house

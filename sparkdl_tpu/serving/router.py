@@ -995,8 +995,7 @@ class Router:
         push it through the (device_fn, geometry) feeder stream. Exact
         fill means the feeder flushes every batch immediately — no
         linger on the serving path."""
-        from sparkdl_tpu.runtime.feeder import get_feeder
-        from sparkdl_tpu.transformers.execution import default_prefetch
+        from sparkdl_tpu.runtime.feeder import default_prefetch, get_feeder
 
         # Waterfall edges: queue_wait ends at the pop stamp, group_wait
         # ends HERE — so the batch window, the worker-slot wait, the

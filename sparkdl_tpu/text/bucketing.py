@@ -235,28 +235,23 @@ def run_bucketed(
     ladder: Optional[Sequence[int]] = None,
 ) -> List[Optional[np.ndarray]]:
     """Length-aware equivalent of the pad-to-``max_length`` text loop:
-    same per-cell output contract as ``run_batched`` (ndarray rows,
-    None where the cell was null or tokenization failed).
+    same per-cell output contract as ``run_batched_shared`` (ndarray
+    rows, None where the cell was null or tokenization failed).
 
     Tokenization runs on the partition thread, and a row's length
-    decides its routing — a row's, not the partition's. On the
-    shared-feeder path (``shared_feeder_context``) the partition is
+    decides its routing — a row's, not the partition's. The partition is
     tokenized in chunks, and each chunk's rows go to their (device_fn,
     bucket) feeder streams as soon as the chunk is routed, largest edge
     first: the device starts on the first batch that the concurrent
     partitions fill TOGETHER while they tokenize the rest, so a chunk is
     a quarter of one partition's share of a dispatched batch: its rows
-    over the observed concurrency, in ``_CHUNKS_PER_SHARE``. Every
-    bucket's stream stays open until the partition's last chunk is in
-    (``feeder.run_shared``'s ``stream``), so no part-filled batch is
-    flushed while rows are still to come, and the device fn compiles one
-    program per bucket it actually sees.
-
-    The legacy engine (one partition at a time, ``SPARKDL_SHARED_FEEDER=0``)
-    takes the whole partition as one chunk and runs its buckets one
-    after another, largest first: the longest sequences are the slowest
-    programs.
+    over the observed concurrency (1 outside an executor), in
+    ``_CHUNKS_PER_SHARE``. Every bucket's stream stays open until the
+    partition's last chunk is in (``feeder.run_shared``'s ``stream``),
+    so no part-filled batch is flushed while rows are still to come, and
+    the device fn compiles one program per bucket it actually sees.
     """
+    from sparkdl_tpu.runtime.executor import current_task_context
     from sparkdl_tpu.runtime.feeder import ingest_span
     from sparkdl_tpu.transformers import execution
 
@@ -264,27 +259,14 @@ def run_bucketed(
     if n == 0:
         return []
     ladder = tuple(ladder) if ladder is not None else bucket_ladder(max_length)
-    ctx = execution.shared_feeder_context(device_fn)
-    if ctx is None:
-        out: List[Optional[np.ndarray]] = [None] * n
-        routed = _route_chunk(cells, 0, n, tokenize, ladder)
-        for b in sorted(routed, reverse=True):
-            idxs, rows = routed[b]
-
-            def to_batch(chunk, _b=b):
-                return _pack(chunk, _b), np.ones((len(chunk),), bool)
-
-            results = execution.run_batched_shared(
-                rows, to_batch, device_fn, batch_size, prefetch=prefetch
-            )
-            for i, y in zip(idxs, results):
-                out[i] = y
-        return out
+    ctx = current_task_context()
+    concurrency = 1 if ctx is None else max(1, ctx.concurrency)
+    partition = None if ctx is None else ctx.partition_index
 
     def stream(dispatch_rows):
         chunk_rows = max(
             _MIN_CHUNK_ROWS,
-            dispatch_rows // (ctx.concurrency * _CHUNKS_PER_SHARE),
+            dispatch_rows // (concurrency * _CHUNKS_PER_SHARE),
         )
         for start in range(0, n, chunk_rows):
             routed = _route_chunk(
@@ -292,7 +274,7 @@ def run_bucketed(
             )
             for b in sorted(routed, reverse=True):
                 idxs, rows = routed[b]
-                with ingest_span(start, ctx.partition_index) as sp:
+                with ingest_span(start, partition) as sp:
                     batch = _pack(rows, b)
                     sp.add(rows=len(rows), bytes=int(batch.nbytes))
                 yield np.asarray(idxs), batch

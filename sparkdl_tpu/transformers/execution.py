@@ -9,31 +9,13 @@ unpadded after. Invalid rows (nulls, undecodable images) ride through as
 zero rows with mask=False and come back as None cells — the reference's
 null-row semantics, preserved through the batched path.
 
-TPU-first pipelining: the loop is a three-stage software pipeline —
-
-  host assembly (background thread) → device dispatch → D2H readback
-
-JAX dispatch is asynchronous: ``device_fn(batch)`` returns a device array
-future immediately and the TPU runs the program in the background. The
-host thread therefore keeps a window of ``prefetch`` batches in flight,
-assembling batch i+2 (decode/resize in numpy or the C++ bridge) while the
-device computes batch i+1 and batch i's output streams back over PCIe.
-Without this overlap the chip idles during every host batch-assembly.
-
-The readback half is pipelined too (``SPARKDL_ASYNC_READBACK``, default
-on): each dispatched result's ``copy_to_host_async()`` is issued at
-dispatch time via ``runtime/readback.py``, so by the time the drain loop
-reaches a batch its D2H transfer has been streaming under the later
-dispatches — the drain pays only the residual (the ``drain_wait`` span;
-the legacy synchronous arm keeps the ``device_wait`` name).
-
-And so is the input half (``SPARKDL_DEVICE_STAGE``, default on, both
-engines): when the device fn exposes its transfer half (``stage_put``),
-each popped batch's H2D copy is issued on the staging pool
-(``runtime/transfer.py``) the moment it leaves the producer queue, so
-batch N+1's copy lands in its device staging slot while batch N
-computes and the dispatch call itself never waits on a transfer
-(``transfer.stage_hits``/``stage_misses``; residual = ``stage_wait``).
+The batch engine itself is ``runtime/feeder.py``'s ``DeviceFeeder``:
+:func:`run_batched_shared` is the one entry every transformer, UDF and
+estimator calls, and this module holds what is decided before a batch
+reaches it — which devices, which inference mode, which feed plan — and
+the device fns built from those decisions. The feeder's own docstring
+describes the pipeline (host assembly on the partition threads → owner
+thread dispatch with staged H2D → drainer thread readback).
 """
 
 from __future__ import annotations
@@ -42,22 +24,18 @@ import itertools
 import queue
 import threading
 import time
-from collections import deque
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from sparkdl_tpu.obs import span
-from sparkdl_tpu.runtime import knobs, readback, transfer
+from sparkdl_tpu.runtime import feeder, knobs
+from sparkdl_tpu.runtime.executor import current_task_context
+from sparkdl_tpu.runtime.feeder import (  # noqa: F401 — re-exported
+    default_prefetch,
+    prefetch_per_device,
+)
 from sparkdl_tpu.utils.metrics import metrics
-
-def prefetch_per_device() -> int:
-    """In-flight device batches per device. The default (2) covers
-    host/device overlap when dispatch is cheap; a deeper window keeps
-    more transfers in flight — tune with SPARKDL_PREFETCH_PER_DEVICE.
-    More in-flight batches hold more input+output buffers (HBM
-    pressure), so the default stays 2."""
-    return knobs.get_int("SPARKDL_PREFETCH_PER_DEVICE")
 
 
 def inference_devices() -> list:
@@ -83,7 +61,7 @@ def inference_mode() -> str:
     - ``shard_map`` (default): ONE mesh-sharded program whose global
       batch (batchSize x n_devices) splits across the 'dp' mesh — the
       mesh-native SPMD formulation (one executable, one dispatch per
-      global batch; same per-device batch via run_batched's
+      global batch; same per-device batch via the feeder's
       batch_multiplier). Not timed against round-robin on real chips.
     - ``roundrobin``: successive batches land on successive devices — N
       independent single-device executables, N batches in flight; zero
@@ -242,8 +220,8 @@ def model_device_fn(model_function, jitted=None, mesh_width=None):
 
         single.n_devices = 1
         single.mesh_width = 1
-        # whole-mesh programs keep their partition-owned dispatch loops;
-        # the shared feeder only coalesces roundrobin/shard_map fns
+        # read by TextEmbedder: the sequence sharding was built for one
+        # geometry, so such a fn takes no length buckets
         single.single_stream = True
         return single
     if mesh_width is not None:
@@ -264,7 +242,7 @@ def sharded_data_parallel_fn(device_fn, devices=None, donate=False):
     every device. The alternative to per-device round-robin: one cached
     executable instead of N, one dispatch per global batch instead of N
     host-thread rotations; per-device rows stay equal to the configured
-    batch size because ``run_batched`` scales dispatch size by
+    batch size because ``feeder.run_shared`` scales dispatch size by
     ``batch_multiplier``. ``device_fn`` therefore sees the PER-DEVICE
     batch, exactly as it would on one chip.
 
@@ -336,7 +314,7 @@ def data_parallel_device_fn(device_fn, devices=None):
 
     jax dispatch is asynchronous, so with a prefetch window >= the device
     count, N devices run N different batches concurrently; results are
-    read back (and re-ordered by row index) in ``run_batched``. The
+    read back (and re-ordered by row index) by the feeder. The
     compiled executable is cached per device by jax's jit cache; captured
     params are materialized once per device. With one device this reduces
     to an explicit device_put to it — same behavior, no rotation."""
@@ -369,10 +347,6 @@ def data_parallel_device_fn(device_fn, devices=None):
     return fn
 
 
-def default_prefetch(device_fn=None) -> int:
-    """In-flight window: prefetch_per_device() per participating device."""
-    return prefetch_per_device() * max(1, getattr(device_fn, "n_devices", 1))
-
 _SENTINEL = object()
 
 
@@ -400,8 +374,7 @@ def prefetch_iter(gen, depth: int = 2):
     the consumer with their traceback; abandoning the returned iterator
     (break/raise/GC) stops the producer — every put, including the
     terminal sentinel/exception, goes through :func:`_put_or_stop`, so a
-    full queue can never wedge the thread. Used by the streaming trainer;
-    the batched inference path has its own specialized producer below."""
+    full queue can never wedge the thread. Used by the streaming trainer."""
     q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
 
@@ -430,199 +403,6 @@ def prefetch_iter(gen, depth: int = 2):
         stop.set()
 
 
-def _batch_producer(
-    cells: Sequence,
-    to_batch: Callable[[Sequence], Tuple[np.ndarray, np.ndarray]],
-    batch_size: int,
-    out_q: "queue.Queue",
-    stop: threading.Event,
-    host_prepare: Optional[Callable[[np.ndarray], np.ndarray]] = None,
-) -> None:
-    """Host stage, run on a background thread: assemble padded fixed-size
-    batches (plus the device fn's host_prepare relayout, if any) and hand
-    them to the dispatch loop through a bounded queue."""
-    try:
-        n = len(cells)
-        for start in range(0, n, batch_size):
-            if stop.is_set():
-                return
-            t0 = time.perf_counter()
-            with span("ingest", batch_start=start) as sp:
-                chunk = list(cells[start : start + batch_size])
-                pad = batch_size - len(chunk)
-                batch, mask = to_batch(chunk)
-                if pad and mask.any():
-                    pad_shape = (pad, *batch.shape[1:])
-                    batch = np.concatenate(
-                        [batch, np.zeros(pad_shape, dtype=batch.dtype)],
-                        axis=0,
-                    )
-                if host_prepare is not None and mask.any():
-                    batch = host_prepare(batch)
-                sp.add(
-                    rows=int(mask.sum()),
-                    bytes=int(getattr(batch, "nbytes", 0)),
-                )
-            metrics.record_time(
-                "transform.host_batch", time.perf_counter() - t0
-            )
-            if not _put_or_stop(out_q, (start, batch, mask), stop):
-                return
-        _put_or_stop(out_q, _SENTINEL, stop)
-    except BaseException as e:  # propagate into the consumer loop
-        _put_or_stop(out_q, e, stop)
-
-
-def run_batched(
-    cells: Sequence,
-    to_batch: Callable[[Sequence], Tuple[np.ndarray, np.ndarray]],
-    device_fn: Callable[[np.ndarray], np.ndarray],
-    batch_size: int,
-    prefetch: Optional[int] = None,
-) -> List[Optional[np.ndarray]]:
-    """Map ``device_fn`` over ``cells`` in fixed-size batches, pipelined.
-
-    Args:
-        cells: partition column values (may contain None).
-        to_batch: host stage: list of cells -> (batch array, bool mask).
-        device_fn: jitted fn over one full batch (static shape).
-        batch_size: device batch size; last batch is zero-padded to it.
-        prefetch: max batches in flight on the device ahead of readback;
-            defaults to 2 per participating device (so a multi-device
-            ``data_parallel_device_fn`` keeps every chip busy).
-
-    Returns one output per cell: np.ndarray rows, or None where masked out.
-    """
-    # shard_map-mode device fns consume (batchSize x n_devices)-row global
-    # batches so each device still sees batchSize rows per program
-    batch_size *= getattr(device_fn, "batch_multiplier", 1)
-    if prefetch is None:
-        prefetch = default_prefetch(device_fn)
-    n = len(cells)
-    out: List[Optional[np.ndarray]] = [None] * n
-    if n == 0:
-        return out
-
-    # Bounded handoff queue: producer stays at most `prefetch` batches
-    # ahead, so host memory for assembled-but-undispatched batches is
-    # bounded by prefetch * batch bytes.
-    q: "queue.Queue" = queue.Queue(maxsize=max(1, prefetch))
-    stop = threading.Event()
-    producer = threading.Thread(
-        target=_batch_producer,
-        name="sparkdl-batch-producer",
-        args=(
-            cells,
-            to_batch,
-            batch_size,
-            q,
-            stop,
-            getattr(device_fn, "host_prepare", None),
-        ),
-        daemon=True,
-    )
-    producer.start()
-
-    def drain_one(inflight):
-        start, mask, y_dev, arm = inflight.popleft()
-        valid = np.flatnonzero(mask)
-        t0 = time.perf_counter()
-        # drain_wait (async-readback arm) = the residual wait after the
-        # dispatch-time copy_to_host_async; device_wait (legacy arm) =
-        # the full block on program completion + D2H.
-        with span(
-            "drain_wait" if arm else "device_wait",
-            batch_start=start,
-            rows=int(len(valid)),
-        ):
-            y = np.asarray(y_dev)  # blocks until this batch's result lands
-        metrics.record_time("transform.device_wait", time.perf_counter() - t0)
-        metrics.inc("transform.rows", int(len(valid)))
-        readback.scatter_rows(
-            out,
-            start + valid,
-            y if len(valid) == len(mask) else y[valid],
-        )
-
-    inflight: deque = deque()
-    # Device-side input staging (same arm as the shared feeder): batches
-    # popped from the producer queue hand their H2D copy to the staging
-    # pool immediately; dispatch claims the oldest slot once the ring is
-    # stage_depth ahead (or the queue runs dry — a shallow stream gains
-    # nothing from holding a packed batch). Engages only when the device
-    # fn exposes its transfer half.
-    staged: deque = deque()
-    stage_fn = getattr(device_fn, "stage_put", None)
-
-    def dispatch_one(start, batch, mask):
-        # Async dispatch: returns a device-array future; TPU runs in
-        # the background while we assemble/readback other batches.
-        while len(inflight) >= max(1, prefetch):
-            drain_one(inflight)  # cap device residency at `prefetch`
-        # The dispatch span measures the SYNCHRONOUS slice of the
-        # device call (argument transfer + enqueue); the program's
-        # run time shows up in the matching drain_wait/device_wait span.
-        with span(
-            "dispatch",
-            batch_start=start,
-            rows=int(mask.sum()),
-            bytes=int(getattr(batch, "nbytes", 0)),
-        ):
-            y_dev = device_fn(batch)
-        arm = readback.async_readback_enabled()
-        if arm:
-            # D2H starts now, overlapped under the next dispatches,
-            # instead of when drain_one finally blocks on this batch.
-            readback.start_copy(y_dev)
-        inflight.append((start, mask, y_dev, arm))
-
-    try:
-        while True:
-            item = q.get()
-            if item is _SENTINEL:
-                break
-            if isinstance(item, BaseException):
-                raise item
-            start, batch, mask = item
-            if not mask.any():
-                continue  # every row null/undecodable: nothing to run
-            if stage_fn is not None and transfer.device_stage_enabled():
-                staged.append(
-                    (
-                        start,
-                        mask,
-                        transfer.stage_batch(
-                            stage_fn, batch, rows=int(mask.sum())
-                        ),
-                    )
-                )
-                while len(staged) >= transfer.stage_depth() or (
-                    staged and q.empty()
-                ):
-                    s_start, s_mask, slot = staged.popleft()
-                    dispatch_one(s_start, slot.take(), s_mask)
-            else:
-                dispatch_one(start, batch, mask)
-        while staged:
-            s_start, s_mask, slot = staged.popleft()
-            dispatch_one(s_start, slot.take(), s_mask)
-        while inflight:
-            drain_one(inflight)
-    finally:
-        stop.set()
-        while staged:  # error path: the pool must stop reading buffers
-            staged.popleft()[2].settle()
-        producer.join(timeout=5.0)
-    return out
-
-
-def shared_feeder_enabled() -> bool:
-    """SPARKDL_SHARED_FEEDER gates cross-partition continuous batching
-    (default ON; 0/off restores the per-partition legacy path — the A/B
-    arm and the escape hatch)."""
-    return knobs.get_flag("SPARKDL_SHARED_FEEDER")
-
-
 def device_preproc_enabled() -> bool:
     """SPARKDL_DEVICE_PREPROC gates the on-device image preprocessing
     arm: resize (and the normalize it feeds) move INSIDE the jitted
@@ -635,23 +415,6 @@ def device_preproc_enabled() -> bool:
     return knobs.get_flag("SPARKDL_DEVICE_PREPROC")
 
 
-def shared_feeder_context(device_fn: Callable):
-    """The TaskContext of this partition call where its rows go through
-    the shared DeviceFeeder, None where the legacy per-partition engine
-    runs them: the one choice between the two engines."""
-    from sparkdl_tpu.runtime.executor import current_task_context
-
-    ctx = current_task_context()
-    if (
-        not shared_feeder_enabled()
-        or ctx is None
-        or getattr(ctx, "concurrency", ctx.num_partitions) <= 1
-        or getattr(device_fn, "single_stream", False)
-    ):
-        return None
-    return ctx
-
-
 def run_batched_shared(
     cells: Sequence,
     to_batch: Optional[Callable[[Sequence], Tuple[np.ndarray, np.ndarray]]],
@@ -660,35 +423,26 @@ def run_batched_shared(
     prefetch: Optional[int] = None,
     stream: Optional[Callable] = None,
 ) -> List[Optional[np.ndarray]]:
-    """``run_batched`` that coalesces across concurrent partitions.
+    """Map ``device_fn`` over ``cells`` in fixed-size batches through the
+    shared ``DeviceFeeder`` (``feeder.run_shared``, whose arguments these
+    are): one output per cell, np.ndarray rows, or None where masked out.
 
-    When the executor is running this call as one of >1 partitions (it
-    publishes a TaskContext on the partition thread) and the shared
-    feeder is enabled, rows stream into the per-(device_fn, batch
-    geometry) DeviceFeeder so N partitions feed ONE dispatch loop with
-    full batches packed across partition boundaries — only the final
-    quiet-period flush is ever padded, instead of every partition's tail.
-    Whole-mesh ``single_stream`` fns and single-partition runs keep the
-    legacy per-partition pipeline; so does ``SPARKDL_SHARED_FEEDER=0``.
-    Output contract is identical to :func:`run_batched`.
-
-    ``stream`` is ``run_shared``'s: the host stage of a caller whose rows
-    leave in several shapes, in place of ``to_batch`` (then None). The
-    legacy engine has no such stage, so that caller asks
-    :func:`shared_feeder_context` first."""
-    ctx = shared_feeder_context(device_fn)
-    if ctx is None:
-        return run_batched(cells, to_batch, device_fn, batch_size, prefetch)
-    from sparkdl_tpu.runtime.feeder import run_shared
-
-    return run_shared(
+    Called as one of several concurrent partitions (the executor
+    publishes a TaskContext on the partition thread), the rows pack into
+    full batches across partition boundaries. Called directly, or from a
+    sequential executor, the call is a one-producer stream of the same
+    engine and says so (``alone``), so its tail batch is not held back
+    for partitions that cannot come."""
+    ctx = current_task_context()
+    return feeder.run_shared(
         device_fn,
         cells,
         to_batch,
         batch_size,
         prefetch=prefetch,
-        partition=ctx.partition_index,
+        partition=None if ctx is None else ctx.partition_index,
         stream=stream,
+        alone=ctx is None or ctx.concurrency <= 1,
     )
 
 
@@ -819,10 +573,9 @@ def flat_device_fn(pipeline_mf, batch_shape, devices=None):
     _warmed: list = []
 
     def device_fn(batch: np.ndarray):
-        # Already-flat batches were prepared on the producer thread
-        # (run_batched applies .host_prepare there, keeping the copy off
-        # the dispatch critical path); N-D batches from direct callers
-        # are prepared here.
+        # Already-flat batches were prepared by the feeder's owner
+        # (.host_prepare at flush); N-D batches from direct callers are
+        # prepared here.
         b = batch if batch.ndim == 1 else host_prepare(batch)
         if _warmed:
             return _dispatch(b)

@@ -141,7 +141,6 @@ def registerModelUDF(
     from sparkdl_tpu.transformers.execution import (
         arrays_to_batch,
         model_device_fn,
-        run_batched,
         run_batched_shared,
     )
 
@@ -149,7 +148,7 @@ def registerModelUDF(
     tb = to_batch or arrays_to_batch
 
     def partition_fn(cells):
-        return run_batched(
+        return run_batched_shared(
             cells, to_batch=tb, device_fn=device_fn, batch_size=batch_size
         )
 
@@ -218,7 +217,6 @@ def registerImageUDF(
     from sparkdl_tpu.transformers.execution import (
         flat_device_fn,
         model_device_fn,
-        run_batched,
         run_batched_shared,
     )
 
@@ -295,7 +293,7 @@ def registerImageUDF(
             )
 
     def partition_fn(cells):
-        return run_batched(
+        return run_batched_shared(
             cells,
             to_batch=to_batch,
             device_fn=device_fn,

@@ -6,7 +6,7 @@ are first-class: transformers and estimators record counters/timers into a
 process-global registry, and the throughput numbers that bench.py
 reports (images/sec/chip, step time) are computed from these.
 
-Thread-safe: executor partition threads and the batch-producer threads all
+Thread-safe: executor partition threads and the feeders' owner threads all
 record concurrently.
 """
 

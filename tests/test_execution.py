@@ -8,7 +8,10 @@ batches, null-mask passthrough, ordering — independent of any model.
 import numpy as np
 import pytest
 
-from sparkdl_tpu.transformers.execution import arrays_to_batch, run_batched
+from sparkdl_tpu.transformers.execution import (
+    arrays_to_batch,
+    run_batched_shared,
+)
 
 
 def _identity_batcher(chunk):
@@ -30,7 +33,7 @@ def test_ordering_and_padding():
         calls.append(b.shape)
         return b * 2.0
 
-    out = run_batched(cells, _identity_batcher, device_fn, batch_size=4)
+    out = run_batched_shared(cells, _identity_batcher, device_fn, batch_size=4)
     assert all(s == (4, 2) for s in calls)  # last batch padded to 4
     assert len(calls) == 3
     for i, o in enumerate(out):
@@ -39,7 +42,7 @@ def test_ordering_and_padding():
 
 def test_null_rows_stay_null():
     cells = [np.ones(2, dtype=np.float32), None, np.full(2, 3.0), None]
-    out = run_batched(
+    out = run_batched_shared(
         cells, _identity_batcher, lambda b: b + 1.0, batch_size=2
     )
     assert out[1] is None and out[3] is None
@@ -55,19 +58,19 @@ def test_all_null_batch_skips_device():
         n_calls.append(1)
         return b
 
-    out = run_batched(cells, _identity_batcher, device_fn, batch_size=2)
+    out = run_batched_shared(cells, _identity_batcher, device_fn, batch_size=2)
     assert sum(n_calls) == 1  # the two all-null batches never dispatch
     assert out[:4] == [None, None, None, None]
     assert out[4] is not None
 
 
 def test_empty_input():
-    assert run_batched([], _identity_batcher, lambda b: b, batch_size=4) == []
+    assert run_batched_shared([], _identity_batcher, lambda b: b, batch_size=4) == []
 
 
 def test_prefetch_larger_than_batches():
     cells = [np.full(2, i, dtype=np.float32) for i in range(3)]
-    out = run_batched(
+    out = run_batched_shared(
         cells, _identity_batcher, lambda b: b, batch_size=2, prefetch=16
     )
     assert len(out) == 3
@@ -79,7 +82,7 @@ def test_host_stage_exception_propagates():
         raise ValueError("decode exploded")
 
     with pytest.raises(ValueError, match="decode exploded"):
-        run_batched([1, 2, 3], bad_batcher, lambda b: b, batch_size=2)
+        run_batched_shared([1, 2, 3], bad_batcher, lambda b: b, batch_size=2)
 
 
 def test_arrays_to_batch_shape_mismatch():
@@ -123,7 +126,7 @@ def test_data_parallel_device_fn_round_robins_all_devices():
     dp_fn = data_parallel_device_fn(lambda b: spy(b), devices=devs)
     assert default_prefetch(dp_fn) == 16
     cells = [np.full(2, i, dtype=np.float32) for i in range(16)]
-    out = run_batched(cells, _identity_batcher, dp_fn, batch_size=2)
+    out = run_batched_shared(cells, _identity_batcher, dp_fn, batch_size=2)
     used = set().union(*seen)
     assert used == set(devs)  # every device got work
     for i, o in enumerate(out):
@@ -210,7 +213,7 @@ def test_flat_device_fn_uses_nchw_for_images():
     fn = flat_device_fn(mf, (3, 4, 5, 3))
     assert hasattr(fn, "host_prepare")  # producer-thread relayout hook
     np.testing.assert_array_equal(np.asarray(fn(batch)), batch)
-    # the prepared-flat path (what run_batched's producer feeds) agrees
+    # the prepared-flat path (what the feeder's owner dispatches) agrees
     np.testing.assert_array_equal(
         np.asarray(fn(fn.host_prepare(batch))), batch
     )
@@ -364,12 +367,12 @@ def test_prefetch_env_knob(monkeypatch):
     from sparkdl_tpu.transformers.execution import default_prefetch
 
     cells = [np.full(2, i, dtype=np.float32) for i in range(7)]
-    baseline = run_batched(
+    baseline = run_batched_shared(
         cells, _identity_batcher, lambda b: b, batch_size=2
     )
     monkeypatch.setenv("SPARKDL_PREFETCH_PER_DEVICE", "8")
     assert default_prefetch() == 8
-    deep = run_batched(cells, _identity_batcher, lambda b: b, batch_size=2)
+    deep = run_batched_shared(cells, _identity_batcher, lambda b: b, batch_size=2)
     assert len(deep) == len(baseline) == 7
     for a, b in zip(deep, baseline):
         np.testing.assert_array_equal(a, b)

@@ -1,12 +1,13 @@
 """Cross-partition continuous batching (runtime/feeder.py) + the
 executor/engine changes that ride along with it.
 
-The shared DeviceFeeder replaces N per-partition dispatch loops with one
-owner thread packing rows across partition boundaries; these tests pin
-its contract: output parity with the legacy per-partition path (Nones
-included, ordered), padding accounting (ONE tail flush per quiet period,
-not one padded tail per partition), producer-exception propagation, and
-an owner thread that can never be wedged by an abandoned consumer.
+The shared DeviceFeeder is the batch engine: one owner thread packs the
+rows of every producer across partition boundaries, and a lone partition
+or a direct call is a one-producer stream of it. These tests pin its
+contract: right answers in the right cells (Nones included, ordered),
+padding accounting (ONE tail flush per quiet period, not one padded tail
+per partition), producer-exception propagation, and an owner thread that
+can never be wedged by an abandoned consumer.
 
 The async-readback arm (runtime/readback.py + the feeder's drainer
 thread, SPARKDL_ASYNC_READBACK) rides the same contract: both arms must
@@ -31,9 +32,7 @@ from sparkdl_tpu.runtime import readback
 from sparkdl_tpu.runtime.feeder import run_shared, shutdown_feeders
 from sparkdl_tpu.transformers.execution import (
     arrays_to_batch,
-    run_batched,
     run_batched_shared,
-    shared_feeder_enabled,
 )
 from sparkdl_tpu.utils.metrics import metrics
 
@@ -92,73 +91,188 @@ def _run_parts(parts, device_fn, batch_size, max_workers=None, prefetch=None):
     )
 
 
-# -- parity vs the per-partition path -----------------------------------------
+# -- the one entry, against a numpy expectation ------------------------------
 
 
-def test_parity_many_partitions(monkeypatch):
-    """Shared-feeder outputs are row-identical to the legacy path across
-    many concurrent partitions — Nones included, partition order kept."""
-    parts = _make_parts(6, 23)
-    device_fn = lambda b: b * 2.0  # noqa: E731
-
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
-    shared = _run_parts(parts, device_fn, batch_size=4)
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "0")
-    legacy = _run_parts(parts, device_fn, batch_size=4)
-
-    assert len(shared) == len(legacy) == 6
-    for sp, lp in zip(shared, legacy):
-        assert len(sp) == len(lp)
-        for a, b in zip(sp, lp):
-            if b is None:
+def _assert_parts_equal(got, parts, expect):
+    """Every cell of every partition: None stays None, a row is
+    ``expect(row)``, in the cell it came from."""
+    assert len(got) == len(parts)
+    for gp, part in zip(got, parts):
+        assert len(gp) == len(part)
+        for a, cell in zip(gp, part):
+            if cell is None:
                 assert a is None
             else:
-                np.testing.assert_array_equal(a, b)
+                np.testing.assert_array_equal(a, expect(cell))
 
 
-def test_single_partition_uses_legacy_path(monkeypatch):
-    """With one partition there is nothing to coalesce with: the shared
-    entry must route to run_batched (no feeder counters move)."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+def test_parity_many_partitions():
+    """Many concurrent partitions: every row comes back in its own cell
+    — Nones included, partition order kept."""
+    parts = _make_parts(6, 23)
+    out = _run_parts(parts, lambda b: b * 2.0, batch_size=4)
+    _assert_parts_equal(out, parts, lambda c: c * 2.0)
+
+
+def test_single_partition_goes_through_the_feeder():
+    """One partition on a sequential executor is a one-producer stream
+    of the same engine: right answers, and the feeder's counters move."""
     before = _feeder_counters()
     parts = _make_parts(1, 10)
     out = _run_parts(parts, lambda b: b + 1.0, batch_size=4)
-    assert _counter_delta(before)["coalesced_batches"] == 0
-    assert out[0][1] is None
-    np.testing.assert_array_equal(out[0][0], parts[0][0] + 1.0)
+    got = _counter_delta(before)
+    assert got["coalesced_batches"] == 2 and got["rows"] == 8
+    _assert_parts_equal(out, parts, lambda c: c + 1.0)
 
 
-def test_gate_off_matches_legacy_byte_for_byte(monkeypatch):
-    """SPARKDL_SHARED_FEEDER=0 restores today's path exactly: same code,
-    so byte-for-byte equal outputs and no feeder engagement."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "0")
-    assert not shared_feeder_enabled()
-    before = _feeder_counters()
-    parts = _make_parts(4, 11)
-    out = _run_parts(parts, lambda b: b * 3.0, batch_size=4)
-    ref = [
-        run_batched(p, _identity_batcher, lambda b: b * 3.0, batch_size=4)
-        for p in parts
-    ]
-    assert _counter_delta(before)["coalesced_batches"] == 0
-    for op, rp in zip(out, ref):
-        for a, b in zip(op, rp):
-            if b is None:
-                assert a is None
-            else:
-                assert a.tobytes() == b.tobytes()
-
-
-def test_outside_executor_falls_back_to_legacy(monkeypatch):
-    """run_batched_shared called with no TaskContext (direct use) runs
-    the legacy pipeline — the feeder needs partition context."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+def test_direct_call_goes_through_the_feeder():
+    """run_batched_shared called with no TaskContext (direct use) is a
+    one-producer stream too: one tail flush, 3 pad rows."""
     assert current_task_context() is None
     before = _feeder_counters()
+    flushes = metrics.counter("feeder.flushes")
     cells = [np.full(2, i, dtype=np.float32) for i in range(9)]
     out = run_batched_shared(cells, _identity_batcher, lambda b: b, 4)
-    assert _counter_delta(before)["coalesced_batches"] == 0
-    np.testing.assert_array_equal(out[8], [8.0, 8.0])
+    got = _counter_delta(before)
+    assert got["coalesced_batches"] == 3 and got["pad_rows"] == 3
+    assert metrics.counter("feeder.flushes") - flushes == 1
+    _assert_parts_equal([out], [cells], lambda c: c)
+
+
+def _bucket_by_width_stream(cells):
+    """A ``stream`` host stage: rows leave in two shapes (width 2 and
+    width 5), in chunks of its own size, as ``run_bucketed`` does."""
+
+    def stream(dispatch_rows):
+        for start in range(0, len(cells), 3):
+            for width in (5, 2):
+                idx = [
+                    i
+                    for i in range(start, min(start + 3, len(cells)))
+                    if cells[i] is not None and len(cells[i]) == width
+                ]
+                if idx:
+                    yield (
+                        np.asarray(idx),
+                        np.stack([cells[i] for i in idx]).astype(np.float32),
+                    )
+
+    return stream
+
+
+@pytest.mark.parametrize("host_stage", ["to_batch", "stream"])
+@pytest.mark.parametrize(
+    "caller", ["no_context", "one_partition_sequential", "four_concurrent"]
+)
+def test_one_entry_matches_numpy(caller, host_stage):
+    """The one entry serves a direct call, a lone partition and
+    concurrent partitions, with either host stage, and every answer is
+    the plain numpy one — nulls included."""
+    rng = np.random.default_rng(7)
+    n_parts = 4 if caller == "four_concurrent" else 1
+    parts = []
+    for p in range(n_parts):
+        widths = [2] * 13 if host_stage == "to_batch" else [2, 5, 5, 2] * 4
+        cells = [rng.normal(size=(w,)).astype(np.float32) for w in widths]
+        cells[1] = None
+        cells[-1] = None
+        parts.append(cells)
+    device_fn = lambda b: b * 3.0 - 1.0  # noqa: E731
+
+    def run(_i, cells):
+        if host_stage == "to_batch":
+            return run_batched_shared(cells, arrays_to_batch, device_fn, 4)
+        return run_batched_shared(
+            cells, None, device_fn, 4, stream=_bucket_by_width_stream(cells)
+        )
+
+    before = _feeder_counters()
+    if caller == "no_context":
+        out = [run(None, parts[0])]
+    else:
+        workers = 1 if caller == "one_partition_sequential" else 4
+        out = Executor(max_workers=workers).map_partitions(run, parts)
+    got = _counter_delta(before)
+    assert got["rows"] == sum(c is not None for p in parts for c in p)
+    assert got["coalesced_batches"] > 0
+    _assert_parts_equal(out, parts, lambda c: c * 3.0 - 1.0)
+
+
+def test_single_stream_device_fn_goes_through_the_feeder():
+    """A whole-mesh (``single_stream``) device fn is a device fn like any
+    other to the engine: its rows pack across concurrent partitions."""
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.graph.function import ModelFunction
+    from sparkdl_tpu.transformers.execution import model_device_fn
+
+    mf = ModelFunction(
+        lambda p, x: jnp.tanh(x) * 2.0, None, input_shape=(2,), name="mesh"
+    )
+    mf.single_stream = True
+    device_fn = model_device_fn(mf)
+    assert device_fn.single_stream and device_fn.n_devices == 1
+    parts = _make_parts(3, 9)
+    before = _feeder_counters()
+    out = _run_parts(parts, device_fn, batch_size=4)
+    got = _counter_delta(before)
+    assert got["rows"] == 3 * 7 and got["coalesced_batches"] >= 6
+    for gp, part in zip(out, parts):
+        for a, cell in zip(gp, part):
+            if cell is None:
+                assert a is None
+            else:
+                np.testing.assert_allclose(a, np.tanh(cell) * 2.0, rtol=1e-6)
+
+
+def test_lone_producer_does_not_wait_out_the_linger(monkeypatch):
+    """A producer that knows it is alone (a direct call; a one-partition
+    job) has its tail padded and flushed when its stream ends: a 5 s
+    linger is never waited out. Two CONCURRENT partitions still share
+    one tail batch inside the linger."""
+    import time
+
+    monkeypatch.setenv("SPARKDL_FEEDER_LINGER_MS", "5000")
+    device_fn = lambda b: b * 2.0  # noqa: E731
+    cells = [np.full(2, i, dtype=np.float32) for i in range(10)]
+
+    t0 = time.monotonic()
+    out = run_batched_shared(cells, _identity_batcher, device_fn, 4)
+    direct_s = time.monotonic() - t0
+    _assert_parts_equal([out], [cells], lambda c: c * 2.0)
+
+    t0 = time.monotonic()
+    out = _run_parts([cells], device_fn, batch_size=4)
+    one_partition_s = time.monotonic() - t0
+    _assert_parts_equal(out, [cells], lambda c: c * 2.0)
+    assert direct_s < 1.0 and one_partition_s < 1.0, (
+        direct_s, one_partition_s,
+    )
+
+    # 2 x 5 rows at batch 4 on a concurrent executor: the tails meet in
+    # ONE padded batch (10 rows -> 3 batches, 2 pad rows), where a flush
+    # per partition would pad 3 + 3
+    monkeypatch.setenv("SPARKDL_FEEDER_LINGER_MS", "300")
+    parts = [cells[:5], cells[5:]]
+    before = _feeder_counters()
+    out = _run_parts(parts, device_fn, batch_size=4, max_workers=2)
+    got = _counter_delta(before)
+    assert got == {"coalesced_batches": 3, "pad_rows": 2, "rows": 10}, got
+    _assert_parts_equal(out, parts, lambda c: c * 2.0)
+
+
+def test_identity_device_fn_gets_its_own_rows_back():
+    """A device fn that returns its input (or a view of it) hands back
+    rows that alias the feeder's ring buffer; the drain copies them, so
+    later batches reusing the buffer cannot overwrite earlier answers."""
+    cells = [np.full(2, i, dtype=np.float32) for i in range(41)]
+    for device_fn in (lambda b: b, lambda b: b[::1]):
+        # prefetch 1 -> a ring of 5 buffers; 11 batches go round it twice
+        out = run_batched_shared(
+            cells, _identity_batcher, device_fn, 4, prefetch=1
+        )
+        _assert_parts_equal([out], [cells], lambda c: c)
 
 
 # -- the acceptance workload: padding accounting ------------------------------
@@ -167,9 +281,8 @@ def test_outside_executor_falls_back_to_legacy(monkeypatch):
 def test_pad_rows_one_tail_flush_not_per_partition(monkeypatch):
     """16 partitions x 100 rows at batch_size=32: the shared feeder must
     dispatch <= ceil(1600/32)+1 batches with total pad rows <= 32 — vs
-    the legacy path's 16 padded tails (ISSUE 2 acceptance criterion)."""
+    a dispatch loop per partition's 16 padded tails."""
     n_parts, rows, batch = 16, 100, 32
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     # generous linger so staggered thread starts on a loaded CI box can't
     # split the stream into multiple quiet periods
     monkeypatch.setenv("SPARKDL_FEEDER_LINGER_MS", "200")
@@ -186,10 +299,9 @@ def test_pad_rows_one_tail_flush_not_per_partition(monkeypatch):
             np.testing.assert_array_equal(out[p][i], cell * 2.0)
 
 
-def test_null_rows_never_occupy_device_rows(monkeypatch):
+def test_null_rows_never_occupy_device_rows():
     """Invalid cells come back as None AND are squeezed out of the device
     stream entirely (the feeder packs only valid rows)."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     parts = [
         [np.ones(2, np.float32), None, np.full(2, 3.0, np.float32), None],
         [None, None, np.full(2, 5.0, np.float32), None],
@@ -204,8 +316,7 @@ def test_null_rows_never_occupy_device_rows(monkeypatch):
     np.testing.assert_array_equal(out[1][2], [6.0, 6.0])
 
 
-def test_all_null_partitions_complete(monkeypatch):
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+def test_all_null_partitions_complete():
     parts = [[None, None, None], [None]]
     out = _run_parts(parts, lambda b: b, batch_size=2)
     assert out == [[None, None, None], [None]]
@@ -216,7 +327,6 @@ def test_shard_map_multiplier_packs_global_batches(monkeypatch):
     batches: dispatch size = batch_size x multiplier, always full except
     the tail flush — the mesh never sees an odd-sized (recompiling)
     batch."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     monkeypatch.setenv("SPARKDL_FEEDER_LINGER_MS", "200")
     sizes = []
 
@@ -235,11 +345,10 @@ def test_shard_map_multiplier_packs_global_batches(monkeypatch):
 # -- failure paths ------------------------------------------------------------
 
 
-def test_producer_exception_propagates_and_isolates(monkeypatch):
+def test_producer_exception_propagates_and_isolates():
     """A to_batch (host stage) error in one partition fails THAT
     partition's task; concurrently-coalescing partitions still complete
     with correct results, and the owner thread survives."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     parts = _make_parts(4, 20, with_nones=False)
 
     def batcher(chunk):
@@ -264,8 +373,7 @@ def test_producer_exception_propagates_and_isolates(monkeypatch):
     np.testing.assert_array_equal(out[1][8], clean[1][8] * 2.0)
 
 
-def test_device_error_propagates_to_all_waiting_partitions(monkeypatch):
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+def test_device_error_propagates_to_all_waiting_partitions():
 
     def bad_device(b):
         raise RuntimeError("device fell over")
@@ -327,11 +435,9 @@ def test_feeder_close_fails_pending_handles():
         f.open_handle([None] * 2)
 
 
-def test_varying_row_shapes_route_to_separate_feeders(monkeypatch):
-    """Chunks whose row shape differs (legal on the legacy path, which
-    recompiles per batch) transparently stream into one feeder per
-    shape — outputs land in the right cells either way."""
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
+def test_varying_row_shapes_route_to_separate_feeders():
+    """Chunks whose row shape differs transparently stream into one
+    feeder per shape — outputs land in the right cells either way."""
 
     def ragged_batcher(chunk):
         shapes = {np.asarray(c).shape for c in chunk if c is not None}
@@ -390,7 +496,6 @@ def test_async_vs_sync_arm_output_parity(monkeypatch):
     concurrent partitions (the A/B acceptance criterion)."""
     parts = _make_parts(5, 27)
     device_fn = lambda b: b * 3.0 + 1.0  # noqa: E731
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
 
     monkeypatch.setenv("SPARKDL_ASYNC_READBACK", "1")
     async_out = _run_parts(parts, device_fn, batch_size=4)
@@ -408,16 +513,16 @@ def test_async_vs_sync_arm_output_parity(monkeypatch):
 
 
 def test_run_batched_async_vs_sync_arm_parity(monkeypatch):
-    """The legacy per-partition engine honors the same A/B gate: both
-    readback arms return identical cells."""
+    """A direct call honors the same A/B gate: both readback arms
+    return identical cells."""
     cells = [
         None if i % 7 == 3 else np.full(2, i, dtype=np.float32)
         for i in range(25)
     ]
     monkeypatch.setenv("SPARKDL_ASYNC_READBACK", "1")
-    a = run_batched(cells, _identity_batcher, lambda b: b * 2.0, 4)
+    a = run_batched_shared(cells, _identity_batcher, lambda b: b * 2.0, 4)
     monkeypatch.setenv("SPARKDL_ASYNC_READBACK", "0")
-    b = run_batched(cells, _identity_batcher, lambda b: b * 2.0, 4)
+    b = run_batched_shared(cells, _identity_batcher, lambda b: b * 2.0, 4)
     for x, y in zip(a, b):
         if y is None:
             assert x is None
@@ -734,14 +839,14 @@ def test_feed_plan_chunk_env_overrides_default(monkeypatch):
 
 
 def test_run_batched_drain_order_with_deque():
-    """The legacy engine's in-flight window drains FIFO (deque.popleft)
-    and scatters via flatnonzero — results stay ordered with a deep
-    prefetch window and interleaved nulls."""
+    """The in-flight window drains FIFO (deque.popleft) and scatters via
+    flatnonzero — results stay ordered with a deep prefetch window and
+    interleaved nulls."""
     cells = [
         None if i % 5 == 2 else np.full(2, i, dtype=np.float32)
         for i in range(23)
     ]
-    out = run_batched(
+    out = run_batched_shared(
         cells, _identity_batcher, lambda b: b * 2.0, batch_size=3,
         prefetch=8,
     )
@@ -755,9 +860,9 @@ def test_run_batched_drain_order_with_deque():
 # -- end-to-end through a real transformer ------------------------------------
 
 
-def test_transformer_parity_shared_vs_legacy(monkeypatch):
-    """ModelTransformer over a multi-partition DataFrame: shared feeder
-    ON vs OFF produce identical columns (the documented A/B flip)."""
+def test_transformer_parity_shared_vs_legacy():
+    """ModelTransformer over a multi-partition DataFrame: the column is
+    the plain numpy answer, null row included, through the feeder."""
     import jax.numpy as jnp
 
     from sparkdl_tpu.dataframe import DataFrame
@@ -777,7 +882,7 @@ def test_transformer_parity_shared_vs_legacy(monkeypatch):
     df = DataFrame.fromColumns({"v": cells}, numPartitions=3)
 
     # a concurrent default executor: on a 1-core box the default would be
-    # sequential (concurrency 1) and the feeder would correctly stand down
+    # sequential (concurrency 1), a one-producer stream
     from sparkdl_tpu.runtime.executor import (
         default_executor,
         set_default_executor,
@@ -786,21 +891,19 @@ def test_transformer_parity_shared_vs_legacy(monkeypatch):
     prev = default_executor()
     set_default_executor(Executor(max_workers=3))
     try:
-        monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
         before = _feeder_counters()
-        shared = xf.transform(df).collect()
+        rows = xf.transform(df).collect()
         engaged = _counter_delta(before)["coalesced_batches"]
-        monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "0")
-        legacy = xf.transform(df).collect()
     finally:
         set_default_executor(prev)
 
-    assert engaged > 0  # the shared path really ran
-    for a, b in zip(shared, legacy):
-        if b.o is None:
-            assert a.o is None
+    assert engaged > 0
+    assert len(rows) == len(cells)
+    for row, cell in zip(rows, cells):
+        if cell is None:
+            assert row.o is None
         else:
-            np.testing.assert_allclose(a.o, b.o, rtol=0, atol=0)
+            np.testing.assert_allclose(row.o, cell * 2.0 + 1.0, rtol=0, atol=0)
 
 
 # -- device-side input staging ------------------------------------------------
@@ -836,7 +939,6 @@ def test_staged_on_off_parity_and_counters(monkeypatch):
     concurrent partitions; the staged arm's hit+miss pair accounts for
     every coalesced batch and the legacy arm never moves it."""
     parts = _make_parts(5, 21)
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
 
     monkeypatch.setenv("SPARKDL_DEVICE_STAGE", "1")
     before = {**_stage_counters(), **_feeder_counters()}
@@ -1057,9 +1159,9 @@ def test_device_preproc_transformer_parity(monkeypatch):
 
 
 def test_run_batched_staged_vs_legacy_parity(monkeypatch):
-    """The legacy per-partition engine honors the staging A/B gate too:
-    both arms return identical cells, and the staged arm's hit+miss
-    pair accounts for every dispatched batch."""
+    """A direct call honors the staging A/B gate: both arms return
+    identical cells, and the staged arm's hit+miss pair accounts for
+    every dispatched batch."""
     device_fn = _staging_device_fn()
     cells = [
         None if i % 7 == 3 else np.full(2, i, dtype=np.float32)
@@ -1067,14 +1169,14 @@ def test_run_batched_staged_vs_legacy_parity(monkeypatch):
     ]
     monkeypatch.setenv("SPARKDL_DEVICE_STAGE", "1")
     before = _stage_counters()
-    a = run_batched(cells, _identity_batcher, device_fn, 4)
+    a = run_batched_shared(cells, _identity_batcher, device_fn, 4)
     got = {
         k: metrics.counter(f"transfer.{k}") - v for k, v in before.items()
     }
-    # ceil(25/4) = 7 chunks, minus the all-null tail chunk ([24] is None)
+    # 21 valid rows (3, 10, 17 and 24 are None) pack into ceil(21/4)
     assert got["stage_hits"] + got["stage_misses"] == 6
     monkeypatch.setenv("SPARKDL_DEVICE_STAGE", "0")
-    b = run_batched(cells, _identity_batcher, device_fn, 4)
+    b = run_batched_shared(cells, _identity_batcher, device_fn, 4)
     for x, y in zip(a, b):
         if y is None:
             assert x is None

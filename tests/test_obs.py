@@ -360,7 +360,7 @@ def test_batched_engine_end_to_end_snapshot(fresh_recorder, tmp_path):
     from sparkdl_tpu.transformers.execution import (
         arrays_to_batch,
         data_parallel_device_fn,
-        run_batched,
+        run_batched_shared,
     )
 
     device_fn = data_parallel_device_fn(
@@ -370,7 +370,7 @@ def test_batched_engine_end_to_end_snapshot(fresh_recorder, tmp_path):
     rng = np.random.default_rng(0)
     cells = [rng.normal(size=(16,)).astype(np.float32) for _ in range(10)]
     cells[3] = None  # null row rides through masked
-    out = run_batched(cells, arrays_to_batch, device_fn, batch_size=4)
+    out = run_batched_shared(cells, arrays_to_batch, device_fn, batch_size=4)
     assert out[3] is None and sum(o is not None for o in out) == 9
 
     snap = export.snapshot()
@@ -398,12 +398,12 @@ def test_batched_engine_legacy_arm_keeps_device_wait_span(
     historical device_wait span name, and no drain_wait appears."""
     from sparkdl_tpu.transformers.execution import (
         arrays_to_batch,
-        run_batched,
+        run_batched_shared,
     )
 
     monkeypatch.setenv("SPARKDL_ASYNC_READBACK", "0")
     cells = [np.ones(4, np.float32) * i for i in range(6)]
-    run_batched(cells, arrays_to_batch, lambda b: b * 2.0, batch_size=2)
+    run_batched_shared(cells, arrays_to_batch, lambda b: b * 2.0, batch_size=2)
     stages = {s["name"] for s in export.snapshot()["spans"]}
     assert "device_wait" in stages and "drain_wait" not in stages
 
@@ -498,7 +498,6 @@ def test_text_path_spans_are_on_the_profilers_clock(
     fresh_recorder, tmp_path, monkeypatch, obs_on
 ):
     monkeypatch.setenv("SPARKDL_OBS", "1" if obs_on else "0")
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     with _profiled(tmp_path) as events:
         out = _bucketed_job()
     assert out[0][1] is None and float(out[0][0][0]) == 1 + 6 + 7 + 8 + 2

@@ -523,7 +523,6 @@ def test_chunked_streaming_matches_whole_partition(monkeypatch, case, n_parts):
     every cell, the same text counters, no more padded rows."""
     from sparkdl_tpu.text import bucketing
 
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     rows, batch_size, length_of = _STREAM_CASES[case]
     parts = _partitions(n_parts, rows, length_of)
     chunk = max(32, batch_size // (4 * n_parts))
@@ -561,14 +560,13 @@ def test_chunked_streaming_matches_whole_partition(monkeypatch, case, n_parts):
             )
 
 
-def test_device_starts_before_the_partition_is_tokenized(monkeypatch):
+def test_device_starts_before_the_partition_is_tokenized():
     """The mechanism engages: the first batch is dispatched while every
     partition still has rows to tokenize. Each partition's last row
     waits for the device's first call, which on an engine that
     tokenizes the whole partition first never comes."""
     import threading
 
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     dispatched = threading.Event()
     seen_at_last_row = []
 
@@ -595,7 +593,7 @@ def test_device_starts_before_the_partition_is_tokenized(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", ["tokenizers_caller", "device_function"])
-def test_streaming_failure_leaves_no_handle_open(monkeypatch, fault):
+def test_streaming_failure_leaves_no_handle_open(fault):
     """A partition that fails mid-stream, with chunks already handed
     over in three buckets, fails every handle it holds and ends them: a
     device error reaches every partition, an error of run_bucketed's
@@ -605,7 +603,6 @@ def test_streaming_failure_leaves_no_handle_open(monkeypatch, fault):
     from sparkdl_tpu.runtime import feeder as feeder_mod
     from sparkdl_tpu.runtime.executor import Executor
 
-    monkeypatch.setenv("SPARKDL_SHARED_FEEDER", "1")
     parts = _partitions(4, 100, lambda r: _MIXED[r % 6])
 
     def tokenize(text):

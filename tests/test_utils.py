@@ -63,7 +63,7 @@ def test_snapshot_and_reset():
 
 
 def test_execution_records_metrics():
-    from sparkdl_tpu.transformers.execution import run_batched
+    from sparkdl_tpu.transformers.execution import run_batched_shared
     from sparkdl_tpu.utils.metrics import metrics
 
     metrics.reset()
@@ -73,7 +73,7 @@ def test_execution_records_metrics():
         b = np.stack([c for c in chunk])
         return b, np.ones(len(chunk), dtype=bool)
 
-    run_batched(cells, batcher, lambda b: b, batch_size=3)
+    run_batched_shared(cells, batcher, lambda b: b, batch_size=3)
     assert metrics.counter("transform.rows") == 6
     assert metrics.timing("transform.host_batch").count == 2
     assert metrics.timing("transform.device_wait").count == 2

@@ -3,7 +3,7 @@
 Loaded INSIDE each serving worker subprocess via
 ``python -m sparkdl_tpu.serving worker --loader tools._chaos_models:loader``
 (the workers run with the repo root as cwd, so the ``tools`` package is
-importable), and inside the smoke process itself for the ``run_batched``
+importable), and inside the smoke process itself for the ``run_batched_shared``
 parity oracle — one definition, so "row-identical to the oracle" is a
 statement about the serving path, not about two model builds agreeing.
 

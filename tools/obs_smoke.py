@@ -3,10 +3,10 @@ per-stage report table.
 
 Proves the flight recorder end-to-end without a chip or a model zoo
 compile: a small tensor-cell workload goes through the REAL batched
-engine (``run_batched`` + executor partitions + explicit device_put), and
-the resulting snapshot must contain a non-empty breakdown with the four
-canonical stages — ingest, h2d, dispatch, and the drain stage, whose
-name is readback-arm dependent (``drain_wait`` under the async default,
+engine (``run_batched_shared`` + executor partitions + explicit
+device_put), and the resulting snapshot must contain a non-empty
+breakdown with the four canonical stages — ingest, h2d, dispatch, and
+the drain stage, whose name is readback-arm dependent (``drain_wait`` under the async default,
 ``device_wait`` when ``SPARKDL_ASYNC_READBACK=0``). Exit 0 and the
 rendered table on success; exit 1 naming the missing stages otherwise.
 
@@ -48,7 +48,7 @@ def run_smoke():
     from sparkdl_tpu.transformers.execution import (
         arrays_to_batch,
         data_parallel_device_fn,
-        run_batched,
+        run_batched_shared,
     )
 
     obs.get_recorder().clear()
@@ -62,7 +62,7 @@ def run_smoke():
         for _ in range(3)
     ]
     Executor(max_workers=2).map_partitions(
-        lambda i, cells: run_batched(
+        lambda i, cells: run_batched_shared(
             cells, arrays_to_batch, device_fn, batch_size=4
         ),
         parts,

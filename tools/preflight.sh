@@ -16,7 +16,7 @@
 # budget, parity with the offline engine), the supervised serving gang
 # (serving_chaos_smoke: gateway + 2 workers, fault-plan worker crash
 # mid-flood -> exactly 1 supervisor restart, zero lost accepted
-# requests, outputs row-identical to the run_batched oracle, canary
+# requests, outputs row-identical to the run_batched_shared oracle, canary
 # split within tolerance, drain semantics, no leaked threads), and the
 # sequence-bucketed text engine (text_smoke: per-bucket pad ratio,
 # bucketed-vs-unbucketed row parity, long-context model over

@@ -18,7 +18,7 @@ the gateway's re-dispatch all happen underneath it. Asserts:
   holds across generations via ``SPARKDL_FAULT_STATE``), and the
   post-restart gang reaches generation 1 with every worker ready;
 - **row-identical outputs**: every response (including post-restart
-  ones) matches a direct ``run_batched`` oracle over the SAME model
+  ones) matches a direct ``run_batched_shared`` oracle over the SAME model
   builds (``tools/_chaos_models.py`` is deterministic per name) — the
   response's ``model`` field names the version that served it, so
   canary-served rows check against the canary oracle;
@@ -96,15 +96,16 @@ def _get(port, path, timeout=10):
 
 
 def _offline_outputs(name, rows):
-    """run_batched over the identical model build — the parity oracle."""
+    """run_batched_shared over the identical model build: the parity
+    oracle."""
     from sparkdl_tpu.transformers.execution import (
         arrays_to_batch,
         model_device_fn,
-        run_batched,
+        run_batched_shared,
     )
 
     device_fn = model_device_fn(loader(name, "features"))
-    return run_batched(
+    return run_batched_shared(
         list(rows), arrays_to_batch, device_fn, batch_size=32
     )
 
@@ -175,8 +176,8 @@ def _flood(gw_port, problems):
 
 
 def _check_parity(jobs, results, problems):
-    """Every 200 response row-identical to the run_batched oracle of the
-    model VERSION that served it."""
+    """Every 200 response row-identical to the run_batched_shared oracle
+    of the model VERSION that served it."""
     import numpy as np
 
     by_version = {}
@@ -395,8 +396,8 @@ def main(argv=None) -> int:
         gw.stop()
         os.environ.pop("SPARKDL_OBS_JSONL", None)
 
-    # the oracle ran run_batched in THIS process: its H2D pools must
-    # shut down before the leak check, same as serving_smoke
+    # the oracle ran run_batched_shared in THIS process: its feeders and
+    # H2D pools must shut down before the leak check, as in serving_smoke
     from sparkdl_tpu.runtime.feeder import shutdown_feeders
 
     shutdown_feeders()

@@ -15,7 +15,7 @@ queue -> feeder streams -> device dispatch):
    - ``serve.batch_rows`` min == 1 (short batch at low depth) and
      max == full geometry (growth under load),
    - serving outputs row-identical to the OFFLINE path (the same rows
-     through ``run_batched`` with the same model).
+     through ``run_batched_shared`` with the same model).
 
 2. **Residency** (two 2 MB models under a 3 MB
    ``SPARKDL_SERVE_HBM_BUDGET_MB``): serve A, then B, then A again.
@@ -78,16 +78,16 @@ def _loader(name, mode):
 
 
 def _offline_outputs(name, rows_batch):
-    """The batch pipeline's answer for the same rows: ``run_batched``
+    """The batch pipeline's answer for the same rows: ``run_batched_shared``
     over the same ModelFunction — the parity oracle."""
     from sparkdl_tpu.transformers.execution import (
         arrays_to_batch,
         model_device_fn,
-        run_batched,
+        run_batched_shared,
     )
 
     device_fn = model_device_fn(_loader(name, "features"))
-    return run_batched(
+    return run_batched_shared(
         list(rows_batch),
         arrays_to_batch,
         device_fn,
@@ -236,7 +236,7 @@ def _phase_residency(problems):
 def _serving_threads():
     """ALL live 'sparkdl-*' threads — the serve/feeder-only prefix list
     used to miss the H2D staging pool the offline parity oracle spins
-    up (run_batched stages batches too)."""
+    up (the oracle's feeder stages batches too)."""
     return [
         t
         for t in threading.enumerate()

@@ -54,7 +54,6 @@ def _run_workload():
         run_batched_shared,
     )
 
-    os.environ["SPARKDL_SHARED_FEEDER"] = "1"
     device_fn = data_parallel_device_fn(
         jax.jit(lambda b: jnp.tanh(b).sum(axis=1, keepdims=True)),
         devices=[jax.devices()[0]],

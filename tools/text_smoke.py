@@ -24,7 +24,7 @@ Two phases over the REAL stack:
    self-selected on CPU) served through a real HTTP
    ``POST /v1/predict`` round-trip. Two requests of different lengths
    seq-bucket to ONE 2048 stream (router grouping key carries the
-   bucket); outputs match a direct ``run_batched`` oracle over the
+   bucket); outputs match a direct ``run_batched_shared`` oracle over the
    same model function.
 
 Epilogue: zero leaked ``sparkdl-*`` threads after shutdown, and the
@@ -214,7 +214,7 @@ def _phase_long_context(problems):
     from sparkdl_tpu.serving import Router, start_server
     from sparkdl_tpu.transformers.execution import (
         model_device_fn,
-        run_batched,
+        run_batched_shared,
     )
     from sparkdl_tpu.utils.metrics import metrics
 
@@ -261,14 +261,14 @@ def _phase_long_context(problems):
                 "1800-token request did not seq-bucket to the 2048 "
                 f"stream (pad tokens added: {pad_added:.0f})"
             )
-        # oracle: the same rows through the batch engine's run_batched
+        # oracle: the same rows through the batch engine's one entry
         # over the same registry model function
         dfn = model_device_fn(spec.model_function(mode="embed"))
 
         def to_batch(chunk):
             return np.stack(chunk), np.ones((len(chunk),), bool)
 
-        oracle = run_batched(
+        oracle = run_batched_shared(
             [row.astype(np.int32) for _, row in seqs],
             to_batch,
             dfn,
@@ -277,7 +277,7 @@ def _phase_long_context(problems):
         for i, (got, want) in enumerate(zip(outputs, oracle)):
             if not np.allclose(got[0], want, rtol=2e-4, atol=2e-4):
                 problems.append(
-                    f"long-context serving/run_batched mismatch at "
+                    f"long-context serving/run_batched_shared mismatch at "
                     f"request {i}"
                 )
         resident = [
