@@ -8,6 +8,7 @@ started: only one process may load the TPU's library, and every worker
 imports every test file. All such tests live in this one file."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from sparkdl_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_packed,
 )
+from sparkdl_tpu.models import jamba
 from sparkdl_tpu.ops.selective_scan import selective_scan
 
 ROWS, D_INNER, D_STATE = 8, 5120, 16  # a dispatch of the cell; Jamba2-3B
@@ -61,6 +63,46 @@ def test_selective_scan_compiles_at_the_published_widths(shape, length):
     # the transposed B and C
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * ROWS * length * D_STATE * 4 + (1 << 20)
+
+
+@pytest.mark.parametrize("length", [1024, 2048])
+def test_nothing_stands_between_the_projections_and_the_scan(shape, length):
+    """The call as `models/jamba.py` makes it, inside a jitted Mamba mixer
+    of the published widths: the kernel reads ``h``, ``dt`` and ``z`` as
+    ``[8, L, 5120]`` with the tokens on the sublanes, as the fusions
+    before it write them. A relayout outside the kernel would be a
+    ``copy`` or a ``transpose`` of that shape in this text (0.8 ms each
+    on the chip); what the kernel needs turned, it turns in VMEM."""
+    config, bf16 = jamba.jamba2_3b(), jnp.bfloat16
+    params = {
+        path.split("/")[1]: shape(dims, jamba._leaf_dtype(path, dims, bf16))
+        for path, dims in jamba.layer_shapes(config, 0).items()
+        if path.startswith("mamba/")
+    }
+    scan = functools.partial(selective_scan, out_dtype=bf16)
+    text = (
+        jax.jit(lambda p, u: jamba._mamba(config, p, u, scan))
+        .lower(params, shape((ROWS, length, config.hidden_size), bf16))
+        .compile()
+        .as_text()
+    )
+    call = re.search(
+        r"%selective_scan\S* = .*custom-call\((.*?)\), custom_call_target", text
+    )
+    assert call, "no %selective_scan custom call in the compiled mixer"
+    wide = rf"\[{ROWS},{length},({D_INNER}|{2 * D_INNER})\]"
+    moved = re.findall(rf"%\S+ = \w+{wide}\S* (?:copy|transpose)\(", text)
+    assert not moved, moved
+    # h, dt and z come straight from fusions (z as one output of the
+    # fusion that splits in_proj's product)
+    operands = [name.strip() for name in call.group(1).split(",")]
+    made_by = [
+        made.group(2)
+        for name in operands
+        if (made := re.search(rf"{re.escape(name)} = \w+{wide}\S* ([\w-]+)\(", text))
+    ]
+    assert len(made_by) == 3, made_by
+    assert set(made_by) <= {"fusion", "get-tuple-element"}, made_by
 
 
 def test_causal_flash_with_one_shared_head_compiles(shape):

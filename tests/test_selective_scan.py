@@ -3,11 +3,11 @@ recurrence stepped one token at a time.
 
 The kernel runs under the Pallas interpreter on the CPU: the real kernel
 body, tiny shapes. Tolerances: the kernel and the oracle do the same
-float32 arithmetic in another order (the kernel sums the state over
-``d_state`` along the sublanes), so they agree to a few float32 roundings
-of values of order 1: 2e-5 absolute. With bfloat16 ``h`` and ``z`` both
-sides read the same rounded inputs, and the kernel's output is rounded to
-bfloat16 once more: 2^-8 relative."""
+float32 arithmetic in another order (the kernel adds ``d_state`` registers
+as a tree, and takes ``exp(dt A)`` as ``2 ** (dt (A log2 e))``), so they
+agree to a few float32 roundings of values of order 1: 2e-5 absolute.
+With bfloat16 ``h`` and ``z`` both sides read the same rounded inputs, and
+the kernel's output is rounded to bfloat16 once more: 2^-8 relative."""
 
 import jax
 import jax.numpy as jnp
@@ -64,10 +64,16 @@ def inputs(rows, length, d_inner, n=16, seed=0, dtype=jnp.float32):
 @pytest.mark.parametrize(
     "length, d_inner, block_d",
     [
-        (256, 256, 128),  # two whole chunks, two blocks of d_inner
-        (200, 256, 256),  # the last chunk part empty; two lane groups a loop
+        # part-filled tiles: fewer than 8 lane groups on the sublanes
+        (256, 256, 128),  # two whole chunks, two blocks of one lane group
+        (200, 256, 256),  # the last chunk part empty; two lane groups
         (130, 384, 384),  # two tokens into the second chunk; three groups
         (128, 640, 1280),  # one chunk; five groups, block_d above d_inner
+        # whole tiles of 1,024 channels
+        (136, 1024, 1024),  # one block of one tile
+        (128, 2048, 1024),  # two blocks
+        (128, 2048, 2048),  # one block of two tiles
+        (128, 1280, 1280),  # a whole tile and a part-filled one in a block
     ],
 )
 def test_kernel_matches_the_token_by_token_scan(length, d_inner, block_d):
@@ -76,6 +82,27 @@ def test_kernel_matches_the_token_by_token_scan(length, d_inner, block_d):
     got = selective_scan(*args, chunk=128, block_d=block_d, interpret=True)
     assert got.shape == want.shape and got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=F32_ATOL)
+
+
+def test_b_and_c_are_read_at_their_row_state_and_token():
+    """``B_t[n]`` and ``C_t[n]`` reach the kernel as scalars, found by
+    (row, chunk, token in the chunk, state index). Every one of them is a
+    value of its own here, over two rows, two chunks and two blocks of
+    ``d_inner``; and the same inputs with ``B`` or ``C`` moved by one place
+    along any of the three axes give another answer by far more than the
+    tolerance, so a scalar read one place off could not pass."""
+    h, dt, _, _, z, a, d = inputs(2, 256, 256, seed=13)
+    r, t, m = np.meshgrid(np.arange(2), np.arange(256), np.arange(16), indexing="ij")
+    b = jnp.asarray(np.sin(0.37 * t + 1.3 * m + 2.1 * r), jnp.float32)
+    c = jnp.asarray(np.cos(0.23 * t + 0.7 * m + 1.7 * r), jnp.float32)
+    want, _ = token_scan(h, dt, b, c, z, a, d)
+    got = selective_scan(h, dt, b, c, z, a, d, chunk=128, block_d=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=F32_ATOL)
+    for axis in range(3):
+        for moved in ((jnp.roll(b, 1, axis), c), (b, jnp.roll(c, 1, axis))):
+            other, _ = token_scan(h, dt, *moved, z, a, d)
+            off = np.abs(np.asarray(other) - np.asarray(want)).max()
+            assert off > 1e3 * F32_ATOL, (axis, off)
 
 
 def test_state_is_carried_across_the_chunk_edge():
@@ -147,6 +174,17 @@ def test_sizes_the_lanes_cannot_hold_are_refused():
         selective_scan(*args, interpret=True)
     with pytest.raises(ValueError, match="multiples of 128"):
         selective_scan(*inputs(1, 128, 128), chunk=64, interpret=True)
+
+
+def test_a_state_the_registers_cannot_hold_is_refused():
+    """``d_state`` counts the vector registers a tile's state takes: 32 is
+    the most, and runs; 64 would be the whole register file."""
+    args = inputs(1, 128, 128, n=32, seed=2)
+    want, _ = token_scan(*args)
+    got = selective_scan(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=F32_ATOL)
+    with pytest.raises(ValueError, match="d_state 64"):
+        selective_scan(*inputs(1, 128, 128, n=64), interpret=True)
 
 
 def test_scan_choice_is_made_at_build_and_recorded():
