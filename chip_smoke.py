@@ -20,7 +20,10 @@ out against the repo's own references:
            device, two epochs of 4 steps
   kernel   the compiled Pallas flash kernel, blocked and packed (heads
            under the lane width, side by side), against dense attention, and
-           the hybrid family's: selective scan, causal shared-head flash
+           the hybrid family's: selective scan, causal shared-head flash,
+           and the expert family's at its cell's widths: causal latent
+           attention over the projections' arrays, the routed experts'
+           grouped product
 
 Each phase prints one JSON line: wall seconds, the seconds jax spent
 compiling (or fetching from the persistent cache) and tracing, and the
@@ -662,6 +665,7 @@ def phase_kernel(sizes: Sizes, interpret: bool) -> dict:
         "rel_err_vs_dense": errs,
         "run_s": round(run_s, 3),
         **_hybrid_kernels(sizes, interpret),
+        **_expert_kernels(sizes, interpret),
     }
 
 
@@ -724,6 +728,77 @@ def _hybrid_kernels(sizes: Sizes, interpret: bool) -> dict:
     return {
         "hybrid_rel_err": errs,
         "jamba_built_with": {"attention": mf.attention, "scan": mf.scan},
+    }
+
+
+def _expert_kernels(sizes: Sizes, interpret: bool) -> dict:
+    """The kernels of the latent-attention, routed-expert family
+    (models/deepseek_v2.py) at its cell's widths: causal latent attention
+    over the projections' arrays against dense, and the grouped product
+    of an expert layer's gate and down shapes against ``lax.ragged_dot``
+    over the rows the groups cover; and which of each a model built here
+    gets. A rehearsal keeps the head sizes and cuts the rest."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparkdl_tpu.models import get_model
+    from sparkdl_tpu.ops.flash_attention import (
+        dense_latent_attention,
+        flash_attention_latent,
+    )
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul, ragged_matmul
+
+    rng = np.random.default_rng(5)
+    errs = {}
+    rows, heads = 2, 2 if interpret else 16
+    scale = 192**-0.5 * 1.589626
+    for length in sizes.kernel_lengths:
+        block = 128 if interpret else min(1024, length)  # the model's blocks
+        # the model's own entry: the projections' arrays, a head's
+        # [nope | rope] query, [key | value] and the shared rotary key
+        nope = 128
+        q = jnp.asarray(rng.normal(size=(rows, length, heads * 2 * nope)), jnp.bfloat16)
+        kv = jnp.asarray(rng.normal(size=(rows, length, heads * 2 * nope)), jnp.bfloat16)
+        kr = jnp.asarray(rng.normal(size=(rows, length, nope)), jnp.bfloat16)
+        got = jax.jit(
+            lambda q, kv, kr: flash_attention_latent(
+                q, kv, kr, num_heads=heads, scale=scale, block=block,
+                interpret=interpret,
+            )
+        )(q, kv, kr)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(
+                lambda q, kv, kr: dense_latent_attention(
+                    *(t.astype(jnp.float32) for t in (q, kv, kr)), jnp.float32,
+                    num_heads=heads, scale=scale,
+                )
+            )(q, kv, kr)
+        errs[f"latent_flash/L{length}"] = _check_close(
+            f"latent flash L{length}", got, want
+        )
+    # slots sorted by expert: 40 held experts, uneven, one empty, and a
+    # tail of slots whose expert is elsewhere
+    hidden, width, groups = (256, 128, 5) if interpret else (5120, 1536, 40)
+    slots = 640 if interpret else 32768
+    share = rng.dirichlet(np.full(groups, 2.0)) * 0.7 * slots
+    sizes_ = np.floor(share).astype(np.int32)
+    sizes_[1] = 0
+    covered = int(sizes_.sum())
+    for k_dim, n_dim in ((hidden, width), (width, hidden)):
+        lhs = jnp.asarray(rng.normal(size=(slots, k_dim)), jnp.bfloat16)
+        rhs = jnp.asarray(
+            rng.normal(size=(groups, k_dim, n_dim)) / np.sqrt(k_dim), jnp.bfloat16
+        )
+        got = grouped_matmul(lhs, rhs, jnp.asarray(sizes_), interpret=interpret)
+        want = ragged_matmul(lhs, rhs, jnp.asarray(sizes_))
+        errs[f"grouped/{k_dim}x{n_dim}"] = _check_close(
+            f"grouped product {k_dim}x{n_dim}", got[:covered], want[:covered]
+        )
+    mf = get_model("deepseek-v2-tiny").model_function(mode="embed")
+    return {
+        "expert_rel_err": errs,
+        "deepseek_v2_built_with": {"attention": mf.attention, "experts": mf.experts},
     }
 
 

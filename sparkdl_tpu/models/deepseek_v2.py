@@ -1,0 +1,493 @@
+"""DeepSeek-V2 (``model_type: deepseek_v2``; DeepSeek-AI 2024,
+arXiv:2405.04434): latent attention (MLA) and a feed-forward of shared
+plus routed experts, the first family here with either.
+
+    h = h + MLA(rms(h; w_in));  h = h + FFN_i(rms(h; w_ff))
+    FFN_i = SwiGLU(intermediate_size) for i < first_k_dense, else Shared(u) + Routed(u)
+
+    MLA(u), heads of [nope | rope] queries and keys, causal, positions from 0:
+      c_q = rms(W_qa u; w_qn);        q = W_qb c_q -> per head [q_nope | q_pe]
+      [c_kv | k_pe] = W_kva u;        kv = W_kvb rms(c_kv; w_kvn) -> per head [k_nope | v]
+      q_pe, k_pe = rope(q_pe, t), rope(k_pe, t)     k_pe one vector a token, for all heads
+      score(t,s) = (q_nope_t.k_nope_s + q_pe_t.k_pe_s) * (nope + rope)^-0.5 * m^2
+      out = W_o concat_heads(softmax_{s<=t}(score) v)
+    Routed(u): s = softmax(W_g u) over ALL the model's experts, float32;
+      the ``topk_group`` groups with the largest best score are kept, top-k
+      of s over them -> (e_k, s_k); w_k = routed_scaling_factor * s_k, not
+      renormalised; sum over the k whose expert this chip holds.
+
+**The chip's share.** ``experts_held = (first, end)`` are the experts
+whose weights are here. The router keeps the model's width and its
+experts a token; a (token, k) slot whose expert is absent is computed by
+nobody here and nothing stands in for it: the partial sum goes on. Pad
+tokens (id 0) are not routed: a causal stack never lets a real token see
+them. No slot is dropped at any load: the slots are sorted by expert,
+absent ones last, into a buffer of tokens x k rows, and
+``ops/grouped_matmul.py`` is given the held experts' group sizes.
+
+The rotary part follows YaRN with frequencies fixed at build time. The
+source de-interleaves a rotary vector (x0,x1,x2,..) -> (x0,x2,..|x1,x3,..)
+and then rotates halves; here the de-interleave is a permutation of the
+projections' output columns (of ``q_b`` and ``kv_a``), applied to the
+weights inside the program, so the rotation reads contiguous halves and
+every score is the source's (``_mla`` says how the rotation is shared
+between the two sides of a score).
+
+``embed`` is the mean, over a row's real tokens, of the final RMSNorm of
+their states: every token's routing then moves the answer by its share
+and no single discrete choice decides a row (``_mean_real_state``).
+Precision: matrices and the activations that feed them are ``dtype``,
+every product accumulates in float32; the residual stream, norms,
+softmax, rotary angles, the router (operands too, ``highest``), the
+routing weights and the combine are float32. Attention is
+``ops/flash_attention.py:flash_attention_latent`` (causal, over the
+projections' own arrays) and the routed experts ``ops/grouped_matmul.py``: the Pallas
+kernels on TPU, plain ``jax.numpy`` elsewhere, chosen at build time and
+reported as ``mf.attention`` and ``mf.experts``. Layers are unrolled
+into one program with every layer's weights an argument of its own
+(``weights_as_arguments``), as ``models/jamba.py`` does.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from sparkdl_tpu.models.jamba import (
+    _dense,
+    _rms,
+    _silu,
+    _unflatten,
+    load_flat,
+)
+
+
+@dataclass(frozen=True)
+class DeepseekV2Config:
+    vocab_size: int = 102400
+    hidden_size: int = 5120
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 1536
+    num_layers: int = 60
+    first_k_dense: int = 1
+    num_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 160
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    n_group: int = 8
+    topk_group: int = 3
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 16.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.707
+    rope_mscale_all_dim: float = 0.707
+    #: [first, end) of the routed experts whose weights this chip holds
+    experts_held: Tuple[int, int] = (0, 160)
+
+    @property
+    def expert_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @property
+    def softmax_scale(self) -> float:
+        m = _yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def deepseek_v2() -> DeepseekV2Config:
+    """One chip's share of DeepSeek-V2 as ``benchmarks/configs/
+    deepseek-v2.json`` cuts it: every published width, the leading dense
+    layer and four expert layers of the 60, experts 0-39 of the 160
+    (routing groups 0 and 1 of 8), a quarter of the vocabulary."""
+    return DeepseekV2Config(vocab_size=25600, num_layers=5, experts_held=(0, 40))
+
+
+def deepseek_v2_tiny() -> DeepseekV2Config:
+    """The same family at a size the CPU tests hold: a dense layer and
+    two expert layers, 16 experts in 4 groups of which 2, top-3, this
+    chip's share the first group."""
+    return DeepseekV2Config(
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        moe_intermediate_size=32,
+        num_layers=3,
+        num_heads=4,
+        q_lora_rank=24,
+        kv_lora_rank=16,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        n_routed_experts=16,
+        num_experts_per_tok=3,
+        n_group=4,
+        topk_group=2,
+        experts_held=(0, 4),
+    )
+
+
+_SIZES = {"deepseek-v2": deepseek_v2, "deepseek-v2-tiny": deepseek_v2_tiny}
+
+
+def layer_shapes(config: DeepseekV2Config, i: int) -> dict:
+    """{path under ``layers/<i>/``: shape}; matrices are [in, out], a
+    layer's held experts stacked [expert, in, out]."""
+    h, heads = config.hidden_size, config.num_heads
+    nope, rope, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    rq, rkv = config.q_lora_rank, config.kv_lora_rank
+    shapes = {
+        "norm_in": (h,),
+        "norm_ff": (h,),
+        "attn/q_a": (h, rq),
+        "attn/q_norm": (rq,),
+        "attn/q_b": (rq, heads * (nope + rope)),
+        "attn/kv_a": (h, rkv + rope),
+        "attn/kv_norm": (rkv,),
+        "attn/kv_b": (rkv, heads * (nope + dv)),
+        "attn/o": (heads * dv, h),
+    }
+    if i < config.first_k_dense:
+        f = config.intermediate_size
+        shapes.update({"mlp/gate": (h, f), "mlp/up": (h, f), "mlp/down": (f, h)})
+        return shapes
+    f = config.moe_intermediate_size
+    shared = config.n_shared_experts * f
+    held = config.experts_held[1] - config.experts_held[0]
+    shapes.update({
+        "moe/router": (h, config.n_routed_experts),
+        "moe/shared/gate": (h, shared),
+        "moe/shared/up": (h, shared),
+        "moe/shared/down": (shared, h),
+        "moe/experts/gate": (held, h, f),
+        "moe/experts/up": (held, h, f),
+        "moe/experts/down": (held, f, h),
+    })
+    return shapes
+
+
+def param_shapes(config: DeepseekV2Config) -> dict:
+    """{flat path: shape} of every leaf, as a weights file names them."""
+    h = config.hidden_size
+    shapes = {"embed": (config.vocab_size, h), "final_norm": (h,)}
+    for i in range(config.num_layers):
+        for name, shape in layer_shapes(config, i).items():
+            shapes[f"layers/{i}/{name}"] = shape
+    return shapes
+
+
+def _leaf_dtype(path: str, shape: tuple, dtype) -> Any:
+    """Matrices (and the embedding) are ``dtype``; norm weights and the
+    router feed float32 arithmetic and stay float32."""
+    small = len(shape) == 1 or path.endswith("/router")
+    return jnp.float32 if small else dtype
+
+
+def init_params(config: DeepseekV2Config, seed: int, dtype) -> dict:
+    """Random weights scaled by fan-in; the router's twice as wide, so
+    that its scores are spread and not flat."""
+    rng = np.random.default_rng([int(seed), 0xD5E2])
+    flat = {}
+    for path, shape in param_shapes(config).items():
+        kind = path.rsplit("/", 1)[-1]
+        if "norm" in kind:
+            v = np.ones(shape, np.float32)
+        elif kind == "embed":
+            v = rng.standard_normal(shape, dtype=np.float32)
+        else:
+            fan_in = shape[-2]
+            v = rng.standard_normal(shape, dtype=np.float32) / math.sqrt(fan_in)
+            if kind == "router":
+                v *= 2.0
+        flat[path] = jnp.asarray(v, _leaf_dtype(path, shape, dtype))
+    return _unflatten(flat)
+
+
+# -- rotary positions (YaRN) --------------------------------------------------
+
+
+def _yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_range(config: DeepseekV2Config) -> Tuple[int, int]:
+    """(low, high): the frequency pairs between which YaRN blends."""
+    dim = config.qk_rope_head_dim
+
+    def correction(rotations):
+        return (
+            dim
+            * math.log(config.rope_original_max_position / (rotations * 2 * math.pi))
+            / (2 * math.log(config.rope_theta))
+        )
+
+    low = math.floor(correction(config.rope_beta_fast))
+    high = math.ceil(correction(config.rope_beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_inv_freq(config: DeepseekV2Config) -> np.ndarray:
+    dim = config.qk_rope_head_dim
+    f = 1.0 / config.rope_theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_range(config)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (f * (1 - ramp) + f / config.rope_factor * ramp).astype(np.float32)
+
+
+def rope_tables(config: DeepseekV2Config, length: int):
+    """(cos, sin), each [L, rope] float32: both halves carry the pair's
+    angle, scaled by mscale(factor, mscale) / mscale(factor, all_dim)."""
+    angle = np.arange(length, dtype=np.float32)[:, None] * yarn_inv_freq(config)
+    angle = np.concatenate([angle, angle], -1)
+    factor = _yarn_mscale(config.rope_factor, config.rope_mscale) / _yarn_mscale(
+        config.rope_factor, config.rope_mscale_all_dim
+    )
+    return (
+        jnp.asarray(np.cos(angle) * factor, jnp.float32),
+        jnp.asarray(np.sin(angle) * factor, jnp.float32),
+    )
+
+
+def _deinterleave(width: int) -> np.ndarray:
+    """Column order (0, 2, 4, .. | 1, 3, 5, ..): the source's
+    de-interleave, as a permutation of a projection's output columns."""
+    return np.concatenate([np.arange(0, width, 2), np.arange(1, width, 2)])
+
+
+def _turn(x):
+    """Rotate halves: (a | b) -> (-b | a) on the last axis."""
+    half = x.shape[-1] // 2
+    return jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+
+
+def _rotate(x, cos, sin):
+    """x [..., L, rope] float32, de-interleaved: halves rotated by the
+    tables' angles."""
+    return x * cos + _turn(x) * sin
+
+
+# -- the layers ---------------------------------------------------------------
+
+
+def _query_weights(config: DeepseekV2Config, w):
+    """``q_b`` [rank, H * (nope + rope)] -> [rank, H * (nope + 2 * rope)]:
+    a head's columns [nope | r | turn(r)], r its rotary columns
+    de-interleaved. ``x @ turn(w) = turn(x @ w)``, so the projection
+    itself writes both terms of ``rope(x) = x * cos + turn(x) * sin``
+    side by side and no lane moves afterwards."""
+    heads, nope = config.num_heads, config.qk_nope_head_dim
+    w = w.reshape(w.shape[0], heads, -1)
+    r = w[..., nope:][..., _deinterleave(config.qk_rope_head_dim)]
+    return jnp.concatenate([w[..., :nope], r, _turn(r)], -1).reshape(w.shape[0], -1)
+
+
+def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
+    """u [B, L, hidden] in the compute dtype -> [B, L, hidden] float32.
+
+    Every array the kernel reads is what a projection wrote, heads side
+    by side on the last axis: q [B, L, H * (nope + 2 rope)], the
+    up-projected latent kv [B, L, H * (nope + v)] whole, and the one
+    rotary key a token [B, L, 2 rope]. The rotation is split between the
+    two sides of the score so that it costs no lane shuffle on q: a
+    head's rotary query is left as [x * cos | turn(x) * sin] and the
+    rotated key is written twice, [k | k]; their product over the 2 rope
+    lanes is rope(x) . k, each term a bfloat16 operand accumulated in
+    float32 with the rest of the score."""
+    dtype, eps = u.dtype, config.rms_norm_eps
+    rows, length, _ = u.shape
+    heads, nope, rope = config.num_heads, config.qk_nope_head_dim, config.qk_rope_head_dim
+    rkv = config.kv_lora_rank
+    cos, sin = tables
+
+    c_q = _rms(_dense(u, p["q_a"]), p["q_norm"], eps).astype(dtype)
+    q = _dense(c_q, _query_weights(config, p["q_b"]))
+    by_lane = jnp.concatenate([jnp.ones((length, nope), jnp.float32), cos, sin], -1)
+    by_lane = jnp.broadcast_to(by_lane[:, None], (length, heads, nope + 2 * rope))
+    q = (q * by_lane.reshape(length, -1)).astype(dtype)
+
+    kv_a = _dense(u, p["kv_a"])  # [B, L, rkv + rope] float32
+    c_kv = _rms(kv_a[..., :rkv], p["kv_norm"], eps).astype(dtype)
+    kv = _dense(c_kv, p["kv_b"]).astype(dtype)  # a head's [k_nope | v]
+    k_pe = _rotate(kv_a[..., rkv:][..., _deinterleave(rope)], cos, sin).astype(dtype)
+    o = attention_fn(q, kv, jnp.concatenate([k_pe, k_pe], -1), dtype)
+    return _dense(o, p["o"])
+
+
+def _swiglu(p, u):
+    gate = _silu(_dense(u, p["gate"]))
+    return _dense((gate * _dense(u, p["up"])).astype(u.dtype), p["down"])
+
+
+def route(config: DeepseekV2Config, u, router):
+    """u [T, hidden], router [hidden, experts], both float32 ->
+    (experts [T, k] int32, weights [T, k] float32) over all the model's
+    experts: ``group_limited_greedy``."""
+    n, groups = config.n_routed_experts, config.n_group
+    logits = jnp.einsum(
+        "ti,io->to", u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.softmax(logits, -1)
+    if groups > 1:
+        best = scores.reshape(-1, groups, n // groups).max(-1)
+        _, kept = jax.lax.top_k(best, config.topk_group)
+        keep = jnp.any(kept[..., None] == jnp.arange(groups), -2)  # [T, groups]
+        scores = jnp.where(jnp.repeat(keep, n // groups, -1), scores, 0.0)
+    weights, experts = jax.lax.top_k(scores, config.num_experts_per_tok)
+    if config.norm_topk_prob and config.num_experts_per_tok > 1:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    else:
+        weights = weights * config.routed_scaling_factor
+    return experts, weights
+
+
+_COMBINE_AT_ONCE = 3
+
+
+def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
+    """u [B, L, hidden] float32 (the norm's output), real [B, L] bool ->
+    (the held experts' part of the routed sum [B, L, hidden] float32,
+    how many of each row's slots fell on held experts [B] int32)."""
+    rows, length, hidden = u.shape
+    tokens, top_k = rows * length, config.num_experts_per_tok
+    first, end = config.experts_held
+    flat = u.reshape(tokens, hidden)
+    experts, weights = route(config, flat, p["router"])
+    held = (experts >= first) & (experts < end) & real.reshape(tokens, 1)
+    # slots sorted by expert, those of absent experts (and of pad tokens) last
+    key = jnp.where(held, experts - first, end - first).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(end - first, dtype=key.dtype), 0, dtype=jnp.int32
+    )
+    dtype = p["experts"]["gate"].dtype
+    x = flat.astype(dtype)[order // top_k]  # [tokens * k, hidden]
+    gate = experts_fn(x, p["experts"]["gate"], sizes)
+    up = experts_fn(x, p["experts"]["up"], sizes)
+    y = experts_fn((_silu(gate) * up).astype(dtype), p["experts"]["down"], sizes)
+    # back to (token, k) order; what the kernel left unwritten is not read
+    slot = jnp.argsort(order).reshape(tokens, top_k)
+
+    # a token's k choices, summed _COMBINE_AT_ONCE gathered parts a pass:
+    # all k at once are 2 GB of gathers beside y; one at a time (a loop
+    # carrying the sum) made k passes over it, 4.40 s a job against 4.19
+    # on the chip (PERF.md, PR 32). The barrier orders the passes.
+    out = jnp.zeros((tokens, hidden), jnp.float32)
+    for j in range(top_k):
+        part = y[slot[:, j]] * weights[:, j, None]
+        out = out + jnp.where(held[:, j, None], part, 0.0)
+        if (j + 1) % _COMBINE_AT_ONCE == 0 and j + 1 < top_k:
+            y, out = jax.lax.optimization_barrier((y, out))
+    count = jnp.sum(held.reshape(rows, -1), 1, dtype=jnp.int32)
+    return out.reshape(rows, length, hidden), count
+
+
+def _mean_real_state(x, real):
+    """x [B, L, hidden] float32 averaged over each row's tokens that are
+    not padding; a row of padding alone gives zeros."""
+    count = jnp.maximum(jnp.sum(real, 1, dtype=jnp.float32), 1.0)
+    return jnp.sum(jnp.where(real[..., None], x, 0.0), 1) / count[:, None]
+
+
+def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, experts_fn):
+    """ids [B, L] int32, zero-padded on the right -> (embeddings
+    [B, hidden] float32, slots that fell on held experts [B] int32)."""
+    eps = config.rms_norm_eps
+    real = ids != 0
+    tables = rope_tables(config, ids.shape[1])
+    x = params["embed"][ids].astype(jnp.float32)
+    slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
+    for i in range(config.num_layers):
+        p = params["layers"][str(i)]
+        u = _rms(x, p["norm_in"], eps).astype(dtype)
+        x = x + _mla(config, p["attn"], u, tables, attention_fn)
+        u = _rms(x, p["norm_ff"], eps)
+        if i < config.first_k_dense:
+            x = x + _swiglu(p["mlp"], u.astype(dtype))
+            continue
+        routed, count = _routed(config, p["moe"], u, real, experts_fn)
+        x = x + _swiglu(p["moe"]["shared"], u.astype(dtype)) + routed
+        slots_held = slots_held + count
+    return _mean_real_state(_rms(x, params["final_norm"], eps), real), slots_held
+
+
+def deepseek_v2_model_function(
+    size: str = "deepseek-v2-tiny",
+    dtype=jnp.float32,
+    seed: int = 0,
+    weights_file: Optional[str] = None,
+    attention_fn=None,
+    experts_fn=None,
+    name: Optional[str] = None,
+):
+    """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
+    tuples, as TextEmbedder feeds them). ``attention_fn`` and
+    ``experts_fn`` default to the build-time choice of
+    ``make_latent_attention_fn(heads, scale)`` and
+    ``make_grouped_matmul_fn()``: the Pallas kernels on TPU.
+
+    The program's result is [B, hidden + 1]: the embedding and, named by
+    ``mf.row_counters``, one more column: how many of the row's routed
+    slots fell on held experts, counted on the device and read back with
+    the row (``TextEmbedder`` strips the column and adds it to counter
+    ``moe.slots_held``; any other caller slices it off)."""
+    from sparkdl_tpu.graph.function import ModelFunction
+    from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
+    from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
+
+    if size not in _SIZES:
+        raise ValueError(
+            f"Unknown DeepSeek-V2 size {size!r}; supported: {sorted(_SIZES)}"
+        )
+    config = _SIZES[size]()
+    if attention_fn is None:
+        # blocks of 1,024 tokens: at 8 x 128 heads x 2,048 the blocked
+        # kernel read 23.4 ms a call against 24.9 at 512 and 42.0 at 256
+        # (7.6, 8.3, 13.5 at 1,024 tokens; PERF.md, PR 32)
+        attention_fn = make_latent_attention_fn(
+            config.num_heads, config.softmax_scale, block=1024
+        )
+    if experts_fn is None:
+        experts_fn = make_grouped_matmul_fn()
+    if weights_file:
+        params = load_flat(param_shapes(config), weights_file, dtype, _leaf_dtype)
+    else:
+        params = init_params(config, seed, dtype)
+
+    def fn(p, x):
+        ids = x[0] if isinstance(x, (tuple, list)) else x
+        out, slots_held = forward(
+            config, p, ids, dtype=dtype, attention_fn=attention_fn,
+            experts_fn=experts_fn,
+        )
+        # at most tokens x k x layers a row: exact in float32
+        return jnp.concatenate([out, slots_held[:, None].astype(jnp.float32)], 1)
+
+    mf = ModelFunction(
+        fn, params, input_dtype=jnp.int32, name=name or f"{size}[embed]"
+    )
+    mf.weights_as_arguments = True
+    mf.vocab_size = config.vocab_size
+    mf.attention = getattr(attention_fn, "kind", "custom")
+    mf.experts = getattr(experts_fn, "kind", "custom")
+    mf.row_counters = ("moe.slots_held",)
+    # per dispatched token (pad rows and pad tokens too), and per real one
+    mf.dispatched_token_counters = {"mla.attention_tokens": config.num_layers}
+    mf.real_token_counters = {
+        "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
+    }
+    return mf

@@ -194,11 +194,11 @@ def init_params(config: JambaConfig, seed: int, dtype) -> dict:
     return _unflatten(flat)
 
 
-def load_params(config: JambaConfig, weights_file: str, dtype) -> dict:
-    """A flat ``.npz`` of ``param_shapes``' paths. A ``uint16`` leaf is
-    the bit pattern of bfloat16 values (numpy has no bfloat16 of its own
-    to store): 2 bytes a parameter on disk and on the way in."""
-    shapes = param_shapes(config)
+def load_flat(shapes: dict, weights_file: str, dtype, leaf_dtype) -> dict:
+    """The tree of a flat ``.npz`` that holds ``shapes``' paths, each
+    leaf as ``leaf_dtype(path, shape, dtype)``. A ``uint16`` leaf is the
+    bit pattern of bfloat16 values (numpy has no bfloat16 of its own to
+    store): 2 bytes a parameter on disk and on the way in."""
     flat = {}
     with np.load(weights_file, allow_pickle=False) as blob:
         missing = sorted(set(shapes) - set(blob.files))
@@ -215,8 +215,13 @@ def load_params(config: JambaConfig, weights_file: str, dtype) -> dict:
                 raise ValueError(
                     f"{weights_file}: {path} is {leaf.shape}, not {shape}"
                 )
-            flat[path] = leaf.astype(_leaf_dtype(path, shape, dtype), copy=False)
+            flat[path] = leaf.astype(leaf_dtype(path, shape, dtype), copy=False)
     return _unflatten(flat)
+
+
+def load_params(config: JambaConfig, weights_file: str, dtype) -> dict:
+    """A flat ``.npz`` of ``param_shapes``' paths."""
+    return load_flat(param_shapes(config), weights_file, dtype, _leaf_dtype)
 
 
 def _rms(x, w, eps):

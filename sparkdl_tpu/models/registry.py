@@ -642,6 +642,48 @@ _register(
 )
 
 
+def _deepseek_v2_text_builder(size: str):
+    """Builder over models/deepseek_v2.py presets: latent attention and
+    shared plus routed experts, of which this chip holds a share;
+    ``embed`` is the mean final state of a row's real tokens (and a
+    column of counts, ``mf.row_counters``). The ModelFunction is marked
+    ``weights_as_arguments`` and reports
+    ``attention`` ('flash' | 'dense') and ``experts`` ('pallas' |
+    'ragged_dot'), both chosen at build time."""
+
+    def build(
+        spec: NamedTextModel, mode: str, dtype, weights_file, seed
+    ) -> ModelFunction:
+        from sparkdl_tpu.models import deepseek_v2 as deepseek_mod
+
+        return deepseek_mod.deepseek_v2_model_function(
+            size,
+            dtype=dtype,
+            seed=seed,
+            weights_file=weights_file,
+            name=f"{spec.name}[{mode}]",
+        )
+
+    return build
+
+
+# One chip's share of DeepSeek-V2 at its published widths (5 of 60
+# layers, experts 0-39 of 160, a quarter of the vocabulary: the cut of
+# benchmarks/configs/deepseek-v2.json), and the family at test size.
+_register(
+    NamedTextModel(
+        "deepseek-v2", 163840, 5120, "jax",
+        _deepseek_v2_text_builder("deepseek-v2"), vocab_size=25600,
+    )
+)
+_register(
+    NamedTextModel(
+        "deepseek-v2-tiny", 4096, 64, "jax",
+        _deepseek_v2_text_builder("deepseek-v2-tiny"), vocab_size=512,
+    )
+)
+
+
 def get_model(name: str):
     """The registered spec for ``name`` — a :class:`NamedImageModel` or
     :class:`NamedTextModel`; both expose ``model_function(mode=...)``

@@ -9,7 +9,11 @@ float32 accumulation, and the softmax runs online (running max/sum in VMEM
 scratch) so the [L, L] score matrix never materializes in HBM — O(L)
 memory instead of O(L²).
 
-Two tilings of that one algorithm, chosen by the heads' shape.
+Three tilings of that one algorithm, chosen by the heads' shape
+(the third, :func:`flash_attention_latent`, is the blocked causal kernel
+over a latent-attention model's own projections: a head's [nope | rope]
+query, its keys and values as two column blocks of the one up-projected
+array, and a rotary key that all heads share).
 :func:`flash_attention` takes [B, H, L, Dh] and gives one (row, head)
 pair to a grid step: right where a head fills the 128 lanes (Jamba's
 128), and what causal and shared-key/value attention run. A head
@@ -38,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG_INF = -1e30  # finite -inf stand-in: keeps exp()/max() NaN-free
+LANES = 128  # the lane width: the last dim of a VMEM tile
 
 
 def _flash_kernel(
@@ -90,20 +95,7 @@ def _flash_kernel(
             col = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col <= row, s, NEG_INF)
 
-        # lanes of m_ref/l_ref all hold the same per-row value; max() reads it
-        # back without a sub-128 lane slice.
-        m_prev = jnp.max(m_ref[:], axis=-1, keepdims=True)  # [bq, 1]
-        l_prev = jnp.max(l_ref[:], axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # [bq, bk]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        _softmax_step(s, v, m_ref, l_ref, acc_ref)
 
     if causal:
         pl.when(ki <= qi)(_block)
@@ -112,10 +104,31 @@ def _flash_kernel(
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l_final = jnp.max(l_ref[:], axis=-1, keepdims=True)
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_final, 1e-30)).astype(
-            o_ref.dtype
-        )
+        _write_result(o_ref, l_ref, acc_ref)
+
+
+def _softmax_step(s, v, m_ref, l_ref, acc_ref):
+    """One key block of the online softmax: scores s [bq, bk] and values
+    v [bk, dv] folded into the running max, sum and accumulator."""
+    # lanes of m_ref/l_ref all hold the same per-row value; max() reads it
+    # back without a sub-128 lane slice.
+    m_prev = jnp.max(m_ref[:], axis=-1, keepdims=True)  # [bq, 1]
+    l_prev = jnp.max(l_ref[:], axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)  # [bq, bk]
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _write_result(o_ref, l_ref, acc_ref):
+    l_final = jnp.max(l_ref[:], axis=-1, keepdims=True)
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_final, 1e-30)).astype(o_ref.dtype)
 
 
 def _pad_len(n: int, block: int) -> int:
@@ -252,9 +265,6 @@ def flash_attention(
 
     out = out.reshape(B, H, Lq_p, Dh_p)
     return out[:, :, :L, :Dh]
-
-
-LANES = 128  # the lane width: the last dim of a VMEM tile
 
 
 def packs(num_heads: Optional[int], head_dim: Optional[int]) -> bool:
@@ -467,6 +477,181 @@ def flash_attention_packed(
         name="flash_attention",
     )(q, k, v, mask2d[:, None, :])
     return out[:, :L]
+
+
+def _latent_kernel(nk, scale, nope, q_ref, k_ref, v_ref, kr_ref, o_ref, m_ref, l_ref, acc_ref):
+    """The blocked causal kernel for latent attention, one (row, head) a
+    grid step: the query block is [bq, nope + rope], the head's own keys
+    [bk, nope] and the token's shared rotary key [bk, rope]; a score is
+    the sum of the two products. Key blocks above the diagonal are
+    skipped; right padding needs no mask under a causal one."""
+    from jax.experimental import pallas as pl
+
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(ki <= qi)
+    def _block():
+        q = q_ref[0].astype(jnp.float32)  # [bq, nope + rope]
+        contract = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(
+            q[:, :nope], k_ref[0].astype(jnp.float32), contract,
+            preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            q[:, nope:], kr_ref[0].astype(jnp.float32), contract,
+            preferred_element_type=jnp.float32,
+        )
+        s = s * scale
+        bq, bk = s.shape
+        row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col <= row, s, NEG_INF)
+        _softmax_step(s, v_ref[0].astype(jnp.float32), m_ref, l_ref, acc_ref)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        _write_result(o_ref, l_ref, acc_ref)
+
+
+def flash_attention_latent(
+    q,
+    kv,
+    k_rope,
+    *,
+    num_heads: int,
+    scale: float,
+    block: int = 128,
+    interpret: bool = False,
+):
+    """Causal latent attention (MLA) over arrays as the projections wrote
+    them: nothing is transposed, concatenated, repeated or padded in HBM
+    but a length off the block size.
+
+    Args:
+        q: [B, L, H * (Dn + Dr)], a head's query [nope Dn | rope Dr].
+        kv: [B, L, H * (Dn + Dv)], a head's [key nope Dn | value Dv] side
+            by side, as the up-projection of the key/value latent writes
+            them; Dn == Dv, so that a head's keys and values are column
+            blocks 2h and 2h + 1 of the one array, each read in place.
+        k_rope: [B, L, Dr], a token's rotary key, shared by all heads:
+            every head's step reads the same block.
+        On the TPU Dn, Dr and Dv are multiples of the 128 lanes (a
+        block's last dim); the interpreter takes any.
+
+    A score is (q_nope . k_nope + q_rope . k_rope) * ``scale``. Returns
+    [B, L, H * Dv] in q's dtype. The blocked kernel's algorithm and
+    name; grid (B * H, L / block, L / block)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, L, wide = q.shape
+    H, rope = num_heads, k_rope.shape[2]
+    nope = wide // H - rope
+    dv = kv.shape[2] // H - nope
+    if (
+        wide % H or kv.shape[2] % H or nope != dv
+        or kv.shape[:2] != (B, L) or k_rope.shape[:2] != (B, L)
+    ):
+        raise ValueError(
+            f"latent attention over {H} heads wants q [B, L, H*(Dn+Dr)], "
+            f"kv [B, L, H*(Dn+Dv)] with Dn == Dv and k_rope [B, L, Dr]; "
+            f"got {q.shape}, {kv.shape}, {k_rope.shape}"
+        )
+    pad = _pad_len(L, block)
+    if pad:
+        q, kv, k_rope = (
+            jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (q, kv, k_rope)
+        )
+    n = (L + pad) // block
+
+    def keys(bh, qi, ki):  # a block above the diagonal is not fetched
+        return bh // H, jnp.minimum(ki, qi)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, n, scale, nope),
+        grid=(B * H, n, n),
+        in_specs=[
+            pl.BlockSpec(
+                (1, block, nope + rope), lambda bh, qi, ki: (bh // H, qi, bh % H)
+            ),
+            pl.BlockSpec(
+                (1, block, nope), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H))
+            ),
+            pl.BlockSpec(
+                (1, block, dv), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H) + 1)
+            ),
+            pl.BlockSpec((1, block, rope), lambda bh, qi, ki: (*keys(bh, qi, ki), 0)),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, block, dv), lambda bh, qi, ki: (bh // H, qi, bh % H)
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, L + pad, H * dv), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, 128), jnp.float32),  # running max
+            pltpu.VMEM((block, 128), jnp.float32),  # running sum
+            pltpu.VMEM((block, dv), jnp.float32),  # output accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        # the blocked kernel's name: one kernel to the trace's readers
+        name="flash_attention",
+    )(q, kv, kv, k_rope)
+    return out[:, :L]
+
+
+def dense_latent_attention(q, kv, k_rope, dtype, *, num_heads: int, scale: float):
+    """What :func:`flash_attention_latent` computes, as dense einsums over
+    the same arrays: float32 scores and softmax. The build-time fallback
+    off the TPU and the kernel's test oracle."""
+    B, L, _ = q.shape
+    rope = k_rope.shape[2]
+    q = q.reshape(B, L, num_heads, -1)
+    nope = q.shape[3] - rope
+    kv = kv.reshape(B, L, num_heads, -1)
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q[..., :nope], kv[..., :nope],
+        preferred_element_type=jnp.float32,
+    ) + jnp.einsum(
+        "bqhd,bkd->bhqk", q[..., nope:], k_rope, preferred_element_type=jnp.float32
+    )
+    s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s * scale, NEG_INF)
+    p = jax.nn.softmax(s, -1).astype(dtype)
+    o = jnp.einsum(
+        "bhqk,bkhd->bqhd", p, kv[..., nope:], preferred_element_type=jnp.float32
+    )
+    return o.reshape(B, L, -1).astype(dtype)
+
+
+def make_latent_attention_fn(
+    num_heads: int, scale: float, block: int = 128, interpret: bool = False
+):
+    """The latent attention a model is BUILT with, ``fn(q, kv, k_rope,
+    dtype)`` over the projections' own arrays: the Pallas kernel on TPU
+    (or interpreted when asked), :func:`dense_latent_attention`
+    elsewhere. ``.kind`` ('flash' | 'dense') says which."""
+    if not interpret and jax.default_backend() != "tpu":
+        dense = functools.partial(
+            dense_latent_attention, num_heads=num_heads, scale=scale
+        )
+        dense.kind = "dense"
+        return dense
+
+    def attention(q, kv, k_rope, dtype):
+        out = flash_attention_latent(
+            q, kv, k_rope, num_heads=num_heads, scale=scale, block=block,
+            interpret=interpret,
+        )
+        return out.astype(dtype)
+
+    attention.kind = "flash"
+    return attention
 
 
 def dense_causal_attention(q, k, v, mask, dtype):

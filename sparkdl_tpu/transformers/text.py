@@ -125,19 +125,42 @@ class TextEmbedder(
             # dispatched token (pad rows and pad tokens too) is scanned
             # once by each
             scan_layers = getattr(mf, "scan_layers", 0)
+            # and a model says what else its layers run over: counters
+            # per dispatched token and per real (non-pad) token
+            dispatched = dict(getattr(mf, "dispatched_token_counters", {}))
+            real = dict(getattr(mf, "real_token_counters", {}))
+            if scan_layers:
+                dispatched["ssm.scan_tokens"] = scan_layers
 
             def device_call(ids_batch, _fn=fn):
-                if scan_layers:
-                    metrics.inc(
-                        "ssm.scan_tokens", int(ids_batch.size) * scan_layers
-                    )
                 attn = (ids_batch != 0).astype(np.int32)
+                for name, each in dispatched.items():
+                    metrics.inc(name, int(ids_batch.size) * each)
+                if real:
+                    real_tokens = int(attn.sum())
+                    for name, each in real.items():
+                        metrics.inc(name, real_tokens * each)
                 return _fn((ids_batch, attn))
 
             device_call.n_devices = getattr(fn, "n_devices", 1)
             device_call.single_stream = getattr(fn, "single_stream", False)
             cache[key] = (mf, device_call)
         return cache[key][1]
+
+    def _strip_row_counters(self, rows):
+        """Rows as the program returned them -> rows of embeddings. A model
+        whose result carries counts made on the device names them in
+        ``row_counters``: that many trailing columns go to their counters."""
+        names = getattr(self.getModelFunction(), "row_counters", ())
+        if not names:
+            return rows
+        width = len(names)
+        live = [r for r in rows if r is not None]
+        if live:
+            totals = np.sum([r[-width:] for r in live], 0)
+            for name, total in zip(names, totals):
+                metrics.inc(name, int(round(float(total))))
+        return [None if r is None else r[:-width] for r in rows]
 
     def _tokenizer(self):
         if self.isDefined("tokenizer"):
@@ -167,12 +190,14 @@ class TextEmbedder(
             # sharding was built for exactly max_len.
             def run_partition_bucketed(part):
                 return {
-                    out_col: run_bucketed(
-                        part[in_col],
-                        tok,
-                        device_fn,
-                        batch_size,
-                        max_len,
+                    out_col: self._strip_row_counters(
+                        run_bucketed(
+                            part[in_col],
+                            tok,
+                            device_fn,
+                            batch_size,
+                            max_len,
+                        )
                     )
                 }
 
@@ -201,6 +226,6 @@ class TextEmbedder(
                 device_fn=device_fn,
                 batch_size=batch_size,
             )
-            return {out_col: outputs}
+            return {out_col: self._strip_row_counters(outputs)}
 
         return dataset.withColumnPartition(out_col, run_partition)

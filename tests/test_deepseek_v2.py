@@ -1,0 +1,368 @@
+"""The DeepSeek-V2 family (models/deepseek_v2.py) on the offline embed
+path, at the tiny preset with seeded random weights, against the plain
+reference (benchmarks/reference/deepseek_v2.py) row by row; the chip's
+share against the uncut layer; routing, rotary and the published
+configuration's count by hand.
+
+Tolerances. In float32 the program and the reference at `highest` do the
+same arithmetic in another order: 1e-6 of the spread of the rows, held to
+1e-5. In bfloat16 both round the operands of every matrix product to
+bfloat16, at other points: the program rounds a rotary query's two terms,
+x cos and turn(x) sin, apart (models/deepseek_v2.py:_mla) and the
+reference their sum, and a token whose last chosen expert swaps with the
+next (routing is discrete) moves its row by its share of the row's mean,
+most in a row of ten tokens: 0.008 at the median and 0.09 at the widest
+with the plain forms, 0.013 and 0.03 with the interpreted kernels, held to
+0.03 and 0.12; the float8 control reads 0.30
+(tests/benchmarks/test_deepseek_v2_cell.py holds that)."""
+
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "tests", "benchmarks"))
+
+from deepseek_v2_tiny import published_config, tiny_config, write_weights  # noqa: E402
+
+from benchmarks import compare  # noqa: E402
+from benchmarks.data import texts  # noqa: E402
+from benchmarks.reference import deepseek_v2 as reference  # noqa: E402
+from sparkdl_tpu.dataframe import DataFrame  # noqa: E402
+from sparkdl_tpu.models import deepseek_v2 as program  # noqa: E402
+from sparkdl_tpu.models import get_model  # noqa: E402
+from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn  # noqa: E402
+from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn  # noqa: E402
+from sparkdl_tpu.transformers.text import TextEmbedder  # noqa: E402
+from sparkdl_tpu.utils.metrics import metrics  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def tiny(tmp_path_factory):
+    config = tiny_config()
+    path = str(tmp_path_factory.mktemp("deepseek") / "tiny.npz")
+    return config, write_weights(path, config), path
+
+
+@pytest.fixture(scope="module")
+def corpus():
+    """Twelve texts whose token counts fall either side of the 128 edge,
+    two of them full windows of 256."""
+    data = {
+        "rows": 12, "vocabulary_words": 300,
+        "word_counts": [[254, 2], [10, 2], [60, 2], [100, 2], [130, 2], [200, 2]],
+    }
+    return list(texts.rows(data, np.random.default_rng(0), set()))
+
+
+def _counters():
+    return dict(metrics.scalar_snapshot()["counters"])
+
+
+def _embed(path, inputs, dtype, interpret, batch=4, max_length=256):
+    preset = program.deepseek_v2_tiny()
+    mf = program.deepseek_v2_model_function(
+        "deepseek-v2-tiny", dtype=dtype, weights_file=path,
+        attention_fn=make_latent_attention_fn(
+            preset.num_heads, preset.softmax_scale, block=64, interpret=interpret
+        ),
+        experts_fn=make_grouped_matmul_fn(interpret=interpret),
+    )
+    out = TextEmbedder(
+        inputCol="in", outputCol="out", modelFunction=mf, maxLength=max_length,
+        batchSize=batch,
+    ).transform(DataFrame.fromColumns({"in": inputs}, numPartitions=2)).collect()
+    return mf, np.stack([np.asarray(r["out"], np.float32) for r in out])
+
+
+def test_tiny_preset_is_the_family(tiny):
+    config, _, _ = tiny
+    preset = program.deepseek_v2_tiny()
+    assert reference.weight_shapes(config) == program.param_shapes(preset)
+    assert preset.expert_layers == 2 and preset.first_k_dense == 1
+    assert (preset.n_routed_experts, preset.n_group, preset.topk_group) == (16, 4, 2)
+    assert preset.experts_held == (0, 4)
+    assert preset.softmax_scale == pytest.approx(reference.softmax_scale(config))
+
+
+def test_published_preset_is_the_configuration_file():
+    """Shapes only: nothing of 5 B parameters is made."""
+    config = published_config()
+    preset = program.deepseek_v2()
+    assert reference.weight_shapes(config) == program.param_shapes(preset)
+    assert preset.experts_held == tuple(config["experts_held"]) == (0, 40)
+    assert preset.n_routed_experts == config["published"]["n_routed_experts"] == 160
+    spec = get_model("deepseek-v2")
+    assert spec.feature_dim == 5120 and spec.vocab_size == 25600
+    assert get_model("deepseek-v2-tiny").feature_dim == 64
+
+
+@pytest.mark.parametrize(
+    "dtype, precision, interpret, median, widest",
+    [
+        (jnp.float32, "highest", False, 1e-5, 1e-5),
+        (jnp.float32, "highest", True, 1e-5, 1e-5),
+        (jnp.bfloat16, "reference", False, 3e-2, 1.2e-1),
+        (jnp.bfloat16, "reference", True, 3e-2, 1.2e-1),
+    ],
+)
+def test_embedder_matches_the_reference_row_by_row(
+    monkeypatch, tiny, corpus, dtype, precision, interpret, median, widest
+):
+    config, weights, path = tiny
+    monkeypatch.setenv("SPARKDL_TEXT_BUCKETS", "128,256")
+    monkeypatch.setenv("SPARKDL_TEXT_MIN_BUCKET", "128")
+    before = _counters()
+    mf, got = _embed(path, corpus, dtype, interpret)
+    assert (mf.attention, mf.experts) == (
+        ("flash", "pallas") if interpret else ("dense", "ragged_dot")
+    )
+    assert mf.weights_as_arguments and mf.row_counters == ("moe.slots_held",)
+    assert got.shape == (12, 64)  # the column of counts is stripped
+    want = reference.outputs(config, weights, corpus, precision=precision)
+    errs = compare.row_errors(got, want)
+    assert np.median(errs) <= median and errs.max() <= widest, errs
+    assert compare.rows_mismatched(got, want) == 0
+    delta = {k: v - before.get(k, 0) for k, v in _counters().items()}
+    # rows of both buckets in batches of 4: every dispatched token, 3 layers
+    dispatched = delta["mla.attention_tokens"] / 3
+    assert dispatched >= sum(len(reference.tokenize(t, 512, 256)) for t in corpus)
+    real = sum(len(reference.tokenize(t, 512, 256)) for t in corpus)
+    assert delta["moe.slots_routed"] == real * 3 * 2  # top-3, two expert layers
+    # a quarter of the experts are held: about a quarter of the slots
+    share = delta["moe.slots_held"] / delta["moe.slots_routed"]
+    assert 0.1 < share < 0.45, share
+
+
+def test_a_row_does_not_change_with_what_pads_it(monkeypatch, tiny, corpus):
+    """Alone in its batch (padded by empty rows), beside longer rows, in
+    a wider bucket: the same answer."""
+    _, _, path = tiny
+    monkeypatch.setenv("SPARKDL_TEXT_BUCKETS", "128,256")
+    monkeypatch.setenv("SPARKDL_TEXT_MIN_BUCKET", "128")
+    short = [t for t in corpus if len(t.split()) < 120][:2]
+    _, together = _embed(path, corpus, jnp.float32, False)
+    _, alone = _embed(path, short, jnp.float32, False)
+    at = [corpus.index(t) for t in short]
+    np.testing.assert_allclose(alone, together[at], atol=2e-5)
+    monkeypatch.setenv("SPARKDL_TEXT_BUCKETS", "256")
+    monkeypatch.setenv("SPARKDL_TEXT_MIN_BUCKET", "256")
+    _, wide = _embed(path, short, jnp.float32, False)
+    np.testing.assert_allclose(wide, alone, atol=2e-5)
+
+
+# -- the share adds up ---------------------------------------------------------
+
+
+def test_four_shares_and_the_shared_experts_once_are_the_uncut_layer():
+    """One expert layer of the tiny preset: the routed parts that the
+    four shares (4 experts each) compute in the program, plus the shared
+    experts once, equal the uncut layer of the reference (all 16)."""
+    uncut = tiny_config(held=None)
+    weights = reference.make_weights(uncut, 3)
+    name = "layers/1/moe/"
+    moe = {
+        k[len(name):]: jnp.asarray(reference.from_bits(v), jnp.float32)
+        for k, v in weights.items() if k.startswith(name)
+    }
+    rng = np.random.default_rng(0)
+    u = jnp.asarray(rng.standard_normal((2, 24, 64)), jnp.float32)
+    # the reference, whole: shared + all 16 experts as a masked loop
+    with jax.default_matmul_precision("highest"):
+        experts, gates = reference.route(uncut, u, moe["router"])
+        shared = {k.split("/")[1]: v for k, v in moe.items() if k.startswith("shared/")}
+        whole = reference._swiglu("highest", shared, u)
+        for e in range(16):
+            w = {k.split("/")[1]: v[e] for k, v in moe.items() if k.startswith("experts/")}
+            whole = whole + reference._expert("highest", e, w, u, experts, gates)
+    # the program, share by share
+    real = jnp.ones((2, 24), bool)
+    total, slots = 0.0, 0
+    for first in (0, 4, 8, 12):
+        preset = program.DeepseekV2Config(
+            **{**program.deepseek_v2_tiny().__dict__, "experts_held": (first, first + 4)}
+        )
+        p = {
+            "router": moe["router"],
+            "experts": {
+                k: moe[f"experts/{k}"][first : first + 4] for k in ("gate", "up", "down")
+            },
+        }
+        part, count = program._routed(preset, p, u, real, make_grouped_matmul_fn())
+        total, slots = total + part, slots + int(count.sum())
+    assert slots == 2 * 24 * 3  # every slot is held by exactly one share
+    p_shared = {k: moe[f"shared/{k}"] for k in ("gate", "up", "down")}
+    total = total + program._swiglu(p_shared, u)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole), atol=2e-4, rtol=2e-4)
+
+
+# -- routing by hand -----------------------------------------------------------
+
+
+def _route_by_hand(logits, groups=4, keep=2, top=3, scaling=16.0):
+    s = np.exp(logits - logits.max())
+    s = s / s.sum()
+    per = len(s) // groups
+    best = [s[g * per : (g + 1) * per].max() for g in range(groups)]
+    kept = sorted(range(groups), key=lambda g: -best[g])[:keep]
+    masked = np.array([s[e] if e // per in kept else 0.0 for e in range(len(s))])
+    chosen = sorted(range(len(s)), key=lambda e: -masked[e])[:top]
+    return chosen, [scaling * s[e] for e in chosen]
+
+
+def test_routing_by_hand():
+    """16 experts in 4 groups of which 2, top-3. Group 3 holds the
+    second-largest and third-largest scores but its best is under the
+    bests of groups 0 and 2, so it is dropped whole: a high-scoring
+    expert in a dropped group is not chosen. Weights are 16 x the
+    softmax score and do not sum to 16."""
+    preset = program.deepseek_v2_tiny()
+    logits = np.full(16, -2.0, np.float32)
+    logits[1] = 3.0  # group 0: the largest
+    logits[9] = 2.6  # group 2: its best
+    logits[12], logits[13] = 2.5, 2.4  # group 3: high, but dropped
+    logits[2], logits[8] = 1.0, 0.5
+    # a router that hands the logits through: u = one-hot rows of an identity
+    router = jnp.asarray(np.eye(64, 16, dtype=np.float32))
+    u = np.zeros((2, 64), np.float32)
+    u[0, :16] = logits
+    u[1, :16] = logits[::-1]
+    experts, weights = program.route(preset, jnp.asarray(u), router)
+    chosen, by_hand = _route_by_hand(logits)
+    assert chosen == [1, 9, 2]
+    assert 12 not in chosen and 13 not in chosen
+    assert np.asarray(experts[0]).tolist() == chosen
+    np.testing.assert_allclose(np.asarray(weights[0]), by_hand, rtol=1e-5)
+    assert float(weights[0].sum()) != pytest.approx(16.0, rel=0.05)
+    assert np.asarray(experts[1]).tolist() == _route_by_hand(logits[::-1])[0]
+    # the reference routes the same way
+    config = tiny_config()
+    r_experts, r_weights = reference.route(config, jnp.asarray(u), router)
+    assert np.asarray(r_experts).tolist() == np.asarray(experts).tolist()
+    np.testing.assert_allclose(np.asarray(r_weights), np.asarray(weights), rtol=1e-6)
+
+
+def test_a_pad_token_takes_no_slot():
+    preset = program.deepseek_v2_tiny()
+    params = program.init_params(preset, 0, jnp.float32)
+    moe = params["layers"]["1"]["moe"]
+    u = jnp.asarray(np.random.default_rng(1).standard_normal((2, 16, 64)), jnp.float32)
+    real = np.ones((2, 16), bool)
+    real[0, 5:] = False  # a row of five tokens
+    real[1, :] = False  # a row that only fills the batch
+    part, count = program._routed(
+        preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn()
+    )
+    experts, _ = program.route(preset, u.reshape(-1, 64), moe["router"])
+    held = np.asarray((experts >= 0) & (experts < 4)).reshape(2, 16, 3)
+    assert count.tolist() == [int(held[0, :5].sum()), 0]
+    assert not np.asarray(part[0, 5:]).any() and not np.asarray(part[1]).any()
+    # the real tokens' part is what it is with every token real
+    whole, _ = program._routed(
+        preset, moe, u, jnp.ones((2, 16), bool), make_grouped_matmul_fn()
+    )
+    np.testing.assert_allclose(np.asarray(part[0, :5]), np.asarray(whole[0, :5]), atol=1e-6)
+
+
+# -- rotary --------------------------------------------------------------------
+
+
+def test_yarn_by_hand():
+    config = published_config()
+    preset = program.deepseek_v2()
+
+    def d(rotations):  # 64 * ln(4096 / (2 pi r)) / (2 ln 10000)
+        return 64 * math.log(4096 / (2 * math.pi * rotations)) / (2 * math.log(10000))
+
+    assert (math.floor(d(32)), math.ceil(d(1))) == (10, 23)
+    assert reference.yarn_range(config) == program.yarn_range(preset) == (10, 23)
+    m = 0.1 * 0.707 * math.log(40) + 1
+    assert m == pytest.approx(1.260804, abs=1e-6)
+    assert m * m == pytest.approx(1.589626, abs=1e-6)
+    assert preset.softmax_scale == pytest.approx(192**-0.5 * 1.589626, rel=1e-6)
+    assert reference.softmax_scale(config) == pytest.approx(preset.softmax_scale)
+    f = 10000.0 ** (-np.arange(32) / 32)
+    ramp = np.clip((np.arange(32) - 10) / 13, 0, 1)
+    by_hand = f * (1 - ramp) + f / 40 * ramp
+    np.testing.assert_allclose(program.yarn_inv_freq(preset), by_hand, rtol=1e-6)
+    np.testing.assert_allclose(reference.yarn_inv_freq(config), by_hand, rtol=1e-6)
+    # the fastest pairs turn as unscaled rope does, the slowest 40 times slower
+    assert by_hand[0] == 1.0 and by_hand[31] == pytest.approx(f[31] / 40)
+
+
+def test_the_programs_pairing_gives_the_sources_scores():
+    """The source de-interleaves a rotary vector and rotates halves; the
+    program permutes the projection's columns and rotates halves. The
+    rotated vectors are the same, and so is every score."""
+    config = published_config()
+    preset = program.deepseek_v2()
+    rng = np.random.default_rng(2)
+    length = 48
+    q = jnp.asarray(rng.standard_normal((3, length, 64)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((length, 64)), jnp.float32)
+    tables = program.rope_tables(preset, length)
+    perm = program._deinterleave(64)
+    assert perm[:4].tolist() == [0, 2, 4, 6] and perm[32:35].tolist() == [1, 3, 5]
+    ours_q = program._rotate(q[..., perm], *tables)
+    ours_k = program._rotate(k[..., perm], *tables)
+    source_q = reference._rope(config, q, length)
+    source_k = reference._rope(config, k, length)
+    np.testing.assert_allclose(np.asarray(ours_q), np.asarray(source_q), atol=1e-5)
+    scores = lambda a, b: np.einsum("hqd,kd->hqk", np.asarray(a), np.asarray(b))  # noqa: E731
+    np.testing.assert_allclose(scores(ours_q, ours_k), scores(source_q, source_k), atol=1e-4)
+    # the pair (2i, 2i + 1) of position t turns by t * inv_freq[i]
+    t, i = 7, 3
+    angle = t * program.yarn_inv_freq(preset)[i]
+    x0, x1 = float(q[0, t, 2 * i]), float(q[0, t, 2 * i + 1])
+    assert float(source_q[0, t, i]) == pytest.approx(
+        x0 * math.cos(angle) - x1 * math.sin(angle), abs=1e-5
+    )
+    assert float(source_q[0, t, 32 + i]) == pytest.approx(
+        x1 * math.cos(angle) + x0 * math.sin(angle), abs=1e-5
+    )
+    # and a rotation of another pairing does not give these scores
+    other = program._rotate(q, *tables)
+    assert np.abs(np.asarray(other) - np.asarray(source_q)).max() > 0.1
+    # the program leaves a query as [x cos | turn(x) sin] and writes the
+    # rotated key twice: the product over both halves is rope(x) . k
+    x = q[..., perm]
+    split = jnp.concatenate([x * tables[0], program._turn(x) * tables[1]], -1)
+    twice = jnp.concatenate([ours_k, ours_k], -1)
+    np.testing.assert_allclose(scores(split, twice), scores(source_q, source_k), atol=1e-4)
+    # and the projection writes turn(x) itself: x @ turn(w) == turn(x @ w)
+    w = jnp.asarray(rng.standard_normal((24, 4 * (16 + 8))), jnp.float32)
+    tiny = program.deepseek_v2_tiny()
+    wide = program._query_weights(tiny, w).reshape(24, 4, 32)
+    c = jnp.asarray(rng.standard_normal((5, 24)), jnp.float32)
+    got = jnp.einsum("tr,rhd->thd", c, wide)
+    plain = jnp.einsum("tr,rhd->thd", c, w.reshape(24, 4, 24))
+    r = plain[..., 16:][..., program._deinterleave(8)]
+    np.testing.assert_allclose(np.asarray(got[..., :16]), np.asarray(plain[..., :16]), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[..., 16:24]), np.asarray(r), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[..., 24:]), np.asarray(program._turn(r)), atol=1e-5)
+
+
+def test_weights_file_is_read_strictly(tmp_path, tiny):
+    config, weights, _ = tiny
+    short = {k: np.asarray(v) for k, v in weights.items() if "router" not in k}
+    path = str(tmp_path / "short.npz")
+    np.savez(path, **short)
+    with pytest.raises(ValueError, match="lacks 2 leaves"):
+        program.deepseek_v2_model_function("deepseek-v2-tiny", weights_file=path)
+    with pytest.raises(ValueError, match="Unknown DeepSeek-V2 size"):
+        program.deepseek_v2_model_function("deepseek-v3")
+    mf = program.deepseek_v2_model_function("deepseek-v2-tiny", weights_file=tiny[2])
+    # the router stays float32 whatever the compute dtype, matrices follow it
+    mf16 = program.deepseek_v2_model_function(
+        "deepseek-v2-tiny", dtype=jnp.bfloat16, weights_file=tiny[2]
+    )
+    moe = mf16.params["layers"]["1"]["moe"]
+    assert moe["router"].dtype == jnp.float32
+    assert moe["experts"]["gate"].dtype == jnp.bfloat16
+    assert mf.params["layers"]["0"]["attn"]["q_norm"].dtype == jnp.float32

@@ -169,3 +169,124 @@ def test_bidirectional_equal_heads_kernel_is_untouched():
     got = flash_attention(q, k, v, mask, block_q=32, block_k=32, interpret=True)
     want = dense_attention(q, k, v, mask[:, None, None, :], jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+#: sha256 of the traced kernel call at the shapes that ran before the
+#: online-softmax step was shared with the latent kernel, taken from the
+#: parent commit (PR 32): the
+#: hybrid family's causal 20 x 128 over one key/value head in blocks of
+#: 512, bert's 12 x 64 padded to the lanes, and a ring shard's 4 x 32.
+PINNED = {
+    (2, 20, 1, 1024, 128, True, 512): "0a60b61336085f04",
+    (2, 12, 12, 256, 64, False, 128): "c91f841b90097964",
+    (1, 4, 4, 96, 32, False, 32): "11f3602d0556a1d0",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(PINNED, key=str))
+def test_existing_shapes_lower_to_what_they_were(shape):
+    import hashlib
+
+    B, H, Hkv, L, Dh, causal, block = shape
+    q = jnp.zeros((B, H, L, Dh), jnp.bfloat16)
+    k = jnp.zeros((B, Hkv, L, Dh), jnp.bfloat16)
+    mask = jnp.zeros((B, L), jnp.float32)
+    text = str(
+        jax.make_jaxpr(
+            lambda q, k, v, m: flash_attention(
+                q, k, v, m, block_q=block, block_k=block, causal=causal
+            )
+        )(q, k, k, mask)
+    )
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[shape]
+
+
+# -- latent attention over the projections' own arrays -------------------------
+
+
+def latent_arrays(seed, B, L, H, nope, rope, dtype=jnp.float32):
+    r = np.random.default_rng(seed)
+    q = jnp.asarray(r.normal(size=(B, L, H * (nope + rope))), dtype)
+    kv = jnp.asarray(r.normal(size=(B, L, H * 2 * nope)), dtype)
+    k_rope = jnp.asarray(r.normal(size=(B, L, rope)), dtype)
+    return q, kv, k_rope
+
+
+def latent_by_heads(q, kv, k_rope, H, scale):
+    """The same attention written out head by head: heads split out, the
+    shared rotary key repeated for every head, float32 throughout."""
+    B, L, _ = q.shape
+    rope = k_rope.shape[2]
+    q = q.reshape(B, L, H, -1).transpose(0, 2, 1, 3)
+    kv = kv.reshape(B, L, H, -1).transpose(0, 2, 1, 3)
+    nope = q.shape[3] - rope
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope[:, None], (B, H, L, rope))], -1
+    )
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -jnp.inf)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), kv[..., nope:])
+    return o.transpose(0, 2, 1, 3).reshape(B, L, -1)
+
+
+@pytest.mark.parametrize(
+    "H, L, nope, rope, block",
+    [
+        (3, 200, 128, 128, 64),  # the sizes the chip runs; a length off the block
+        (4, 200, 128, 64, 128),  # the published 128 + 64 over values of 128
+        (128, 64, 16, 16, 32),  # the published head count
+        (4, 333, 32, 16, 64),  # six blocks, the last part-filled
+        (4, 192, 128, 64, 64),  # whole blocks, the diagonal's alone masked
+        (1, 130, 128, 64, 128),  # one head, two blocks, the second nearly empty
+    ],
+)
+def test_latent_kernel_matches_dense_over_split_heads(H, L, nope, rope, block):
+    from sparkdl_tpu.ops.flash_attention import (
+        dense_latent_attention,
+        flash_attention_latent,
+    )
+
+    q, kv, k_rope = latent_arrays(H + L, 2 if H < 8 else 1, L, H, nope, rope)
+    scale = 0.07
+    got = flash_attention_latent(
+        q, kv, k_rope, num_heads=H, scale=scale, block=block, interpret=True
+    )
+    assert got.shape == (q.shape[0], L, H * nope)
+    with jax.default_matmul_precision("highest"):
+        want = latent_by_heads(q, kv, k_rope, H, scale)
+        plain = dense_latent_attention(
+            q, kv, k_rope, jnp.float32, num_heads=H, scale=scale
+        )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+    np.testing.assert_allclose(np.asarray(plain), np.asarray(want), **TOL)
+
+
+def test_latent_kernel_moves_nothing_in_hbm_and_says_what_it_cannot_do():
+    from sparkdl_tpu.ops.flash_attention import (
+        flash_attention_latent,
+        make_latent_attention_fn,
+    )
+
+    q, kv, k_rope = latent_arrays(11, 1, 128, 2, 128, 128)
+    text = str(
+        jax.make_jaxpr(
+            lambda *a: flash_attention_latent(*a, num_heads=2, scale=0.1, block=64)
+        )(q, kv, k_rope)
+    )
+    # the call and nothing beside it: no pad, transpose, concatenate, broadcast
+    for moved in ("pad", "transpose", "concatenate", "broadcast_in_dim[\n"):
+        assert moved not in text.split("pallas_call")[0], moved
+    with pytest.raises(ValueError, match="Dn == Dv"):
+        flash_attention_latent(
+            q, kv[..., :384], k_rope, num_heads=2, scale=0.1, interpret=True
+        )
+    fn = make_latent_attention_fn(2, 0.1)
+    assert fn.kind == "dense"  # the tests run on the CPU
+    kernel = make_latent_attention_fn(2, 0.1, block=64, interpret=True)
+    assert kernel.kind == "flash"
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            np.asarray(kernel(q, kv, k_rope, jnp.float32)),
+            np.asarray(fn(q, kv, k_rope, jnp.float32)),
+            **TOL,
+        )
