@@ -22,8 +22,14 @@ experts a token; a (token, k) slot whose expert is absent is computed by
 nobody here and nothing stands in for it: the partial sum goes on. Pad
 tokens (id 0) are not routed: a causal stack never lets a real token see
 them. No slot is dropped at any load: the slots are sorted by expert,
-absent ones last, into a buffer of tokens x k rows, and
-``ops/grouped_matmul.py`` is given the held experts' group sizes.
+absent ones last, and ``ops/grouped_matmul.py`` is given the held
+experts' group sizes. The buffer they are sorted into has the rows the
+share implies (``slot_capacity``: held / routed experts of the tokens x k
+slots, with room above), and the worst-case buffer of tokens x k rows is
+the other arm of a ``lax.cond`` on the measured load (``_routed``), so a
+load over the share costs time and never a slot; a chip that holds every
+expert has the one worst-case body and no conditional. Which arm each
+expert layer took rides back with the rows (``mf.row_counters``).
 
 The rotary part follows YaRN with frequencies fixed at build time. The
 source de-interleaves a rotary vector (x0,x1,x2,..) -> (x0,x2,..|x1,x3,..)
@@ -50,6 +56,7 @@ into one program with every layer's weights an argument of its own
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
@@ -355,13 +362,66 @@ def route(config: DeepseekV2Config, u, router):
     return experts, weights
 
 
+#: the grouped kernel's row tile at an expert layer's sizes
+#: (``ops/grouped_matmul.py:choose_tiling``)
+_ROW_TILE = 256
+#: room above the held share's expectation in the sized slot buffer. The
+#: load of a dispatch of 16,384 tokens spreads under 1% and its fullest
+#: reading is 24.60% of the routed slots for a held quarter (PERF.md,
+#: PR 32); a load over the buffer costs the worst-case arm's time and no
+#: slot, so the low end of what covers every reading is the one to take
+_CAPACITY_MARGIN = 1.25
+#: gathered parts of the worst-case arm's combine summed a pass
 _COMBINE_AT_ONCE = 3
+
+
+def slot_capacity(config: DeepseekV2Config, tokens: int) -> int:
+    """Rows of the routed path's slot buffer for a dispatch of ``tokens``:
+    the chip's share of the ``tokens x k`` slots with ``_CAPACITY_MARGIN``
+    of room, in whole row tiles; all the slots where that is no fewer (a
+    chip that holds every expert, shapes too small to round)."""
+    slots = tokens * config.num_experts_per_tok
+    first, end = config.experts_held
+    room = _CAPACITY_MARGIN * (end - first) / config.n_routed_experts * slots
+    return min(slots, -(-math.ceil(room) // _ROW_TILE) * _ROW_TILE)
+
+
+def _experts_and_combine(
+    rows, at_once, experts_fn, experts, flat, order, sizes, slot, weights, held
+):
+    """The held slots' three products over a buffer of ``rows`` slot rows
+    (static; the sorted slots' first ``rows``, which must hold every held
+    one) and their weighted sum back in token order [tokens, hidden]
+    float32, ``at_once`` gathered parts a pass."""
+    top_k = weights.shape[1]
+    x = flat[order[:rows] // top_k]  # [rows, hidden]
+    gate = experts_fn(x, experts["gate"], sizes)
+    up = experts_fn(x, experts["up"], sizes)
+    y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], sizes)
+    # back to (token, k) order; what the kernel left unwritten is not read
+    at = jnp.minimum(slot, rows - 1)
+    out = jnp.zeros((flat.shape[0], y.shape[1]), jnp.float32)
+    for j in range(top_k):
+        part = y[at[:, j]] * weights[:, j, None]
+        out = out + jnp.where(held[:, j, None], part, 0.0)
+        if (j + 1) % at_once == 0 and j + 1 < top_k:
+            y, out = jax.lax.optimization_barrier((y, out))
+    return out
 
 
 def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
     """u [B, L, hidden] float32 (the norm's output), real [B, L] bool ->
     (the held experts' part of the routed sum [B, L, hidden] float32,
-    how many of each row's slots fell on held experts [B] int32)."""
+    how many of each row's slots fell on held experts [B] int32, whether
+    the sized slot buffer held the load, a bool scalar).
+
+    The slot buffer is ``slot_capacity`` rows, and the worst-case buffer
+    of every slot is the other arm of a ``lax.cond`` on the measured load:
+    no slot is dropped in either. The slots are sorted held-first, so the
+    first ``sum(sizes)`` rows are the same rows in both arms, the kernel
+    visits the same tiles and the sized arm does the full one's arithmetic
+    in its order: the same bits wherever the compiler fuses the two alike
+    (PERF.md, PR 33). Where the two row counts are one there is one body."""
     rows, length, hidden = u.shape
     tokens, top_k = rows * length, config.num_experts_per_tok
     first, end = config.experts_held
@@ -374,26 +434,26 @@ def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
     sizes = jnp.sum(
         key[:, None] == jnp.arange(end - first, dtype=key.dtype), 0, dtype=jnp.int32
     )
-    dtype = p["experts"]["gate"].dtype
-    x = flat.astype(dtype)[order // top_k]  # [tokens * k, hidden]
-    gate = experts_fn(x, p["experts"]["gate"], sizes)
-    up = experts_fn(x, p["experts"]["up"], sizes)
-    y = experts_fn((_silu(gate) * up).astype(dtype), p["experts"]["down"], sizes)
-    # back to (token, k) order; what the kernel left unwritten is not read
     slot = jnp.argsort(order).reshape(tokens, top_k)
+    operands = (
+        p["experts"], flat.astype(p["experts"]["gate"].dtype), order, sizes,
+        slot, weights, held,
+    )
+    slots, capacity = tokens * top_k, slot_capacity(config, tokens)
 
-    # a token's k choices, summed _COMBINE_AT_ONCE gathered parts a pass:
-    # all k at once are 2 GB of gathers beside y; one at a time (a loop
-    # carrying the sum) made k passes over it, 4.40 s a job against 4.19
-    # on the chip (PERF.md, PR 32). The barrier orders the passes.
-    out = jnp.zeros((tokens, hidden), jnp.float32)
-    for j in range(top_k):
-        part = y[slot[:, j]] * weights[:, j, None]
-        out = out + jnp.where(held[:, j, None], part, 0.0)
-        if (j + 1) % _COMBINE_AT_ONCE == 0 and j + 1 < top_k:
-            y, out = jax.lax.optimization_barrier((y, out))
+    # the worst-case arm: all k gathered parts at once are 2 GB beside its
+    # y; one at a time (a loop carrying the sum) made k passes over it,
+    # 4.40 s a job against 4.19 on the chip (PERF.md, PR 32). The barrier
+    # orders the passes. The sized arm's y is a third of that: one pass.
+    full = functools.partial(_experts_and_combine, slots, _COMBINE_AT_ONCE, experts_fn)
+    if capacity == slots:
+        fits, out = jnp.zeros((), bool), full(*operands)
+    else:
+        sized = functools.partial(_experts_and_combine, capacity, top_k, experts_fn)
+        fits = jnp.sum(sizes) <= capacity
+        out = jax.lax.cond(fits, sized, full, *operands)
     count = jnp.sum(held.reshape(rows, -1), 1, dtype=jnp.int32)
-    return out.reshape(rows, length, hidden), count
+    return out.reshape(rows, length, hidden), count, fits
 
 
 def _mean_real_state(x, real):
@@ -405,12 +465,14 @@ def _mean_real_state(x, real):
 
 def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, experts_fn):
     """ids [B, L] int32, zero-padded on the right -> (embeddings
-    [B, hidden] float32, slots that fell on held experts [B] int32)."""
+    [B, hidden] float32, slots that fell on held experts [B] int32, how
+    many expert layers worked on the sized slot buffer, an int32 scalar)."""
     eps = config.rms_norm_eps
     real = ids != 0
     tables = rope_tables(config, ids.shape[1])
     x = params["embed"][ids].astype(jnp.float32)
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
+    sized = jnp.zeros((), jnp.int32)
     for i in range(config.num_layers):
         p = params["layers"][str(i)]
         u = _rms(x, p["norm_in"], eps).astype(dtype)
@@ -419,10 +481,10 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
         if i < config.first_k_dense:
             x = x + _swiglu(p["mlp"], u.astype(dtype))
             continue
-        routed, count = _routed(config, p["moe"], u, real, experts_fn)
+        routed, count, fits = _routed(config, p["moe"], u, real, experts_fn)
         x = x + _swiglu(p["moe"]["shared"], u.astype(dtype)) + routed
-        slots_held = slots_held + count
-    return _mean_real_state(_rms(x, params["final_norm"], eps), real), slots_held
+        slots_held, sized = slots_held + count, sized + fits
+    return _mean_real_state(_rms(x, params["final_norm"], eps), real), slots_held, sized
 
 
 def deepseek_v2_model_function(
@@ -440,11 +502,15 @@ def deepseek_v2_model_function(
     ``make_latent_attention_fn(heads, scale)`` and
     ``make_grouped_matmul_fn()``: the Pallas kernels on TPU.
 
-    The program's result is [B, hidden + 1]: the embedding and, named by
-    ``mf.row_counters``, one more column: how many of the row's routed
-    slots fell on held experts, counted on the device and read back with
-    the row (``TextEmbedder`` strips the column and adds it to counter
-    ``moe.slots_held``; any other caller slices it off)."""
+    The program's result is [B, hidden + 3]: the embedding and, named by
+    ``mf.row_counters``, three more columns counted on the device and
+    read back with the row: how many of the row's routed slots fell on
+    held experts, and how many of its dispatch's expert layers worked on
+    the sized slot buffer and on the worst-case one (``_routed``; the
+    two sum to the expert layers, so over a job to live rows x expert
+    layers). ``TextEmbedder`` strips the columns and adds them to
+    counters ``moe.slots_held``, ``moe.buffer_sized`` and
+    ``moe.buffer_full``; any other caller slices them off."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
@@ -470,12 +536,14 @@ def deepseek_v2_model_function(
 
     def fn(p, x):
         ids = x[0] if isinstance(x, (tuple, list)) else x
-        out, slots_held = forward(
+        out, slots_held, sized = forward(
             config, p, ids, dtype=dtype, attention_fn=attention_fn,
             experts_fn=experts_fn,
         )
+        sized = jnp.broadcast_to(sized, slots_held.shape)
+        counts = jnp.stack([slots_held, sized, config.expert_layers - sized], 1)
         # at most tokens x k x layers a row: exact in float32
-        return jnp.concatenate([out, slots_held[:, None].astype(jnp.float32)], 1)
+        return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
 
     mf = ModelFunction(
         fn, params, input_dtype=jnp.int32, name=name or f"{size}[embed]"
@@ -484,7 +552,7 @@ def deepseek_v2_model_function(
     mf.vocab_size = config.vocab_size
     mf.attention = getattr(attention_fn, "kind", "custom")
     mf.experts = getattr(experts_fn, "kind", "custom")
-    mf.row_counters = ("moe.slots_held",)
+    mf.row_counters = ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
     # per dispatched token (pad rows and pad tokens too), and per real one
     mf.dispatched_token_counters = {"mla.attention_tokens": config.num_layers}
     mf.real_token_counters = {

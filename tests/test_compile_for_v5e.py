@@ -144,3 +144,57 @@ def test_packed_flash_compiles_at_bert_base_widths(shape, rows, length):
     # no padded, transposed or sliced copy on the way in or out: nothing
     # of the size of q is made beside the output
     assert compiled.memory_analysis().temp_size_in_bytes < rows * length * 768 * 4
+
+
+@pytest.mark.parametrize("length", [1024, 2048])
+def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
+    """One expert layer of `deepseek-v2` as `models/deepseek_v2.py:_routed`
+    builds it for a chip that holds 40 of the 160 experts: the sized slot
+    buffer and the worst-case one are the arms of one conditional. The
+    arms never live together, so the layer's temporaries are the larger
+    arm's and not their sum; the 2.8 GB of expert matrices go into the
+    conditional as they are; and the kernels inside it keep the name the
+    trace's readers look for."""
+    from sparkdl_tpu.models import deepseek_v2
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    config = deepseek_v2.deepseek_v2()
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    shapes = deepseek_v2.layer_shapes(config, config.first_k_dense)
+    moe = {
+        "router": shape(shapes["moe/router"], f32),
+        "experts": {
+            k: shape(shapes[f"moe/experts/{k}"], bf16) for k in ("gate", "up", "down")
+        },
+    }
+    tokens, hidden = ROWS * length, config.hidden_size
+    slots = tokens * config.num_experts_per_tok
+    capacity = deepseek_v2.slot_capacity(config, tokens)
+    assert capacity == 15 * length == 1.25 * slots / 4
+
+    def layer(p, u, real):
+        return deepseek_v2._routed(config, p, u, real, grouped_matmul)
+
+    compiled = (
+        jax.jit(layer)
+        .lower(moe, shape((ROWS, length, hidden), f32), shape((ROWS, length), bool))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    # gate, up and down in each arm: `%moe_grouped_matmul.N = ... custom-call`
+    assert len(re.findall(r"%moe_grouped_matmul[.\w]* = ", text)) == 6
+    made = re.findall(
+        r"= bf16\[40,(?:5120,1536|1536,5120)\]\S* (?!parameter|get-tuple-element)(\w[\w-]*)\(",
+        text,
+    )
+    assert made == [], made
+    # the worst-case arm at its fullest: its y in float32 and three
+    # gathered parts of the combine (the sum is the layer's result, no
+    # temporary); the sized arm (y a third of that, six parts) lies under it
+    f32_rows = lambda n: n * hidden * 4  # noqa: E731
+    worst = f32_rows(slots) + 3 * f32_rows(tokens)
+    sized = f32_rows(capacity) + 6 * f32_rows(tokens)
+    assert sized < worst
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert worst <= temp < worst + f32_rows(tokens) // 2, (temp, worst, sized)

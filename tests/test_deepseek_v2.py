@@ -65,7 +65,7 @@ def _counters():
     return dict(metrics.scalar_snapshot()["counters"])
 
 
-def _embed(path, inputs, dtype, interpret, batch=4, max_length=256):
+def _embed(path, inputs, dtype, interpret, batch=4, max_length=256, edit=None):
     preset = program.deepseek_v2_tiny()
     mf = program.deepseek_v2_model_function(
         "deepseek-v2-tiny", dtype=dtype, weights_file=path,
@@ -74,6 +74,8 @@ def _embed(path, inputs, dtype, interpret, batch=4, max_length=256):
         ),
         experts_fn=make_grouped_matmul_fn(interpret=interpret),
     )
+    if edit is not None:
+        edit(mf.params)
     out = TextEmbedder(
         inputCol="in", outputCol="out", modelFunction=mf, maxLength=max_length,
         batchSize=batch,
@@ -123,8 +125,10 @@ def test_embedder_matches_the_reference_row_by_row(
     assert (mf.attention, mf.experts) == (
         ("flash", "pallas") if interpret else ("dense", "ragged_dot")
     )
-    assert mf.weights_as_arguments and mf.row_counters == ("moe.slots_held",)
-    assert got.shape == (12, 64)  # the column of counts is stripped
+    assert mf.weights_as_arguments and mf.row_counters == (
+        "moe.slots_held", "moe.buffer_sized", "moe.buffer_full"
+    )
+    assert got.shape == (12, 64)  # the columns of counts are stripped
     want = reference.outputs(config, weights, corpus, precision=precision)
     errs = compare.row_errors(got, want)
     assert np.median(errs) <= median and errs.max() <= widest, errs
@@ -138,6 +142,10 @@ def test_embedder_matches_the_reference_row_by_row(
     # a quarter of the experts are held: about a quarter of the slots
     share = delta["moe.slots_held"] / delta["moe.slots_routed"]
     assert 0.1 < share < 0.45, share
+    # every live row carries its dispatch's two expert layers, and a router
+    # that spreads evenly leaves every one of them on the sized buffer
+    assert delta["moe.buffer_sized"] == 12 * 2
+    assert delta.get("moe.buffer_full", 0) == 0
 
 
 def test_a_row_does_not_change_with_what_pads_it(monkeypatch, tiny, corpus):
@@ -185,21 +193,201 @@ def test_four_shares_and_the_shared_experts_once_are_the_uncut_layer():
     real = jnp.ones((2, 24), bool)
     total, slots = 0.0, 0
     for first in (0, 4, 8, 12):
-        preset = program.DeepseekV2Config(
-            **{**program.deepseek_v2_tiny().__dict__, "experts_held": (first, first + 4)}
-        )
+        preset = _share(first, first + 4)
         p = {
             "router": moe["router"],
             "experts": {
                 k: moe[f"experts/{k}"][first : first + 4] for k in ("gate", "up", "down")
             },
         }
-        part, count = program._routed(preset, p, u, real, make_grouped_matmul_fn())
+        part, count, _ = program._routed(preset, p, u, real, make_grouped_matmul_fn())
         total, slots = total + part, slots + int(count.sum())
     assert slots == 2 * 24 * 3  # every slot is held by exactly one share
     p_shared = {k: moe[f"shared/{k}"] for k in ("gate", "up", "down")}
     total = total + program._swiglu(p_shared, u)
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), atol=2e-4, rtol=2e-4)
+
+
+# -- the slot buffer -----------------------------------------------------------
+
+
+def _share(first, end):
+    """The tiny preset with another share of its 16 experts."""
+    return program.DeepseekV2Config(
+        **{**program.deepseek_v2_tiny().__dict__, "experts_held": (first, end)}
+    )
+
+
+@pytest.mark.parametrize(
+    "preset, tokens, capacity",
+    [
+        # a quarter held: 1.25 x 1/4 of the slots, in whole tiles of 256 rows
+        (program.deepseek_v2(), 8 * 2048, 30720),  # of 98,304: 120 tiles, exact
+        (program.deepseek_v2(), 8 * 1024, 15360),
+        (program.deepseek_v2_tiny(), 4 * 128, 512),  # 480 of 1,536 rounded up
+        (program.deepseek_v2_tiny(), 4 * 1024, 3840),
+        # every expert held, and shapes too small to round: all the slots
+        (program.DeepseekV2Config(), 8 * 2048, 8 * 2048 * 6),
+        (_share(0, 16), 4 * 128, 4 * 128 * 3),
+        (program.deepseek_v2_tiny(), 48, 144),
+    ],
+)
+def test_the_buffer_is_sized_by_the_held_share(preset, tokens, capacity):
+    assert program.slot_capacity(preset, tokens) == capacity
+    assert 1.25 <= program._CAPACITY_MARGIN <= 1.5
+
+
+def _layer(preset, seed=0):
+    params = program.init_params(preset, seed, jnp.float32)
+    return params["layers"]["1"]["moe"]
+
+
+def _to_the_held_group(moe, u, experts=4):
+    """(moe, u) with a router whose first `experts` columns, and a first
+    feature of every token, send all three of a token's slots to the held
+    group (the first four experts), or one with `experts` 1: the other two
+    then fall on the second group."""
+    router = moe["router"].at[0, :].set(0.0).at[0, :experts].set(3.0).at[0, 4:8].add(1.5)
+    return dict(moe, router=router), u.at[..., 0].set(8.0)
+
+
+def _oracle(preset, moe, u, real):
+    """The held experts' part by the plain reference: every token through
+    every held expert, weighted by zero where it was not chosen."""
+    first, end = preset.experts_held
+    config = tiny_config(held=(first, end))
+    with jax.default_matmul_precision("highest"):
+        experts, gates = reference.route(config, u, moe["router"])
+        total = 0.0
+        for e in range(first, end):
+            w = {k: v[e - first] for k, v in moe["experts"].items()}
+            total = total + reference._expert("highest", e, w, u, experts, gates)
+    return jnp.where(real[..., None], total, 0.0)
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["ragged_dot", "pallas"])
+@pytest.mark.parametrize("forced", [False, True], ids=["sized", "full"])
+def test_both_arms_are_the_oracle(interpret, forced):
+    """An even router leaves the quarter share on the sized buffer; one
+    that sends every slot to the held group overflows it: every slot is
+    computed there too."""
+    preset = program.deepseek_v2_tiny()
+    rng = np.random.default_rng(4)
+    u = jnp.asarray(rng.standard_normal((4, 128, 64)), jnp.float32)
+    moe = _layer(preset)
+    if forced:
+        moe, u = _to_the_held_group(moe, u)
+    real = np.ones((4, 128), bool)
+    real[3, 70:] = False
+    real = jnp.asarray(real)
+    part, count, fits = program._routed(
+        preset, moe, u, real, make_grouped_matmul_fn(interpret=interpret)
+    )
+    assert program.slot_capacity(preset, 4 * 128) == 512 < 4 * 128 * 3
+    assert bool(fits) is not forced
+    held = int(count.sum())
+    if forced:
+        assert held == 3 * int(real.sum()) > 512  # no slot dropped
+    else:
+        assert 0 < held <= 512
+    want = _oracle(preset, moe, u, real)
+    np.testing.assert_allclose(np.asarray(part), np.asarray(want), atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["ragged_dot", "pallas"])
+def test_the_two_arms_are_bit_equal_on_a_load_both_hold(monkeypatch, interpret):
+    """The first sum(sizes) rows are the same rows in both buffers. With
+    room for one tile the same load overflows and the worst-case arm, the
+    one body of a chip that holds everything, gives the same bits."""
+    preset = program.deepseek_v2_tiny()
+    u = jnp.asarray(np.random.default_rng(5).standard_normal((4, 128, 64)), jnp.float32)
+    real, moe = jnp.ones((4, 128), bool), _layer(preset)
+    experts_fn = make_grouped_matmul_fn(interpret=interpret)
+    sized, count, fits = program._routed(preset, moe, u, real, experts_fn)
+    assert bool(fits) and int(count.sum()) > 256
+    monkeypatch.setattr(program, "_CAPACITY_MARGIN", 0.01)
+    assert program.slot_capacity(preset, 4 * 128) == 256
+    full, count_full, fits = program._routed(preset, moe, u, real, experts_fn)
+    assert not bool(fits) and count_full.tolist() == count.tolist()
+    assert np.asarray(sized).tobytes() == np.asarray(full).tobytes()
+
+
+@pytest.mark.parametrize("over", [0, 1])
+def test_a_load_of_exactly_the_buffer_and_of_one_more(over):
+    """One held slot a real token (the router's first column), so the
+    load is the count of real tokens: 3,840 fill the buffer of 4 x 1,024
+    tokens to its last row, 3,841 take the other arm."""
+    preset = program.deepseek_v2_tiny()
+    rng = np.random.default_rng(6)
+    u = jnp.asarray(rng.standard_normal((4, 1024, 64)), jnp.float32)
+    moe, u = _to_the_held_group(_layer(preset), u, experts=1)
+    capacity = program.slot_capacity(preset, 4 * 1024)
+    real = (np.arange(4 * 1024) < capacity + over).reshape(4, 1024)
+    part, count, fits = program._routed(
+        preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn()
+    )
+    assert int(count.sum()) == capacity + over == 3840 + over
+    assert bool(fits) is (over == 0)
+    want = _oracle(preset, moe, u, jnp.asarray(real))
+    np.testing.assert_allclose(np.asarray(part), np.asarray(want), atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize(
+    "preset, rows, length, conditionals, barriers",
+    [
+        (program.DeepseekV2Config(), 8, 2048, 0, 1),  # the parent's one body
+        (program.deepseek_v2(), 8, 2048, 1, 1),  # the barrier is the worst-case arm's
+        (_share(0, 16), 4, 128, 0, 0),  # top-3: one pass of three, no barrier
+        (program.deepseek_v2_tiny(), 4, 128, 1, 0),
+        (program.deepseek_v2_tiny(), 2, 24, 0, 0),  # too small to round
+    ],
+)
+def test_a_chip_that_holds_every_expert_has_no_conditional(
+    preset, rows, length, conditionals, barriers
+):
+    """One body where the two row counts are one, one `cond` where they
+    differ. Shapes only: nothing of the published size is made."""
+    shapes = program.layer_shapes(preset, preset.first_k_dense)
+    moe = {
+        "router": jax.ShapeDtypeStruct(shapes["moe/router"], jnp.float32),
+        "experts": {
+            k: jax.ShapeDtypeStruct(shapes[f"moe/experts/{k}"], jnp.bfloat16)
+            for k in ("gate", "up", "down")
+        },
+    }
+    u = jax.ShapeDtypeStruct((rows, length, preset.hidden_size), jnp.float32)
+    real = jax.ShapeDtypeStruct((rows, length), bool)
+    text = str(jax.make_jaxpr(
+        lambda p, u, real: program._routed(preset, p, u, real, make_grouped_matmul_fn())
+    )(moe, u, real))
+    assert (text.count("cond["), text.count("optimization_barrier")) == (
+        conditionals, barriers
+    )
+
+
+def test_the_buffer_counters_follow_the_arm_taken(monkeypatch, tiny):
+    """A router that sends every slot to the held group, through the
+    embedder: a lone live row of 120 words in a dispatch of 2 x 128 tokens
+    already overflows the buffer of 256 rows, so every expert layer of
+    every dispatch takes the worst-case arm, and every slot is held."""
+    _, _, path = tiny
+    monkeypatch.setenv("SPARKDL_TEXT_BUCKETS", "128")
+    monkeypatch.setenv("SPARKDL_TEXT_MIN_BUCKET", "128")
+    data = {"rows": 6, "vocabulary_words": 300, "word_counts": [[120, 6]]}
+    inputs = list(texts.rows(data, np.random.default_rng(1), set()))
+
+    def forced(params):  # the leaves of a weights file are numpy's
+        params["embed"][:, 0] = 1000.0
+        for i in ("1", "2"):
+            router = params["layers"][i]["moe"]["router"]
+            router[0, :], router[0, :4] = 0.0, 3.0
+
+    before = _counters()
+    _, got = _embed(path, inputs, jnp.float32, False, batch=2, max_length=128, edit=forced)
+    delta = {k: v - before.get(k, 0) for k, v in _counters().items()}
+    assert got.shape == (6, 64) and np.isfinite(got).all()
+    assert delta["moe.buffer_full"] == 6 * 2 and delta.get("moe.buffer_sized", 0) == 0
+    assert delta["moe.slots_held"] == delta["moe.slots_routed"] > 6 * 120 * 3 * 2
 
 
 # -- routing by hand -----------------------------------------------------------
@@ -256,7 +444,7 @@ def test_a_pad_token_takes_no_slot():
     real = np.ones((2, 16), bool)
     real[0, 5:] = False  # a row of five tokens
     real[1, :] = False  # a row that only fills the batch
-    part, count = program._routed(
+    part, count, _ = program._routed(
         preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn()
     )
     experts, _ = program.route(preset, u.reshape(-1, 64), moe["router"])
@@ -264,7 +452,7 @@ def test_a_pad_token_takes_no_slot():
     assert count.tolist() == [int(held[0, :5].sum()), 0]
     assert not np.asarray(part[0, 5:]).any() and not np.asarray(part[1]).any()
     # the real tokens' part is what it is with every token real
-    whole, _ = program._routed(
+    whole, _, _ = program._routed(
         preset, moe, u, jnp.ones((2, 16), bool), make_grouped_matmul_fn()
     )
     np.testing.assert_allclose(np.asarray(part[0, :5]), np.asarray(whole[0, :5]), atol=1e-6)
