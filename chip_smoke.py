@@ -666,6 +666,7 @@ def phase_kernel(sizes: Sizes, interpret: bool) -> dict:
         "run_s": round(run_s, 3),
         **_hybrid_kernels(sizes, interpret),
         **_expert_kernels(sizes, interpret),
+        **_sparse_attention_kernels(sizes, interpret),
     }
 
 
@@ -799,6 +800,92 @@ def _expert_kernels(sizes: Sizes, interpret: bool) -> dict:
     return {
         "expert_rel_err": errs,
         "deepseek_v2_built_with": {"attention": mf.attention, "experts": mf.experts},
+    }
+
+
+def _sparse_attention_kernels(sizes: Sizes, interpret: bool) -> dict:
+    """The kernels of the family that selects each query's keys
+    (models/deepseek_v32.py, ops/dsa_indexer.py) at its widths: the index
+    scores of 64 heads of 128 against their dense sum; the selection
+    against ``lax.top_k`` over the masked rows, query by query; latent
+    attention over the selected keys against dense; and which of each a
+    model built here gets. A rehearsal keeps the head sizes and cuts the
+    rest."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparkdl_tpu.models import get_model
+    from sparkdl_tpu.ops import dsa_indexer
+    from sparkdl_tpu.ops.flash_attention import (
+        dense_latent_attention,
+        flash_attention_latent,
+    )
+
+    rng = np.random.default_rng(6)
+    errs = {}
+    index_heads, heads = (4, 2) if interpret else (64, 8)
+    scale = 192**-0.5 * 1.3689**2
+    for length in sizes.kernel_lengths:
+        top_k = length // 8
+        q = jnp.asarray(rng.normal(size=(1, length, index_heads * 128)), jnp.bfloat16)
+        k = jnp.asarray(rng.normal(size=(1, length, 128)), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(1, length, index_heads)) * 0.1, jnp.float32)
+        scores = jax.jit(
+            lambda q, k, w: dsa_indexer.dsa_index_scores(
+                q, k, w, num_heads=index_heads, interpret=interpret
+            )
+        )(q, k, w)
+        want = jax.jit(
+            lambda q, k, w: dsa_indexer.index_scores(q, k, w, num_heads=index_heads)
+        )(q, k, w)
+        causal = jnp.tril(jnp.ones((length, length), bool))
+        errs[f"index_scores/L{length}"] = _check_close(
+            f"index scores L{length}", jnp.where(causal, scores, 0.0),
+            jnp.where(causal, want, 0.0),
+        )
+        selection = jax.jit(
+            lambda s: dsa_indexer.dsa_select(
+                s, top_k=top_k, block_q=32 if interpret else 64,
+                chunk=128 if interpret else 512, interpret=interpret,
+            )
+        )(scores)
+        _, best = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), top_k)
+        by_top_k = np.zeros((1, length, length), np.int8)
+        np.put_along_axis(by_top_k, np.asarray(best), 1, -1)
+        by_top_k &= np.asarray(causal)
+        differing = int((np.asarray(selection) != by_top_k).sum())
+        if differing:
+            raise RuntimeError(
+                f"selection L{length}: {differing} pairs differ from lax.top_k"
+            )
+        errs[f"selection/L{length}"] = 0.0
+        block = 128 if interpret else min(1024, length)
+        qa = jnp.asarray(rng.normal(size=(1, length, heads * 256)), jnp.bfloat16)
+        kv = jnp.asarray(rng.normal(size=(1, length, heads * 256)), jnp.bfloat16)
+        kr = jnp.asarray(rng.normal(size=(1, length, 128)), jnp.bfloat16)
+        got = jax.jit(
+            lambda qa, kv, kr, sel: flash_attention_latent(
+                qa, kv, kr, sel, num_heads=heads, scale=scale, block=block,
+                interpret=interpret,
+            )
+        )(qa, kv, kr, selection)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(
+                lambda qa, kv, kr, sel: dense_latent_attention(
+                    *(t.astype(jnp.float32) for t in (qa, kv, kr)), jnp.float32,
+                    sel, num_heads=heads, scale=scale,
+                )
+            )(qa, kv, kr, selection)
+        errs[f"selecting_flash/L{length}"] = _check_close(
+            f"selecting flash L{length}", got, want
+        )
+    mf = get_model("deepseek-v3.2-exp-tiny").model_function(mode="embed")
+    return {
+        "sparse_attention_rel_err": errs,
+        "deepseek_v32_built_with": {
+            "attention": mf.attention, "experts": mf.experts, "indexer": mf.indexer,
+        },
     }
 
 

@@ -95,6 +95,9 @@ class DeepseekV2Config:
     topk_group: int = 3
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 16.0
+    #: which gate ``route`` computes: ``softmax`` (this family) or
+    #: ``sigmoid`` (``models/deepseek_v32.py``)
+    scoring_func: str = "softmax"
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     rope_factor: float = 40.0
@@ -105,6 +108,10 @@ class DeepseekV2Config:
     rope_mscale_all_dim: float = 0.707
     #: [first, end) of the routed experts whose weights this chip holds
     experts_held: Tuple[int, int] = (0, 160)
+    #: slot rows a pass of the routed path's worst-case arm (``_routed``),
+    #: for a configuration whose one buffer of every slot does not fit
+    #: beside its weights; None: one buffer
+    worst_case_chunk_rows: Optional[int] = None
 
     @property
     def expert_layers(self) -> int:
@@ -187,8 +194,9 @@ def layer_shapes(config: DeepseekV2Config, i: int) -> dict:
     return shapes
 
 
-def param_shapes(config: DeepseekV2Config) -> dict:
-    """{flat path: shape} of every leaf, as a weights file names them."""
+def param_shapes(config: DeepseekV2Config, layer_shapes=layer_shapes) -> dict:
+    """{flat path: shape} of every leaf, as a weights file names them;
+    ``layer_shapes`` is the family's (this module's by default)."""
     h = config.hidden_size
     shapes = {"embed": (config.vocab_size, h), "final_norm": (h,)}
     for i in range(config.num_layers):
@@ -302,8 +310,10 @@ def _query_weights(config: DeepseekV2Config, w):
     return jnp.concatenate([w[..., :nope], r, _turn(r)], -1).reshape(w.shape[0], -1)
 
 
-def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
-    """u [B, L, hidden] in the compute dtype -> [B, L, hidden] float32.
+def _mla_inputs(config: DeepseekV2Config, p, u, tables):
+    """u [B, L, hidden] in the compute dtype -> (the query latent c_q
+    [B, L, q_lora_rank], and the attention kernel's three operands q, kv,
+    k_rope), all in the compute dtype.
 
     Every array the kernel reads is what a projection wrote, heads side
     by side on the last axis: q [B, L, H * (nope + 2 rope)], the
@@ -330,8 +340,16 @@ def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
     c_kv = _rms(kv_a[..., :rkv], p["kv_norm"], eps).astype(dtype)
     kv = _dense(c_kv, p["kv_b"]).astype(dtype)  # a head's [k_nope | v]
     k_pe = _rotate(kv_a[..., rkv:][..., _deinterleave(rope)], cos, sin).astype(dtype)
-    o = attention_fn(q, kv, jnp.concatenate([k_pe, k_pe], -1), dtype)
-    return _dense(o, p["o"])
+    return c_q, q, kv, jnp.concatenate([k_pe, k_pe], -1)
+
+
+def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
+    """u [B, L, hidden] in the compute dtype -> [B, L, hidden] float32:
+    dense causal latent attention. A family that selects each query's
+    keys (``models/deepseek_v32.py``) takes :func:`_mla_inputs` and hands
+    its selection to the kernel itself."""
+    _, q, kv, k_pe = _mla_inputs(config, p, u, tables)
+    return _dense(attention_fn(q, kv, k_pe, u.dtype), p["o"])
 
 
 def _swiglu(p, u):
@@ -339,15 +357,29 @@ def _swiglu(p, u):
     return _dense((gate * _dense(u, p["up"])).astype(u.dtype), p["down"])
 
 
-def route(config: DeepseekV2Config, u, router):
+def route(config: DeepseekV2Config, u, router, bias=None):
     """u [T, hidden], router [hidden, experts], both float32 ->
     (experts [T, k] int32, weights [T, k] float32) over all the model's
-    experts: ``group_limited_greedy``."""
+    experts. The configuration's own keys say which gate:
+
+    - DeepSeek-V2 (``scoring_func`` softmax, ``group_limited_greedy``):
+      softmax scores, a group ranked by
+      its best score, top-k of the scores over the kept groups, and the
+      weights EITHER renormalised (``norm_topk_prob``) OR scaled by
+      ``routed_scaling_factor``.
+    - DeepSeek-V3 and V3.2 (``scoring_func`` sigmoid, ``noaux_tc``):
+      sigmoid scores s; the CHOICE is made on s + ``bias`` (a learned
+      correction, [experts] float32), a group ranked by the sum of its
+      two best, top-k over the kept groups; the weights are the chosen
+      experts' s without the bias, renormalised (``norm_topk_prob``) and
+      THEN scaled."""
     n, groups = config.n_routed_experts, config.n_group
     logits = jnp.einsum(
         "ti,io->to", u.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
+    if config.scoring_func == "sigmoid":
+        return _route_by_corrected_sigmoid(config, logits, bias)
     scores = jax.nn.softmax(logits, -1)
     if groups > 1:
         best = scores.reshape(-1, groups, n // groups).max(-1)
@@ -360,6 +392,23 @@ def route(config: DeepseekV2Config, u, router):
     else:
         weights = weights * config.routed_scaling_factor
     return experts, weights
+
+
+def _route_by_corrected_sigmoid(config, logits, bias):
+    """``route``'s second gate (``noaux_tc``), from the router's logits."""
+    n, groups, top_k = config.n_routed_experts, config.n_group, config.num_experts_per_tok
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + bias.astype(jnp.float32)
+    if groups > 1:
+        two_best, _ = jax.lax.top_k(choice.reshape(-1, groups, n // groups), 2)
+        _, kept = jax.lax.top_k(two_best.sum(-1), config.topk_group)
+        keep = jnp.any(kept[..., None] == jnp.arange(groups), -2)  # [T, groups]
+        choice = jnp.where(jnp.repeat(keep, n // groups, -1), choice, -jnp.inf)
+    _, experts = jax.lax.top_k(choice, top_k)
+    weights = jnp.take_along_axis(scores, experts, -1)
+    if config.norm_topk_prob and top_k > 1:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts, weights * config.routed_scaling_factor
 
 
 #: the grouped kernel's row tile at an expert layer's sizes
@@ -409,6 +458,39 @@ def _experts_and_combine(
     return out
 
 
+def _experts_in_chunks(
+    rows, experts_fn, experts, flat, order, sizes, slot, weights, held
+):
+    """:func:`_experts_and_combine` over every slot, ``rows`` sorted slot
+    rows a pass (static, a divisor of the slots): each pass gives the
+    kernel the part of every group that lies in its rows and adds its
+    weighted rows to their tokens. A pass past the last held slot does
+    nothing, so the arm costs what the load costs. No slot is dropped."""
+    tokens, top_k = weights.shape
+    ends = jnp.cumsum(sizes)
+    starts, total = ends - sizes, ends[-1]
+    by_slot = weights.reshape(-1)
+
+    def one_pass(out, lo):
+        def compute(out):
+            at = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+            x = flat[at // top_k]
+            gate = experts_fn(x, experts["gate"], part)
+            up = experts_fn(x, experts["up"], part)
+            y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], part)
+            # what the kernel left unwritten is not read
+            mine = (lo + jnp.arange(rows) < total)[:, None]
+            y = jnp.where(mine, y * by_slot[at][:, None], 0.0)
+            return out.at[at // top_k].add(y)
+
+        return jax.lax.cond(lo < total, compute, lambda out: out, out), None
+
+    out = jnp.zeros((tokens, flat.shape[1]), jnp.float32)
+    out, _ = jax.lax.scan(one_pass, out, jnp.arange(0, tokens * top_k, rows))
+    return out
+
+
 def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
     """u [B, L, hidden] float32 (the norm's output), real [B, L] bool ->
     (the held experts' part of the routed sum [B, L, hidden] float32,
@@ -426,7 +508,12 @@ def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
     tokens, top_k = rows * length, config.num_experts_per_tok
     first, end = config.experts_held
     flat = u.reshape(tokens, hidden)
-    experts, weights = route(config, flat, p["router"])
+    # the first gate is called as it always was: what stands in for
+    # ``route`` in a test or a planted fault takes three arguments
+    if "router_bias" in p:
+        experts, weights = route(config, flat, p["router"], p["router_bias"])
+    else:
+        experts, weights = route(config, flat, p["router"])
     held = (experts >= first) & (experts < end) & real.reshape(tokens, 1)
     # slots sorted by expert, those of absent experts (and of pad tokens) last
     key = jnp.where(held, experts - first, end - first).reshape(-1)
@@ -446,6 +533,9 @@ def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
     # 4.40 s a job against 4.19 on the chip (PERF.md, PR 32). The barrier
     # orders the passes. The sized arm's y is a third of that: one pass.
     full = functools.partial(_experts_and_combine, slots, _COMBINE_AT_ONCE, experts_fn)
+    chunk = config.worst_case_chunk_rows
+    if chunk and slots > chunk:
+        full = functools.partial(_experts_in_chunks, chunk, experts_fn)
     if capacity == slots:
         fits, out = jnp.zeros((), bool), full(*operands)
     else:

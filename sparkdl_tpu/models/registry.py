@@ -684,6 +684,46 @@ _register(
 )
 
 
+def _deepseek_v32_text_builder(size: str):
+    """Builder over models/deepseek_v32.py presets: DeepSeek-V2's stack
+    with a lightning indexer in every layer that selects each query's
+    keys (``mf.indexer``: 'pallas' | 'jnp', chosen at build time like
+    ``attention`` and ``experts``) and the sigmoid gate with a correction
+    bias; two more row counters ride back with each row."""
+
+    def build(
+        spec: NamedTextModel, mode: str, dtype, weights_file, seed
+    ) -> ModelFunction:
+        from sparkdl_tpu.models import deepseek_v32 as deepseek_mod
+
+        return deepseek_mod.deepseek_v32_model_function(
+            size,
+            dtype=dtype,
+            seed=seed,
+            weights_file=weights_file,
+            name=f"{spec.name}[{mode}]",
+        )
+
+    return build
+
+
+# One chip's share of DeepSeek-V3.2-Exp at its published widths (5 of 61
+# layers, experts 0-7 of 256, an eighth of the vocabulary: the cut of
+# benchmarks/configs/deepseek-v3.2-exp.json), and the family at test size.
+_register(
+    NamedTextModel(
+        "deepseek-v3.2-exp", 163840, 7168, "jax",
+        _deepseek_v32_text_builder("deepseek-v3.2-exp"), vocab_size=16160,
+    )
+)
+_register(
+    NamedTextModel(
+        "deepseek-v3.2-exp-tiny", 4096, 64, "jax",
+        _deepseek_v32_text_builder("deepseek-v3.2-exp-tiny"), vocab_size=512,
+    )
+)
+
+
 def get_model(name: str):
     """The registered spec for ``name`` — a :class:`NamedImageModel` or
     :class:`NamedTextModel`; both expose ``model_function(mode=...)``

@@ -13,7 +13,8 @@ Three tilings of that one algorithm, chosen by the heads' shape
 (the third, :func:`flash_attention_latent`, is the blocked causal kernel
 over a latent-attention model's own projections: a head's [nope | rope]
 query, its keys and values as two column blocks of the one up-projected
-array, and a rotary key that all heads share).
+array, and a rotary key that all heads share; given a selection, a byte
+a (query, key) pair, it attends to the selected keys only).
 :func:`flash_attention` takes [B, H, L, Dh] and gives one (row, head)
 pair to a grid step: right where a head fills the 128 lanes (Jamba's
 128), and what causal and shared-key/value attention run. A head
@@ -479,14 +480,22 @@ def flash_attention_packed(
     return out[:, :L]
 
 
-def _latent_kernel(nk, scale, nope, q_ref, k_ref, v_ref, kr_ref, o_ref, m_ref, l_ref, acc_ref):
+def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs):
     """The blocked causal kernel for latent attention, one (row, head) a
     grid step: the query block is [bq, nope + rope], the head's own keys
     [bk, nope] and the token's shared rotary key [bk, rope]; a score is
     the sum of the two products. Key blocks above the diagonal are
-    skipped; right padding needs no mask under a causal one."""
+    skipped; right padding needs no mask under a causal one. With
+    ``selected`` a fifth operand is the block [bq, bk] of a selection
+    (int8, nonzero where the query attends to the key, zero above the
+    diagonal): it takes the causal mask's place, before the running
+    maximum."""
     from jax.experimental import pallas as pl
 
+    if selected:
+        sel_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -507,10 +516,13 @@ def _latent_kernel(nk, scale, nope, q_ref, k_ref, v_ref, kr_ref, o_ref, m_ref, l
             preferred_element_type=jnp.float32,
         )
         s = s * scale
-        bq, bk = s.shape
-        row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col <= row, s, NEG_INF)
+        if selected:
+            s = jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+        else:
+            bq, bk = s.shape
+            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col <= row, s, NEG_INF)
         _softmax_step(s, v_ref[0].astype(jnp.float32), m_ref, l_ref, acc_ref)
 
     @pl.when(ki == nk - 1)
@@ -522,6 +534,7 @@ def flash_attention_latent(
     q,
     kv,
     k_rope,
+    selection=None,
     *,
     num_heads: int,
     scale: float,
@@ -540,6 +553,11 @@ def flash_attention_latent(
             blocks 2h and 2h + 1 of the one array, each read in place.
         k_rope: [B, L, Dr], a token's rotary key, shared by all heads:
             every head's step reads the same block.
+        selection: None, or [B, L, L] int8, nonzero where query t
+            attends to key s and zero for every s > t (sparse attention
+            by a learned selection, ``ops/dsa_indexer.py``): one
+            selection for all heads, a byte a pair, read a block a step
+            in the causal mask's place. Every query selects a key.
         On the TPU Dn, Dr and Dv are multiples of the 128 lanes (a
         block's last dim); the interpreter takes any.
 
@@ -562,6 +580,10 @@ def flash_attention_latent(
             f"kv [B, L, H*(Dn+Dv)] with Dn == Dv and k_rope [B, L, Dr]; "
             f"got {q.shape}, {kv.shape}, {k_rope.shape}"
         )
+    if selection is not None and selection.shape != (B, L, L):
+        raise ValueError(
+            f"a selection for q {q.shape} is [B, L, L], got {selection.shape}"
+        )
     pad = _pad_len(L, block)
     if pad:
         q, kv, k_rope = (
@@ -572,21 +594,35 @@ def flash_attention_latent(
     def keys(bh, qi, ki):  # a block above the diagonal is not fetched
         return bh // H, jnp.minimum(ki, qi)
 
+    in_specs = [
+        pl.BlockSpec(
+            (1, block, nope + rope), lambda bh, qi, ki: (bh // H, qi, bh % H)
+        ),
+        pl.BlockSpec(
+            (1, block, nope), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H))
+        ),
+        pl.BlockSpec(
+            (1, block, dv), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H) + 1)
+        ),
+        pl.BlockSpec((1, block, rope), lambda bh, qi, ki: (*keys(bh, qi, ki), 0)),
+    ]
+    operands = [q, kv, kv, k_rope]
+    if selection is not None:
+        if pad:
+            # a padded query attends to key 0, so that its row has a maximum
+            selection = jnp.pad(selection, ((0, 0), (0, pad), (0, pad)))
+            selection = selection.at[:, L:, 0].set(1)
+        in_specs.append(
+            pl.BlockSpec(
+                (1, block, block), lambda bh, qi, ki: (bh // H, qi, jnp.minimum(ki, qi))
+            )
+        )
+        operands.append(selection)
+
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, n, scale, nope),
+        functools.partial(_latent_kernel, n, scale, nope, selection is not None),
         grid=(B * H, n, n),
-        in_specs=[
-            pl.BlockSpec(
-                (1, block, nope + rope), lambda bh, qi, ki: (bh // H, qi, bh % H)
-            ),
-            pl.BlockSpec(
-                (1, block, nope), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H))
-            ),
-            pl.BlockSpec(
-                (1, block, dv), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H) + 1)
-            ),
-            pl.BlockSpec((1, block, rope), lambda bh, qi, ki: (*keys(bh, qi, ki), 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(
             (1, block, dv), lambda bh, qi, ki: (bh // H, qi, bh % H)
         ),
@@ -602,11 +638,13 @@ def flash_attention_latent(
         interpret=interpret,
         # the blocked kernel's name: one kernel to the trace's readers
         name="flash_attention",
-    )(q, kv, kv, k_rope)
+    )(*operands)
     return out[:, :L]
 
 
-def dense_latent_attention(q, kv, k_rope, dtype, *, num_heads: int, scale: float):
+def dense_latent_attention(
+    q, kv, k_rope, dtype, selection=None, *, num_heads: int, scale: float
+):
     """What :func:`flash_attention_latent` computes, as dense einsums over
     the same arrays: float32 scores and softmax. The build-time fallback
     off the TPU and the kernel's test oracle."""
@@ -621,7 +659,10 @@ def dense_latent_attention(q, kv, k_rope, dtype, *, num_heads: int, scale: float
     ) + jnp.einsum(
         "bqhd,bkd->bhqk", q[..., nope:], k_rope, preferred_element_type=jnp.float32
     )
-    s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s * scale, NEG_INF)
+    seen = jnp.tril(jnp.ones((L, L), bool))
+    if selection is not None:
+        seen = (selection != 0)[:, None]
+    s = jnp.where(seen, s * scale, NEG_INF)
     p = jax.nn.softmax(s, -1).astype(dtype)
     o = jnp.einsum(
         "bhqk,bkhd->bqhd", p, kv[..., nope:], preferred_element_type=jnp.float32
@@ -633,9 +674,10 @@ def make_latent_attention_fn(
     num_heads: int, scale: float, block: int = 128, interpret: bool = False
 ):
     """The latent attention a model is BUILT with, ``fn(q, kv, k_rope,
-    dtype)`` over the projections' own arrays: the Pallas kernel on TPU
-    (or interpreted when asked), :func:`dense_latent_attention`
-    elsewhere. ``.kind`` ('flash' | 'dense') says which."""
+    dtype, selection=None)`` over the projections' own arrays: the Pallas
+    kernel on TPU (or interpreted when asked),
+    :func:`dense_latent_attention` elsewhere. ``.kind`` ('flash' |
+    'dense') says which."""
     if not interpret and jax.default_backend() != "tpu":
         dense = functools.partial(
             dense_latent_attention, num_heads=num_heads, scale=scale
@@ -643,10 +685,10 @@ def make_latent_attention_fn(
         dense.kind = "dense"
         return dense
 
-    def attention(q, kv, k_rope, dtype):
+    def attention(q, kv, k_rope, dtype, selection=None):
         out = flash_attention_latent(
-            q, kv, k_rope, num_heads=num_heads, scale=scale, block=block,
-            interpret=interpret,
+            q, kv, k_rope, selection, num_heads=num_heads, scale=scale,
+            block=block, interpret=interpret,
         )
         return out.astype(dtype)
 

@@ -129,6 +129,10 @@ class TextEmbedder(
             # per dispatched token and per real (non-pad) token
             dispatched = dict(getattr(mf, "dispatched_token_counters", {}))
             real = dict(getattr(mf, "real_token_counters", {}))
+            # and what neither is a multiple of (a count that depends on
+            # the bucket, or on each row's length): {name: count} of a
+            # batch, from its ids and which of them are real
+            of_batch = getattr(mf, "batch_counters", None)
             if scan_layers:
                 dispatched["ssm.scan_tokens"] = scan_layers
 
@@ -140,6 +144,9 @@ class TextEmbedder(
                     real_tokens = int(attn.sum())
                     for name, each in real.items():
                         metrics.inc(name, real_tokens * each)
+                if of_batch:
+                    for name, count in of_batch(ids_batch, attn.astype(bool)).items():
+                        metrics.inc(name, count)
                 return _fn((ids_batch, attn))
 
             device_call.n_devices = getattr(fn, "n_devices", 1)

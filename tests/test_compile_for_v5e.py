@@ -198,3 +198,62 @@ def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
     assert sized < worst
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert worst <= temp < worst + f32_rows(tokens) // 2, (temp, worst, sized)
+
+
+# -- DeepSeek sparse attention at the published widths -------------------------
+
+
+@pytest.mark.parametrize("length", [8192, 16384])
+def test_the_indexers_two_kernels_compile_at_the_published_widths(shape, length):
+    """A dispatch of `deepseek-v3.2-exp-embed-long-docs` (one row): 64
+    index heads of 128 against the one index key, and the search for each
+    query's 2,048th score. What leaves the first kernel is the [L, L]
+    float32 of summed scores and nothing of the 64 per-head tiles; what
+    leaves the second is a byte a pair."""
+    from sparkdl_tpu.ops import dsa_indexer
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def indexer(q, k, w):
+        scores = dsa_indexer.dsa_index_scores(q, k, w, num_heads=64)
+        return dsa_indexer.dsa_select(scores, top_k=2048)
+
+    compiled = (
+        jax.jit(indexer)
+        .lower(shape((1, length, 64 * 128), bf16), shape((1, length, 128), bf16),
+               shape((1, length, 64), f32))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # the names the trace's readers look for, each call by its own name
+    assert len(re.findall(r"%dsa_index_scores[.\w]* = ", text)) == 1
+    assert len(re.findall(r"%dsa_select[.\w]* = ", text)) == 1
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == length * length  # int8
+    assert memory.temp_size_in_bytes < length * length * 4 + (64 << 20)
+
+
+@pytest.mark.parametrize("length", [8192, 16384])
+def test_latent_flash_with_a_selection_compiles(shape, length):
+    """128 heads of 128 + 2 x 64 query lanes over the up-projected latent,
+    blocks of 1,024, the selection a fifth operand read a [1024, 1024]
+    int8 block a step: nothing is padded, repeated or converted in HBM."""
+    from sparkdl_tpu.ops.flash_attention import flash_attention_latent
+
+    bf16 = jnp.bfloat16
+    wide = shape((1, length, 128 * 256), bf16)
+    compiled = (
+        jax.jit(
+            lambda q, kv, k_rope, selection: flash_attention_latent(
+                q, kv, k_rope, selection, num_heads=128, scale=0.1353, block=1024
+            )
+        )
+        .lower(wide, wide, shape((1, length, 128), bf16),
+               shape((1, length, length), jnp.int8))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%flash_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)

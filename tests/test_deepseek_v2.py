@@ -554,3 +554,68 @@ def test_weights_file_is_read_strictly(tmp_path, tiny):
     assert moe["router"].dtype == jnp.float32
     assert moe["experts"]["gate"].dtype == jnp.bfloat16
     assert mf.params["layers"]["0"]["attn"]["q_norm"].dtype == jnp.float32
+
+
+# -- the family stays what it was beside the second one ------------------------
+
+
+@pytest.mark.parametrize("normalise", [False, True], ids=["scaled", "renormalised"])
+def test_the_first_gate_is_bit_for_bit_what_it_was(normalise):
+    """`route` learned DeepSeek-V3.2's gate (sigmoid, a correction bias,
+    renormalise AND scale); this family's branch is the parent's
+    arithmetic written out again here, op for op: softmax, a group ranked
+    by its best, top-k over the kept groups, and the weights EITHER
+    renormalised OR scaled."""
+    import dataclasses
+
+    preset = dataclasses.replace(program.deepseek_v2_tiny(), norm_topk_prob=normalise)
+    rng = np.random.default_rng(7)
+    u = jnp.asarray(rng.standard_normal((5, 64)), jnp.float32)
+    router = jnp.asarray(rng.standard_normal((64, 16)) * 0.25, jnp.float32)
+    experts, weights = program.route(preset, u, router)
+    logits = jnp.einsum("ti,io->to", u, router, precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, -1)
+    best = scores.reshape(-1, 4, 4).max(-1)
+    _, kept = jax.lax.top_k(best, 2)
+    keep = jnp.any(kept[..., None] == jnp.arange(4), -2)
+    scores = jnp.where(jnp.repeat(keep, 4, -1), scores, 0.0)
+    want, chosen = jax.lax.top_k(scores, 3)
+    if normalise:
+        want = want / (want.sum(-1, keepdims=True) + 1e-20)
+    else:
+        want = want * 16.0
+    assert np.asarray(experts).tolist() == np.asarray(chosen).tolist()
+    assert (np.asarray(weights).view(np.uint32) == np.asarray(want).view(np.uint32)).all()
+    total = np.asarray(weights).sum(-1)
+    assert np.allclose(total, 1.0, atol=1e-6) == normalise
+    # the family's own key takes this branch, with or without the bias argument
+    assert preset.scoring_func == "softmax" and preset.worst_case_chunk_rows is None
+    again = program.route(preset, u, router, None)
+    assert (np.asarray(again[1]) == np.asarray(weights)).all()
+
+
+#: sha256 (16 hex digits) of the text of `jax.make_jaxpr` of the tiny
+#: preset's program AT THE PARENT COMMIT (3c6d674, before the second
+#: family shared this module's functions), by dtype and batch shape.
+#: They change with jax's version too: then take them again from a
+#: checkout of that commit, not from this tree.
+PARENT_JAXPR = {
+    ("float32", (2, 32)): "b04eb2e28e3f9aa0",
+    ("float32", (4, 128)): "260752f43db7f8b5",
+    ("bfloat16", (2, 32)): "f4608b0e5f1c5cea",
+    ("bfloat16", (4, 128)): "abbc2a3c9edda152",
+}
+
+
+@pytest.mark.parametrize("dtype, shape", sorted(PARENT_JAXPR), ids=str)
+def test_the_programs_jaxpr_is_the_parents(dtype, shape):
+    """`_mla` was split for the family that selects its keys, `route` and
+    `_routed` learned its gate and its worst-case arm in passes: this
+    family's program is to the letter the one it was (no indexer, no
+    second gate, no scan of passes)."""
+    import hashlib
+
+    mf = program.deepseek_v2_model_function("deepseek-v2-tiny", dtype=jnp.dtype(dtype))
+    text = str(jax.make_jaxpr(mf.fn)(mf.params, jnp.zeros(shape, jnp.int32)))
+    assert "scan[" not in text and "i8[" not in text and "bitcast_convert_type" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPR[dtype, shape]

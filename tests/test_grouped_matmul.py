@@ -35,6 +35,10 @@ CASES = {
     "no_tile_multiple": (75, 24, 256, [7, 1, 30, 19, 5], (16, 24, 128)),
     "several_k_steps": (64, 256, 256, [5, 40, 3, 16], (32, 128, 128)),
     "default_tiling": (300, 40, 384, [100, 0, 150, 40], None),
+    # DeepSeek-V3.2's down product at an eighth: N = 7 x 128 lanes has no
+    # power-of-two column tile, and the one chosen is 7 lane tiles wide
+    "column_tile_of_seven_lane_tiles": (48, 64, 1792, [20, 0, 25], (16, 64, 896)),
+    "seven_lane_tiles_by_default": (272, 256, 896, [100, 3, 150], None),
 }
 
 
@@ -78,6 +82,25 @@ def test_tiling_of_an_expert_layer_keeps_the_contraction_whole():
     assert gm.choose_tiling(98304, 5120, 1536) == (256, 5120, 768)
     assert gm.choose_tiling(98304, 1536, 5120) == (256, 1536, 2560)
     assert gm.choose_tiling(40, 24, 64) == (48, 24, 64)
+
+
+@pytest.mark.parametrize(
+    "m, k, n, tiling",
+    [
+        # DeepSeek-V3.2, hidden 7,168 and experts of 2,048: the sized
+        # buffer of a 16,384-token dispatch and a pass of the worst case
+        (5120, 7168, 2048, (256, 7168, 512)),
+        (16384, 7168, 2048, (256, 7168, 512)),
+        (5120, 2048, 7168, (256, 2048, 1792)),  # 7,168 = 4 x 1,792 = 4 x 14 x 128
+        (2560, 2048, 7168, (256, 2048, 1792)),
+        (272, 256, 896, (256, 256, 896)),
+    ],
+)
+def test_tiling_at_the_widths_of_the_second_expert_family(m, k, n, tiling):
+    tm, tk, tn = gm.choose_tiling(m, k, n)
+    assert (tm, tk, tn) == tiling
+    assert k % tk == 0 and n % tn == 0 and tn % 128 == 0
+    assert tk * tn * 2 <= gm._RHS_BLOCK_BYTES  # one weight block, bfloat16
 
 
 def test_plain_form_and_the_build_time_choice():
