@@ -577,6 +577,20 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
     return _mean_real_state(_rms(x, params["final_norm"], eps), real), slots_held, sized
 
 
+def attention_batch_counters(attention_fn, layers: int, ids) -> dict:
+    """What ``mf.batch_counters`` counts of attention for a dispatched
+    batch: ``mla.pairs_computed``, rows x layers x the (query, key) pairs
+    a head's attention runs at the bucket's edge, where the attention it
+    was built with says (``.pairs_computed``, as
+    ``make_latent_attention_fn``'s do): how much of the square was run,
+    beside ``mla.attention_tokens``."""
+    pairs = getattr(attention_fn, "pairs_computed", None)
+    if pairs is None:
+        return {}
+    rows, edge = ids.shape
+    return {"mla.pairs_computed": rows * layers * pairs(edge)}
+
+
 def deepseek_v2_model_function(
     size: str = "deepseek-v2-tiny",
     dtype=jnp.float32,
@@ -600,7 +614,9 @@ def deepseek_v2_model_function(
     two sum to the expert layers, so over a job to live rows x expert
     layers). ``TextEmbedder`` strips the columns and adds them to
     counters ``moe.slots_held``, ``moe.buffer_sized`` and
-    ``moe.buffer_full``; any other caller slices them off."""
+    ``moe.buffer_full``; any other caller slices them off. On the host,
+    ``mf.batch_counters(ids, real)`` counts ``mla.pairs_computed`` for
+    every dispatched batch (:func:`attention_batch_counters`)."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
@@ -648,4 +664,7 @@ def deepseek_v2_model_function(
     mf.real_token_counters = {
         "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
     }
+    mf.batch_counters = lambda ids, real: attention_batch_counters(
+        attention_fn, config.num_layers, ids
+    )
     return mf

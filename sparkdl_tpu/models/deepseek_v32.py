@@ -292,8 +292,10 @@ def deepseek_v32_model_function(
     queries, counted on the device from the selection itself. On the
     host, ``mf.batch_counters(ids, real)`` counts for every dispatched
     batch ``dsa.index_tokens`` (rows x bucket edge x layers, where the
-    bucket runs the indexer) and ``dsa.pairs_causal`` (what dense causal
-    attention would have read, from the real lengths)."""
+    bucket runs the indexer), ``dsa.pairs_causal`` (what dense causal
+    attention would have read, from the real lengths) and
+    ``mla.pairs_computed`` (what the attention it was built with runs at
+    the bucket's edge, ``deepseek_v2.attention_batch_counters``)."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.dsa_indexer import make_indexer_fn
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
@@ -340,6 +342,7 @@ def deepseek_v32_model_function(
         return {
             "dsa.index_tokens": int(ids.size) * layers * (ids.shape[1] > top_k),
             "dsa.pairs_causal": int((n * (n + 1) // 2).sum()) * layers,
+            **v2.attention_batch_counters(attention_fn, layers, ids),
         }
 
     mf = ModelFunction(

@@ -108,13 +108,14 @@ def _flash_kernel(
         _write_result(o_ref, l_ref, acc_ref)
 
 
-def _softmax_step(s, v, m_ref, l_ref, acc_ref):
+def _softmax_step(s, v, m_ref, l_ref, acc_ref, rows=slice(None)):
     """One key block of the online softmax: scores s [bq, bk] and values
-    v [bk, dv] folded into the running max, sum and accumulator."""
+    v [bk, dv] folded into the running max, sum and accumulator, of the
+    query rows ``rows`` of the block (all of them, as ``[:]`` reads)."""
     # lanes of m_ref/l_ref all hold the same per-row value; max() reads it
     # back without a sub-128 lane slice.
-    m_prev = jnp.max(m_ref[:], axis=-1, keepdims=True)  # [bq, 1]
-    l_prev = jnp.max(l_ref[:], axis=-1, keepdims=True)
+    m_prev = jnp.max(m_ref[rows], axis=-1, keepdims=True)  # [bq, 1]
+    l_prev = jnp.max(l_ref[rows], axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)  # [bq, bk]
@@ -122,9 +123,9 @@ def _softmax_step(s, v, m_ref, l_ref, acc_ref):
     pv = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    acc_ref[:] = acc_ref[:] * alpha + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    acc_ref[rows] = acc_ref[rows] * alpha + pv
+    m_ref[rows] = jnp.broadcast_to(m_new, s.shape[:1] + m_ref.shape[1:])
+    l_ref[rows] = jnp.broadcast_to(l_new, s.shape[:1] + l_ref.shape[1:])
 
 
 def _write_result(o_ref, l_ref, acc_ref):
@@ -480,6 +481,27 @@ def flash_attention_packed(
     return out[:, :L]
 
 
+#: Key columns of one sub-tile of the latent kernel's step (a block of
+#: ``block`` keys is walked ``block // LATENT_KEY_TILE`` sub-tiles; a block
+#: that is no multiple of it is one sub-tile). Chosen on the chip from
+#: {128, 256, 512} at the four shapes the cells run: PERF.md, PR 35.
+LATENT_KEY_TILE = 256
+
+
+def _key_tile(block: int) -> int:
+    return LATENT_KEY_TILE if block % LATENT_KEY_TILE == 0 else block
+
+
+def latent_pairs_computed(length: int, block: int, tile: int) -> int:
+    """(query, key) pairs the latent kernel runs for one head of one row
+    of ``length`` tokens in blocks of ``block`` walked in key sub-tiles of
+    ``tile``: every block under the diagonal whole, and of a diagonal
+    block sub-tile j against the query rows from j * tile on."""
+    n = -(-length // block)
+    sub = block // tile
+    return n * (n - 1) // 2 * block * block + n * tile * tile * sub * (sub + 1) // 2
+
+
 def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs):
     """The blocked causal kernel for latent attention, one (row, head) a
     grid step: the query block is [bq, nope + rope], the head's own keys
@@ -489,7 +511,15 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
     ``selected`` a fifth operand is the block [bq, bk] of a selection
     (int8, nonzero where the query attends to the key, zero above the
     diagonal): it takes the causal mask's place, before the running
-    maximum."""
+    maximum.
+
+    A fetched block is walked in key sub-tiles of ``_key_tile(bk)``
+    columns. Under the diagonal every sub-tile meets every query row, and
+    without a selection nothing is masked there. On the diagonal sub-tile
+    j meets the query rows from j * tile on (those above see none of its
+    keys), and the causal compare falls on the tile x tile square the
+    diagonal crosses alone; a selection's bytes are applied wherever a
+    sub-tile runs."""
     from jax.experimental import pallas as pl
 
     if selected:
@@ -497,6 +527,8 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
     qi, ki = pl.program_id(1), pl.program_id(2)
+    bq = q_ref.shape[1]
+    tile = _key_tile(bq)
 
     @pl.when(ki == 0)
     def _init():
@@ -504,26 +536,40 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ki <= qi)
-    def _block():
-        q = q_ref[0].astype(jnp.float32)  # [bq, nope + rope]
+    def _sub_tile(j, rows, crossed):
+        """Key sub-tile j against the query rows ``rows``; ``crossed``:
+        the first ``tile`` of them are the square the diagonal crosses."""
+        cols = slice(j * tile, (j + 1) * tile)
         contract = (((1,), (1,)), ((), ()))
+        q = q_ref[0, rows].astype(jnp.float32)  # [rows, nope + rope]
         s = jax.lax.dot_general(
-            q[:, :nope], k_ref[0].astype(jnp.float32), contract,
+            q[:, :nope], k_ref[0, cols].astype(jnp.float32), contract,
             preferred_element_type=jnp.float32,
         ) + jax.lax.dot_general(
-            q[:, nope:], kr_ref[0].astype(jnp.float32), contract,
+            q[:, nope:], kr_ref[0, cols].astype(jnp.float32), contract,
             preferred_element_type=jnp.float32,
         )
         s = s * scale
         if selected:
-            s = jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
-        else:
-            bq, bk = s.shape
-            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col <= row, s, NEG_INF)
-        _softmax_step(s, v_ref[0].astype(jnp.float32), m_ref, l_ref, acc_ref)
+            s = jnp.where(sel_ref[0, rows, cols].astype(jnp.int32) != 0, s, NEG_INF)
+        elif crossed:
+            row = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+            square = jnp.where(col <= row, s[:tile], NEG_INF)
+            s = jnp.concatenate([square, s[tile:]]) if s.shape[0] > tile else square
+        _softmax_step(
+            s, v_ref[0, cols].astype(jnp.float32), m_ref, l_ref, acc_ref, rows
+        )
+
+    @pl.when(ki < qi)
+    def _under_the_diagonal():
+        for j in range(bq // tile):
+            _sub_tile(j, slice(None), False)
+
+    @pl.when(ki == qi)
+    def _diagonal():
+        for j in range(bq // tile):
+            _sub_tile(j, slice(j * tile, bq), True)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -563,7 +609,17 @@ def flash_attention_latent(
 
     A score is (q_nope . k_nope + q_rope . k_rope) * ``scale``. Returns
     [B, L, H * Dv] in q's dtype. The blocked kernel's algorithm and
-    name; grid (B * H, L / block, L / block)."""
+    name; grid (B * H, L / block, L / block), the last axis sequential.
+    A step fetches one [block, block] pair of a query and a key block
+    (none above the diagonal) and walks it in key sub-tiles of
+    ``LATENT_KEY_TILE`` columns (``block`` a multiple of it; any other
+    ``block`` is one sub-tile). A block under the diagonal runs every
+    sub-tile against every query row, unmasked but for a selection's
+    bytes. A diagonal block runs sub-tile j against the query rows from
+    j * tile on, (n + 1) / 2n of its pairs with n sub-tiles, and masks
+    the tile x tile squares the diagonal crosses alone (with a selection,
+    its bytes wherever a sub-tile runs): a pair left out is one whose
+    probability is zero. :func:`latent_pairs_computed` counts the pairs."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -677,12 +733,15 @@ def make_latent_attention_fn(
     dtype, selection=None)`` over the projections' own arrays: the Pallas
     kernel on TPU (or interpreted when asked),
     :func:`dense_latent_attention` elsewhere. ``.kind`` ('flash' |
-    'dense') says which."""
+    'dense') says which, and ``.pairs_computed(length)`` how many (query,
+    key) pairs it runs for a head of one row of that length: the square
+    for the dense one, :func:`latent_pairs_computed` for the kernel."""
     if not interpret and jax.default_backend() != "tpu":
         dense = functools.partial(
             dense_latent_attention, num_heads=num_heads, scale=scale
         )
         dense.kind = "dense"
+        dense.pairs_computed = lambda length: length * length
         return dense
 
     def attention(q, kv, k_rope, dtype, selection=None):
@@ -693,6 +752,9 @@ def make_latent_attention_fn(
         return out.astype(dtype)
 
     attention.kind = "flash"
+    attention.pairs_computed = lambda length: latent_pairs_computed(
+        length, block, _key_tile(block)
+    )
     return attention
 
 

@@ -234,23 +234,31 @@ def test_the_indexers_two_kernels_compile_at_the_published_widths(shape, length)
     assert memory.temp_size_in_bytes < length * length * 4 + (64 << 20)
 
 
-@pytest.mark.parametrize("length", [8192, 16384])
-def test_latent_flash_with_a_selection_compiles(shape, length):
+@pytest.mark.parametrize(
+    "rows, length, selected",
+    [(1, 8192, True), (1, 16384, True), (8, 2048, False), (8, 1024, False)],
+)
+def test_latent_flash_compiles_at_the_cells_shapes(shape, rows, length, selected):
     """128 heads of 128 + 2 x 64 query lanes over the up-projected latent,
-    blocks of 1,024, the selection a fifth operand read a [1024, 1024]
-    int8 block a step: nothing is padded, repeated or converted in HBM."""
+    blocks of 1,024 walked in key sub-tiles: a dispatch of
+    `deepseek-v3.2-exp-embed-long-docs` (one row, the selection a fifth
+    operand read a [1024, 1024] int8 block a step) and of
+    `deepseek-v2-embed-windows` (eight rows, no selection). Nothing is
+    padded, repeated or converted in HBM."""
     from sparkdl_tpu.ops.flash_attention import flash_attention_latent
 
     bf16 = jnp.bfloat16
-    wide = shape((1, length, 128 * 256), bf16)
+    wide = shape((rows, length, 128 * 256), bf16)
+    operands = [wide, wide, shape((rows, length, 128), bf16)]
+    if selected:
+        operands.append(shape((rows, length, length), jnp.int8))
     compiled = (
         jax.jit(
-            lambda q, kv, k_rope, selection: flash_attention_latent(
+            lambda q, kv, k_rope, selection=None: flash_attention_latent(
                 q, kv, k_rope, selection, num_heads=128, scale=0.1353, block=1024
             )
         )
-        .lower(wide, wide, shape((1, length, 128), bf16),
-               shape((1, length, length), jnp.int8))
+        .lower(*operands)
         .compile()
     )
     text = compiled.as_text()

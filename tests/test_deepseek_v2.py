@@ -65,6 +65,22 @@ def _counters():
     return dict(metrics.scalar_snapshot()["counters"])
 
 
+def _pairs_computed(delta, edges, interpret, layers=3):
+    """What `mla.pairs_computed` should have counted, by hand from the
+    rows and tokens dispatched at the two bucket edges: the dense
+    fallback runs the square, the kernel (blocks of 64, one sub-tile
+    each) the blocks at and under the diagonal."""
+    low, high = edges
+    rows = delta["feeder.rows"] + delta.get("feeder.pad_rows", 0)
+    at_high = (delta["mla.attention_tokens"] // layers - low * rows) // (high - low)
+
+    def pairs(edge):
+        n = edge // 64
+        return n * (n + 1) // 2 * 64 * 64 if interpret else edge * edge
+
+    return layers * ((rows - at_high) * pairs(low) + at_high * pairs(high))
+
+
 def _embed(path, inputs, dtype, interpret, batch=4, max_length=256, edit=None):
     preset = program.deepseek_v2_tiny()
     mf = program.deepseek_v2_model_function(
@@ -146,6 +162,7 @@ def test_embedder_matches_the_reference_row_by_row(
     # that spreads evenly leaves every one of them on the sized buffer
     assert delta["moe.buffer_sized"] == 12 * 2
     assert delta.get("moe.buffer_full", 0) == 0
+    assert delta["mla.pairs_computed"] == _pairs_computed(delta, (128, 256), interpret)
 
 
 def test_a_row_does_not_change_with_what_pads_it(monkeypatch, tiny, corpus):

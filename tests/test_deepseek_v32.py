@@ -65,6 +65,22 @@ def _counters():
     return dict(metrics.scalar_snapshot()["counters"])
 
 
+def _pairs_computed(delta, edges, interpret, layers=3):
+    """`mla.pairs_computed` by hand, as `test_deepseek_v2.py` reckons it:
+    from the rows and tokens dispatched at the two bucket edges, the
+    square for the dense fallback and the blocks of 64 at and under the
+    diagonal for the kernel."""
+    low, high = edges
+    rows = delta["feeder.rows"] + delta.get("feeder.pad_rows", 0)
+    at_high = (delta["mla.attention_tokens"] // layers - low * rows) // (high - low)
+
+    def pairs(edge):
+        n = edge // 64
+        return n * (n + 1) // 2 * 64 * 64 if interpret else edge * edge
+
+    return layers * ((rows - at_high) * pairs(low) + at_high * pairs(high))
+
+
 def _built(path, dtype, interpret):
     preset = program.deepseek_v32_tiny()
     return program.deepseek_v32_model_function(
@@ -173,6 +189,7 @@ def test_embedder_matches_the_reference_row_by_row(
         min(16, t + 1) for n in lengths for t in range(n)
     )
     assert delta["dsa.pairs_selected"] < 0.25 * delta["dsa.pairs_causal"]
+    assert delta["mla.pairs_computed"] == _pairs_computed(delta, (64, 256), interpret)
 
 
 def test_a_large_count_of_pairs_rides_back_exactly():

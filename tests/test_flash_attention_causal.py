@@ -229,36 +229,142 @@ def latent_by_heads(q, kv, k_rope, H, scale):
     return o.transpose(0, 2, 1, 3).reshape(B, L, -1)
 
 
+def _selection(kind, B, L, seed):
+    """None; every key at or before the query; or the eight keys a query
+    scores highest at random among those (all of them for its first
+    eight): what `ops/dsa_indexer.py` hands over, a byte a pair."""
+    if kind is None:
+        return None
+    seen = np.tril(np.ones((L, L), bool))
+    if kind == "top-k":
+        scores = np.where(seen, np.random.default_rng(seed).normal(size=(B, L, L)), -np.inf)
+        kth = np.sort(scores, -1)[..., ::-1][..., min(7, L - 1)]
+        seen = seen & (scores >= kth[..., None])
+    return jnp.asarray(np.broadcast_to(seen, (B, L, L)).astype(np.int8))
+
+
+@pytest.mark.parametrize("selection", [None, "causal", "top-k"])
 @pytest.mark.parametrize(
-    "H, L, nope, rope, block",
+    "H, L, nope, rope, block, tile",
     [
-        (3, 200, 128, 128, 64),  # the sizes the chip runs; a length off the block
-        (4, 200, 128, 64, 128),  # the published 128 + 64 over values of 128
-        (128, 64, 16, 16, 32),  # the published head count
-        (4, 333, 32, 16, 64),  # six blocks, the last part-filled
-        (4, 192, 128, 64, 64),  # whole blocks, the diagonal's alone masked
-        (1, 130, 128, 64, 128),  # one head, two blocks, the second nearly empty
+        (3, 200, 128, 128, 64, None),  # the sizes the chip runs; a length off the block
+        (4, 200, 128, 64, 128, None),  # the published 128 + 64 over values of 128
+        (128, 64, 16, 16, 32, None),  # the published head count
+        (4, 333, 32, 16, 64, None),  # six blocks, the last part-filled
+        (4, 192, 128, 64, 64, None),  # whole blocks, the diagonal's alone masked
+        (1, 130, 128, 64, 128, None),  # one head, two blocks, the second nearly empty
+        # a block walked in 2, 4 and 8 key sub-tiles: one block exactly, one
+        # part-filled (its padded queries attend to key 0, in sub-tile 0),
+        # and several with the last part-filled
+        (2, 128, 32, 16, 128, 64),
+        (2, 100, 32, 16, 128, 64),
+        (2, 300, 32, 16, 128, 64),
+        (2, 128, 32, 16, 128, 32),
+        (2, 77, 32, 16, 128, 32),
+        (2, 333, 32, 16, 128, 32),
+        (2, 128, 32, 16, 128, 16),
+        (2, 50, 32, 16, 128, 16),
+        (2, 400, 32, 16, 128, 16),
     ],
 )
-def test_latent_kernel_matches_dense_over_split_heads(H, L, nope, rope, block):
-    from sparkdl_tpu.ops.flash_attention import (
-        dense_latent_attention,
-        flash_attention_latent,
-    )
+def test_latent_kernel_matches_dense_over_split_heads(
+    monkeypatch, H, L, nope, rope, block, tile, selection
+):
+    from sparkdl_tpu.ops import flash_attention as ops
 
-    q, kv, k_rope = latent_arrays(H + L, 2 if H < 8 else 1, L, H, nope, rope)
+    if tile is None:  # the module's own: these blocks are one sub-tile each
+        assert ops._key_tile(block) == block
+    else:
+        monkeypatch.setattr(ops, "LATENT_KEY_TILE", tile)
+        assert ops._key_tile(block) == tile
+    B = 2 if H < 8 else 1
+    q, kv, k_rope = latent_arrays(H + L, B, L, H, nope, rope)
+    chosen = _selection(selection, B, L, H + L)
     scale = 0.07
-    got = flash_attention_latent(
-        q, kv, k_rope, num_heads=H, scale=scale, block=block, interpret=True
+    got = ops.flash_attention_latent(
+        q, kv, k_rope, chosen, num_heads=H, scale=scale, block=block, interpret=True
     )
-    assert got.shape == (q.shape[0], L, H * nope)
+    assert got.shape == (B, L, H * nope)
     with jax.default_matmul_precision("highest"):
-        want = latent_by_heads(q, kv, k_rope, H, scale)
-        plain = dense_latent_attention(
-            q, kv, k_rope, jnp.float32, num_heads=H, scale=scale
+        plain = ops.dense_latent_attention(
+            q, kv, k_rope, jnp.float32, chosen, num_heads=H, scale=scale
         )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
-    np.testing.assert_allclose(np.asarray(plain), np.asarray(want), **TOL)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(plain), **TOL)
+        if selection != "top-k":  # a causal-everything selection is causal attention
+            want = latent_by_heads(q, kv, k_rope, H, scale)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+def _latent_branches(selected, block=128):
+    """The traced kernel's two branches that do work, as text: a block
+    under the diagonal and one on it, from a trace of two blocks a side."""
+    from sparkdl_tpu.ops import flash_attention as ops
+
+    q, kv, k_rope = latent_arrays(3, 1, 2 * block, 2, 32, 16)
+    args = [q, kv, k_rope]
+    if selected:
+        args.append(jnp.ones((1, 2 * block, 2 * block), jnp.int8))
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ops.flash_attention_latent(*a, num_heads=2, scale=0.1, block=block)
+    )(*args)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    conds = [e for e in call.params["jaxpr"].eqns if e.primitive.name == "cond"]
+    # the first key block, under the diagonal, on it, the last key block
+    assert len(conds) == 4
+    # a `cond` on a boolean holds (not taken, taken)
+    return [str(e.params["branches"][1]) for e in conds[1:3]]
+
+
+def test_latent_kernel_runs_and_masks_only_what_the_diagonal_leaves(monkeypatch):
+    """The traced kernel's text at four sub-tiles a block. Under the
+    diagonal every sub-tile meets every query row and, without a
+    selection, nothing is compared: no `iota`. On the diagonal there are
+    as many products as sub-tiles, over falling row counts, and the
+    compare falls on [tile, tile] squares alone. A selection's bytes
+    are read wherever a sub-tile runs."""
+    import re
+
+    from sparkdl_tpu.ops import flash_attention as ops
+
+    monkeypatch.setattr(ops, "LATENT_KEY_TILE", 32)
+
+    def rows_of_products(text):
+        return [int(r) for r in re.findall(r"f32\[(\d+),32\] = dot_general", text)]
+
+    under, diagonal = _latent_branches(selected=False)
+    assert "iota" not in under and "select_n" not in under
+    # nope 32 = dv = tile here: a sub-tile's three products (the score's
+    # two and p . v) each write [rows, 32]
+    assert rows_of_products(under) == [128] * 12
+    assert rows_of_products(diagonal) == [128] * 3 + [96] * 3 + [64] * 3 + [32] * 3
+    assert diagonal.count("iota") == 8  # a row and a column index a crossed square
+    assert set(re.findall(r"bool\[(\d+,\d+)\] = le", diagonal)) == {"32,32"}
+
+    under, diagonal = _latent_branches(selected=True)
+    assert "iota" not in under and "iota" not in diagonal
+    assert rows_of_products(under) == [128] * 12
+    assert rows_of_products(diagonal) == [128] * 3 + [96] * 3 + [64] * 3 + [32] * 3
+    assert re.findall(r"bool\[(\d+),32\] = ne", under) == ["128"] * 4
+    assert re.findall(r"bool\[(\d+),32\] = ne", diagonal) == ["128", "96", "64", "32"]
+
+
+@pytest.mark.parametrize(
+    "length, block, tile, blocks",
+    [
+        (2048, 1024, 256, 2.25),  # one block whole, two diagonals at 5/8
+        (1024, 1024, 256, 0.625),
+        (16384, 1024, 256, 130),  # 120 under the diagonal, 16 on it
+        (8192, 1024, 256, 33),
+        (2000, 1024, 256, 2.25),  # a length off the block runs its padding too
+        (1024, 1024, 1024, 1),  # one sub-tile a block: the square, as before
+        (2048, 1024, 128, 2.125),
+        (300, 128, 32, 3 + 3 * 0.625),
+    ],
+)
+def test_pairs_the_latent_kernel_computes_by_hand(length, block, tile, blocks):
+    from sparkdl_tpu.ops.flash_attention import latent_pairs_computed
+
+    assert latent_pairs_computed(length, block, tile) == blocks * block * block
 
 
 def test_latent_kernel_moves_nothing_in_hbm_and_says_what_it_cannot_do():
