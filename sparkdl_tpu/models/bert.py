@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sparkdl_tpu.utils.profiler import scope
+
 
 @dataclass(frozen=True)
 class BertConfig:
@@ -143,18 +145,27 @@ class BertSelfAttention(nn.Module):
         def proj(name):
             return nn.Dense(c.hidden_size, dtype=c.dtype, name=name)(x)
 
-        q, k, v = proj("query"), proj("key"), proj("value")
         attn = self.attention_fn or dense_attention
-        if attention_layout(attn) == "packed":
-            out = attn(q, k, v, mask, c.dtype, num_heads=h)
-        else:
+        packed = attention_layout(attn) == "packed"
 
-            def split(t):  # [B, L, D] -> [B, H, L, Dh]
-                return t.reshape(*t.shape[:2], h, dh).transpose(0, 2, 1, 3)
+        def split(t):  # [B, L, D] -> [B, H, L, Dh]
+            return t.reshape(*t.shape[:2], h, dh).transpose(0, 2, 1, 3)
 
-            out = attn(split(q), split(k), split(v), mask, c.dtype)
-            out = out.transpose(0, 2, 1, 3).reshape(*x.shape[:2], c.hidden_size)
-        out = nn.Dense(c.hidden_size, dtype=c.dtype, name="output")(out)
+        with scope("attn.qkv"):
+            q, k, v = proj("query"), proj("key"), proj("value")
+            if not packed:
+                q, k, v = split(q), split(k), split(v)
+        with scope("attn.core"):
+            if packed:
+                out = attn(q, k, v, mask, c.dtype, num_heads=h)
+            else:
+                out = attn(q, k, v, mask, c.dtype)
+        with scope("attn.out"):
+            if not packed:
+                out = out.transpose(0, 2, 1, 3).reshape(
+                    *x.shape[:2], c.hidden_size
+                )
+            out = nn.Dense(c.hidden_size, dtype=c.dtype, name="output")(out)
         return out
 
 
@@ -168,15 +179,21 @@ class BertLayer(nn.Module):
         attn_out = BertSelfAttention(
             c, attention_fn=self.attention_fn, name="attention"
         )(x, mask)
-        x = nn.LayerNorm(epsilon=c.layer_norm_eps, name="attention_norm")(
-            (x + attn_out).astype(jnp.float32)
-        ).astype(c.dtype)
-        mlp = nn.Dense(c.intermediate_size, dtype=c.dtype, name="intermediate")(x)
-        mlp = nn.gelu(mlp, approximate=False)
-        mlp = nn.Dense(c.hidden_size, dtype=c.dtype, name="mlp_output")(mlp)
-        x = nn.LayerNorm(epsilon=c.layer_norm_eps, name="output_norm")(
-            (x + mlp).astype(jnp.float32)
-        ).astype(c.dtype)
+        # post-norm: a norm closes the block before it, so its scope is
+        # that block's last
+        with scope("attn.out"):
+            x = nn.LayerNorm(epsilon=c.layer_norm_eps, name="attention_norm")(
+                (x + attn_out).astype(jnp.float32)
+            ).astype(c.dtype)
+        with scope("mlp"):
+            mlp = nn.Dense(
+                c.intermediate_size, dtype=c.dtype, name="intermediate"
+            )(x)
+            mlp = nn.gelu(mlp, approximate=False)
+            mlp = nn.Dense(c.hidden_size, dtype=c.dtype, name="mlp_output")(mlp)
+            x = nn.LayerNorm(epsilon=c.layer_norm_eps, name="output_norm")(
+                (x + mlp).astype(jnp.float32)
+            ).astype(c.dtype)
         return x
 
 
@@ -199,22 +216,26 @@ class BertEncoder(nn.Module):
         c = self.config
         if attention_mask is None:
             attention_mask = jnp.ones_like(input_ids)
-        additive = (1.0 - attention_mask[:, None, None, :].astype(jnp.float32))
-        additive = additive * jnp.finfo(jnp.float32).min
-        x = BertEmbeddings(c, name="embeddings")(
-            input_ids, token_type_ids, position_offset=position_offset
-        )
+        with scope("embed"):
+            additive = (
+                1.0 - attention_mask[:, None, None, :].astype(jnp.float32)
+            )
+            additive = additive * jnp.finfo(jnp.float32).min
+            x = BertEmbeddings(c, name="embeddings")(
+                input_ids, token_type_ids, position_offset=position_offset
+            )
         for i in range(c.num_layers):
             x = BertLayer(
                 c, attention_fn=self.attention_fn, name=f"layer_{i}"
             )(x, additive)
-        x = x.astype(jnp.float32)
-        if pooled:
-            m = attention_mask[..., None].astype(jnp.float32)
-            return jnp.sum(x * m, axis=1) / jnp.maximum(
-                jnp.sum(m, axis=1), 1.0
-            )
-        return x
+        with scope("pool"):
+            x = x.astype(jnp.float32)
+            if pooled:
+                m = attention_mask[..., None].astype(jnp.float32)
+                return jnp.sum(x * m, axis=1) / jnp.maximum(
+                    jnp.sum(m, axis=1), 1.0
+                )
+            return x
 
     def embed(self, input_ids, attention_mask=None, token_type_ids=None):
         return self(

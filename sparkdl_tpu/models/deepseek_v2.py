@@ -72,6 +72,7 @@ from sparkdl_tpu.models.jamba import (
     _unflatten,
     load_flat,
 )
+from sparkdl_tpu.utils.profiler import scope
 
 
 @dataclass(frozen=True)
@@ -330,17 +331,19 @@ def _mla_inputs(config: DeepseekV2Config, p, u, tables):
     rkv = config.kv_lora_rank
     cos, sin = tables
 
-    c_q = _rms(_dense(u, p["q_a"]), p["q_norm"], eps).astype(dtype)
-    q = _dense(c_q, _query_weights(config, p["q_b"]))
-    by_lane = jnp.concatenate([jnp.ones((length, nope), jnp.float32), cos, sin], -1)
-    by_lane = jnp.broadcast_to(by_lane[:, None], (length, heads, nope + 2 * rope))
-    q = (q * by_lane.reshape(length, -1)).astype(dtype)
+    with scope("mla.q"):
+        c_q = _rms(_dense(u, p["q_a"]), p["q_norm"], eps).astype(dtype)
+        q = _dense(c_q, _query_weights(config, p["q_b"]))
+        by_lane = jnp.concatenate([jnp.ones((length, nope), jnp.float32), cos, sin], -1)
+        by_lane = jnp.broadcast_to(by_lane[:, None], (length, heads, nope + 2 * rope))
+        q = (q * by_lane.reshape(length, -1)).astype(dtype)
 
-    kv_a = _dense(u, p["kv_a"])  # [B, L, rkv + rope] float32
-    c_kv = _rms(kv_a[..., :rkv], p["kv_norm"], eps).astype(dtype)
-    kv = _dense(c_kv, p["kv_b"]).astype(dtype)  # a head's [k_nope | v]
-    k_pe = _rotate(kv_a[..., rkv:][..., _deinterleave(rope)], cos, sin).astype(dtype)
-    return c_q, q, kv, jnp.concatenate([k_pe, k_pe], -1)
+    with scope("mla.kv"):
+        kv_a = _dense(u, p["kv_a"])  # [B, L, rkv + rope] float32
+        c_kv = _rms(kv_a[..., :rkv], p["kv_norm"], eps).astype(dtype)
+        kv = _dense(c_kv, p["kv_b"]).astype(dtype)  # a head's [k_nope | v]
+        k_pe = _rotate(kv_a[..., rkv:][..., _deinterleave(rope)], cos, sin).astype(dtype)
+        return c_q, q, kv, jnp.concatenate([k_pe, k_pe], -1)
 
 
 def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
@@ -349,7 +352,10 @@ def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
     keys (``models/deepseek_v32.py``) takes :func:`_mla_inputs` and hands
     its selection to the kernel itself."""
     _, q, kv, k_pe = _mla_inputs(config, p, u, tables)
-    return _dense(attention_fn(q, kv, k_pe, u.dtype), p["o"])
+    with scope("mla.core"):
+        o = attention_fn(q, kv, k_pe, u.dtype)
+    with scope("mla.out"):
+        return _dense(o, p["o"])
 
 
 def _swiglu(p, u):
@@ -443,19 +449,22 @@ def _experts_and_combine(
     one) and their weighted sum back in token order [tokens, hidden]
     float32, ``at_once`` gathered parts a pass."""
     top_k = weights.shape[1]
-    x = flat[order[:rows] // top_k]  # [rows, hidden]
-    gate = experts_fn(x, experts["gate"], sizes)
-    up = experts_fn(x, experts["up"], sizes)
-    y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], sizes)
-    # back to (token, k) order; what the kernel left unwritten is not read
-    at = jnp.minimum(slot, rows - 1)
-    out = jnp.zeros((flat.shape[0], y.shape[1]), jnp.float32)
-    for j in range(top_k):
-        part = y[at[:, j]] * weights[:, j, None]
-        out = out + jnp.where(held[:, j, None], part, 0.0)
-        if (j + 1) % at_once == 0 and j + 1 < top_k:
-            y, out = jax.lax.optimization_barrier((y, out))
-    return out
+    with scope("moe.gather"):
+        x = flat[order[:rows] // top_k]  # [rows, hidden]
+    with scope("moe.experts"):
+        gate = experts_fn(x, experts["gate"], sizes)
+        up = experts_fn(x, experts["up"], sizes)
+        y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], sizes)
+    with scope("moe.combine"):
+        # back to (token, k) order; what the kernel left unwritten is not read
+        at = jnp.minimum(slot, rows - 1)
+        out = jnp.zeros((flat.shape[0], y.shape[1]), jnp.float32)
+        for j in range(top_k):
+            part = y[at[:, j]] * weights[:, j, None]
+            out = out + jnp.where(held[:, j, None], part, 0.0)
+            if (j + 1) % at_once == 0 and j + 1 < top_k:
+                y, out = jax.lax.optimization_barrier((y, out))
+        return out
 
 
 def _experts_in_chunks(
@@ -473,16 +482,19 @@ def _experts_in_chunks(
 
     def one_pass(out, lo):
         def compute(out):
-            at = jax.lax.dynamic_slice(order, (lo,), (rows,))
-            part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
-            x = flat[at // top_k]
-            gate = experts_fn(x, experts["gate"], part)
-            up = experts_fn(x, experts["up"], part)
-            y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], part)
-            # what the kernel left unwritten is not read
-            mine = (lo + jnp.arange(rows) < total)[:, None]
-            y = jnp.where(mine, y * by_slot[at][:, None], 0.0)
-            return out.at[at // top_k].add(y)
+            with scope("moe.gather"):
+                at = jax.lax.dynamic_slice(order, (lo,), (rows,))
+                part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+                x = flat[at // top_k]
+            with scope("moe.experts"):
+                gate = experts_fn(x, experts["gate"], part)
+                up = experts_fn(x, experts["up"], part)
+                y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], part)
+            with scope("moe.combine"):
+                # what the kernel left unwritten is not read
+                mine = (lo + jnp.arange(rows) < total)[:, None]
+                y = jnp.where(mine, y * by_slot[at][:, None], 0.0)
+                return out.at[at // top_k].add(y)
 
         return jax.lax.cond(lo < total, compute, lambda out: out, out), None
 
@@ -510,40 +522,47 @@ def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
     flat = u.reshape(tokens, hidden)
     # the first gate is called as it always was: what stands in for
     # ``route`` in a test or a planted fault takes three arguments
-    if "router_bias" in p:
-        experts, weights = route(config, flat, p["router"], p["router_bias"])
-    else:
-        experts, weights = route(config, flat, p["router"])
-    held = (experts >= first) & (experts < end) & real.reshape(tokens, 1)
-    # slots sorted by expert, those of absent experts (and of pad tokens) last
-    key = jnp.where(held, experts - first, end - first).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(
-        key[:, None] == jnp.arange(end - first, dtype=key.dtype), 0, dtype=jnp.int32
-    )
-    slot = jnp.argsort(order).reshape(tokens, top_k)
-    operands = (
-        p["experts"], flat.astype(p["experts"]["gate"].dtype), order, sizes,
-        slot, weights, held,
-    )
-    slots, capacity = tokens * top_k, slot_capacity(config, tokens)
+    with scope("moe.route"):
+        if "router_bias" in p:
+            experts, weights = route(config, flat, p["router"], p["router_bias"])
+        else:
+            experts, weights = route(config, flat, p["router"])
+    with scope("moe.routed"):
+        held = (experts >= first) & (experts < end) & real.reshape(tokens, 1)
+        # slots sorted by expert, those of absent experts (and of pad tokens) last
+        key = jnp.where(held, experts - first, end - first).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(
+            key[:, None] == jnp.arange(end - first, dtype=key.dtype), 0, dtype=jnp.int32
+        )
+        slot = jnp.argsort(order).reshape(tokens, top_k)
+        operands = (
+            p["experts"], flat.astype(p["experts"]["gate"].dtype), order, sizes,
+            slot, weights, held,
+        )
+        slots, capacity = tokens * top_k, slot_capacity(config, tokens)
 
-    # the worst-case arm: all k gathered parts at once are 2 GB beside its
-    # y; one at a time (a loop carrying the sum) made k passes over it,
-    # 4.40 s a job against 4.19 on the chip (PERF.md, PR 32). The barrier
-    # orders the passes. The sized arm's y is a third of that: one pass.
-    full = functools.partial(_experts_and_combine, slots, _COMBINE_AT_ONCE, experts_fn)
-    chunk = config.worst_case_chunk_rows
-    if chunk and slots > chunk:
-        full = functools.partial(_experts_in_chunks, chunk, experts_fn)
-    if capacity == slots:
-        fits, out = jnp.zeros((), bool), full(*operands)
-    else:
-        sized = functools.partial(_experts_and_combine, capacity, top_k, experts_fn)
-        fits = jnp.sum(sizes) <= capacity
-        out = jax.lax.cond(fits, sized, full, *operands)
-    count = jnp.sum(held.reshape(rows, -1), 1, dtype=jnp.int32)
-    return out.reshape(rows, length, hidden), count, fits
+        # the worst-case arm: all k gathered parts at once are 2 GB beside its
+        # y; one at a time (a loop carrying the sum) made k passes over it,
+        # 4.40 s a job against 4.19 on the chip (PERF.md, PR 32). The barrier
+        # orders the passes. The sized arm's y is a third of that: one pass.
+        full = functools.partial(_experts_and_combine, slots, _COMBINE_AT_ONCE, experts_fn)
+        chunk = config.worst_case_chunk_rows
+        if chunk and slots > chunk:
+            full = functools.partial(_experts_in_chunks, chunk, experts_fn)
+        if capacity == slots:
+            fits, out = jnp.zeros((), bool), full(*operands)
+        else:
+            sized = functools.partial(_experts_and_combine, capacity, top_k, experts_fn)
+
+            def worst_case(*operands):
+                with scope("moe.worst_case"):
+                    return full(*operands)
+
+            fits = jnp.sum(sizes) <= capacity
+            out = jax.lax.cond(fits, sized, worst_case, *operands)
+        count = jnp.sum(held.reshape(rows, -1), 1, dtype=jnp.int32)
+        return out.reshape(rows, length, hidden), count, fits
 
 
 def _mean_real_state(x, real):
@@ -558,23 +577,35 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
     [B, hidden] float32, slots that fell on held experts [B] int32, how
     many expert layers worked on the sized slot buffer, an int32 scalar)."""
     eps = config.rms_norm_eps
-    real = ids != 0
-    tables = rope_tables(config, ids.shape[1])
-    x = params["embed"][ids].astype(jnp.float32)
+    with scope("embed"):
+        real = ids != 0
+        tables = rope_tables(config, ids.shape[1])
+        x = params["embed"][ids].astype(jnp.float32)
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
     for i in range(config.num_layers):
         p = params["layers"][str(i)]
-        u = _rms(x, p["norm_in"], eps).astype(dtype)
-        x = x + _mla(config, p["attn"], u, tables, attention_fn)
-        u = _rms(x, p["norm_ff"], eps)
-        if i < config.first_k_dense:
-            x = x + _swiglu(p["mlp"], u.astype(dtype))
-            continue
+        # a norm is in the scope of the first part it feeds, a residual
+        # sum in that of the part it closes
+        with scope("mla.q"):
+            u = _rms(x, p["norm_in"], eps).astype(dtype)
+        attended = _mla(config, p["attn"], u, tables, attention_fn)
+        with scope("mla.out"):
+            x = x + attended
+        with scope("mlp"):
+            u = _rms(x, p["norm_ff"], eps)
+            if i < config.first_k_dense:
+                x = x + _swiglu(p["mlp"], u.astype(dtype))
+                continue
         routed, count, fits = _routed(config, p["moe"], u, real, experts_fn)
-        x = x + _swiglu(p["moe"]["shared"], u.astype(dtype)) + routed
+        with scope("mlp"):
+            x = x + _swiglu(p["moe"]["shared"], u.astype(dtype))
+        with scope("moe.routed"):
+            x = x + routed
         slots_held, sized = slots_held + count, sized + fits
-    return _mean_real_state(_rms(x, params["final_norm"], eps), real), slots_held, sized
+    with scope("pool"):
+        out = _mean_real_state(_rms(x, params["final_norm"], eps), real)
+    return out, slots_held, sized
 
 
 def attention_batch_counters(attention_fn, layers: int, ids) -> dict:
@@ -646,10 +677,11 @@ def deepseek_v2_model_function(
             config, p, ids, dtype=dtype, attention_fn=attention_fn,
             experts_fn=experts_fn,
         )
-        sized = jnp.broadcast_to(sized, slots_held.shape)
-        counts = jnp.stack([slots_held, sized, config.expert_layers - sized], 1)
-        # at most tokens x k x layers a row: exact in float32
-        return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
+        with scope("pool"):
+            sized = jnp.broadcast_to(sized, slots_held.shape)
+            counts = jnp.stack([slots_held, sized, config.expert_layers - sized], 1)
+            # at most tokens x k x layers a row: exact in float32
+            return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
 
     mf = ModelFunction(
         fn, params, input_dtype=jnp.int32, name=name or f"{size}[embed]"

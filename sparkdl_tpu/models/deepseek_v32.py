@@ -61,6 +61,7 @@ import numpy as np
 from sparkdl_tpu.models import deepseek_v2 as v2
 from sparkdl_tpu.models.deepseek_v2 import DeepseekV2Config
 from sparkdl_tpu.models.jamba import _dense, _rms, _unflatten, load_flat
+from sparkdl_tpu.utils.profiler import scope
 
 
 @dataclass(frozen=True)
@@ -231,37 +232,53 @@ def forward(
     the (query, key) pairs the layers' attention read for the row's real
     queries [B] int32). A length within ``index_topk`` runs no indexer."""
     eps = config.rms_norm_eps
-    real = ids != 0
     selects = ids.shape[1] > config.index_topk
-    tables = v2.rope_tables(config, ids.shape[1])
-    x = params["embed"][ids].astype(jnp.float32)
+    with scope("embed"):
+        real = ids != 0
+        tables = v2.rope_tables(config, ids.shape[1])
+        x = params["embed"][ids].astype(jnp.float32)
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     pairs = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
     for i in range(config.num_layers):
         p = params["layers"][str(i)]
-        u = _rms(x, p["norm_in"], eps).astype(dtype)
+        # the scopes are ``deepseek_v2.forward``'s, and the indexer's own:
+        # its operands, its two kernels (one call here), and the count of
+        # what it selected
+        with scope("mla.q"):
+            u = _rms(x, p["norm_in"], eps).astype(dtype)
         c_q, q, kv, k_pe = v2._mla_inputs(config, p["attn"], u, tables)
         if selects:
-            selection = indexer_fn(
-                *index_inputs(config, p["attn"]["indexer"], c_q, u, tables)
-            )
-            o = attention_fn(q, kv, k_pe, dtype, selection)
-            pairs = pairs + jnp.sum(
-                jnp.where(real[:, :, None], selection, 0), (1, 2), dtype=jnp.int32
-            )
+            with scope("dsa.index_inputs"):
+                operands = index_inputs(config, p["attn"]["indexer"], c_q, u, tables)
+            with scope("dsa.select"):
+                selection = indexer_fn(*operands)
+            with scope("mla.core"):
+                o = attention_fn(q, kv, k_pe, dtype, selection)
+            with scope("dsa.count"):
+                pairs = pairs + jnp.sum(
+                    jnp.where(real[:, :, None], selection, 0), (1, 2), dtype=jnp.int32
+                )
         else:
-            o = attention_fn(q, kv, k_pe, dtype)
-            pairs = pairs + causal_pairs(real)
-        x = x + _dense(o, p["attn"]["o"])
-        u = _rms(x, p["norm_ff"], eps)
-        if i < config.first_k_dense:
-            x = x + v2._swiglu(p["mlp"], u.astype(dtype))
-            continue
+            with scope("mla.core"):
+                o = attention_fn(q, kv, k_pe, dtype)
+            with scope("dsa.count"):
+                pairs = pairs + causal_pairs(real)
+        with scope("mla.out"):
+            x = x + _dense(o, p["attn"]["o"])
+        with scope("mlp"):
+            u = _rms(x, p["norm_ff"], eps)
+            if i < config.first_k_dense:
+                x = x + v2._swiglu(p["mlp"], u.astype(dtype))
+                continue
         routed, count, fits = v2._routed(config, p["moe"], u, real, experts_fn)
-        x = x + v2._swiglu(p["moe"]["shared"], u.astype(dtype)) + routed
+        with scope("mlp"):
+            x = x + v2._swiglu(p["moe"]["shared"], u.astype(dtype))
+        with scope("moe.routed"):
+            x = x + routed
         slots_held, sized = slots_held + count, sized + fits
-    out = v2._mean_real_state(_rms(x, params["final_norm"], eps), real)
+    with scope("pool"):
+        out = v2._mean_real_state(_rms(x, params["final_norm"], eps), real)
     return out, slots_held, sized, pairs
 
 
@@ -325,15 +342,16 @@ def deepseek_v32_model_function(
             config, p, ids, dtype=dtype, attention_fn=attention_fn,
             experts_fn=experts_fn, indexer_fn=indexer_fn,
         )
-        sized = jnp.broadcast_to(sized, slots_held.shape)
-        counts = jnp.stack(
-            [
-                slots_held, sized, config.expert_layers - sized,
-                pairs // _PAIRS_SPLIT * _PAIRS_SPLIT, pairs % _PAIRS_SPLIT,
-            ],
-            1,
-        )
-        return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
+        with scope("pool"):
+            sized = jnp.broadcast_to(sized, slots_held.shape)
+            counts = jnp.stack(
+                [
+                    slots_held, sized, config.expert_layers - sized,
+                    pairs // _PAIRS_SPLIT * _PAIRS_SPLIT, pairs % _PAIRS_SPLIT,
+                ],
+                1,
+            )
+            return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
 
     layers, top_k = config.num_layers, config.index_topk
 

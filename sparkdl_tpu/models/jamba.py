@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sparkdl_tpu.utils.profiler import scope
+
 
 @dataclass(frozen=True)
 class JambaConfig:
@@ -258,17 +260,23 @@ def _mamba(config: JambaConfig, p, u, scan_fn):
     them; what feeds a matrix product is rounded to ``u``'s dtype."""
     dtype, eps = u.dtype, config.rms_norm_eps
     n, r = config.d_state, config.dt_rank
-    h, z = jnp.split(_dense(u, p["in_proj"]), 2, -1)
-    h = _silu(_causal_conv(h, p["conv_w"], p["conv_b"]))
-    rbc = _dense(h.astype(dtype), p["x_proj"])
-    dt = jax.nn.softplus(
-        _dense(_rms(rbc[..., :r], p["dt_norm"], eps).astype(dtype), p["dt_proj"])
-        + p["dt_bias"]
-    )
-    b = _rms(rbc[..., r : r + n], p["b_norm"], eps)
-    c = _rms(rbc[..., r + n :], p["c_norm"], eps)
-    gated = scan_fn(h, dt, b, c, z, -jnp.exp(p["A_log"]), p["D"])
-    return _dense(gated.astype(dtype), p["out_proj"])
+    with scope("mamba.in_proj"):
+        h, z = jnp.split(_dense(u, p["in_proj"]), 2, -1)
+    with scope("mamba.conv"):
+        h = _silu(_causal_conv(h, p["conv_w"], p["conv_b"]))
+    with scope("mamba.ssm_inputs"):
+        rbc = _dense(h.astype(dtype), p["x_proj"])
+        dt = jax.nn.softplus(
+            _dense(_rms(rbc[..., :r], p["dt_norm"], eps).astype(dtype), p["dt_proj"])
+            + p["dt_bias"]
+        )
+        b = _rms(rbc[..., r : r + n], p["b_norm"], eps)
+        c = _rms(rbc[..., r + n :], p["c_norm"], eps)
+        a = -jnp.exp(p["A_log"])
+    with scope("mamba.scan"):
+        gated = scan_fn(h, dt, b, c, z, a, p["D"])
+    with scope("mamba.out_proj"):
+        return _dense(gated.astype(dtype), p["out_proj"])
 
 
 def _attention(config: JambaConfig, p, u, attention_fn):
@@ -279,26 +287,39 @@ def _attention(config: JambaConfig, p, u, attention_fn):
         t = _dense(u, w).astype(dtype)
         return t.reshape(rows, length, -1, config.head_dim).transpose(0, 2, 1, 3)
 
-    o = attention_fn(heads(p["q"]), heads(p["k"]), heads(p["v"]), None, dtype)
-    return _dense(o.transpose(0, 2, 1, 3).reshape(rows, length, -1), p["o"])
+    with scope("attn.qkv"):
+        q, k, v = heads(p["q"]), heads(p["k"]), heads(p["v"])
+    with scope("attn.core"):
+        o = attention_fn(q, k, v, None, dtype)
+    with scope("attn.out"):
+        return _dense(o.transpose(0, 2, 1, 3).reshape(rows, length, -1), p["o"])
 
 
 def forward(config: JambaConfig, params, ids, *, dtype, attention_fn, scan_fn):
     """ids [B, L] int32, zero-padded on the right -> [B, hidden] float32."""
     eps = config.rms_norm_eps
-    x = params["embed"][ids].astype(jnp.float32)
+    with scope("embed"):
+        x = params["embed"][ids].astype(jnp.float32)
     for i in range(config.num_layers):
         p = params["layers"][str(i)]
-        u = _rms(x, p["norm_in"], eps).astype(dtype)
-        if config.is_attention(i):
-            x = x + _attention(config, p["attn"], u, attention_fn)
+        attends = config.is_attention(i)
+        # a norm is in the scope of the part it feeds, a residual sum in
+        # that of the part it closes
+        with scope("attn.qkv" if attends else "mamba.in_proj"):
+            u = _rms(x, p["norm_in"], eps).astype(dtype)
+        if attends:
+            mixed = _attention(config, p["attn"], u, attention_fn)
         else:
-            x = x + _mamba(config, p["mamba"], u, scan_fn)
-        u = _rms(x, p["norm_ff"], eps).astype(dtype)
-        mlp = p["mlp"]
-        gate = _silu(_dense(u, mlp["gate"]))
-        x = x + _dense((gate * _dense(u, mlp["up"])).astype(dtype), mlp["down"])
-    return _rms(_last_real_state(x, ids), params["final_norm"], eps)
+            mixed = _mamba(config, p["mamba"], u, scan_fn)
+        with scope("attn.out" if attends else "mamba.out_proj"):
+            x = x + mixed
+        with scope("mlp"):
+            u = _rms(x, p["norm_ff"], eps).astype(dtype)
+            mlp = p["mlp"]
+            gate = _silu(_dense(u, mlp["gate"]))
+            x = x + _dense((gate * _dense(u, mlp["up"])).astype(dtype), mlp["down"])
+    with scope("pool"):
+        return _rms(_last_real_state(x, ids), params["final_norm"], eps)
 
 
 def jamba_model_function(

@@ -134,3 +134,23 @@ def annotate(name: str, **attrs):
         return jax.profiler.TraceAnnotation(name, **attrs)
     except Exception:
         return _NullAnnotation()
+
+
+#: prefix of every scope in the ``op_name`` of a device operation: the
+#: spans' own (``sparkdl_tpu.obs.spans.ANNOTATION_PREFIX``), so that one
+#: name reads the host's lines and the device's
+SCOPE_PREFIX = "sparkdl:"
+
+
+def scope(name: str):
+    """A part of a model's program, named on the device's timeline:
+    ``jax.named_scope("sparkdl:<name>")`` around the calls that make the
+    part while the program is traced to a jaxpr. The name is metadata of
+    the lowered operations (``op_name="jit(fn)/.../sparkdl:mlp/..."``),
+    which any ``jax.profiler`` capture shows on the device's lines: it
+    adds no operation, is in no jaxpr's text and in no compile-cache key,
+    and costs nothing when the program runs. The text families' names
+    are listed in docs/OBSERVABILITY.md."""
+    import jax
+
+    return jax.named_scope(SCOPE_PREFIX + name)
