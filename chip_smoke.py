@@ -156,6 +156,30 @@ def _check_close(what: str, got, want) -> float:
     return round(err, 5)
 
 
+def _check_latent(what: str, got, want, lengths, block: int) -> float:
+    """Latent attention that was handed its rows' ``lengths``: a row's
+    real positions against the reference's (`_check_close`), every
+    position of a query block of padding alone exactly zero, and nothing
+    non-finite anywhere."""
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    if not np.isfinite(got).all():
+        raise AssertionError(f"{what}: non-finite values")
+    err = 0.0
+    for row, length in enumerate(lengths):
+        dead = -(-length // block) * block
+        if got[row, dead:].any():
+            raise AssertionError(f"{what}: row {row} is not zero from {dead} on")
+        if length:
+            err = max(
+                err,
+                _check_close(f"{what} row {row}", got[row, :length], want[row, :length]),
+            )
+    return err
+
+
 def _device_marks() -> Optional[List[Dict[str, int]]]:
     """Per-device allocator counters, or None where the backend keeps
     none (the CPU). ``num_allocs`` only ever grows, so it moves on every
@@ -735,7 +759,8 @@ def _hybrid_kernels(sizes: Sizes, interpret: bool) -> dict:
 def _expert_kernels(sizes: Sizes, interpret: bool) -> dict:
     """The kernels of the latent-attention, routed-expert family
     (models/deepseek_v2.py) at its cell's widths: causal latent attention
-    over the projections' arrays against dense, and the grouped product
+    over the projections' arrays, handed its rows' lengths as the cells
+    hand them, against dense (`_check_latent`), and the grouped product
     of an expert layer's gate and down shapes against ``lax.ragged_dot``
     over the rows the groups cover; and which of each a model built here
     gets. A rehearsal keeps the head sizes and cuts the rest."""
@@ -752,10 +777,13 @@ def _expert_kernels(sizes: Sizes, interpret: bool) -> dict:
 
     rng = np.random.default_rng(5)
     errs = {}
-    rows, heads = 2, 2 if interpret else 16
+    rows, heads = 3, 2 if interpret else 16
     scale = 192**-0.5 * 1.589626
     for length in sizes.kernel_lengths:
         block = 128 if interpret else min(1024, length)  # the model's blocks
+        # as the cells call it: a whole row, one whose real tokens end
+        # inside its first query block, and a row that only fills a batch
+        lengths = [length, block - 3, 0]
         # the model's own entry: the projections' arrays, a head's
         # [nope | rope] query, [key | value] and the shared rotary key
         nope = 128
@@ -763,11 +791,11 @@ def _expert_kernels(sizes: Sizes, interpret: bool) -> dict:
         kv = jnp.asarray(rng.normal(size=(rows, length, heads * 2 * nope)), jnp.bfloat16)
         kr = jnp.asarray(rng.normal(size=(rows, length, nope)), jnp.bfloat16)
         got = jax.jit(
-            lambda q, kv, kr: flash_attention_latent(
-                q, kv, kr, num_heads=heads, scale=scale, block=block,
-                interpret=interpret,
+            lambda q, kv, kr, lengths: flash_attention_latent(
+                q, kv, kr, None, lengths, num_heads=heads, scale=scale,
+                block=block, interpret=interpret,
             )
-        )(q, kv, kr)
+        )(q, kv, kr, jnp.asarray(lengths, jnp.int32))
         with jax.default_matmul_precision("highest"):
             want = jax.jit(
                 lambda q, kv, kr: dense_latent_attention(
@@ -775,8 +803,8 @@ def _expert_kernels(sizes: Sizes, interpret: bool) -> dict:
                     num_heads=heads, scale=scale,
                 )
             )(q, kv, kr)
-        errs[f"latent_flash/L{length}"] = _check_close(
-            f"latent flash L{length}", got, want
+        errs[f"latent_flash/L{length}"] = _check_latent(
+            f"latent flash L{length}", got, want, lengths, block
         )
     # slots sorted by expert: 40 held experts, uneven, one empty, and a
     # tail of slots whose expert is elsewhere
@@ -808,7 +836,8 @@ def _sparse_attention_kernels(sizes: Sizes, interpret: bool) -> dict:
     (models/deepseek_v32.py, ops/dsa_indexer.py) at its widths: the index
     scores of 64 heads of 128 against their dense sum; the selection
     against ``lax.top_k`` over the masked rows, query by query; latent
-    attention over the selected keys against dense; and which of each a
+    attention over the selected keys of a row whose last query block is
+    padding alone, against dense; and which of each a
     model built here gets. A rehearsal keeps the head sizes and cuts the
     rest."""
     import jax
@@ -864,12 +893,14 @@ def _sparse_attention_kernels(sizes: Sizes, interpret: bool) -> dict:
         qa = jnp.asarray(rng.normal(size=(1, length, heads * 256)), jnp.bfloat16)
         kv = jnp.asarray(rng.normal(size=(1, length, heads * 256)), jnp.bfloat16)
         kr = jnp.asarray(rng.normal(size=(1, length, 128)), jnp.bfloat16)
+        # the last of several query blocks is padding alone
+        lengths = [max(length - block, block) - 5]
         got = jax.jit(
-            lambda qa, kv, kr, sel: flash_attention_latent(
-                qa, kv, kr, sel, num_heads=heads, scale=scale, block=block,
-                interpret=interpret,
+            lambda qa, kv, kr, sel, lengths: flash_attention_latent(
+                qa, kv, kr, sel, lengths, num_heads=heads, scale=scale,
+                block=block, interpret=interpret,
             )
-        )(qa, kv, kr, selection)
+        )(qa, kv, kr, selection, jnp.asarray(lengths, jnp.int32))
         with jax.default_matmul_precision("highest"):
             want = jax.jit(
                 lambda qa, kv, kr, sel: dense_latent_attention(
@@ -877,8 +908,8 @@ def _sparse_attention_kernels(sizes: Sizes, interpret: bool) -> dict:
                     sel, num_heads=heads, scale=scale,
                 )
             )(qa, kv, kr, selection)
-        errs[f"selecting_flash/L{length}"] = _check_close(
-            f"selecting flash L{length}", got, want
+        errs[f"selecting_flash/L{length}"] = _check_latent(
+            f"selecting flash L{length}", got, want, lengths, block
         )
     mf = get_model("deepseek-v3.2-exp-tiny").model_function(mode="embed")
     return {

@@ -47,7 +47,9 @@ every product accumulates in float32; the residual stream, norms,
 softmax, rotary angles, the router (operands too, ``highest``), the
 routing weights and the combine are float32. Attention is
 ``ops/flash_attention.py:flash_attention_latent`` (causal, over the
-projections' own arrays) and the routed experts ``ops/grouped_matmul.py``: the Pallas
+projections' own arrays; handed each row's length where it takes them,
+``row_lengths``, so that query blocks of padding alone are not run) and
+the routed experts ``ops/grouped_matmul.py``: the Pallas
 kernels on TPU, plain ``jax.numpy`` elsewhere, chosen at build time and
 reported as ``mf.attention`` and ``mf.experts``. Layers are unrolled
 into one program with every layer's weights an argument of its own
@@ -346,14 +348,35 @@ def _mla_inputs(config: DeepseekV2Config, p, u, tables):
         return c_q, q, kv, jnp.concatenate([k_pe, k_pe], -1)
 
 
-def _mla(config: DeepseekV2Config, p, u, tables, attention_fn):
+def _last_real_position(real, xp):
+    """real [B, L] bool -> [B] int32, a row's last real position + 1 (0
+    for a row of padding): its length. Not the count of its real tokens,
+    so that an id 0 inside a text could never cut a row short. ``xp``:
+    ``jax.numpy`` in the program, ``numpy`` where the host counts."""
+    position = xp.arange(1, real.shape[1] + 1, dtype=xp.int32)
+    return xp.max(xp.where(real, position, 0), 1)
+
+
+def row_lengths(attention_fn, real):
+    """What an attention that takes its rows' lengths is handed besides
+    its operands, as keywords: real [B, L] bool -> ``{"lengths": [B]
+    int32}`` (:func:`_last_real_position`). Nothing for any other
+    attention: what stands in for one in a test or a planted fault keeps
+    its arguments."""
+    if not getattr(attention_fn, "takes_lengths", False):
+        return {}
+    return {"lengths": _last_real_position(real, jnp)}
+
+
+def _mla(config: DeepseekV2Config, p, u, tables, attention_fn, by_length):
     """u [B, L, hidden] in the compute dtype -> [B, L, hidden] float32:
-    dense causal latent attention. A family that selects each query's
-    keys (``models/deepseek_v32.py``) takes :func:`_mla_inputs` and hands
-    its selection to the kernel itself."""
+    dense causal latent attention (``by_length``: :func:`row_lengths`).
+    A family that selects each query's keys (``models/deepseek_v32.py``)
+    takes :func:`_mla_inputs` and hands its selection to the kernel
+    itself."""
     _, q, kv, k_pe = _mla_inputs(config, p, u, tables)
     with scope("mla.core"):
-        o = attention_fn(q, kv, k_pe, u.dtype)
+        o = attention_fn(q, kv, k_pe, u.dtype, **by_length)
     with scope("mla.out"):
         return _dense(o, p["o"])
 
@@ -581,6 +604,7 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
         real = ids != 0
         tables = rope_tables(config, ids.shape[1])
         x = params["embed"][ids].astype(jnp.float32)
+        by_length = row_lengths(attention_fn, real)
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
     for i in range(config.num_layers):
@@ -589,7 +613,7 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
         # sum in that of the part it closes
         with scope("mla.q"):
             u = _rms(x, p["norm_in"], eps).astype(dtype)
-        attended = _mla(config, p["attn"], u, tables, attention_fn)
+        attended = _mla(config, p["attn"], u, tables, attention_fn, by_length)
         with scope("mla.out"):
             x = x + attended
         with scope("mlp"):
@@ -608,18 +632,35 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
     return out, slots_held, sized
 
 
-def attention_batch_counters(attention_fn, layers: int, ids) -> dict:
+def attention_batch_counters(attention_fn, layers: int, ids, real) -> dict:
     """What ``mf.batch_counters`` counts of attention for a dispatched
-    batch: ``mla.pairs_computed``, rows x layers x the (query, key) pairs
-    a head's attention runs at the bucket's edge, where the attention it
-    was built with says (``.pairs_computed``, as
-    ``make_latent_attention_fn``'s do): how much of the square was run,
-    beside ``mla.attention_tokens``."""
+    batch ``ids`` [B, L] whose real tokens are ``real``, where the
+    attention it was built with says what it runs
+    (``.pairs_computed``, ``.query_blocks``, ``.takes_lengths``, as
+    ``make_latent_attention_fn``'s do):
+
+    - ``mla.pairs_computed``: layers x the (query, key) pairs a head's
+      attention runs, summed over the rows: how much of the square was
+      run, beside ``mla.attention_tokens``;
+    - ``mla.query_blocks``: rows x layers x the bucket's query blocks;
+    - ``mla.query_blocks_run``: those of them that hold a real token.
+
+    A row counts at its length (its last real position + 1, as
+    ``row_lengths`` hands it over) where the attention takes lengths, and
+    at the bucket's edge where it takes none: every block runs."""
     pairs = getattr(attention_fn, "pairs_computed", None)
     if pairs is None:
         return {}
     rows, edge = ids.shape
-    return {"mla.pairs_computed": rows * layers * pairs(edge)}
+    lengths = [edge] * rows
+    if getattr(attention_fn, "takes_lengths", False):
+        lengths = _last_real_position(real, np).tolist()
+    counters = {"mla.pairs_computed": layers * sum(pairs(n) for n in lengths)}
+    blocks = getattr(attention_fn, "query_blocks", None)
+    if blocks is not None:
+        counters["mla.query_blocks"] = rows * layers * blocks(edge)
+        counters["mla.query_blocks_run"] = layers * sum(blocks(n) for n in lengths)
+    return counters
 
 
 def deepseek_v2_model_function(
@@ -646,8 +687,9 @@ def deepseek_v2_model_function(
     layers). ``TextEmbedder`` strips the columns and adds them to
     counters ``moe.slots_held``, ``moe.buffer_sized`` and
     ``moe.buffer_full``; any other caller slices them off. On the host,
-    ``mf.batch_counters(ids, real)`` counts ``mla.pairs_computed`` for
-    every dispatched batch (:func:`attention_batch_counters`)."""
+    ``mf.batch_counters(ids, real)`` counts ``mla.pairs_computed``,
+    ``mla.query_blocks`` and ``mla.query_blocks_run`` for every
+    dispatched batch (:func:`attention_batch_counters`)."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
@@ -696,7 +738,7 @@ def deepseek_v2_model_function(
     mf.real_token_counters = {
         "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
     }
-    mf.batch_counters = lambda ids, real: attention_batch_counters(
-        attention_fn, config.num_layers, ids
+    mf.batch_counters = functools.partial(
+        attention_batch_counters, attention_fn, config.num_layers
     )
     return mf

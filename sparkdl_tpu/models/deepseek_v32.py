@@ -237,6 +237,7 @@ def forward(
         real = ids != 0
         tables = v2.rope_tables(config, ids.shape[1])
         x = params["embed"][ids].astype(jnp.float32)
+        by_length = v2.row_lengths(attention_fn, real)
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     pairs = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
@@ -254,14 +255,14 @@ def forward(
             with scope("dsa.select"):
                 selection = indexer_fn(*operands)
             with scope("mla.core"):
-                o = attention_fn(q, kv, k_pe, dtype, selection)
+                o = attention_fn(q, kv, k_pe, dtype, selection, **by_length)
             with scope("dsa.count"):
                 pairs = pairs + jnp.sum(
                     jnp.where(real[:, :, None], selection, 0), (1, 2), dtype=jnp.int32
                 )
         else:
             with scope("mla.core"):
-                o = attention_fn(q, kv, k_pe, dtype)
+                o = attention_fn(q, kv, k_pe, dtype, **by_length)
             with scope("dsa.count"):
                 pairs = pairs + causal_pairs(real)
         with scope("mla.out"):
@@ -311,8 +312,10 @@ def deepseek_v32_model_function(
     batch ``dsa.index_tokens`` (rows x bucket edge x layers, where the
     bucket runs the indexer), ``dsa.pairs_causal`` (what dense causal
     attention would have read, from the real lengths) and
-    ``mla.pairs_computed`` (what the attention it was built with runs at
-    the bucket's edge, ``deepseek_v2.attention_batch_counters``)."""
+    ``mla.pairs_computed``, ``mla.query_blocks`` and
+    ``mla.query_blocks_run`` (what the attention it was built with runs,
+    at each row's length where it takes lengths:
+    ``deepseek_v2.attention_batch_counters``)."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.dsa_indexer import make_indexer_fn
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
@@ -360,7 +363,7 @@ def deepseek_v32_model_function(
         return {
             "dsa.index_tokens": int(ids.size) * layers * (ids.shape[1] > top_k),
             "dsa.pairs_causal": int((n * (n + 1) // 2).sum()) * layers,
-            **v2.attention_batch_counters(attention_fn, layers, ids),
+            **v2.attention_batch_counters(attention_fn, layers, ids, real),
         }
 
     mf = ModelFunction(

@@ -502,7 +502,7 @@ def latent_pairs_computed(length: int, block: int, tile: int) -> int:
     return n * (n - 1) // 2 * block * block + n * tile * tile * sub * (sub + 1) // 2
 
 
-def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs):
+def _latent_kernel(nk, scale, nope, selected, heads, *refs):
     """The blocked causal kernel for latent attention, one (row, head) a
     grid step: the query block is [bq, nope + rope], the head's own keys
     [bk, nope] and the token's shared rotary key [bk, rope]; a score is
@@ -513,6 +513,15 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
     diagonal): it takes the causal mask's place, before the running
     maximum.
 
+    ``heads``: None, or the head count of a call that knows its rows'
+    lengths: the first ref is then the prefetched [B] int32 of how many
+    leading query blocks of each row are *live*, hold a real token (a
+    grid step's row is its first index over ``heads``). The others are
+    *dead*: padding alone, which no real token reads. A dead block runs
+    nothing at any key step and, at its last, writes zeros itself. A live
+    block, the one that holds the row's last real token too, runs whole,
+    as without lengths.
+
     A fetched block is walked in key sub-tiles of ``_key_tile(bk)``
     columns. Under the diagonal every sub-tile meets every query row, and
     without a selection nothing is masked there. On the diagonal sub-tile
@@ -522,6 +531,9 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
     sub-tile runs."""
     from jax.experimental import pallas as pl
 
+    if heads:
+        live_ref, *refs = refs
+    q_ref, k_ref, v_ref, kr_ref, *refs = refs
     if selected:
         sel_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -529,8 +541,12 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
     qi, ki = pl.program_id(1), pl.program_id(2)
     bq = q_ref.shape[1]
     tile = _key_tile(bq)
+    live = qi < live_ref[jax.lax.div(pl.program_id(0), heads)] if heads else None
 
-    @pl.when(ki == 0)
+    def when(step):
+        return pl.when(step if live is None else jnp.logical_and(step, live))
+
+    @when(ki == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -561,19 +577,25 @@ def _latent_kernel(nk, scale, nope, selected, q_ref, k_ref, v_ref, kr_ref, *refs
             s, v_ref[0, cols].astype(jnp.float32), m_ref, l_ref, acc_ref, rows
         )
 
-    @pl.when(ki < qi)
+    @when(ki < qi)
     def _under_the_diagonal():
         for j in range(bq // tile):
             _sub_tile(j, slice(None), False)
 
-    @pl.when(ki == qi)
+    @when(ki == qi)
     def _diagonal():
         for j in range(bq // tile):
             _sub_tile(j, slice(j * tile, bq), True)
 
-    @pl.when(ki == nk - 1)
+    @when(ki == nk - 1)
     def _finalize():
         _write_result(o_ref, l_ref, acc_ref)
+
+    if heads:
+
+        @pl.when(jnp.logical_and(ki == nk - 1, jnp.logical_not(live)))
+        def _dead():
+            o_ref[...] = jnp.zeros_like(o_ref)
 
 
 def flash_attention_latent(
@@ -581,6 +603,7 @@ def flash_attention_latent(
     kv,
     k_rope,
     selection=None,
+    lengths=None,
     *,
     num_heads: int,
     scale: float,
@@ -604,6 +627,20 @@ def flash_attention_latent(
             by a learned selection, ``ops/dsa_indexer.py``): one
             selection for all heads, a byte a pair, read a block a step
             in the causal mask's place. Every query selects a key.
+        lengths: None, or [B] int32: how many leading positions of each
+            row hold its real tokens, the rest right padding (0 for a
+            row of padding). A real query's answer needs no length: right
+            padding needs no mask under a causal one. What a length saves
+            is the padding's own queries: a query block that starts at or
+            past its row's length is neither fetched nor run, and its
+            positions of the result are zeros. None: every block runs,
+            through the call without the operand (no prefetch, index maps
+            that read nothing): what a caller whose rows are all whole
+            wants, since the maps' reads of the live count cost a
+            full-length call 2-3% at the cells' shapes and 12% at
+            8 x 1,024 (PERF.md, PR 37). Both DeepSeek models always hand
+            lengths; None is the yardstick the kernel with lengths is
+            held to bit for bit, in the tests and timed alone.
         On the TPU Dn, Dr and Dv are multiples of the 128 lanes (a
         block's last dim); the interpreter takes any.
 
@@ -619,7 +656,16 @@ def flash_attention_latent(
     j * tile on, (n + 1) / 2n of its pairs with n sub-tiles, and masks
     the tile x tile squares the diagonal crosses alone (with a selection,
     its bytes wherever a sub-tile runs): a pair left out is one whose
-    probability is zero. :func:`latent_pairs_computed` counts the pairs."""
+    probability is zero. :func:`latent_pairs_computed` counts the pairs.
+
+    With ``lengths`` each row's count of live query blocks (those that
+    start before its length) is a scalar-prefetch operand, which the
+    index maps read too: every step of a dead query block names the
+    blocks the row's last live one left resident (block 0 for a row of
+    padding), so nothing is copied for it, and it costs what a step above
+    the diagonal costs. A live block, the one that holds the row's last
+    real token too, runs the steps it runs without lengths, in their
+    order: a real query's answer is the same to the bit."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -640,6 +686,10 @@ def flash_attention_latent(
         raise ValueError(
             f"a selection for q {q.shape} is [B, L, L], got {selection.shape}"
         )
+    if lengths is not None and (lengths.shape != (B,) or lengths.dtype != jnp.int32):
+        raise ValueError(
+            f"lengths for q {q.shape} is [B] int32, got {lengths.shape} {lengths.dtype}"
+        )
     pad = _pad_len(L, block)
     if pad:
         q, kv, k_rope = (
@@ -647,20 +697,36 @@ def flash_attention_latent(
         )
     n = (L + pad) // block
 
-    def keys(bh, qi, ki):  # a block above the diagonal is not fetched
-        return bh // H, jnp.minimum(ki, qi)
+    def last_live(bh, live):
+        """The last live query block of the step's row; 0 for a row of
+        padding. ``live``: each row's count of live query blocks, the
+        index maps' last argument where there are lengths."""
+        return jnp.maximum(live[bh // H], 1) - 1
+
+    def queries(bh, qi, *live):  # a dead block is not fetched
+        return jnp.minimum(qi, last_live(bh, *live)) if live else qi
+
+    def keys(bh, qi, ki, *live):
+        diagonal = jnp.minimum(ki, qi)  # a block above it is not fetched
+        if not live:
+            return diagonal
+        last = last_live(bh, *live)
+        return jnp.where(qi > last, last, diagonal)
 
     in_specs = [
         pl.BlockSpec(
-            (1, block, nope + rope), lambda bh, qi, ki: (bh // H, qi, bh % H)
+            (1, block, nope + rope),
+            lambda bh, qi, ki, *live: (bh // H, queries(bh, qi, *live), bh % H),
         ),
         pl.BlockSpec(
-            (1, block, nope), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H))
+            (1, block, nope),
+            lambda bh, *step: (bh // H, keys(bh, *step), 2 * (bh % H)),
         ),
         pl.BlockSpec(
-            (1, block, dv), lambda bh, qi, ki: (*keys(bh, qi, ki), 2 * (bh % H) + 1)
+            (1, block, dv),
+            lambda bh, *step: (bh // H, keys(bh, *step), 2 * (bh % H) + 1),
         ),
-        pl.BlockSpec((1, block, rope), lambda bh, qi, ki: (*keys(bh, qi, ki), 0)),
+        pl.BlockSpec((1, block, rope), lambda bh, *step: (bh // H, keys(bh, *step), 0)),
     ]
     operands = [q, kv, kv, k_rope]
     if selection is not None:
@@ -670,30 +736,45 @@ def flash_attention_latent(
             selection = selection.at[:, L:, 0].set(1)
         in_specs.append(
             pl.BlockSpec(
-                (1, block, block), lambda bh, qi, ki: (bh // H, qi, jnp.minimum(ki, qi))
+                (1, block, block),
+                lambda bh, qi, ki, *live: (
+                    bh // H, queries(bh, qi, *live), keys(bh, qi, ki, *live)
+                ),
             )
         )
         operands.append(selection)
 
-    out = pl.pallas_call(
-        functools.partial(_latent_kernel, n, scale, nope, selection is not None),
+    grid = dict(
         grid=(B * H, n, n),
         in_specs=in_specs,
+        # the result's map alone keeps a dead block's own index: its zeros
         out_specs=pl.BlockSpec(
-            (1, block, dv), lambda bh, qi, ki: (bh // H, qi, bh % H)
+            (1, block, dv), lambda bh, qi, ki, *live: (bh // H, qi, bh % H)
         ),
-        out_shape=jax.ShapeDtypeStruct((B, L + pad, H * dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block, 128), jnp.float32),  # running max
             pltpu.VMEM((block, 128), jnp.float32),  # running sum
             pltpu.VMEM((block, dv), jnp.float32),  # output accumulator
         ],
+    )
+    if lengths is not None:
+        grid = dict(
+            grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **grid)
+        )
+        operands.insert(0, (lengths + (block - 1)) // block)
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_kernel, n, scale, nope, selection is not None,
+            H if lengths is not None else None,
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, L + pad, H * dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
         # the blocked kernel's name: one kernel to the trace's readers
         name="flash_attention",
+        **grid,
     )(*operands)
     return out[:, :L]
 
@@ -735,7 +816,14 @@ def make_latent_attention_fn(
     :func:`dense_latent_attention` elsewhere. ``.kind`` ('flash' |
     'dense') says which, and ``.pairs_computed(length)`` how many (query,
     key) pairs it runs for a head of one row of that length: the square
-    for the dense one, :func:`latent_pairs_computed` for the kernel."""
+    for the dense one, :func:`latent_pairs_computed` for the kernel.
+
+    The kernel's also takes ``lengths=None`` ([B] int32, each row's
+    leading real positions) and runs no query block of padding alone:
+    its ``.takes_lengths`` is True, and ``.query_blocks(length)`` is how
+    many query blocks a row of that length has. The dense one has neither
+    attribute: a caller hands lengths only to an attention that says it
+    takes them."""
     if not interpret and jax.default_backend() != "tpu":
         dense = functools.partial(
             dense_latent_attention, num_heads=num_heads, scale=scale
@@ -744,14 +832,16 @@ def make_latent_attention_fn(
         dense.pairs_computed = lambda length: length * length
         return dense
 
-    def attention(q, kv, k_rope, dtype, selection=None):
+    def attention(q, kv, k_rope, dtype, selection=None, lengths=None):
         out = flash_attention_latent(
-            q, kv, k_rope, selection, num_heads=num_heads, scale=scale,
+            q, kv, k_rope, selection, lengths, num_heads=num_heads, scale=scale,
             block=block, interpret=interpret,
         )
         return out.astype(dtype)
 
     attention.kind = "flash"
+    attention.takes_lengths = True
+    attention.query_blocks = lambda length: -(-length // block)
     attention.pairs_computed = lambda length: latent_pairs_computed(
         length, block, _key_tile(block)
     )

@@ -234,28 +234,33 @@ def test_the_indexers_two_kernels_compile_at_the_published_widths(shape, length)
     assert memory.temp_size_in_bytes < length * length * 4 + (64 << 20)
 
 
+@pytest.mark.parametrize("lengths", [False, True], ids=["whole", "lengths"])
 @pytest.mark.parametrize(
     "rows, length, selected",
     [(1, 8192, True), (1, 16384, True), (8, 2048, False), (8, 1024, False)],
 )
-def test_latent_flash_compiles_at_the_cells_shapes(shape, rows, length, selected):
+def test_latent_flash_compiles_at_the_cells_shapes(shape, rows, length, selected, lengths):
     """128 heads of 128 + 2 x 64 query lanes over the up-projected latent,
     blocks of 1,024 walked in key sub-tiles: a dispatch of
     `deepseek-v3.2-exp-embed-long-docs` (one row, the selection a fifth
     operand read a [1024, 1024] int8 block a step) and of
-    `deepseek-v2-embed-windows` (eight rows, no selection). Nothing is
-    padded, repeated or converted in HBM."""
+    `deepseek-v2-embed-windows` (eight rows, no selection), without and
+    with the rows' lengths (a scalar-prefetch operand of `rows` int32).
+    Nothing is padded, repeated or converted in HBM."""
     from sparkdl_tpu.ops.flash_attention import flash_attention_latent
 
     bf16 = jnp.bfloat16
     wide = shape((rows, length, 128 * 256), bf16)
-    operands = [wide, wide, shape((rows, length, 128), bf16)]
-    if selected:
-        operands.append(shape((rows, length, length), jnp.int8))
+    operands = [
+        wide, wide, shape((rows, length, 128), bf16),
+        shape((rows, length, length), jnp.int8) if selected else None,
+        shape((rows,), jnp.int32) if lengths else None,
+    ]
     compiled = (
         jax.jit(
-            lambda q, kv, k_rope, selection=None: flash_attention_latent(
-                q, kv, k_rope, selection, num_heads=128, scale=0.1353, block=1024
+            lambda q, kv, k_rope, selection, lengths: flash_attention_latent(
+                q, kv, k_rope, selection, lengths, num_heads=128, scale=0.1353,
+                block=1024,
             )
         )
         .lower(*operands)
@@ -264,4 +269,9 @@ def test_latent_flash_compiles_at_the_cells_shapes(shape, rows, length, selected
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "%flash_attention" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < (1 << 20)
+    # the operands as they came and the [rows, length, 128 * 128] result
+    given = 2 * wide.size * 2 + rows * length * 128 * 2 + selected * rows * length * length
+    assert memory.argument_size_in_bytes - given in ((0, 512)[lengths],)
+    assert memory.output_size_in_bytes == rows * length * 128 * 128 * 2

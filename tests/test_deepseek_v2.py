@@ -65,20 +65,20 @@ def _counters():
     return dict(metrics.scalar_snapshot()["counters"])
 
 
-def _pairs_computed(delta, edges, interpret, layers=3):
-    """What `mla.pairs_computed` should have counted, by hand from the
-    rows and tokens dispatched at the two bucket edges: the dense
-    fallback runs the square, the kernel (blocks of 64, one sub-tile
-    each) the blocks at and under the diagonal."""
+def _pairs_computed(delta, edges, interpret, lengths, layers=3):
+    """What `mla.pairs_computed` should have counted, by hand: the dense
+    fallback runs the square at the bucket's edge for every dispatched
+    row (from the rows and tokens dispatched at the two edges), the
+    kernel (blocks of 64, one sub-tile each; it takes lengths) the blocks
+    at and under the diagonal of each live row's own query blocks, and
+    nothing for a row that only fills a batch."""
+    if interpret:
+        blocks = [-(-n // 64) for n in lengths]
+        return layers * sum(n * (n + 1) // 2 * 64 * 64 for n in blocks)
     low, high = edges
     rows = delta["feeder.rows"] + delta.get("feeder.pad_rows", 0)
     at_high = (delta["mla.attention_tokens"] // layers - low * rows) // (high - low)
-
-    def pairs(edge):
-        n = edge // 64
-        return n * (n + 1) // 2 * 64 * 64 if interpret else edge * edge
-
-    return layers * ((rows - at_high) * pairs(low) + at_high * pairs(high))
+    return layers * ((rows - at_high) * low * low + at_high * high * high)
 
 
 def _embed(path, inputs, dtype, interpret, batch=4, max_length=256, edit=None):
@@ -162,7 +162,17 @@ def test_embedder_matches_the_reference_row_by_row(
     # that spreads evenly leaves every one of them on the sized buffer
     assert delta["moe.buffer_sized"] == 12 * 2
     assert delta.get("moe.buffer_full", 0) == 0
-    assert delta["mla.pairs_computed"] == _pairs_computed(delta, (128, 256), interpret)
+    lengths = [len(reference.tokenize(t, 512, 256)) for t in corpus]
+    assert delta["mla.pairs_computed"] == _pairs_computed(
+        delta, (128, 256), interpret, lengths
+    )
+    if interpret:  # the kernel says its blocks: 2 and 4 of 64 a row of each bucket
+        rows = delta["feeder.rows"] + delta.get("feeder.pad_rows", 0)
+        assert delta["mla.query_blocks"] == delta["mla.attention_tokens"] // 64
+        assert delta["mla.query_blocks_run"] == 3 * sum(-(-n // 64) for n in lengths)
+        assert delta["mla.query_blocks_run"] < delta["mla.query_blocks"] <= 3 * rows * 4
+    else:
+        assert not delta.get("mla.query_blocks") and not delta.get("mla.query_blocks_run")
 
 
 def test_a_row_does_not_change_with_what_pads_it(monkeypatch, tiny, corpus):
@@ -636,3 +646,157 @@ def test_the_programs_jaxpr_is_the_parents(dtype, shape):
     text = str(jax.make_jaxpr(mf.fn)(mf.params, jnp.zeros(shape, jnp.int32)))
     assert "scan[" not in text and "i8[" not in text and "bitcast_convert_type" not in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPR[dtype, shape]
+
+
+# -- the attention is handed its rows' lengths -----------------------------------
+
+
+def _without_lengths(kernel):
+    """The same kernel with `takes_lengths` taken away: what the parent
+    commit built."""
+
+    def blind(q, kv, k_rope, dtype, selection=None):
+        return kernel(q, kv, k_rope, dtype, selection)
+
+    blind.kind, blind.pairs_computed = kernel.kind, kernel.pairs_computed
+    blind.query_blocks = kernel.query_blocks
+    return blind
+
+
+def _uneven_batch(length=256):
+    """A row of zeros, and rows of 10, 65, 130 and `length` tokens (those
+    the bucket holds)."""
+    lengths = [n for n in (0, 10, 65, 130) if n < length] + [length]
+    ids = np.zeros((len(lengths), length), np.int32)
+    for row, n in enumerate(lengths):
+        ids[row, :n] = np.random.default_rng(row).integers(1, 512, n)
+    return jnp.asarray(ids)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_lengths_change_no_embedding_to_the_bit(tiny, dtype):
+    """The tiny preset built with the interpreted kernel: with the rows'
+    lengths handed over (the query blocks of padding alone not run, zeros
+    in their place) every row's embedding and counters are, to the bit,
+    those of the same kernel without `takes_lengths`."""
+    _, _, path = tiny
+    preset = program.deepseek_v2_tiny()
+    kernel = make_latent_attention_fn(
+        preset.num_heads, preset.softmax_scale, block=64, interpret=True
+    )
+    assert kernel.takes_lengths
+
+    def built(attention_fn):
+        return program.deepseek_v2_model_function(
+            "deepseek-v2-tiny", dtype=dtype, weights_file=path,
+            attention_fn=attention_fn, experts_fn=make_grouped_matmul_fn(),
+        )
+
+    ids = _uneven_batch()
+    given, blind = built(kernel), built(_without_lengths(kernel))
+    assert "i32[5]" in str(jax.make_jaxpr(given.fn)(given.params, ids)).split("pallas_call")[1]
+    got = np.asarray(given.fn(given.params, ids))
+    want = np.asarray(blind.fn(blind.params, ids))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+    assert not got[0, :64].any() and np.abs(got[1:, :64]).min(1).max() > 0
+
+
+def test_a_row_is_as_long_as_its_last_real_position():
+    """Not the count of its real tokens: an id 0 inside a text could
+    never cut a row short. Nothing is computed for an attention that
+    does not say it takes lengths."""
+    real = jnp.asarray(
+        [[1, 1, 0, 1, 0, 0], [0, 0, 0, 0, 0, 0], [1, 1, 1, 1, 1, 1], [0, 0, 1, 0, 0, 0]], bool
+    )
+
+    def takes(*a, **k):
+        raise AssertionError("not called")
+
+    assert program.row_lengths(takes, real) == {}
+    takes.takes_lengths = True
+    (name, lengths), = program.row_lengths(takes, real).items()
+    assert name == "lengths" and lengths.dtype == jnp.int32
+    assert lengths.tolist() == [4, 0, 6, 3]
+
+
+def test_an_attention_of_four_arguments_still_builds_and_runs(tiny):
+    """What stands in for `attention_fn` in a test or a planted fault
+    takes (q, kv, k_rope, dtype) and no more."""
+    from sparkdl_tpu.ops.flash_attention import dense_latent_attention
+
+    _, _, path = tiny
+    preset = program.deepseek_v2_tiny()
+    calls = []
+
+    def four(q, kv, k_rope, dtype):
+        calls.append(q.shape)
+        return dense_latent_attention(
+            q, kv, k_rope, dtype, num_heads=preset.num_heads, scale=preset.softmax_scale
+        )
+
+    mf = program.deepseek_v2_model_function(
+        "deepseek-v2-tiny", weights_file=path, attention_fn=four,
+        experts_fn=make_grouped_matmul_fn(),
+    )
+    plain = program.deepseek_v2_model_function("deepseek-v2-tiny", weights_file=path)
+    ids = _uneven_batch(128)
+    got = np.asarray(mf.fn(mf.params, ids))
+    assert len(calls) == 3 and mf.attention == "custom"
+    np.testing.assert_array_equal(got, np.asarray(plain.fn(plain.params, ids)))
+    # it says nothing of what it runs: no attention counter
+    assert mf.batch_counters(np.asarray(ids), np.asarray(ids) != 0) == {}
+
+
+#: the ten live rows of `deepseek-v3.2-exp-embed-long-docs` (tokens =
+#: words + 2), by bucket: 87 of their 120 query blocks of 1,024 hold a
+#: real token
+CLAIMED_CELL = {
+    8192: (2690, 3797, 4343, 5361, 6453),
+    16384: (8408, 10121, 12494, 14172, 16384),
+}
+
+
+@pytest.mark.parametrize("takes_lengths", [True, False])
+def test_attention_counters_of_the_claimed_cells_job_by_hand(takes_lengths):
+    """A job's ten dispatches of one row, 5 layers, blocks of 1,024 in
+    sub-tiles of 256: a block under the diagonal counts 1 and a diagonal
+    block 0.625 of 1,024 x 1,024 pairs."""
+    kernel = make_latent_attention_fn(128, 0.1353, block=1024, interpret=True)
+    if not takes_lengths:
+        kernel = _without_lengths(kernel)
+    total = {}
+    for edge, lengths in CLAIMED_CELL.items():
+        for n in lengths:
+            ids = np.zeros((1, edge), np.int32)
+            ids[0, :n] = 7
+            ids[0, n // 2] = 0  # an id 0 inside the text
+            counted = program.attention_batch_counters(kernel, 5, ids, ids != 0)
+            for name, count in counted.items():
+                total[name] = total.get(name, 0) + count
+    steps = {True: 70.625 + 408.75, False: 5 * 33 + 5 * 130}[takes_lengths]
+    assert total == {
+        "mla.pairs_computed": 5 * int(steps * 1024 * 1024),
+        "mla.query_blocks": 5 * 120,
+        "mla.query_blocks_run": 5 * (87 if takes_lengths else 120),
+    }
+
+
+def test_attention_counters_of_a_batch_with_rows_of_padding():
+    """`deepseek-v2-embed-windows`' kind of dispatch: 8 rows of 2,048 of
+    which 3 only fill the batch, and a live row never has a query block
+    of padding alone."""
+    kernel = make_latent_attention_fn(128, 0.1147, block=1024, interpret=True)
+    ids = np.zeros((8, 2048), np.int32)
+    for row, n in enumerate((2048, 1025, 1500, 2048, 1026)):
+        ids[row, :n] = 3
+    counted = program.attention_batch_counters(kernel, 5, ids, ids != 0)
+    assert counted == {
+        "mla.pairs_computed": 5 * 5 * int(2.25 * 1024 * 1024),
+        "mla.query_blocks": 5 * 8 * 2,
+        "mla.query_blocks_run": 5 * 5 * 2,
+    }
+    dense = make_latent_attention_fn(128, 0.1147)
+    assert program.attention_batch_counters(dense, 5, ids, ids != 0) == {
+        "mla.pairs_computed": 5 * 8 * 2048 * 2048
+    }

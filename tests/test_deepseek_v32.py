@@ -65,20 +65,18 @@ def _counters():
     return dict(metrics.scalar_snapshot()["counters"])
 
 
-def _pairs_computed(delta, edges, interpret, layers=3):
+def _pairs_computed(delta, edges, interpret, lengths, layers=3):
     """`mla.pairs_computed` by hand, as `test_deepseek_v2.py` reckons it:
-    from the rows and tokens dispatched at the two bucket edges, the
-    square for the dense fallback and the blocks of 64 at and under the
-    diagonal for the kernel."""
+    the square at the bucket's edge for every dispatched row for the
+    dense fallback; for the kernel, which takes lengths, the blocks of 64
+    at and under the diagonal of each live row's own query blocks."""
+    if interpret:
+        blocks = [-(-n // 64) for n in lengths]
+        return layers * sum(n * (n + 1) // 2 * 64 * 64 for n in blocks)
     low, high = edges
     rows = delta["feeder.rows"] + delta.get("feeder.pad_rows", 0)
     at_high = (delta["mla.attention_tokens"] // layers - low * rows) // (high - low)
-
-    def pairs(edge):
-        n = edge // 64
-        return n * (n + 1) // 2 * 64 * 64 if interpret else edge * edge
-
-    return layers * ((rows - at_high) * pairs(low) + at_high * pairs(high))
+    return layers * ((rows - at_high) * low * low + at_high * high * high)
 
 
 def _built(path, dtype, interpret):
@@ -189,7 +187,14 @@ def test_embedder_matches_the_reference_row_by_row(
         min(16, t + 1) for n in lengths for t in range(n)
     )
     assert delta["dsa.pairs_selected"] < 0.25 * delta["dsa.pairs_causal"]
-    assert delta["mla.pairs_computed"] == _pairs_computed(delta, (64, 256), interpret)
+    assert delta["mla.pairs_computed"] == _pairs_computed(
+        delta, (64, 256), interpret, lengths
+    )
+    if interpret:
+        assert delta["mla.query_blocks"] == delta["mla.attention_tokens"] // 64
+        assert delta["mla.query_blocks_run"] == 3 * sum(-(-n // 64) for n in lengths)
+    else:
+        assert not delta.get("mla.query_blocks") and not delta.get("mla.query_blocks_run")
 
 
 def test_a_large_count_of_pairs_rides_back_exactly():
@@ -502,3 +507,97 @@ def test_the_published_preset_works_its_worst_case_in_passes():
     assert "scan[" in text and "length=8" in text
     assert "f32[131072,7168]" not in text and "f32[16384,7168]" in text
     assert "bf16[5120,7168]" in text  # the sized arm's slot buffer
+
+
+# -- the attention is handed its rows' lengths -----------------------------------
+
+
+def _without_lengths(kernel):
+    """The same kernel with `takes_lengths` taken away: what the parent
+    commit built."""
+
+    def blind(q, kv, k_rope, dtype, selection=None):
+        return kernel(q, kv, k_rope, dtype, selection)
+
+    blind.kind, blind.pairs_computed = kernel.kind, kernel.pairs_computed
+    blind.query_blocks = kernel.query_blocks
+    return blind
+
+
+def _uneven_batch(length):
+    """A row of zeros, and rows of 10, 65, 130 and `length` tokens (those
+    the bucket holds)."""
+    lengths = [n for n in (0, 10, 65, 130) if n < length] + [length]
+    ids = np.zeros((len(lengths), length), np.int32)
+    for row, n in enumerate(lengths):
+        ids[row, :n] = np.random.default_rng(row).integers(1, 512, n)
+    return jnp.asarray(ids)
+
+
+@pytest.mark.parametrize("length", [16, 256], ids=["dense-16", "selected-256"])
+def test_lengths_change_no_embedding_to_the_bit(tiny, length):
+    """The tiny preset built with the interpreted kernels, a bucket that
+    selects and one within `index_topk`: with the rows' lengths handed
+    over every row's embedding and counters (the selected pairs too) are,
+    to the bit, those of the same kernel without `takes_lengths`."""
+    _, _, path = tiny
+    preset = program.deepseek_v32_tiny()
+    kernel = make_latent_attention_fn(
+        preset.num_heads, preset.softmax_scale, block=64, interpret=True
+    )
+
+    def built(attention_fn):
+        return program.deepseek_v32_model_function(
+            "deepseek-v3.2-exp-tiny", dtype=jnp.bfloat16, weights_file=path,
+            attention_fn=attention_fn,
+            indexer_fn=make_indexer_fn(preset.index_n_heads, preset.index_topk, interpret=True),
+        )
+
+    ids = _uneven_batch(length)
+    given, blind = built(kernel), built(_without_lengths(kernel))
+    got = np.asarray(given.fn(given.params, ids))
+    want = np.asarray(blind.fn(blind.params, ids))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+    assert not got[0, :64].any() and np.abs(got[1:, :64]).min(1).max() > 0
+
+
+def test_an_attention_of_five_arguments_still_builds_and_runs(tiny):
+    """What stands in for `attention_fn` in a test or a planted fault
+    takes (q, kv, k_rope, dtype, selection) and no more."""
+    from sparkdl_tpu.ops.flash_attention import dense_latent_attention
+
+    _, _, path = tiny
+    preset = program.deepseek_v32_tiny()
+    calls = []
+
+    def five(q, kv, k_rope, dtype, selection=None):
+        calls.append(selection is not None)
+        return dense_latent_attention(
+            q, kv, k_rope, dtype, selection, num_heads=preset.num_heads,
+            scale=preset.softmax_scale,
+        )
+
+    mf = program.deepseek_v32_model_function(
+        "deepseek-v3.2-exp-tiny", weights_file=path, attention_fn=five
+    )
+    plain = program.deepseek_v32_model_function("deepseek-v3.2-exp-tiny", weights_file=path)
+    for length, selects in ((16, False), (64, True)):
+        ids = _uneven_batch(length)
+        del calls[:]
+        got = np.asarray(mf.fn(mf.params, ids))
+        assert calls == [selects] * 3
+        np.testing.assert_array_equal(got, np.asarray(plain.fn(plain.params, ids)))
+    counted = mf.batch_counters(np.asarray(ids), np.asarray(ids) != 0)
+    assert not any(name.startswith("mla.") for name in counted)
+
+
+def test_the_models_batch_counters_hold_the_attentions(tiny):
+    _, _, path = tiny
+    mf = _built(path, jnp.float32, True)
+    ids = np.asarray(_uneven_batch(256))  # 0, 10, 65, 130, 256 tokens: 0, 1, 2, 3, 4 blocks
+    counted = mf.batch_counters(ids, ids != 0)
+    assert counted["mla.query_blocks"] == 3 * 5 * 4
+    assert counted["mla.query_blocks_run"] == 3 * (0 + 1 + 2 + 3 + 4)
+    assert counted["mla.pairs_computed"] == 3 * (0 + 1 + 3 + 6 + 10) * 64 * 64
+    assert counted["dsa.index_tokens"] == 3 * 5 * 256

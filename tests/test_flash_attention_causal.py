@@ -8,6 +8,8 @@ kernel's online softmax rescales its running sum once a key block, a few
 float32 roundings of values of order 1: 2e-5, as test_flash_attention.py
 holds the bidirectional kernel to."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -359,6 +361,15 @@ def test_latent_kernel_runs_and_masks_only_what_the_diagonal_leaves(monkeypatch)
         (1024, 1024, 1024, 1),  # one sub-tile a block: the square, as before
         (2048, 1024, 128, 2.125),
         (300, 128, 32, 3 + 3 * 0.625),
+        # a row's length inside a block: the block that holds its last
+        # real token runs whole, those past it not at all
+        (0, 1024, 256, 0),
+        (1, 1024, 256, 0.625),
+        (1025, 1024, 256, 2.25),
+        (2690, 1024, 256, 3 + 3 * 0.625),  # the claimed cell's shortest row
+        (8408, 1024, 256, 36 + 9 * 0.625),  # and the 16,384 bucket's shortest
+        (12494, 1024, 256, 78 + 13 * 0.625),
+        (129, 128, 32, 1 + 2 * 0.625),
     ],
 )
 def test_pairs_the_latent_kernel_computes_by_hand(length, block, tile, blocks):
@@ -396,3 +407,141 @@ def test_latent_kernel_moves_nothing_in_hbm_and_says_what_it_cannot_do():
             np.asarray(fn(q, kv, k_rope, jnp.float32)),
             **TOL,
         )
+
+
+# -- the latent kernel given its rows' lengths ----------------------------------
+
+#: lengths 0, 1, a block's edge, a block's edge + 1 and the whole row, in
+#: blocks of 128 over 384 positions: 0, 1, 1, 2 and 3 live query blocks
+LENGTHS = (0, 1, 128, 129, 384)
+
+
+@pytest.mark.parametrize("tile", [None, 32, 64])
+@pytest.mark.parametrize("selection", [None, "causal", "top-k"])
+def test_latent_kernel_with_lengths_runs_no_block_of_padding(monkeypatch, selection, tile):
+    """The live positions (every position of a query block that holds a
+    real token) are `lengths=None`'s bit for bit, every position of a
+    dead block is exactly zero, and nothing is NaN."""
+    from sparkdl_tpu.ops import flash_attention as ops
+
+    if tile is not None:  # blocks of 128 walked in 4 and 2 key sub-tiles
+        monkeypatch.setattr(ops, "LATENT_KEY_TILE", tile)
+    B, L, H, block = len(LENGTHS), LENGTHS[-1], 2, 128
+    q, kv, k_rope = latent_arrays(17, B, L, H, 32, 16)
+    chosen = _selection(selection, B, L, 17)
+    run = functools.partial(
+        ops.flash_attention_latent, q, kv, k_rope, chosen, num_heads=H, scale=0.07,
+        block=block, interpret=True,
+    )
+    whole = np.asarray(run())
+    got = np.asarray(run(jnp.asarray(LENGTHS, jnp.int32)))
+    assert got.shape == whole.shape and not np.isnan(got).any()
+    for row, length in enumerate(LENGTHS):
+        live = -(-length // block) * block
+        np.testing.assert_array_equal(got[row, :live], whole[row, :live])
+        assert not got[row, live:].any(), (row, length)
+    assert np.abs(got[-1]).max() > 0.01  # the comparison is of something
+
+
+def test_latent_kernel_with_lengths_off_the_block_size():
+    """A row length off the block size (the arrays are padded to 256) and a
+    length that ends in the padded block: still the live blocks alone."""
+    from sparkdl_tpu.ops.flash_attention import flash_attention_latent
+
+    q, kv, k_rope = latent_arrays(19, 2, 200, 2, 32, 16)
+    chosen = _selection("top-k", 2, 200, 19)
+    kw = dict(num_heads=2, scale=0.07, block=128, interpret=True)
+    whole = np.asarray(flash_attention_latent(q, kv, k_rope, chosen, **kw))
+    got = np.asarray(
+        flash_attention_latent(q, kv, k_rope, chosen, jnp.asarray([100, 200], jnp.int32), **kw)
+    )
+    assert got.shape == (2, 200, 64)
+    np.testing.assert_array_equal(got[0, :128], whole[0, :128])
+    assert not got[0, 128:].any()
+    np.testing.assert_array_equal(got[1], whole[1])
+
+
+#: sha256 of the traced call without lengths, taken from the parent commit
+#: (PR 36) at (B, L, H, nope, rope, block, selected): two of the cells'
+#: blocks of 1,024 in sub-tiles of 256, and two part-filled blocks of 128
+PINNED_LATENT = {
+    (1, 2048, 4, 128, 128, 1024, True): "a3934789b54e1124",
+    (2, 2048, 4, 128, 128, 1024, False): "bed3b94fafb2bd1e",
+    (1, 300, 2, 32, 16, 128, False): "70ecc565c7c748fc",
+    (1, 300, 2, 32, 16, 128, True): "afcba3c4abca6fca",
+}
+
+
+def _latent_jaxpr(shape, lengths=False):
+    from sparkdl_tpu.ops.flash_attention import flash_attention_latent
+
+    B, L, H, nope, rope, block, selected = shape
+    bf16 = jnp.bfloat16
+    args = [
+        jnp.zeros((B, L, H * (nope + rope)), bf16),
+        jnp.zeros((B, L, H * 2 * nope), bf16),
+        jnp.zeros((B, L, rope), bf16),
+        jnp.zeros((B, L, L), jnp.int8) if selected else None,
+    ]
+    if lengths:
+        args.append(jnp.zeros((B,), jnp.int32))
+    return str(
+        jax.make_jaxpr(
+            lambda *a: flash_attention_latent(*a, num_heads=H, scale=0.1, block=block)
+        )(*args)
+    )
+
+
+@pytest.mark.parametrize("shape", sorted(PINNED_LATENT, key=str))
+def test_latent_kernel_without_lengths_lowers_to_what_it_did(shape):
+    """`lengths=None` is the parent's call to the letter: no prefetched
+    operand, no live test in the kernel. With lengths the text differs
+    and holds them. (The Mosaic module compiled for a described v5e at
+    the four shapes the cells run is the parent's too, debug locations
+    aside: PERF.md, Findings, PR 37.)"""
+    import hashlib
+
+    text = _latent_jaxpr(shape)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED_LATENT[shape]
+    prefetched = f"Ref<smem>{{i32[{shape[0]}]}}"  # a row's live query blocks
+    assert prefetched not in text
+    assert prefetched in _latent_jaxpr(shape, lengths=True)
+
+
+@pytest.mark.parametrize(
+    "lengths, said",
+    [
+        (np.zeros((3,), np.int32), r"lengths .* is \[B\] int32, got \(3,\) int32"),
+        (np.zeros((2, 1), np.int32), r"lengths .* got \(2, 1\) int32"),
+        (np.zeros((2,), np.float32), r"lengths .* got \(2,\) float32"),
+        (np.zeros((2,), np.int8), r"lengths .* is \[B\] int32, got \(2,\) int8"),
+    ],
+)
+def test_latent_kernel_refuses_lengths_of_another_shape_or_type(lengths, said):
+    from sparkdl_tpu.ops.flash_attention import flash_attention_latent
+
+    q, kv, k_rope = latent_arrays(23, 2, 128, 2, 32, 16)
+    with pytest.raises(ValueError, match=said):
+        flash_attention_latent(
+            q, kv, k_rope, None, jnp.asarray(lengths), num_heads=2, scale=0.1,
+            block=64, interpret=True,
+        )
+
+
+def test_the_built_kernel_says_it_takes_lengths_and_the_dense_one_does_not():
+    from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
+
+    dense = make_latent_attention_fn(2, 0.1)
+    assert dense.kind == "dense"  # the tests run on the CPU
+    assert not hasattr(dense, "takes_lengths") and not hasattr(dense, "query_blocks")
+    kernel = make_latent_attention_fn(2, 0.1, block=64, interpret=True)
+    assert kernel.takes_lengths is True
+    assert [kernel.query_blocks(n) for n in (0, 1, 64, 65, 200)] == [0, 1, 1, 2, 4]
+    assert kernel.pairs_computed(65) == kernel.pairs_computed(128)
+    q, kv, k_rope = latent_arrays(29, 2, 128, 2, 32, 16)
+    whole = np.asarray(kernel(q, kv, k_rope, jnp.float32))
+    got = np.asarray(
+        kernel(q, kv, k_rope, jnp.float32, lengths=jnp.asarray([64, 0], jnp.int32))
+    )
+    np.testing.assert_array_equal(got[0, :64], whole[0, :64])
+    assert not got[0, 64:].any() and not got[1].any()
