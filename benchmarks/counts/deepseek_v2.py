@@ -5,19 +5,23 @@ q_b, kv_a, kv_b, o), of attention's two products at half the square
 dense layer's SwiGLU MLP, of the shared experts, of the router, and of
 the routed experts held here. Nothing for the norms, softmax, rotary,
 gates, sorting, the combine and the embedding's gather. The untied
-output head is not computed on this path and not counted.
+output head is not computed on this path and not counted. Attention's
+pairs are counted at the rows' real lengths and everything that grows
+with the tokens at the tokens dispatched, as
+`benchmarks/counts/__init__.py` rules.
 
 **The routed experts are counted at the slots that fell on held
-experts** where `work` carries them (`slots_held`, the program's
-device-measured counter `moe.slots_held`, which the readers of this
-family's kernel metrics put there); where it does not (the driver's own
-`work`, which `step_mfu` reads) they are counted at their expectation,
-dispatched tokens x experts a token x held / routed, an expectation and
-not a measurement, and over pad tokens too although the program routes
-none of them.
+experts** where `work` carries them (`slots_held`, the window's delta of
+the program's device-measured counter `moe.slots_held`, which the driver
+puts there); where it does not (a hand-made `work`, a program without
+the counter) they are counted at their expectation, dispatched tokens x
+experts a token x held / routed, an expectation and not a measurement,
+and over pad tokens too although the program routes none of them.
 """
 
 from __future__ import annotations
+
+from benchmarks.counts import pair_rows, pairs_unknown
 
 KERNELS = ("flash_attention", "moe_grouped_matmul")
 
@@ -108,14 +112,17 @@ def flops_per_token_dense_parts(config) -> float:
 
 def score_flops(config, work) -> float:
     heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
-    # two products over half the square: 2 * (length / 2) * width a token
+    # two products over half the square of the row's real tokens: 2 *
+    # (real / 2) * width a real query
     return sum(
-        rows * float(int(length)) ** 2 * score_width(config) * heads * layers
-        for length, rows in work["rows_by_length"].items()
+        rows * float(real) ** 2 * score_width(config) * heads * layers
+        for _edge, real, rows in pair_rows(work)
     )
 
 
-def forward_flops(config, work) -> float:
+def forward_flops(config, work):
+    if pairs_unknown(work):
+        return None
     return (
         _tokens(work) * flops_per_token_dense_parts(config)
         + score_flops(config, work)
@@ -126,9 +133,10 @@ def forward_flops(config, work) -> float:
 def kernel_work(config, kernel, work):
     """(operations, bytes) a kernel's calls needed for `work`.
 
-    `flash_attention`: the two products at half the square over the key
-    and the value size; q, k, v in and the result out once each at
-    `param_dtype`, for the rows completed at their dispatched lengths.
+    `flash_attention`: the two products at half the square of the rows'
+    real lengths over the key and the value size; q, k, v in and the
+    result out once each at `param_dtype`, for the rows completed at
+    their dispatched lengths.
 
     `moe_grouped_matmul`: 2 * slots * 3 * hidden * expert width; each
     slot's rows in (hidden twice, the expert width once, at
@@ -138,6 +146,8 @@ def kernel_work(config, kernel, work):
     them the matrices are left out, and the share reads low)."""
     size = {"float32": 4, "bfloat16": 2}[config["param_dtype"]]
     if kernel == "flash_attention":
+        if pairs_unknown(work):
+            return None
         heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
         keys = config["qk_nope_head_dim"] + config["qk_rope_head_dim"]
         moved = heads * (2 * keys + 2 * config["v_head_dim"]) * size

@@ -25,11 +25,19 @@ sorting, the combine and the embedding's gather; no output head and no
 multi-token-prediction block. The routed experts are counted at the
 measured slots where `work` carries them, else at their expectation, as
 `counts/deepseek_v2.py` says.
+
+As `benchmarks/counts/__init__.py` rules, the pairs (selected, causal)
+are those of the rows' REAL lengths and everything that grows with the
+tokens is counted at the tokens dispatched. Whether a row has an
+indexer is decided by its DISPATCHED edge, since the program builds one
+for a bucket and not for a row: a row of 1,500 tokens dispatched at
+8,192 runs an indexer over its 1,500 (and selects every causal key).
 """
 
 from __future__ import annotations
 
 from benchmarks.counts import deepseek_v2 as v2
+from benchmarks.counts import pair_rows, pairs_unknown
 from benchmarks.counts.deepseek_v2 import (  # noqa: F401  attention_params: by this family's name too
     attention_params,
     expert_params,
@@ -85,25 +93,34 @@ def flops_per_token_dense_parts(config, selects: bool = True) -> float:
 
 
 def score_flops(config, work) -> float:
-    """Attention's two products over the selected pairs."""
+    """Attention's two products over the selected pairs of the rows' real
+    lengths."""
     heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
     return sum(
-        rows * selected_pairs(config, length) * 2.0 * score_width(config) * heads * layers
-        for length, rows in _rows(work)
+        rows * selected_pairs(config, real) * 2.0 * score_width(config) * heads * layers
+        for _edge, real, rows in pair_rows(work)
+    )
+
+
+def indexed_pairs(config, work) -> int:
+    """Every causal pair of the real tokens of the rows whose bucket has
+    an indexer."""
+    return sum(
+        rows * causal_pairs(real)
+        for edge, real, rows in pair_rows(work)
+        if edge > config["index_topk"]
     )
 
 
 def index_flops(config, work) -> float:
     """The index scores over every causal pair of the rows that select."""
     width = config["index_n_heads"] * config["index_head_dim"]
-    return sum(
-        rows * causal_pairs(length) * 2.0 * width * config["num_hidden_layers"]
-        for length, rows in _rows(work)
-        if length > config["index_topk"]
-    )
+    return indexed_pairs(config, work) * 2.0 * width * config["num_hidden_layers"]
 
 
-def forward_flops(config, work) -> float:
+def forward_flops(config, work):
+    if pairs_unknown(work):
+        return None
     dense = sum(
         length * rows
         * flops_per_token_dense_parts(config, length > config["index_topk"])
@@ -122,18 +139,23 @@ def kernel_work(config, kernel, work):
 
     `flash_attention`: the two products over the selected pairs; q, the
     up-projected keys and values and the result moved once each at
-    `param_dtype`, and the selection, a byte a causal pair, once.
+    `param_dtype` (a dispatched token), and the selection, a byte a
+    causal pair of the real tokens, once.
 
     `dsa_index_scores`: `index_flops`; the index queries, the one index
-    key a token (`param_dtype`) and the heads' weights (float32) in, and
-    one float32 score a causal pair out: the kernel writes the scores
-    and another (`dsa_select`) reads them.
+    key a token (`param_dtype`) and the heads' weights (float32) in (a
+    dispatched token), and one float32 score a causal pair of the real
+    tokens out: the kernel writes the scores and another (`dsa_select`)
+    reads them.
 
     `moe_grouped_matmul`: as `counts/deepseek_v2.py` counts it."""
+    if kernel == "moe_grouped_matmul":
+        return v2.kernel_work(config, kernel, work)
+    if kernel not in KERNELS or pairs_unknown(work):
+        return None
     size = {"float32": 4, "bfloat16": 2}[config["param_dtype"]]
     layers = config["num_hidden_layers"]
-    selecting = [(n, rows) for n, rows in _rows(work) if n > config["index_topk"]]
-    pairs = sum(rows * causal_pairs(n) for n, rows in selecting)
+    pairs = indexed_pairs(config, work)
     if kernel == "flash_attention":
         heads = config["num_attention_heads"]
         keys = config["qk_nope_head_dim"] + config["qk_rope_head_dim"]
@@ -142,11 +164,7 @@ def kernel_work(config, kernel, work):
             score_flops(config, work),
             float(_tokens(work) * layers * moved + pairs * layers),
         )
-    if kernel == "dsa_index_scores":
-        heads, dim = config["index_n_heads"], config["index_head_dim"]
-        tokens = sum(n * rows for n, rows in selecting)
-        moved = tokens * ((heads * dim + dim) * size + heads * 4) + pairs * 4
-        return index_flops(config, work), float(moved * layers)
-    if kernel == "moe_grouped_matmul":
-        return v2.kernel_work(config, kernel, work)
-    return None
+    heads, dim = config["index_n_heads"], config["index_head_dim"]
+    tokens = sum(n * rows for n, rows in _rows(work) if n > config["index_topk"])
+    moved = tokens * ((heads * dim + dim) * size + heads * 4) + pairs * 4
+    return index_flops(config, work), float(moved * layers)

@@ -4,9 +4,13 @@ q, k, v and o; the SwiGLU MLP's three) and of attention's two products at
 half the square, since causal. Nothing for the recurrence (9 * d_inner *
 d_state a token and Mamba layer, 0.34% of the whole), the convolution,
 the norms, the gates and the embedding's gather: under 1% together. The
-tied output head is not computed on this path and not counted."""
+tied output head is not computed on this path and not counted. The
+projections are counted at the tokens dispatched, attention's pairs at
+the row's real length, as `benchmarks/counts/__init__.py` rules."""
 
 from __future__ import annotations
+
+from benchmarks.counts import pair_rows, pairs_unknown
 
 HEAD_DIM = 128
 
@@ -36,21 +40,25 @@ def layer_params(config) -> tuple:
     return mamba, attention
 
 
-def flops_per_row(config, length: int) -> float:
+def flops_per_row(config, length: int, real: int | None = None) -> float:
+    """Of a row dispatched at `length` tokens of which `real` are its own
+    (all of them where `real` is not given)."""
+    real = length if real is None else real
     layers = config["num_hidden_layers"]
     n_mamba = mamba_layers(config)
     mamba, attention = layer_params(config)
     q = config["num_attention_heads"] * config.get("head_dim", HEAD_DIM)
-    # scores and weighted values: 2 products * 2 * (length / 2) keys * q
-    products = 2.0 * length * q
+    # scores and weighted values: 2 products * 2 * (real / 2) keys * q a query
+    products = 2.0 * real * real * q
     per_token = 2.0 * (n_mamba * mamba + (layers - n_mamba) * attention)
-    return length * (per_token + (layers - n_mamba) * products)
+    return length * per_token + (layers - n_mamba) * products
 
 
-def forward_flops(config, work) -> float:
+def forward_flops(config, work):
+    if pairs_unknown(work):
+        return None
     return sum(
-        flops_per_row(config, int(length)) * rows
-        for length, rows in work["rows_by_length"].items()
+        flops_per_row(config, edge, real) * rows for edge, real, rows in pair_rows(work)
     )
 
 
@@ -58,21 +66,23 @@ def kernel_work(config, kernel, work):
     """`selective_scan`: per token and Mamba layer, 9 * d_inner * d_state
     operations (exp's argument, exp, two products and a sum for the
     state, a product and a sum for y, dt * h, the gate) and the bytes the
-    kernel has to move once: h, dt and z in and y out at `param_dtype`
-    each, B and C at `scan_dtype`. Counted for the rows the window
-    completed at their dispatched lengths: rows that only fill a batch
-    are the kernel's cost and not its work."""
+    kernel has to move once: h, dt and z in, and B and C, at `scan_dtype`
+    (the configuration keeps the recurrence's inputs in it, and float32
+    is what the kernel is handed), y out at `param_dtype`. Every term
+    grows with the tokens: counted for the rows the window completed at
+    their dispatched lengths; rows that only fill a batch are the
+    kernel's cost and not its work."""
     if kernel != "selective_scan":
         return None
     di = config["mamba_expand"] * config["hidden_size"]
     n = config["mamba_d_state"]
     size = {"float32": 4, "bfloat16": 2}
-    wide, narrow = size[config["param_dtype"]], size[config["scan_dtype"]]
+    out, scan = size[config["param_dtype"]], size[config["scan_dtype"]]
     tokens = sum(
         int(length) * rows for length, rows in work["rows_by_length"].items()
     )
     token_layers = float(tokens * mamba_layers(config))
     return (
         token_layers * 9.0 * di * n,
-        token_layers * (4.0 * di * wide + 2.0 * n * narrow),
+        token_layers * (di * (3.0 * scan + out) + 2.0 * n * scan),
     )

@@ -67,6 +67,11 @@ class State:
     sample_at: list
     sample_inputs: list
     weights: dict
+    #: stored row -> the tokens of its own that the program will find in
+    #: it, where the entry's kind can say (`entries/<kind>.py:row_length`),
+    #: and then the job's rows as stored, for `work` to measure
+    row_length: object = None
+    rows: list = None
 
 
 def _reference(cell):
@@ -97,10 +102,13 @@ def build_entry(cell) -> tuple:
     """(the program's transformer, the benchmark's weights it loaded). The
     builder is `entries/<kind>.py`, by the configuration's `entry.kind`."""
     path, weights = weights_file(cell)
-    kind = importlib.import_module(
+    return _entry_kind(cell).build(cell, path, OUT_COL), weights
+
+
+def _entry_kind(cell):
+    return importlib.import_module(
         f"benchmarks.entries.{cell.config['entry']['kind']}"
     )
-    return kind.build(cell, path, OUT_COL), weights
 
 
 def load_job(cell, transformer, weights) -> State:
@@ -108,6 +116,10 @@ def load_job(cell, transformer, weights) -> State:
     check will look at: the ends of every partition and a draw from the
     seed."""
     traffic = cell.traffic
+    row_length = None
+    if transformer is not None:
+        tells = getattr(_entry_kind(cell), "row_length", None)
+        row_length = tells and tells(cell, transformer)
     data, parts = traffic["data"], traffic["partitions"]
     n = data["rows"]
     with jax.profiler.TraceAnnotation("bench:datagen"):
@@ -153,6 +165,8 @@ def load_job(cell, transformer, weights) -> State:
         sample_at=sample_at,
         sample_inputs=[raw_at.get(i) for i in sample_at],
         weights=weights,
+        row_length=row_length,
+        rows=stored if row_length is not None else None,
     )
 
 
@@ -207,13 +221,72 @@ def counters(state: State) -> dict:
     return dict(metrics.scalar_snapshot()["counters"])
 
 
+def real_lengths(state: State, delta: dict, jobs: int, by_length: dict) -> dict:
+    """The real lengths of the rows the window completed, by the edge
+    each was dispatched at: `{"lengths_by_edge": {edge: {real: rows}}}`.
+    From the job's own rows as the benchmark made them, measured by the
+    entry's `row_length`, times the whole jobs completed; a row goes to
+    the least edge the program counted that holds it, the top one cut.
+    Held against what the program counted: the real tokens have to sum
+    to the delta of `text.tokens` and each edge's rows to that of
+    `text.bucket_rows.<edge>`. Where they do not, no real length is
+    given and `pairs_unknown` says why: the counts then have no pair
+    term (`benchmarks/counts/__init__.py`). `lengths_check` is the
+    comparison itself, for the result line."""
+    edges = sorted(int(e) for e in by_length)
+    if not edges:
+        return _pairs_unknown("the program counted no text.bucket_rows.<edge>")
+    try:
+        lengths = [state.row_length(r) for r in state.rows if r is not None]
+    except Exception as e:  # a silent metric, and the run still prints its line
+        return _pairs_unknown(f"row_length raised {e!r}")
+    mine = {e: {} for e in edges}
+    for n in lengths:
+        edge = next((e for e in edges if n <= e), edges[-1])
+        n = min(n, edge)
+        mine[edge][n] = mine[edge].get(n, 0) + jobs
+    tokens = sum(n * rows for of in mine.values() for n, rows in of.items())
+    check = {
+        "jobs": jobs,
+        "tokens": [tokens, int(delta.get("text.tokens", 0))],
+        "rows_by_edge": {
+            str(e): [sum(mine[e].values()), by_length[str(e)]] for e in edges
+        },
+    }
+    if any(a != b for a, b in [check["tokens"], *check["rows_by_edge"].values()]):
+        return _pairs_unknown(
+            "the job's rows as made do not square with the program's counters "
+            "([mine, counted]): text.tokens %s, text.bucket_rows %s"
+            % (check["tokens"], check["rows_by_edge"]),
+            **check,
+        )
+    return {
+        "lengths_by_edge": {str(e): mine[e] for e in edges},
+        "lengths_check": dict(check, ok=True),
+    }
+
+
+def _pairs_unknown(why: str, **check) -> dict:
+    return {"pairs_unknown": why, "lengths_check": dict(check, ok=False, why=why)}
+
+
 def work(state: State, delta: dict, w: Window) -> dict:
+    """What the window completed, as the counts modules take it: the rows
+    by the edge they were dispatched at (the program's counters), the
+    routed slots that fell on held experts where the program measures
+    them (counter `moe.slots_held`), and for an entry that can tell a
+    row's own length the real lengths (`real_lengths`)."""
     by_length = {
         name.rsplit(".", 1)[1]: int(v)
         for name, v in delta.items()
         if name.startswith("text.bucket_rows.") and v
     }
-    return {"rows": w.rows, "rows_by_length": by_length}
+    out = {"rows": w.rows, "rows_by_length": by_length}
+    if delta.get("moe.slots_held", 0) > 0:
+        out["slots_held"] = int(delta["moe.slots_held"])
+    if state.row_length is not None:
+        out.update(real_lengths(state, delta, len(w.jobs), by_length))
+    return out
 
 
 def release(state: State) -> None:

@@ -31,3 +31,12 @@ def build(cell, weights_path, out_col):
         maxLength=cell.config["max_length"],
         batchSize=cell.traffic["batch_rows"],
     )
+
+
+def row_length(cell, transformer):
+    """Stored row -> the tokens of its own that the program will find in
+    it: the transformer's own tokenizer, cut at the configuration's
+    `max_length`. Holds the tokenizer alone, so it outlives the program's
+    release."""
+    tokenize, cap = transformer._tokenizer(), cell.config["max_length"]
+    return lambda text: min(len(tokenize(text)), cap)

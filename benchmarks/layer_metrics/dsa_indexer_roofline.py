@@ -1,8 +1,9 @@
 """The index-scores kernel's share of its roofline: the least time the
 chip could take for the work its calls were needed for (every causal
-(query, key) pair x the index heads x their size, two operations a
-multiply-add; the index queries, keys and weights in and one float32
-score a causal pair out), the larger of operations over the peak bf16
+(query, key) pair of the rows' real tokens x the index heads x their
+size, two operations a multiply-add; the index queries, keys and weights
+of the dispatched tokens in and one float32 score a causal pair out),
+the larger of operations over the peak bf16
 rate and bytes over the memory bandwidth, over the summed device time of
 the kernel's events in the trace. Says which of the two bounds.
 

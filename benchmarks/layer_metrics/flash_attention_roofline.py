@@ -1,14 +1,15 @@
 """The flash-attention kernel's share of its roofline: the least time the
-chip could take for the work its calls were needed for, the larger of
-operations over the peak bf16 rate and bytes over the memory bandwidth,
-over the summed device time of the kernel's events in the trace. Says
-which of the two bounds."""
+chip could take for the work its calls were needed for (the pairs of
+the rows' real tokens; q, k, v and the result moved once over the
+dispatched length), the larger of operations over the peak bf16 rate
+and bytes over the memory bandwidth, over the summed device time of the
+kernel's events in the trace. Says which of the two bounds."""
 
-#: What marks the kernel's events in the trace. The Pallas call has no
-#: `name=`, so the trace names each after its HLO instruction
-#: (`%attention.12 = f32[...] custom-call(...)`, from the wrapper function's
-#: name) and the only stable mark is the custom call's target. bert-base
-#: has no other Mosaic kernel.
+#: What marks the kernel's events in the trace: the custom call's target.
+#: The program names the call (`%flash_attention.N = f32[...]
+#: custom-call(...)`), but the name is the program's to change and the
+#: target is not; bert-base has no other Mosaic kernel. A family that has
+#: two reads by both marks (`mla_attention_roofline.py`).
 EVENT_NAME_PART = 'custom_call_target="tpu_custom_call"'
 KERNEL = "flash_attention"
 

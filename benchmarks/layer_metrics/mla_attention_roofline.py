@@ -1,7 +1,8 @@
 """The latent-attention flash kernel's share of its roofline: the least
 time the chip could take for the work its calls were needed for (the
-two products at half the square over keys of nope + rope and values of
-their own size; q, k, v and the result moved once), the larger of
+two products over the pairs of the rows' real tokens, half the square
+or the selection, over keys of nope + rope and values of their own size;
+q, k, v and the result moved once over the dispatched length), the larger of
 operations over the peak bf16 rate and bytes over the memory bandwidth,
 over the summed device time of the kernel's events in the trace. Says
 which of the two bounds."""

@@ -1,9 +1,12 @@
 """The whole program's share of the chip's peak: the forward operations
 that the rows completed in the traced window needed (the benchmark's own
-count; for text at the padded lengths dispatched), over the window, the
-chips and the peak bf16 rate. Bounds every kernel's roofline share: a
-kernel taken off the path leaves its own metric silent, and this one
-still has to move."""
+count, `benchmarks/counts/`: for text, what grows with a row's tokens at
+the padded length dispatched, what grows with its pairs at its real
+length, and the routed experts at the slots the program measured), over
+the window, the chips and the peak bf16 rate. Nothing where the real
+lengths could not be squared with the program's counters. Bounds every
+kernel's roofline share: a kernel taken off the path leaves its own
+metric silent, and this one still has to move."""
 
 
 def read(ctx):

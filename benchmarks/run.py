@@ -16,8 +16,10 @@ configuration, a traffic mix or a metric by name.
 
 The last line of standard output is the result: `correct`, `attempted`,
 `failed`, `metrics`, `device` (and `breakdown` when traced), then
-`compared`, each number that decided `correct` beside its limit, which
-is also the last thing on standard error. Without a TPU, or with fewer
+`work_check`, the benchmark's own account of the window's rows beside
+the program's counters (what a driver's `work` gives as `lengths_check`),
+and `compared`, each number that decided `correct` beside its limit,
+which is also the last thing on standard error. Without a TPU, or with fewer
 chips than the cell asks for, the exit code is 2 and nothing is printed:
 `--rehearse-cpu` is the explicit rehearsal of the control flow on the
 CPU at the traffic file's `rehearsal` sizes, says so in its line, and
@@ -204,7 +206,7 @@ def run(args) -> int:
         return 2
     import jax
 
-    from benchmarks import compare, trace_reduce
+    from benchmarks import compare, host_spans, trace_reduce
     from benchmarks.peaks import peaks_for
 
     peaks = None if args.rehearse_cpu else peaks_for(dev.device_kind)
@@ -237,12 +239,22 @@ def run(args) -> int:
 
     numbers = driver.check(cell, state, window)
     decided = compare.decide(numbers, cell.limits)
+    # what the window completed, and whether the benchmark's own account
+    # of the rows squares with the program's counters: in every run
+    work = driver.work(state, delta, window)
+    work_check = work.get("lengths_check")
+    if work_check and not work_check["ok"]:
+        print(f"benchmarks: no pair term is counted: {work_check['why']}", file=sys.stderr)
 
     if args.trace:
         trace = None
         if not args.rehearse_cpu:
             trace = trace_reduce.reduce_window(
-                trace_reduce.load_events(trace_dir), "bench:window", "bench:"
+                trace_reduce.load_events(trace_dir),
+                "bench:window",
+                "bench:",
+                span_prefix=host_spans.PREFIX,
+                envelopes=host_spans.ENVELOPES,
             )
             device["busy_s"] = trace.busy_s
             device["window_s"] = trace.window_s
@@ -251,7 +263,7 @@ def run(args) -> int:
             "trace": trace,
             "window": window,
             "counters": delta,
-            "work": driver.work(state, delta, window),
+            "work": work,
             "peaks": peaks,
             "chips": cell.chips,
             "counts": importlib.import_module(
@@ -303,6 +315,7 @@ def run(args) -> int:
         rows=window.rows,
         setup_s=setup_s,
         compiled_in_window=compiled_in_window,
+        work_check=work_check,
         compared=decided,
     )
     shutil.rmtree(trace_dir, ignore_errors=True)

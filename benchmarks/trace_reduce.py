@@ -11,8 +11,10 @@ own annotations, and gives:
   window), averaged over the devices;
 - the time of each operation by name, and `kernel_s(part)` for the
   operations whose name holds `part`;
-- the idle gaps of the first device, each attributed to the innermost
-  harness annotation that was open at its middle, else `unattributed`.
+- the idle gaps of the first device, each named by the innermost span
+  of the program (`span_prefix`, envelopes left out) that was open at
+  its middle on any host thread; where none was, by the innermost
+  harness annotation open there; else `unattributed`.
 
 The reducer is tested on a hand-built trace
 (`tests/benchmarks/test_trace_reduce.py`), so every PR reads the same
@@ -121,7 +123,21 @@ class Reduced:
         }
 
 
-def reduce_window(events, window_name: str, annotation_prefix: str) -> Reduced:
+def _innermost(notes, at_ns: float, prefix: str):
+    """The name, less `prefix`, of the note that started last among those
+    open at `at_ns`; None where none is."""
+    open_ = [a for a in notes if a.start_ns <= at_ns < a.end_ns]
+    inner = max(open_, key=lambda a: a.start_ns, default=None)
+    return inner.name[len(prefix):] if inner else None
+
+
+def reduce_window(
+    events,
+    window_name: str,
+    annotation_prefix: str,
+    span_prefix: str | None = None,
+    envelopes=frozenset(),
+) -> Reduced:
     host = [e for e in events if e.plane == HOST_PLANE]
     spans = [e for e in host if e.name == window_name]
     if not spans:
@@ -151,13 +167,24 @@ def reduce_window(events, window_name: str, annotation_prefix: str) -> Reduced:
             first_busy = merged
     n = len(planes)
     notes = [e for e in host if e.name.startswith(annotation_prefix)]
+    # the program's own spans say what the host was doing; the harness's
+    # annotations only that a job was collecting
+    spans = [
+        e
+        for e in host
+        if span_prefix
+        and e.name.startswith(span_prefix)
+        and e.name[len(span_prefix):] not in envelopes
+    ]
     gaps, at = [], w0
     for s, t in first_busy + [(w1, w1)]:
         if s > at:
             mid = (at + s) / 2
-            open_ = [a for a in notes if a.start_ns <= mid < a.end_ns]
-            inner = max(open_, key=lambda a: a.start_ns, default=None)
-            name = inner.name[len(annotation_prefix):] if inner else "unattributed"
+            name = (
+                _innermost(spans, mid, span_prefix or "")
+                or _innermost(notes, mid, annotation_prefix)
+                or "unattributed"
+            )
             gaps.append((name, (s - at) / 1e9))
         at = max(at, t)
     return Reduced(

@@ -17,6 +17,23 @@ def load_bench() -> dict:
         return json.load(f)
 
 
+def job_lengths_by_edge(traffic: str, edges) -> dict:
+    """One job of a traffic mix as the driver's `work` gives its real
+    lengths: {edge: {tokens: live rows}}, tokens = words + [CLS] + [SEP],
+    each row in the least of `edges` that holds it."""
+    sys.path.insert(0, ROOT)
+    from benchmarks.data import texts
+
+    with open(os.path.join(ROOT, "benchmarks", "traffic", f"{traffic}.json")) as f:
+        data = json.load(f)["data"]
+    words = texts.word_counts(data["rows"] - data["null_rows"], data["word_counts"])
+    by_edge = {str(e): {} for e in edges}
+    for n in (words + 2).tolist():
+        of = by_edge[str(next(e for e in edges if n <= e))]
+        of[n] = of.get(n, 0) + 1
+    return by_edge
+
+
 def make_checkout(directory) -> str:
     directory = str(directory)
     shutil.copytree(

@@ -1,5 +1,8 @@
 """`BENCHMARK.json` against the rules it is refused by before any run, and
-against the files it names."""
+against the files it names. What reads the file's shape is a function of
+`bench` and the checkout's root (`check_configs`, `check_workloads`,
+`check_metrics`, `check_kinds`, `check_limits`), so that `test_benchmark_grows.py` can put a copy with a
+fifth configuration through the same assertions."""
 
 import json
 import os
@@ -48,6 +51,18 @@ def test_top_level_keys_and_sizes(bench):
 
 
 def test_configs(bench):
+    check_configs(bench)
+
+
+def test_workloads(bench):
+    check_workloads(bench)
+
+
+def test_metrics(bench):
+    check_metrics(bench)
+
+
+def check_configs(bench, root=ROOT):
     assert 1 <= len(bench["configs"]) <= 24
     names = [c["name"] for c in bench["configs"]]
     files = [c["file"] for c in bench["configs"]]
@@ -60,13 +75,13 @@ def test_configs(bench):
         assert any(c["file"].startswith(p + "/") for p in bench["paths"])
         assert len(c["reduced"]) <= 16
         assert not any(WIDTH.search(k) for k in c["reduced"])
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             blob = json.load(f)
         assert blob["name"] == c["name"]
         assert all(k in blob for k in c["reduced"])
 
 
-def test_workloads(bench):
+def check_workloads(bench, root=ROOT):
     cells = bench["workloads"]
     assert 1 <= len(cells) <= 24
     assert len({w["name"] for w in cells}) == len(cells)
@@ -80,12 +95,12 @@ def test_workloads(bench):
         for part in ("traffic", "limits"):
             name = w["traffic"] if part == "traffic" else w["name"]
             assert os.path.exists(
-                os.path.join(ROOT, "benchmarks", part, f"{name}.json")
+                os.path.join(root, "benchmarks", part, f"{name}.json")
             )
     assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
 
 
-def test_metrics(bench):
+def check_metrics(bench, root=ROOT):
     e2e, layers = bench["end_to_end"], bench["per_layer"]
     assert 1 <= len(e2e) <= 16 and 1 <= len(layers) <= 128
     names = [m["name"] for m in e2e + layers]
@@ -103,7 +118,7 @@ def test_metrics(bench):
         }
         assert m["moves"] in e2e_names and _line(m["layer"])
         assert os.path.exists(
-            os.path.join(ROOT, "benchmarks", "layer_metrics", f"{m['name']}.py")
+            os.path.join(root, "benchmarks", "layer_metrics", f"{m['name']}.py")
         )
         if "roofline" in m["name"]:
             assert m["name"].endswith("_roofline") and m["unit"] == "%"
@@ -141,17 +156,21 @@ def test_files_under_paths_are_named_from_names(bench):
                 assert re.fullmatch(r"[A-Za-z0-9_.\-]+", f), os.path.join(d, f)
 
 
-def _json(*parts):
-    with open(os.path.join(ROOT, "benchmarks", *parts)) as f:
+def _json(root, *parts):
+    with open(os.path.join(root, "benchmarks", *parts)) as f:
         return json.load(f)
 
 
 def test_every_kind_a_cell_names_is_a_file_of_that_name(bench):
+    check_kinds(bench)
+
+
+def check_kinds(bench, root=ROOT):
     files = {c["name"]: c["file"] for c in bench["configs"]}
     for w in bench["workloads"]:
-        with open(os.path.join(ROOT, files[w["config"]])) as f:
+        with open(os.path.join(root, files[w["config"]])) as f:
             config = json.load(f)
-        traffic = _json("traffic", f"{w['traffic']}.json")
+        traffic = _json(root, "traffic", f"{w['traffic']}.json")
         for directory, name in (
             ("entries", config["entry"]["kind"]),
             ("reference", config["family"]),
@@ -160,7 +179,7 @@ def test_every_kind_a_cell_names_is_a_file_of_that_name(bench):
             ("data", traffic["data"]["kind"]),
         ):
             assert os.path.exists(
-                os.path.join(ROOT, "benchmarks", directory, f"{name}.py")
+                os.path.join(root, "benchmarks", directory, f"{name}.py")
             ), (w["name"], directory, name)
         assert _line(traffic["why"], limit=2000)
         # what a rehearsal overrides is there to override
@@ -168,11 +187,15 @@ def test_every_kind_a_cell_names_is_a_file_of_that_name(bench):
 
 
 def test_limits_lie_between_their_readings(bench):
+    check_limits(bench)
+
+
+def check_limits(bench, root=ROOT):
     """A limit with a tolerance was set from two readings, the program's
     largest and the control's least, at least three times apart, and has
     more room above the lower than a fifth of it; an exact one is 0."""
     for w in bench["workloads"]:
-        blob = _json("limits", f"{w['name']}.json")
+        blob = _json(root, "limits", f"{w['name']}.json")
         assert blob["limits"], w["name"]
         for name, limit in blob["limits"].items():
             set_from = blob["set_from"][name]

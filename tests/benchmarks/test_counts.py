@@ -82,3 +82,33 @@ def test_bert_work_by_length_and_kernel_work():
     # attention's two products are part of the forward count
     assert flops < bert_counts.forward_flops(config, work)
     assert bert_counts.kernel_work(config, "other", work) is None
+
+
+def test_bert_pairs_are_those_of_the_rows_real_lengths():
+    """The cell's passage: 102 tokens dispatched at 128. The dense layers
+    run all 128; attention's pairs are 102 x 102, not causal."""
+    config = _config("bert-base")
+    layers, h, f = 12, 768, 3072
+    at_edges = {"rows": 7, "rows_by_length": {"128": 7}}
+    work = dict(at_edges, lengths_by_edge={"128": {102: 5, 128: 2}})
+    dense = 7 * 128 * (4 * h * h + 2 * h * f)
+    pairs = 5 * 102**2 + 2 * 128**2
+    assert bert_counts.forward_flops(config, work) == pytest.approx(
+        2 * layers * (dense + 2 * pairs * h)
+    )
+    flops, bytes_ = bert_counts.kernel_work(config, "flash_attention", work)
+    assert flops == pytest.approx(layers * 4 * h * pairs)
+    assert bytes_ == pytest.approx(layers * 4 * h * 4 * 7 * 128)  # a dispatched token
+    # the kernel stays bound by its bytes at 102 of 128, as at the edge
+    assert bytes_ / 819e9 > flops / 197e12
+    # without real lengths the rows are as long as their edge
+    full = dict(at_edges, lengths_by_edge={"128": {128: 7}})
+    assert bert_counts.forward_flops(config, at_edges) == bert_counts.forward_flops(config, full)
+    assert bert_counts.flops_per_row(config, 128, 128) == bert_counts.flops_per_row(config, 128)
+    # scores are under 3% of the count at 128, so 102 moves it by about 1%
+    all_102 = dict(at_edges, lengths_by_edge={"128": {102: 7}})
+    ratio = bert_counts.forward_flops(config, all_102) / bert_counts.forward_flops(config, at_edges)
+    assert 0.985 < ratio < 0.995
+    unknown = dict(at_edges, pairs_unknown="text.tokens disagrees")
+    assert bert_counts.forward_flops(config, unknown) is None
+    assert bert_counts.kernel_work(config, "flash_attention", unknown) is None

@@ -20,7 +20,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from bench_checkout import ROOT  # noqa: E402
+from bench_checkout import ROOT, job_lengths_by_edge  # noqa: E402
 from deepseek_v2_tiny import (  # noqa: E402
     an_expert_slot_dropped,
     published_config,
@@ -131,6 +131,52 @@ def test_kernel_work_of_both_kernels():
     flops, bytes_ = counts.kernel_work(config, "moe_grouped_matmul", work)
     assert flops == pytest.approx(tokens * 1.5 * 4 * 2 * EXPERT)
     assert counts.kernel_work(config, "selective_scan", work) is None
+
+
+def test_attention_pairs_are_those_of_the_rows_real_lengths():
+    """Two windows dispatched at 2,048 (one full, one of 1,500 tokens)
+    and a remainder of 300 at 1,024: the scores at half the square of
+    the real lengths, everything a token at the edges."""
+    config = published_config()
+    at_edges = {"rows": 3, "rows_by_length": {"1024": 1, "2048": 2}, "slots_held": 0}
+    work = dict(at_edges, lengths_by_edge={"1024": {300: 1}, "2048": {2048: 1, 1500: 1}})
+    squares = 300**2 + 2048**2 + 1500**2
+    scores = 5 * HEADS * (192 + 128) * squares
+    assert counts.score_flops(config, work) == pytest.approx(scores)
+    tokens = 1024 + 2 * 2048
+    per_token = 2 * (5 * MLA + DENSE_MLP + 4 * (SHARED + ROUTER))
+    assert counts.forward_flops(config, work) == pytest.approx(tokens * per_token + scores)
+    flops, bytes_ = counts.kernel_work(config, "flash_attention", work)
+    assert flops == pytest.approx(scores)
+    assert bytes_ == pytest.approx(tokens * 5 * HEADS * 640 * 2)  # a dispatched token
+    # without real lengths the rows are as long as their edges, as before
+    full = dict(at_edges, lengths_by_edge={"1024": {1024: 1}, "2048": {2048: 2}})
+    assert counts.forward_flops(config, at_edges) == counts.forward_flops(config, full)
+    assert counts.score_flops(config, at_edges) == pytest.approx(
+        5 * HEADS * 320 * (1024**2 + 2 * 2048**2)
+    )
+    # real lengths that could not be squared with the counters: no pair term
+    unknown = dict(at_edges, pairs_unknown="text.tokens disagrees")
+    assert counts.forward_flops(config, unknown) is None
+    assert counts.kernel_work(config, "flash_attention", unknown) is None
+    assert counts.kernel_work(config, "moe_grouped_matmul", dict(unknown, dispatches=1))
+
+
+def test_a_job_of_the_cell_counts_0_898_of_the_edges_scores():
+    """The cell's own job: 42 full windows and 18 remainders (9 at each
+    edge), as `embed-windows.json` gives them."""
+    config = published_config()
+    by_edge = job_lengths_by_edge("embed-windows", (1024, 2048))
+    at_edges = {"rows": 60, "rows_by_length": {"1024": 9, "2048": 51}}
+    work = dict(at_edges, lengths_by_edge=by_edge)
+    assert {e: sum(of.values()) for e, of in by_edge.items()} == at_edges["rows_by_length"]
+    assert by_edge["2048"][2048] == 42
+    assert counts.score_flops(config, work) / counts.score_flops(
+        config, at_edges
+    ) == pytest.approx(0.8984, abs=1e-4)
+    assert counts.forward_flops(config, work) / counts.forward_flops(
+        config, at_edges
+    ) == pytest.approx(0.9861, abs=1e-4)  # the routed experts at their expectation
 
 
 def _xla_flops(fn, *shapes):

@@ -29,7 +29,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from bench_checkout import ROOT  # noqa: E402
+from bench_checkout import ROOT, job_lengths_by_edge  # noqa: E402
 from deepseek_v32_tiny import (  # noqa: E402
     published_config,
     relu_dropped,
@@ -214,6 +214,111 @@ def test_kernel_work_of_the_three_kernels():
     assert bytes_ == pytest.approx(rows + matrices)
     assert counts.kernel_work(config, "selective_scan", work) is None
     assert counts.KERNELS == ("flash_attention", "dsa_index_scores", "moe_grouped_matmul")
+
+
+# -- pairs at the rows' real lengths, tokens as dispatched ----------------------
+
+C = counts.causal_pairs
+PAIR = (192 + 128) * HEADS * 2  # operations a selected pair and layer
+INDEX_PAIR = 64 * 128 * 2  # a causal pair and layer, in the indexer
+
+
+def _one(edge, real):
+    return {"rows": 1, "rows_by_length": {str(edge): 1},
+            "lengths_by_edge": {str(edge): {real: 1}}, "slots_held": 0}
+
+
+@pytest.mark.parametrize(
+    "edge, real, selected, indexed",
+    [
+        # past its 2,048th token a query selects 2,048 keys: 642 of them do
+        (8192, 2690, C(2048) + 642 * 2048, C(2690)),
+        # a short row in a bucket that has an indexer runs it, and selects
+        # every causal key
+        (8192, 1500, C(1500), C(1500)),
+        # the same row in a bucket within `index_topk`: no indexer is built
+        (2048, 1500, C(1500), 0),
+        (16384, 16384, C(2048) + 14336 * 2048, C(16384)),
+        (16384, 0, 0, 0),  # nothing of a row's own: no pair at all
+    ],
+)
+def test_a_rows_pairs_are_those_of_its_real_length(edge, real, selected, indexed):
+    config = published_config()
+    work = _one(edge, real)
+    assert counts.score_flops(config, work) == pytest.approx(5 * selected * PAIR)
+    assert counts.indexed_pairs(config, work) == indexed
+    assert counts.index_flops(config, work) == pytest.approx(5 * indexed * INDEX_PAIR)
+    # what grows with the tokens stays at the edge: projections (the
+    # indexer's where the bucket has one), MLPs, the router
+    selects = edge > 2048
+    dense = edge * 2 * (
+        5 * (MLA + (INDEXER if selects else 0)) + DENSE_MLP + 4 * (EXPERT + ROUTER)
+    )
+    assert counts.forward_flops(config, work) == pytest.approx(
+        dense + 5 * selected * PAIR + 5 * indexed * INDEX_PAIR
+    )
+    flops, bytes_ = counts.kernel_work(config, "flash_attention", work)
+    assert flops == pytest.approx(5 * selected * PAIR)
+    # q, k, v and the result a dispatched token; the selection's byte a
+    # causal pair of the real tokens where the bucket selects
+    assert bytes_ == pytest.approx(5 * (edge * HEADS * 640 * 2 + indexed))
+    flops, bytes_ = counts.kernel_work(config, "dsa_index_scores", work)
+    assert flops == pytest.approx(5 * indexed * INDEX_PAIR)
+    tokens = edge if selects else 0
+    assert bytes_ == pytest.approx(
+        5 * (tokens * ((64 * 128 + 128) * 2 + 64 * 4) + indexed * 4)
+    )
+
+
+def _job_of_the_cell():
+    """A job's ten live rows by edge, as the driver's `work` gives them."""
+    by_edge = job_lengths_by_edge("embed-long-docs", (8192, 16384))
+    return {"rows": 10, "rows_by_length": {"8192": 5, "16384": 5}, "lengths_by_edge": by_edge}
+
+
+def test_a_job_of_the_cell_has_151_5_m_selected_and_454_7_m_causal_pairs():
+    config = published_config()
+    job = _job_of_the_cell()
+    at_edges = {k: v for k, v in job.items() if k != "lengths_by_edge"}
+    assert sorted(n for of in job["lengths_by_edge"].values() for n in of) == [
+        2690, 3797, 4343, 5361, 6453, 8408, 10121, 12494, 14172, 16384,
+    ]
+    assert counts.score_flops(config, job) == pytest.approx(5 * 151_527_424 * PAIR)
+    assert counts.indexed_pairs(config, job) == 454_745_446
+    # what the padded edges counted, and a kernel that skips the padding
+    # does not run
+    assert counts.score_flops(config, at_edges) == pytest.approx(5 * 230_696_960 * PAIR)
+    assert counts.indexed_pairs(config, at_edges) == 838_922_240
+    assert counts.score_flops(config, job) / counts.score_flops(
+        config, at_edges
+    ) == pytest.approx(0.6568, abs=1e-4)
+    assert counts.index_flops(config, job) / counts.index_flops(
+        config, at_edges
+    ) == pytest.approx(0.5421, abs=1e-4)
+    assert counts.forward_flops(config, job) / counts.forward_flops(
+        config, at_edges
+    ) == pytest.approx(0.8866, abs=1e-4)
+    # the index kernel stays bound by its operations
+    flops, bytes_ = counts.kernel_work(config, "dsa_index_scores", job)
+    assert flops / 197e12 > 5 * bytes_ / 819e9
+
+
+def test_work_without_real_lengths_reads_the_edges_and_unknown_pairs_read_nothing():
+    config = published_config()
+    plain = {"rows": 5, "rows_by_length": {"8192": 2, "16384": 3}, "slots_held": 0}
+    full = dict(plain, lengths_by_edge={"8192": {8192: 2}, "16384": {16384: 3}})
+    assert counts.forward_flops(config, plain) == counts.forward_flops(config, full)
+    for kernel in ("flash_attention", "dsa_index_scores"):
+        assert counts.kernel_work(config, kernel, plain) == counts.kernel_work(config, kernel, full)
+    unknown = dict(plain, pairs_unknown="text.tokens disagrees")
+    assert counts.forward_flops(config, unknown) is None
+    assert counts.kernel_work(config, "flash_attention", unknown) is None
+    assert counts.kernel_work(config, "dsa_index_scores", unknown) is None
+    # the grouped product has no pair term, and is counted all the same
+    measured = dict(unknown, slots_held=1000, dispatches=5)
+    assert counts.kernel_work(config, "moe_grouped_matmul", measured) == counts.kernel_work(
+        config, "moe_grouped_matmul", dict(plain, slots_held=1000, dispatches=5)
+    )
 
 
 def _xla_flops(fn, *shapes):

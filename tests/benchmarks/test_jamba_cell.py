@@ -16,7 +16,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from bench_checkout import ROOT  # noqa: E402
+from bench_checkout import ROOT, job_lengths_by_edge  # noqa: E402
 from jamba_tiny import published_config, tiny_config, write_weights  # noqa: E402
 
 sys.path.insert(0, ROOT)
@@ -88,10 +88,44 @@ def test_kernel_work_of_the_scan():
     flops, bytes_ = counts.kernel_work(config, "selective_scan", work)
     tokens = 2 * 1024 + 3 * 2048
     assert flops == pytest.approx(tokens * 26 * 9 * DI * N)
-    # h, dt, z in and y out at 2 bytes x 5,120; B and C at 4 bytes x 16
-    assert bytes_ == pytest.approx(tokens * 26 * (4 * DI * 2 + 2 * N * 4))
+    # h, dt, z in at the 4 bytes the kernel is handed and y out at 2, x
+    # 5,120; B and C at 4 bytes x 16
+    assert bytes_ == pytest.approx(tokens * 26 * (DI * (3 * 4 + 2) + 2 * N * 4))
     assert flops < 0.01 * counts.forward_flops(config, work)
     assert counts.kernel_work(config, "flash_attention", work) is None
+    # bound by its bytes, 1.75 times what 2 bytes each had said
+    assert bytes_ / 819e9 > 10 * flops / 197e12
+    assert bytes_ / (tokens * 26 * (4 * DI * 2 + 2 * N * 4)) == pytest.approx(1.7477, abs=1e-4)
+    # every term of it grows with the tokens: real lengths change nothing,
+    # and it is counted where the pairs are unknown
+    real = dict(work, lengths_by_edge={"1024": {300: 2}, "2048": {2048: 3}})
+    assert counts.kernel_work(config, "selective_scan", real) == (flops, bytes_)
+    unknown = dict(work, pairs_unknown="text.tokens disagrees")
+    assert counts.kernel_work(config, "selective_scan", unknown) == (flops, bytes_)
+    assert counts.forward_flops(config, unknown) is None
+
+
+def test_attention_pairs_are_those_of_the_rows_real_lengths():
+    """Two attention layers of 28: a window of 1,500 tokens dispatched at
+    2,048 counts its projections over 2,048 tokens and its two products
+    over half the square of 1,500."""
+    config = published_config()
+    per_token = 2 * (26 * (MAMBA_MATRICES + MLP) + 2 * (ATTENTION_MATRICES + MLP))
+    work = {"rows": 2, "rows_by_length": {"2048": 2},
+            "lengths_by_edge": {"2048": {2048: 1, 1500: 1}}}
+    products = 2 * 2 * 2560 * (2048**2 + 1500**2)  # 2 layers x 2 products x 2 / 2
+    assert counts.forward_flops(config, work) == pytest.approx(2 * 2048 * per_token + products)
+    assert counts.flops_per_row(config, 2048, 1500) == pytest.approx(
+        2048 * per_token + 2 * 2 * 2560 * 1500**2
+    )
+    assert counts.flops_per_row(config, 2048, 2048) == counts.flops_per_row(config, 2048)
+    # the cell's own job: 0.04% of the count is the padding's pairs
+    by_edge = job_lengths_by_edge("embed-windows", (1024, 2048))
+    at_edges = {"rows": 60, "rows_by_length": {"1024": 9, "2048": 51}}
+    ratio = counts.forward_flops(config, dict(at_edges, lengths_by_edge=by_edge)) / (
+        counts.forward_flops(config, at_edges)
+    )
+    assert ratio == pytest.approx(0.99964, abs=2e-5)
 
 
 def test_weights_are_made_leaf_by_leaf_in_two_bytes():

@@ -1,8 +1,7 @@
-"""`mla.query_blocks_run_pct`: the reader by hand, and the counters it
+"""`mla.query_blocks_run_pct`: the reader by hand, the counters it
 divides as the program counts them for a job of each cell that runs the
-latent kernel. The reader has no entry in BENCHMARK.json yet (PERF.md,
-Open questions): these tests hold what a `benchmark` PR would add one
-line for."""
+latent kernel, and its entry in BENCHMARK.json (PR 38; the reader is PR
+37's)."""
 
 import json
 import os
@@ -99,3 +98,19 @@ def test_a_job_of_each_cell_counts_what_the_issue_counted(config, traffic, block
     assert total["mla.query_blocks"] == 5 * blocks
     assert total["mla.query_blocks_run"] == 5 * run
     assert _read(total) == pytest.approx(100.0 * run / blocks)
+
+
+def test_the_entry_lists_the_cells_that_run_the_latent_kernel():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entries = [m for m in bench["per_layer"] if m["name"] == NAME]
+    assert len(entries) == 1
+    entry = dict(entries[0])
+    # a later cell that runs the kernel may join; these two stay
+    assert set(entry.pop("workloads")) >= {
+        "deepseek-v2-embed-windows", "deepseek-v3.2-exp-embed-long-docs",
+    }
+    assert entry == {
+        "name": NAME, "unit": "%", "better": "lower", "source": "program_counter",
+        "layer": "Kernel", "moves": "rows_per_s",
+    }

@@ -65,6 +65,56 @@ def test_busy_is_averaged_over_devices_and_gap_outside_annotations():
     assert {name for name, _ in r.gaps} == {"unattributed"}
 
 
+def test_a_gap_is_named_by_the_programs_innermost_span_and_else_by_the_harness():
+    """Three gaps of device 0 in a window 10..110: 10..20 (middle 15) under
+    the program's `tokenize` on one thread inside `result_wait` on another
+    and inside the envelope `executor.partition`; 50..80 (middle 65) under
+    no span of the program but the envelope, inside the harness's
+    `collect`; 90..110 (middle 100) under nothing but the window's own
+    annotation."""
+    span = lambda name, start, end, thread: Event(  # noqa: E731
+        HOST, thread, "sparkdl:" + name, start * MS, (end - start) * MS
+    )
+    events = [
+        Event(HOST, "main", "bench:window", 10 * MS, 100 * MS),
+        Event(HOST, "main", "bench:job", 10 * MS, 75 * MS),
+        Event(HOST, "main", "bench:collect", 12 * MS, 70 * MS),
+        span("executor.partition", 11, 84, "exec_0"),
+        span("result_wait", 12, 45, "exec_1"),
+        span("tokenize", 13, 19, "exec_0"),
+        span("ingest", 19.5, 20, "exec_0"),  # opens after the gap's middle
+        span("drain_wait", 82, 84, "drainer"),  # closes before the last gap's
+        Event(DEV0, OPS, "fusion.1", 20 * MS, 30 * MS),
+        Event(DEV0, OPS, "fusion.2", 80 * MS, 10 * MS),
+    ]
+    named = trace_reduce.reduce_window(
+        events, "bench:window", "bench:",
+        span_prefix="sparkdl:",
+        envelopes={"executor.map_partitions", "executor.partition"},
+    )
+    assert named.gaps == [
+        ("tokenize", pytest.approx(0.010)),
+        ("collect", pytest.approx(0.030)),
+        ("window", pytest.approx(0.020)),
+    ]
+    b = named.breakdown()
+    assert b["idle_gaps"][:3] == [
+        ["collect", pytest.approx(0.030)],
+        ["window", pytest.approx(0.020)],
+        ["tokenize", pytest.approx(0.010)],
+    ]
+    assert ["all:tokenize", pytest.approx(0.010)] in b["idle_gaps"]
+    # an envelope that is not left out covers every gap it spans, and
+    # without a span prefix the harness's annotations name them, as before
+    enveloped = trace_reduce.reduce_window(
+        events, "bench:window", "bench:", span_prefix="sparkdl:"
+    )
+    assert [name for name, _ in enveloped.gaps] == ["tokenize", "executor.partition", "window"]
+    plain = trace_reduce.reduce_window(events, "bench:window", "bench:")
+    assert [name for name, _ in plain.gaps] == ["collect", "collect", "window"]
+    assert [s for _, s in plain.gaps] == [s for _, s in named.gaps]
+
+
 def test_merge_unions_overlaps_and_drops_empty():
     assert trace_reduce.merge([(5, 7), (1, 3), (2, 4), (9, 9)]) == [
         (1, 4),
