@@ -724,6 +724,48 @@ _register(
 )
 
 
+
+def _afmoe_text_builder(size: str):
+    """Builder over models/afmoe.py presets: sliding-window and full
+    attention layers in one stack, gated GQA with QK-norm, and shared plus
+    routed experts, every expert held; ``embed`` is the mean final state
+    of a row's real tokens (and a column of counts, ``mf.row_counters``).
+    The ModelFunction is marked ``weights_as_arguments`` and reports
+    ``attention`` and ``window_attention`` ('flash' | 'dense') and
+    ``experts`` ('pallas' | 'ragged_dot'), all chosen at build time."""
+
+    def build(
+        spec: NamedTextModel, mode: str, dtype, weights_file, seed
+    ) -> ModelFunction:
+        from sparkdl_tpu.models import afmoe
+
+        return afmoe.afmoe_model_function(
+            size,
+            dtype=dtype,
+            seed=seed,
+            weights_file=weights_file,
+            name=f"{spec.name}[{mode}]",
+        )
+
+    return build
+
+
+# One chip's share of Trinity-Mini at its published widths (published
+# layers 1-5 of 32, all 128 experts, the whole vocabulary: the cut of
+# benchmarks/configs/trinity-mini.json), and the family at test size.
+_register(
+    NamedTextModel(
+        "trinity-mini", 131072, 2048, "jax",
+        _afmoe_text_builder("trinity-mini"), vocab_size=200192,
+    )
+)
+_register(
+    NamedTextModel(
+        "trinity-mini-tiny", 4096, 64, "jax",
+        _afmoe_text_builder("trinity-mini-tiny"), vocab_size=512,
+    )
+)
+
 def get_model(name: str):
     """The registered spec for ``name`` — a :class:`NamedImageModel` or
     :class:`NamedTextModel`; both expose ``model_function(mode=...)``

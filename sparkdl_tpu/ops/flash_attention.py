@@ -108,6 +108,73 @@ def _flash_kernel(
         _write_result(o_ref, l_ref, acc_ref)
 
 
+def _window_key_block(qi, ki, steps: int):
+    """The key block that step ``ki`` of query block ``qi`` names in the
+    window kernel's band of ``steps`` blocks, the diagonal last; a step
+    below block 0 names block 0, which the band's first live step reads,
+    so that nothing is fetched for it."""
+    return jnp.maximum(qi - (steps - 1) + ki, 0)
+
+
+def _window_kernel(
+    steps: int, scale: float, window: int, q_ref, k_ref, v_ref, mask_ref,
+    o_ref, m_ref, l_ref, acc_ref,
+):
+    """Grid = (B*H, num_q_blocks, steps): step ``ki`` of query block
+    ``qi`` reads key block ``qi - (steps - 1) + ki``, the band of blocks
+    that a query block's window reaches, the diagonal last. A step below
+    block 0 runs nothing. The diagonal block is masked by ``j <= i``, the
+    band's lowest by ``i - j < window`` where it can hold a key out of
+    the window, and the blocks between run unmasked."""
+    from jax.experimental import pallas as pl
+
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    last = steps - 1
+    key_block = qi - last + ki
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def _block(diagonal: bool, band: bool):
+        q = q_ref[0].astype(jnp.float32)  # [bq, dh]
+        k = k_ref[0].astype(jnp.float32)  # [bk, dh]
+        s = (
+            jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            * scale
+        )  # [bq, bk]
+        s = s + mask_ref[0]  # [1, bk] broadcasts over the q rows
+        if diagonal or band:
+            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = key_block * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen = (col <= row) if diagonal else (row - col < window)
+            if diagonal and band:
+                seen = seen & (row - col < window)
+            s = jnp.where(seen, s, NEG_INF)
+        _softmax_step(s, v_ref[0].astype(jnp.float32), m_ref, l_ref, acc_ref)
+
+    # whether the band's lowest block can hold a key out of the window
+    clipped = steps * bk > window
+    if steps == 1:
+        _block(True, clipped)
+    else:
+        live = key_block >= 0
+        pl.when(jnp.logical_and(ki == 0, live))(lambda: _block(False, clipped))
+        if steps > 2:
+            between = jnp.logical_and(ki > 0, ki < last)
+            pl.when(jnp.logical_and(between, live))(lambda: _block(False, False))
+        pl.when(ki == last)(lambda: _block(True, False))
+
+    @pl.when(ki == last)
+    def _finalize():
+        _write_result(o_ref, l_ref, acc_ref)
+
+
 def _softmax_step(s, v, m_ref, l_ref, acc_ref, rows=slice(None)):
     """One key block of the online softmax: scores s [bq, bk] and values
     v [bk, dv] folded into the running max, sum and accumulator, of the
@@ -154,6 +221,7 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
     causal: bool = False,
+    window: Optional[int] = None,
 ):
     """Blockwise-online softmax attention.
 
@@ -170,6 +238,14 @@ def flash_attention(
             (Lk == L, square blocks). Key blocks above the diagonal are
             skipped, and their index clamps to the diagonal's, so they
             are not fetched either.
+        window: with ``causal``, a sliding window: query i sees key j
+            iff 0 <= i - j < window (a multiple of the block). The grid's
+            key axis then walks only the band of ``min(nk, window /
+            block_k + 1)`` blocks that a query block's window reaches,
+            the diagonal last (:func:`_window_kernel`); a step below
+            block 0 is neither run nor fetched. The call is named
+            ``flash_attention_window``. None: the causal or plain kernel,
+            as it was before the option existed.
 
     Returns [B, H, L, Dh] in q's dtype.
     """
@@ -184,6 +260,13 @@ def flash_attention(
         raise ValueError(
             "causal attention wants Lk == L and square blocks, got "
             f"L={L}, Lk={Lk}, blocks {block_q}x{block_k}"
+        )
+    if window is not None and (
+        not causal or window < block_k or window % block_k
+    ):
+        raise ValueError(
+            f"a window of {window} wants causal attention and a multiple "
+            f"of the key block {block_k}"
         )
     mask2d = _key_mask(mask, B, Lk)
 
@@ -221,6 +304,12 @@ def flash_attention(
 
     nq = Lq_p // block_q
     nk = Lk_p // block_k
+    if window is not None:
+        out = _flash_window(
+            qf, kf, vf, mask3d, H, Hkv, min(nk, window // block_k + 1), scale,
+            window, block_q, interpret,
+        )
+        return out.reshape(B, H, Lq_p, Dh_p)[:, :, :L, :Dh]
 
     kernel = functools.partial(_flash_kernel, nk, scale)
     kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
@@ -267,6 +356,48 @@ def flash_attention(
 
     out = out.reshape(B, H, Lq_p, Dh_p)
     return out[:, :, :L, :Dh]
+
+
+def _flash_window(qf, kf, vf, mask3d, H, Hkv, steps, scale, window, block, interpret):
+    """The window kernel's call over :func:`flash_attention`'s flattened,
+    padded operands: q [B*H, L, Dh], k and v [B*Hkv, L, Dh], the key
+    mask [B, 1, L]; grid (B*H, L / block, ``steps``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, L, Dh = qf.shape
+    group = H // Hkv
+
+    def kv_block(bh, qi, ki):
+        head = (bh // H) * Hkv + (bh % H) // group
+        return head, _window_key_block(qi, ki, steps), 0
+
+    return pl.pallas_call(
+        functools.partial(_window_kernel, steps, scale, window),
+        grid=(BH, L // block, steps),
+        in_specs=[
+            pl.BlockSpec((1, block, Dh), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block, Dh), kv_block),
+            pl.BlockSpec((1, block, Dh), kv_block),
+            pl.BlockSpec(
+                (1, 1, block),
+                lambda bh, qi, ki: (bh // H, 0, _window_key_block(qi, ki, steps)),
+            ),
+        ],
+        out_specs=pl.BlockSpec((1, block, Dh), lambda bh, qi, ki: (bh, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, L, Dh), qf.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, 128), jnp.float32),  # running max
+            pltpu.VMEM((block, 128), jnp.float32),  # running sum
+            pltpu.VMEM((block, Dh), jnp.float32),  # output accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        # its own name: a trace tells the window layers from the full ones
+        name="flash_attention_window",
+    )(qf, kf, vf, mask3d)
 
 
 def packs(num_heads: Optional[int], head_dim: Optional[int]) -> bool:
@@ -848,11 +979,12 @@ def make_latent_attention_fn(
     return attention
 
 
-def dense_causal_attention(q, k, v, mask, dtype):
-    """What ``flash_attention(causal=True)`` computes, as dense einsums:
-    q [B, H, L, Dh] over k, v [B, Hkv, L, Dh], float32 scores and
-    softmax, an optional additive key mask. The build-time fallback off
-    the TPU and the kernel's test oracle."""
+def dense_causal_attention(q, k, v, mask, dtype, window=None):
+    """What ``flash_attention(causal=True, window=window)`` computes, as
+    dense einsums: q [B, H, L, Dh] over k, v [B, Hkv, L, Dh], float32
+    scores and softmax, an optional additive key mask; with ``window``,
+    query i sees key j iff 0 <= i - j < window. The build-time fallback
+    off the TPU and the kernel's test oracle."""
     B, H, L, Dh = q.shape
     Hkv = k.shape[1]
     qg = q.reshape(B, Hkv, H // Hkv, L, Dh)
@@ -861,7 +993,10 @@ def dense_causal_attention(q, k, v, mask, dtype):
     ) / np.sqrt(Dh)
     if mask is not None:
         s = s + mask.reshape(B, 1, 1, 1, L).astype(jnp.float32)
-    s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, NEG_INF)
+    seen = jnp.tril(jnp.ones((L, L), bool))
+    if window is not None:
+        seen = seen & ~jnp.tril(seen, -window)
+    s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, -1).astype(dtype)
     o = jnp.einsum(
         "bhgqk,bhkd->bhgqd", p, v, preferred_element_type=jnp.float32
@@ -879,6 +1014,7 @@ def make_flash_attention_fn(
     causal: bool = False,
     num_heads: Optional[int] = None,
     head_dim: Optional[int] = None,
+    window: Optional[int] = None,
 ):
     """Returns an attention fn with the ``dense_attention`` signature
     (q, k, v, mask, dtype) — drop-in for BertEncoder(attention_fn=...).
@@ -898,8 +1034,14 @@ def make_flash_attention_fn(
     :func:`flash_attention_packed` and returns [B, L, H*Dh]; its
     ``.layout`` is 'packed'. Every other shape, and a caller that names
     none, gets the blocked kernel over [B, H, L, Dh] ('heads', what a
-    function without ``.layout`` takes)."""
+    function without ``.layout`` takes). ``window`` (with ``causal``):
+    the sliding-window kernel, ``flash_attention_window``, and off the TPU
+    ``dense_causal_attention`` with that window."""
     if not interpret and jax.default_backend() != "tpu":
+        if window is not None:
+            windowed = functools.partial(dense_causal_attention, window=window)
+            windowed.kind = "dense"
+            return windowed
         if causal:
             return dense_causal_attention
         from sparkdl_tpu.models.bert import dense_attention
@@ -935,6 +1077,7 @@ def make_flash_attention_fn(
             block_k=block_k,
             interpret=interpret,
             causal=causal,
+            window=window,
         )
         return out.astype(dtype)
 

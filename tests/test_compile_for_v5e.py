@@ -275,3 +275,120 @@ def test_latent_flash_compiles_at_the_cells_shapes(shape, rows, length, selected
     given = 2 * wide.size * 2 + rows * length * 128 * 2 + selected * rows * length * length
     assert memory.argument_size_in_bytes - given in ((0, 512)[lengths],)
     assert memory.output_size_in_bytes == rows * length * 128 * 128 * 2
+
+
+# -- Trinity-Mini: the window kernel and the whole program ----------------------
+
+
+def _mosaic_text(compiled_or_lowered_text: str) -> str:
+    """The Mosaic module of the one kernel in a lowered program's text, as
+    MLIR without its debug locations (a line moved in the kernel's source
+    file changes those and nothing else)."""
+    import base64
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    body = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', compiled_or_lowered_text)
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        tpu.register_dialect(ctx)
+        module = ir.Module.parse(base64.b64decode(body.group(1)))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def test_window_none_lowers_jambas_kernel_as_the_parent_did(shape):
+    """The causal kernel at Jamba's cell's shapes (8 rows, 20 query heads
+    over 1, blocks of 512) is the parent commit's Mosaic module to the
+    byte, debug locations aside: the window mode added nothing to it.
+    The hash is the parent's (PR 38's tree), taken when the mode was
+    added."""
+    import hashlib
+
+    q = shape((ROWS, HEADS, 2048, HEAD_DIM), jnp.bfloat16)
+    kv = shape((ROWS, 1, 2048, HEAD_DIM), jnp.bfloat16)
+    text = (
+        jax.jit(
+            lambda q, k, v: flash_attention(
+                q, k, v, block_q=512, block_k=512, causal=True, window=None
+            )
+        )
+        .lower(q, kv, kv)
+        .as_text()
+    )
+    mosaic = _mosaic_text(text)
+    assert hashlib.sha256(mosaic.encode()).hexdigest()[:16] == "faa96c9389a3baa5"
+
+
+@pytest.mark.parametrize("length", [8192, 16384])
+def test_window_kernel_compiles_at_the_cells_shapes(shape, length):
+    """A sliding layer of `trinity-mini-embed-long-docs`: one row, 32
+    query heads over 4 key/value heads of 128, a window of 2,048 in
+    blocks of 512: a band of 5 key steps. Nothing is repeated or padded
+    in HBM; the call carries its own name."""
+    bf16 = jnp.bfloat16
+    q = shape((1, 32, length, HEAD_DIM), bf16)
+    kv = shape((1, 4, length, HEAD_DIM), bf16)
+    compiled = (
+        jax.jit(
+            lambda q, k, v: flash_attention(
+                q, k, v, block_q=512, block_k=512, causal=True, window=2048
+            )
+        )
+        .lower(q, kv, kv)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"%flash_attention_window[.\w]* = ", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("length", [8192, 16384])
+def test_trinity_programs_fit_the_chip(shape, length):
+    """The whole program of a bucket of the Trinity-Mini cell (one row,
+    five layers at the published widths, every expert held, weights as
+    arguments): the window kernel in the four sliding layers, the causal
+    kernel in the full one, three grouped products an expert layer, and
+    no conditional (every expert held: one slot buffer). The weights and
+    the larger bucket's temporaries lie well under the chip's 16 GiB."""
+    from sparkdl_tpu.models import afmoe, deepseek_v2
+    from sparkdl_tpu.models.jamba import _unflatten
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    config, bf16 = afmoe.trinity_mini(), jnp.bfloat16
+    leaves = {
+        p: shape(s, deepseek_v2._leaf_dtype(p, s, bf16))
+        for p, s in afmoe.param_shapes(config).items()
+    }
+
+    def attention(window):
+        def fn(q, k, v, mask, dtype):
+            return flash_attention(
+                q, k, v, mask, block_q=512, block_k=512, causal=True, window=window
+            ).astype(dtype)
+
+        return fn
+
+    def program(p, ids):
+        return afmoe.forward(
+            config, p, ids, dtype=bf16, attention_fn=attention(None),
+            window_attention_fn=attention(2048), experts_fn=grouped_matmul,
+        )
+
+    compiled = (
+        jax.jit(program).lower(_unflatten(leaves), shape((1, length), jnp.int32)).compile()
+    )
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_attention_window[.\w]* = ", text)) == 4
+    assert len(re.findall(r"%flash_attention(?:\.\d+)? = ", text)) == 1
+    assert len(re.findall(r"%moe_grouped_matmul[.\w]* = ", text)) == 12
+    assert len(re.findall(r" conditional\(", text)) == 0
+    memory = compiled.memory_analysis()
+    weights = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves.values())
+    assert weights == pytest.approx(7.665e9, rel=1e-3)
+    assert memory.argument_size_in_bytes - weights < (1 << 20)
+    # every slot of the row's 8 x 4 expert layers in one buffer: 2.2 GB at
+    # 16,384 tokens, half that at 8,192
+    assert memory.temp_size_in_bytes < length * 160 * 1024
+    assert weights + memory.temp_size_in_bytes < 0.65 * 16 * (1 << 30)
