@@ -55,6 +55,7 @@ import numpy as np
 
 from sparkdl_tpu.models import deepseek_v2 as v2
 from sparkdl_tpu.models.jamba import _dense, _rms, _unflatten, load_flat
+from sparkdl_tpu.ops.moe_combine import gather_combine
 from sparkdl_tpu.utils.profiler import scope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -281,7 +282,7 @@ def _attention(config: AfmoeConfig, p, u, tables, attention_fn):
 
 def forward(
     config: AfmoeConfig, params, ids, *, dtype, attention_fn, window_attention_fn,
-    experts_fn,
+    experts_fn, combine_fn=gather_combine,
 ):
     """ids [B, L] int32, zero-padded on the right -> (embeddings
     [B, hidden] float32, slots that fell on held experts [B] int32, how
@@ -313,7 +314,9 @@ def forward(
             if i < config.num_dense_layers:
                 x = x + _rms(v2._swiglu(p["mlp"], u.astype(dtype)), p["norm_post_mlp"], eps)
                 continue
-        routed, count, fits = v2._routed(config, p["moe"], u, real, experts_fn)
+        routed, count, fits = v2._routed(
+            config, p["moe"], u, real, experts_fn, combine_fn=combine_fn
+        )
         with scope("mlp"):
             shared = v2._swiglu(p["moe"]["shared"], u.astype(dtype))
             x = x + _rms(shared + routed, p["norm_post_mlp"], eps)
@@ -331,15 +334,17 @@ def afmoe_model_function(
     attention_fn=None,
     window_attention_fn=None,
     experts_fn=None,
+    combine_fn=None,
     name: Optional[str] = None,
 ):
     """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
     tuples, as TextEmbedder feeds them). ``attention_fn`` (the full
-    layers), ``window_attention_fn`` (the sliding ones) and ``experts_fn``
-    default to the build-time choice of ``make_flash_attention_fn(causal=
-    True)``, the same with ``window=sliding_window``, both in blocks of
-    512 as Jamba's, and ``make_grouped_matmul_fn()``: the Pallas kernels
-    on TPU.
+    layers), ``window_attention_fn`` (the sliding ones), ``experts_fn``
+    and ``combine_fn`` default to the build-time choice of
+    ``make_flash_attention_fn(causal=True)``, the same with
+    ``window=sliding_window``, both in blocks of 512 as Jamba's,
+    ``make_grouped_matmul_fn()`` and ``make_moe_combine_fn()``: the
+    Pallas kernels on TPU.
 
     The program's result is [B, hidden + 3]: the embedding and DeepSeek's
     three row counters (``moe.slots_held``, ``moe.buffer_sized``,
@@ -350,6 +355,7 @@ def afmoe_model_function(
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
+    from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn
 
     if size not in _SIZES:
         raise ValueError(f"Unknown AFMoE size {size!r}; supported: {sorted(_SIZES)}")
@@ -362,6 +368,8 @@ def afmoe_model_function(
         )
     if experts_fn is None:
         experts_fn = make_grouped_matmul_fn()
+    if combine_fn is None:
+        combine_fn = make_moe_combine_fn()
     if weights_file:
         params = load_flat(param_shapes(config), weights_file, dtype, v2._leaf_dtype)
     else:
@@ -372,6 +380,7 @@ def afmoe_model_function(
         out, slots_held, sized = forward(
             config, p, ids, dtype=dtype, attention_fn=attention_fn,
             window_attention_fn=window_attention_fn, experts_fn=experts_fn,
+            combine_fn=combine_fn,
         )
         with scope("pool"):
             sized = jnp.broadcast_to(sized, slots_held.shape)
@@ -384,6 +393,7 @@ def afmoe_model_function(
     mf.attention = getattr(attention_fn, "kind", "custom")
     mf.window_attention = getattr(window_attention_fn, "kind", "custom")
     mf.experts = getattr(experts_fn, "kind", "custom")
+    mf.combine = getattr(combine_fn, "kind", "custom")
     mf.row_counters = ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
     sliding = config.sliding_layers
     mf.dispatched_token_counters = {

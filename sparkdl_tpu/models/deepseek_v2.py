@@ -49,9 +49,10 @@ routing weights and the combine are float32. Attention is
 ``ops/flash_attention.py:flash_attention_latent`` (causal, over the
 projections' own arrays; handed each row's length where it takes them,
 ``row_lengths``, so that query blocks of padding alone are not run) and
-the routed experts ``ops/grouped_matmul.py``: the Pallas
-kernels on TPU, plain ``jax.numpy`` elsewhere, chosen at build time and
-reported as ``mf.attention`` and ``mf.experts``. Layers are unrolled
+the routed experts ``ops/grouped_matmul.py`` and their combine
+``ops/moe_combine.py``: the Pallas kernels on TPU, plain ``jax.numpy``
+elsewhere, chosen at build time and reported as ``mf.attention``,
+``mf.experts`` and ``mf.combine``. Layers are unrolled
 into one program with every layer's weights an argument of its own
 (``weights_as_arguments``), as ``models/jamba.py`` does.
 """
@@ -74,6 +75,7 @@ from sparkdl_tpu.models.jamba import (
     _unflatten,
     load_flat,
 )
+from sparkdl_tpu.ops.moe_combine import gather_combine
 from sparkdl_tpu.utils.profiler import scope
 
 
@@ -449,8 +451,6 @@ _ROW_TILE = 256
 #: PR 32); a load over the buffer costs the worst-case arm's time and no
 #: slot, so the low end of what covers every reading is the one to take
 _CAPACITY_MARGIN = 1.25
-#: gathered parts of the worst-case arm's combine summed a pass
-_COMBINE_AT_ONCE = 3
 
 
 def slot_capacity(config: DeepseekV2Config, tokens: int) -> int:
@@ -465,12 +465,13 @@ def slot_capacity(config: DeepseekV2Config, tokens: int) -> int:
 
 
 def _experts_and_combine(
-    rows, at_once, experts_fn, experts, flat, order, sizes, slot, weights, held
+    rows, experts_fn, experts, flat, order, sizes, slot, weights, held, *,
+    combine_fn=gather_combine,
 ):
     """The held slots' three products over a buffer of ``rows`` slot rows
     (static; the sorted slots' first ``rows``, which must hold every held
     one) and their weighted sum back in token order [tokens, hidden]
-    float32, ``at_once`` gathered parts a pass."""
+    float32 by ``combine_fn`` (``ops/moe_combine.py``)."""
     top_k = weights.shape[1]
     with scope("moe.gather"):
         x = flat[order[:rows] // top_k]  # [rows, hidden]
@@ -480,14 +481,7 @@ def _experts_and_combine(
         y = experts_fn((_silu(gate) * up).astype(x.dtype), experts["down"], sizes)
     with scope("moe.combine"):
         # back to (token, k) order; what the kernel left unwritten is not read
-        at = jnp.minimum(slot, rows - 1)
-        out = jnp.zeros((flat.shape[0], y.shape[1]), jnp.float32)
-        for j in range(top_k):
-            part = y[at[:, j]] * weights[:, j, None]
-            out = out + jnp.where(held[:, j, None], part, 0.0)
-            if (j + 1) % at_once == 0 and j + 1 < top_k:
-                y, out = jax.lax.optimization_barrier((y, out))
-        return out
+        return combine_fn(y, jnp.where(held, slot, -1), weights)
 
 
 def _experts_in_chunks(
@@ -526,11 +520,15 @@ def _experts_in_chunks(
     return out
 
 
-def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
+def _routed(
+    config: DeepseekV2Config, p, u, real, experts_fn, *, combine_fn=gather_combine
+):
     """u [B, L, hidden] float32 (the norm's output), real [B, L] bool ->
     (the held experts' part of the routed sum [B, L, hidden] float32,
     how many of each row's slots fell on held experts [B] int32, whether
-    the sized slot buffer held the load, a bool scalar).
+    the sized slot buffer held the load, a bool scalar). ``combine_fn``
+    sums the rows back in token order (``ops/moe_combine.py``; the
+    worst-case arm in passes, ``worst_case_chunk_rows``, adds its own).
 
     The slot buffer is ``slot_capacity`` rows, and the worst-case buffer
     of every slot is the other arm of a ``lax.cond`` on the measured load:
@@ -565,18 +563,18 @@ def _routed(config: DeepseekV2Config, p, u, real, experts_fn):
         )
         slots, capacity = tokens * top_k, slot_capacity(config, tokens)
 
-        # the worst-case arm: all k gathered parts at once are 2 GB beside its
-        # y; one at a time (a loop carrying the sum) made k passes over it,
-        # 4.40 s a job against 4.19 on the chip (PERF.md, PR 32). The barrier
-        # orders the passes. The sized arm's y is a third of that: one pass.
-        full = functools.partial(_experts_and_combine, slots, _COMBINE_AT_ONCE, experts_fn)
+        full = functools.partial(
+            _experts_and_combine, slots, experts_fn, combine_fn=combine_fn
+        )
         chunk = config.worst_case_chunk_rows
         if chunk and slots > chunk:
             full = functools.partial(_experts_in_chunks, chunk, experts_fn)
         if capacity == slots:
             fits, out = jnp.zeros((), bool), full(*operands)
         else:
-            sized = functools.partial(_experts_and_combine, capacity, top_k, experts_fn)
+            sized = functools.partial(
+                _experts_and_combine, capacity, experts_fn, combine_fn=combine_fn
+            )
 
             def worst_case(*operands):
                 with scope("moe.worst_case"):
@@ -595,7 +593,10 @@ def _mean_real_state(x, real):
     return jnp.sum(jnp.where(real[..., None], x, 0.0), 1) / count[:, None]
 
 
-def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, experts_fn):
+def forward(
+    config: DeepseekV2Config, params, ids, *, dtype, attention_fn, experts_fn,
+    combine_fn=gather_combine,
+):
     """ids [B, L] int32, zero-padded on the right -> (embeddings
     [B, hidden] float32, slots that fell on held experts [B] int32, how
     many expert layers worked on the sized slot buffer, an int32 scalar)."""
@@ -621,7 +622,9 @@ def forward(config: DeepseekV2Config, params, ids, *, dtype, attention_fn, exper
             if i < config.first_k_dense:
                 x = x + _swiglu(p["mlp"], u.astype(dtype))
                 continue
-        routed, count, fits = _routed(config, p["moe"], u, real, experts_fn)
+        routed, count, fits = _routed(
+            config, p["moe"], u, real, experts_fn, combine_fn=combine_fn
+        )
         with scope("mlp"):
             x = x + _swiglu(p["moe"]["shared"], u.astype(dtype))
         with scope("moe.routed"):
@@ -670,13 +673,14 @@ def deepseek_v2_model_function(
     weights_file: Optional[str] = None,
     attention_fn=None,
     experts_fn=None,
+    combine_fn=None,
     name: Optional[str] = None,
 ):
     """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
-    tuples, as TextEmbedder feeds them). ``attention_fn`` and
-    ``experts_fn`` default to the build-time choice of
-    ``make_latent_attention_fn(heads, scale)`` and
-    ``make_grouped_matmul_fn()``: the Pallas kernels on TPU.
+    tuples, as TextEmbedder feeds them). ``attention_fn``, ``experts_fn``
+    and ``combine_fn`` default to the build-time choice of
+    ``make_latent_attention_fn(heads, scale)``, ``make_grouped_matmul_fn()``
+    and ``make_moe_combine_fn()``: the Pallas kernels on TPU.
 
     The program's result is [B, hidden + 3]: the embedding and, named by
     ``mf.row_counters``, three more columns counted on the device and
@@ -693,6 +697,7 @@ def deepseek_v2_model_function(
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
+    from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn
 
     if size not in _SIZES:
         raise ValueError(
@@ -708,6 +713,8 @@ def deepseek_v2_model_function(
         )
     if experts_fn is None:
         experts_fn = make_grouped_matmul_fn()
+    if combine_fn is None:
+        combine_fn = make_moe_combine_fn()
     if weights_file:
         params = load_flat(param_shapes(config), weights_file, dtype, _leaf_dtype)
     else:
@@ -717,7 +724,7 @@ def deepseek_v2_model_function(
         ids = x[0] if isinstance(x, (tuple, list)) else x
         out, slots_held, sized = forward(
             config, p, ids, dtype=dtype, attention_fn=attention_fn,
-            experts_fn=experts_fn,
+            experts_fn=experts_fn, combine_fn=combine_fn,
         )
         with scope("pool"):
             sized = jnp.broadcast_to(sized, slots_held.shape)
@@ -732,6 +739,7 @@ def deepseek_v2_model_function(
     mf.vocab_size = config.vocab_size
     mf.attention = getattr(attention_fn, "kind", "custom")
     mf.experts = getattr(experts_fn, "kind", "custom")
+    mf.combine = getattr(combine_fn, "kind", "custom")
     mf.row_counters = ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
     # per dispatched token (pad rows and pad tokens too), and per real one
     mf.dispatched_token_counters = {"mla.attention_tokens": config.num_layers}

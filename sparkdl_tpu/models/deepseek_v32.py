@@ -61,6 +61,7 @@ import numpy as np
 from sparkdl_tpu.models import deepseek_v2 as v2
 from sparkdl_tpu.models.deepseek_v2 import DeepseekV2Config
 from sparkdl_tpu.models.jamba import _dense, _rms, _unflatten, load_flat
+from sparkdl_tpu.ops.moe_combine import gather_combine
 from sparkdl_tpu.utils.profiler import scope
 
 
@@ -224,7 +225,7 @@ def causal_pairs(real):
 
 def forward(
     config: DeepseekV32Config, params, ids, *, dtype, attention_fn, experts_fn,
-    indexer_fn,
+    indexer_fn, combine_fn=gather_combine,
 ):
     """ids [B, L] int32, zero-padded on the right -> (embeddings
     [B, hidden] float32, slots that fell on held experts [B] int32, how
@@ -272,7 +273,9 @@ def forward(
             if i < config.first_k_dense:
                 x = x + v2._swiglu(p["mlp"], u.astype(dtype))
                 continue
-        routed, count, fits = v2._routed(config, p["moe"], u, real, experts_fn)
+        routed, count, fits = v2._routed(
+            config, p["moe"], u, real, experts_fn, combine_fn=combine_fn
+        )
         with scope("mlp"):
             x = x + v2._swiglu(p["moe"]["shared"], u.astype(dtype))
         with scope("moe.routed"):
@@ -297,6 +300,7 @@ def deepseek_v32_model_function(
     attention_fn=None,
     experts_fn=None,
     indexer_fn=None,
+    combine_fn=None,
     name: Optional[str] = None,
 ):
     """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
@@ -320,6 +324,7 @@ def deepseek_v32_model_function(
     from sparkdl_tpu.ops.dsa_indexer import make_indexer_fn
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
+    from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn
 
     if size not in _SIZES:
         raise ValueError(
@@ -334,6 +339,8 @@ def deepseek_v32_model_function(
         experts_fn = make_grouped_matmul_fn()
     if indexer_fn is None:
         indexer_fn = make_indexer_fn(config.index_n_heads, config.index_topk)
+    if combine_fn is None:
+        combine_fn = make_moe_combine_fn()
     if weights_file:
         params = load_flat(param_shapes(config), weights_file, dtype, v2._leaf_dtype)
     else:
@@ -343,7 +350,7 @@ def deepseek_v32_model_function(
         ids = x[0] if isinstance(x, (tuple, list)) else x
         out, slots_held, sized, pairs = forward(
             config, p, ids, dtype=dtype, attention_fn=attention_fn,
-            experts_fn=experts_fn, indexer_fn=indexer_fn,
+            experts_fn=experts_fn, indexer_fn=indexer_fn, combine_fn=combine_fn,
         )
         with scope("pool"):
             sized = jnp.broadcast_to(sized, slots_held.shape)
@@ -373,6 +380,7 @@ def deepseek_v32_model_function(
     mf.vocab_size = config.vocab_size
     mf.attention = getattr(attention_fn, "kind", "custom")
     mf.experts = getattr(experts_fn, "kind", "custom")
+    mf.combine = getattr(combine_fn, "kind", "custom")
     mf.indexer = getattr(indexer_fn, "kind", "custom")
     mf.row_counters = (
         "moe.slots_held", "moe.buffer_sized", "moe.buffer_full",

@@ -20,6 +20,7 @@ from sparkdl_tpu.ops.flash_attention import (
     flash_attention_packed,
 )
 from sparkdl_tpu.models import jamba
+from sparkdl_tpu.ops import moe_combine
 from sparkdl_tpu.ops.selective_scan import selective_scan
 
 ROWS, D_INNER, D_STATE = 8, 5120, 16  # a dispatch of the cell; Jamba2-3B
@@ -153,8 +154,10 @@ def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
     buffer and the worst-case one are the arms of one conditional. The
     arms never live together, so the layer's temporaries are the larger
     arm's and not their sum; the 2.8 GB of expert matrices go into the
-    conditional as they are; and the kernels inside it keep the name the
-    trace's readers look for."""
+    conditional as they are; the kernels inside it keep the name the
+    trace's readers look for; and the combine kernel reads the last
+    product's rows where they lie, through a bitcast, so that its event
+    names no product kernel."""
     from sparkdl_tpu.models import deepseek_v2
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -173,7 +176,9 @@ def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
     assert capacity == 15 * length == 1.25 * slots / 4
 
     def layer(p, u, real):
-        return deepseek_v2._routed(config, p, u, real, grouped_matmul)
+        return deepseek_v2._routed(
+            config, p, u, real, grouped_matmul, combine_fn=moe_combine.moe_combine
+        )
 
     compiled = (
         jax.jit(layer)
@@ -184,20 +189,124 @@ def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
     assert len(re.findall(r" conditional\(", text)) == 1
     # gate, up and down in each arm: `%moe_grouped_matmul.N = ... custom-call`
     assert len(re.findall(r"%moe_grouped_matmul[.\w]* = ", text)) == 6
+    # the combine, one an arm, reads the down products' rows where they
+    # lie: nothing turns or copies a buffer of slot rows between. A
+    # kernel's trace event lists its operands, and the readers of the
+    # product's time take every event that names `%moe_grouped_matmul`:
+    # the combine takes the rows through a bitcast, the same bytes
+    combines = re.findall(r"%moe_combine[.\w]* = .*", text)
+    assert len(combines) == 2
+    assert not re.findall(rf"= f32\[\d+,(?:1,)?{hidden}\]\S* (?:copy|transpose)\(", text)
+    tiles = rf"%bitcast[.\w]* = f32\[\d+,{hidden // 128},8,1,128\]\S* bitcast\(%moe_grouped_matmul"
+    assert len(re.findall(tiles, text)) == 2
+    assert not [line for line in combines if "%moe_grouped_matmul" in line]
     made = re.findall(
         r"= bf16\[40,(?:5120,1536|1536,5120)\]\S* (?!parameter|get-tuple-element)(\w[\w-]*)\(",
         text,
     )
     assert made == [], made
-    # the worst-case arm at its fullest: its y in float32 and three
-    # gathered parts of the combine (the sum is the layer's result, no
-    # temporary); the sized arm (y a third of that, six parts) lies under it
+    # the worst-case arm at its fullest: its y in float32 and no gathered
+    # part (the kernel writes the layer's result, no temporary); what else
+    # the arm holds is under one [tokens, hidden] float32; the sized arm
+    # (y a third of that) lies under it
     f32_rows = lambda n: n * hidden * 4  # noqa: E731
-    worst = f32_rows(slots) + 3 * f32_rows(tokens)
-    sized = f32_rows(capacity) + 6 * f32_rows(tokens)
+    worst, sized = f32_rows(slots), f32_rows(capacity)
     assert sized < worst
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert worst <= temp < worst + f32_rows(tokens) // 2, (temp, worst, sized)
+    assert worst <= temp < worst + f32_rows(tokens), (temp, worst, sized)
+
+
+#: (tokens, k, slot rows, hidden) of the combine in the three expert
+#: cells: `deepseek-v2` sized and worst-case at both buckets (k = 6),
+#: `deepseek-v3.2-exp` sized (8 held experts) and worst-case, `trinity-mini`
+#: (every expert held: one buffer of every slot)
+COMBINE_SHAPES = [
+    (16384, 6, 30720, 5120), (16384, 6, 98304, 5120), (8192, 6, 15360, 5120),
+    (16384, 8, 5120, 7168), (16384, 8, 131072, 7168), (8192, 8, 2560, 7168),
+    (16384, 8, 131072, 2048), (8192, 8, 65536, 2048),
+]
+
+
+def _lowered_combine(shape, tokens, k, rows, hidden):
+    return jax.jit(moe_combine.moe_combine).lower(
+        shape((rows, hidden), jnp.float32), shape((tokens, k), jnp.int32),
+        shape((tokens, k), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("tokens, k, rows, hidden", COMBINE_SHAPES)
+def test_the_combine_compiles_at_the_cells_shapes(shape, tokens, k, rows, hidden):
+    """One kernel under the name the trace's readers find, the slots in
+    SMEM (every token's k rows: 512 KB at 16,384 x 8, half the v5e's),
+    nothing padded or copied: the kernel reads `y` where it lies and
+    writes the result alone."""
+    compiled = _lowered_combine(shape, tokens, k, rows, hidden).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"%moe_combine[.\w]* = ", text)) == 1
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == tokens * hidden * 4
+    assert memory.temp_size_in_bytes < tokens * k * 4 + (1 << 20)
+
+
+def test_the_combine_loop_is_rolled(shape):
+    """The kernel's Mosaic module at 2,048 and at 16,384 tokens (and at
+    another buffer of slot rows) is one text but for the numbers of its
+    shapes, so the same size within their digits: its token loop is a
+    loop, and what it costs to lower does not grow with the tokens."""
+    texts = [
+        _mosaic_text(_lowered_combine(shape, tokens, 6, rows, 5120).as_text())
+        for tokens, rows in [(2048, 3840), (16384, 30720), (16384, 98304)]
+    ]
+    assert len({re.sub(r"\d+", "0", t) for t in texts}) == 1
+    # about 90 K characters at k = 6: a body unrolled over a tile of 128
+    # tokens would be some hundreds of thousands
+    assert max(map(len, texts)) < 120_000
+
+
+def test_a_buckets_expert_layers_lower_one_combine_an_arm(shape, monkeypatch):
+    """The four expert layers of a `deepseek-v2` bucket call the combine
+    at eight sites (two arms each), of two shapes: the kernel's body is
+    traced and lowered twice, once an arm, since the call sites of one
+    shape share one `pallas_call`."""
+    from sparkdl_tpu.models import deepseek_v2
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    traced = []
+    kernel = moe_combine._kernel
+    monkeypatch.setattr(
+        moe_combine, "_kernel", lambda *a: traced.append(1) or kernel(*a)
+    )
+    moe_combine._build.cache_clear()
+    config = deepseek_v2.deepseek_v2()
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    shapes = deepseek_v2.layer_shapes(config, config.first_k_dense)
+    layers = [
+        {
+            "router": shape(shapes["moe/router"], f32),
+            "experts": {
+                k: shape(shapes[f"moe/experts/{k}"], bf16) for k in ("gate", "up", "down")
+            },
+        }
+        for _ in range(4)
+    ]
+
+    def bucket(layers, u, real):
+        for p in layers:
+            routed, _, _ = deepseek_v2._routed(
+                config, p, u, real, grouped_matmul, combine_fn=moe_combine.moe_combine
+            )
+            u = u + routed
+        return u
+
+    text = (
+        jax.jit(bucket)
+        .lower(layers, shape((ROWS, 2048, config.hidden_size), f32), shape((ROWS, 2048), bool))
+        .as_text()
+    )
+    moe_combine._build.cache_clear()
+    assert text.count('kernel_name = "moe_combine"') == 8
+    assert len(traced) == 2
 
 
 # -- DeepSeek sparse attention at the published widths -------------------------
@@ -374,6 +483,7 @@ def test_trinity_programs_fit_the_chip(shape, length):
         return afmoe.forward(
             config, p, ids, dtype=bf16, attention_fn=attention(None),
             window_attention_fn=attention(2048), experts_fn=grouped_matmul,
+            combine_fn=moe_combine.moe_combine,
         )
 
     compiled = (
@@ -383,6 +493,9 @@ def test_trinity_programs_fit_the_chip(shape, length):
     assert len(re.findall(r"%flash_attention_window[.\w]* = ", text)) == 4
     assert len(re.findall(r"%flash_attention(?:\.\d+)? = ", text)) == 1
     assert len(re.findall(r"%moe_grouped_matmul[.\w]* = ", text)) == 12
+    combines = re.findall(r"%moe_combine[.\w]* = .*", text)
+    assert len(combines) == 4
+    assert not [line for line in combines if "%moe_grouped_matmul" in line]
     assert len(re.findall(r" conditional\(", text)) == 0
     memory = compiled.memory_analysis()
     weights = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves.values())

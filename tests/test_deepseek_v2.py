@@ -39,6 +39,7 @@ from sparkdl_tpu.models import deepseek_v2 as program  # noqa: E402
 from sparkdl_tpu.models import get_model  # noqa: E402
 from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn  # noqa: E402
 from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn  # noqa: E402
+from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn  # noqa: E402
 from sparkdl_tpu.transformers.text import TextEmbedder  # noqa: E402
 from sparkdl_tpu.utils.metrics import metrics  # noqa: E402
 
@@ -297,7 +298,8 @@ def _oracle(preset, moe, u, real):
 def test_both_arms_are_the_oracle(interpret, forced):
     """An even router leaves the quarter share on the sized buffer; one
     that sends every slot to the held group overflows it: every slot is
-    computed there too."""
+    computed there too. The kernels' case runs both interpreted: the
+    grouped product writes the rows apart and the combine fetches them."""
     preset = program.deepseek_v2_tiny()
     rng = np.random.default_rng(4)
     u = jnp.asarray(rng.standard_normal((4, 128, 64)), jnp.float32)
@@ -308,7 +310,8 @@ def test_both_arms_are_the_oracle(interpret, forced):
     real[3, 70:] = False
     real = jnp.asarray(real)
     part, count, fits = program._routed(
-        preset, moe, u, real, make_grouped_matmul_fn(interpret=interpret)
+        preset, moe, u, real, make_grouped_matmul_fn(interpret=interpret),
+        combine_fn=make_moe_combine_fn(interpret=interpret),
     )
     assert program.slot_capacity(preset, 4 * 128) == 512 < 4 * 128 * 3
     assert bool(fits) is not forced
@@ -330,11 +333,16 @@ def test_the_two_arms_are_bit_equal_on_a_load_both_hold(monkeypatch, interpret):
     u = jnp.asarray(np.random.default_rng(5).standard_normal((4, 128, 64)), jnp.float32)
     real, moe = jnp.ones((4, 128), bool), _layer(preset)
     experts_fn = make_grouped_matmul_fn(interpret=interpret)
-    sized, count, fits = program._routed(preset, moe, u, real, experts_fn)
+    combine_fn = make_moe_combine_fn(interpret=interpret)
+    sized, count, fits = program._routed(
+        preset, moe, u, real, experts_fn, combine_fn=combine_fn
+    )
     assert bool(fits) and int(count.sum()) > 256
     monkeypatch.setattr(program, "_CAPACITY_MARGIN", 0.01)
     assert program.slot_capacity(preset, 4 * 128) == 256
-    full, count_full, fits = program._routed(preset, moe, u, real, experts_fn)
+    full, count_full, fits = program._routed(
+        preset, moe, u, real, experts_fn, combine_fn=combine_fn
+    )
     assert not bool(fits) and count_full.tolist() == count.tolist()
     assert np.asarray(sized).tobytes() == np.asarray(full).tobytes()
 
@@ -622,15 +630,21 @@ def test_the_first_gate_is_bit_for_bit_what_it_was(normalise):
 
 
 #: sha256 (16 hex digits) of the text of `jax.make_jaxpr` of the tiny
-#: preset's program AT THE PARENT COMMIT (3c6d674, before the second
-#: family shared this module's functions), by dtype and batch shape.
-#: They change with jax's version too: then take them again from a
-#: checkout of that commit, not from this tree.
+#: preset's program, by dtype and batch shape: the one taken at commit
+#: 3c6d674 (before the second family shared this module's functions),
+#: with one edit since, made on purpose: the combine moved to
+#: `ops/moe_combine.py`, which takes the slots not held as -1 (the gather
+#: form reads row 0 for them where it read the buffer's last, and masks it
+#: as it did);
+#: `test_the_embeddings_are_the_parents_combines_to_the_bit` holds the
+#: embeddings to the earlier loop's. They change with jax's version too:
+#: then take them again from a checkout of the commit that made that
+#: edit, not from a later tree.
 PARENT_JAXPR = {
-    ("float32", (2, 32)): "b04eb2e28e3f9aa0",
-    ("float32", (4, 128)): "260752f43db7f8b5",
-    ("bfloat16", (2, 32)): "f4608b0e5f1c5cea",
-    ("bfloat16", (4, 128)): "abbc2a3c9edda152",
+    ("float32", (2, 32)): "dfc1652eaa7a5783",
+    ("float32", (4, 128)): "87e3603b8ac5b59b",
+    ("bfloat16", (2, 32)): "e7a8c4b907bc2b11",
+    ("bfloat16", (4, 128)): "9172e2fa189fb204",
 }
 
 
@@ -646,6 +660,52 @@ def test_the_programs_jaxpr_is_the_parents(dtype, shape):
     text = str(jax.make_jaxpr(mf.fn)(mf.params, jnp.zeros(shape, jnp.int32)))
     assert "scan[" not in text and "i8[" not in text and "bitcast_convert_type" not in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPR[dtype, shape]
+
+
+def _parents_combine(y, slot, weights):
+    """The combine as `_experts_and_combine` wrote it before
+    `ops/moe_combine.py`, op for op: every slot's row gathered (the
+    buffer's last for a slot not held), weighted, masked and added in
+    slot order, three parts a pass where the buffer holds every slot."""
+    rows, top_k = y.shape[0], slot.shape[1]
+    held = slot >= 0
+    at = jnp.where(held, slot, rows - 1)
+    at_once = 3 if rows >= slot.size else top_k
+    out = jnp.zeros((slot.shape[0], y.shape[1]), jnp.float32)
+    for j in range(top_k):
+        part = y[at[:, j]] * weights[:, j, None]
+        out = out + jnp.where(held[:, j, None], part, 0.0)
+        if (j + 1) % at_once == 0 and j + 1 < top_k:
+            y, out = jax.lax.optimization_barrier((y, out))
+    return out
+
+
+@pytest.mark.parametrize("forced", [False, True], ids=["sized", "worst_case"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_embeddings_are_the_parents_combines_to_the_bit(tiny, monkeypatch, dtype, forced):
+    """The program built off the TPU (`gather_combine`) and the same
+    program with the combine loop it had before, on either arm: every
+    row's embedding and counters alike, to the bit."""
+    _, _, path = tiny
+    if forced:
+        monkeypatch.setattr(program, "_CAPACITY_MARGIN", 0.01)
+
+    def built(combine_fn=None):
+        return program.deepseek_v2_model_function(
+            "deepseek-v2-tiny", dtype=dtype, weights_file=path, combine_fn=combine_fn
+        )
+
+    ids = np.random.default_rng(1).integers(1, 512, (4, 128)).astype(np.int32)
+    ids[3, 70:] = 0
+    new, old = built(), built(_parents_combine)
+    assert new.combine == "gather" and old.combine == "custom"
+    got = np.asarray(new.fn(new.params, jnp.asarray(ids)))
+    want = np.asarray(old.fn(old.params, jnp.asarray(ids)))
+    assert np.isfinite(got).all() and np.abs(got[:, :64]).min(1).min() > 0
+    np.testing.assert_array_equal(got, want)
+    # the counters' columns: the two expert layers took the arm asked for
+    arm = -1 if forced else -2
+    assert (got[:, arm] == 2).all() and (got[:, -3 - arm] == 0).all()
 
 
 # -- the attention is handed its rows' lengths -----------------------------------
