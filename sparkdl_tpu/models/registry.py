@@ -766,6 +766,49 @@ _register(
     )
 )
 
+
+def _xing4_0_text_builder(size: str):
+    """Builder over models/xing4_0.py presets: DeepSeek's latent attention
+    and shared plus routed experts, every expert held, with a residual of
+    four streams mixed by manifold-constrained hyper-connections; ``embed``
+    is the mean final state of a row's real tokens (and a column of
+    counts, ``mf.row_counters``). The ModelFunction is marked
+    ``weights_as_arguments`` and reports ``attention`` ('flash' | 'dense'),
+    ``experts`` ('pallas' | 'ragged_dot') and ``residual`` ('pallas' |
+    'xla'), all chosen at build time."""
+
+    def build(
+        spec: NamedTextModel, mode: str, dtype, weights_file, seed
+    ) -> ModelFunction:
+        from sparkdl_tpu.models import xing4_0
+
+        return xing4_0.xing4_0_model_function(
+            size,
+            dtype=dtype,
+            seed=seed,
+            weights_file=weights_file,
+            name=f"{spec.name}[{mode}]",
+        )
+
+    return build
+
+
+# Xing4.0-29B-A4B at its published widths (1 dense and 4 expert layers of
+# 40, all 64 experts, the whole vocabulary: the cut of
+# benchmarks/configs/xing4.0-29b-a4b.json), and the family at test size.
+_register(
+    NamedTextModel(
+        "xing4.0-29b-a4b", 262144, 3584, "jax",
+        _xing4_0_text_builder("xing4.0-29b-a4b"), vocab_size=131072,
+    )
+)
+_register(
+    NamedTextModel(
+        "xing4.0-tiny", 4096, 64, "jax",
+        _xing4_0_text_builder("xing4.0-tiny"), vocab_size=512,
+    )
+)
+
 def get_model(name: str):
     """The registered spec for ``name`` — a :class:`NamedImageModel` or
     :class:`NamedTextModel`; both expose ``model_function(mode=...)``

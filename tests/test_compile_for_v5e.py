@@ -505,3 +505,103 @@ def test_trinity_programs_fit_the_chip(shape, length):
     # 16,384 tokens, half that at 8,192
     assert memory.temp_size_in_bytes < length * 160 * 1024
     assert weights + memory.temp_size_in_bytes < 0.65 * 16 * (1 << 30)
+
+
+# -- Xing4.0: the hyper-connections' two kernels and the whole program ----------
+
+
+XING_WIDTH = 4 * 3584  # four streams of the hidden size
+
+
+def _hyper(n=4):
+    from sparkdl_tpu.ops import hyper_connection
+
+    constants = hyper_connection.Constants(n, 20, 1e-6, (-30.0, 30.0), 1e-6)
+    return constants, hyper_connection.HyperConnection(constants, "pallas")
+
+
+@pytest.mark.parametrize("tokens", [8 * 1024, 8 * 2048])
+def test_the_hyper_connections_compile_at_the_cells_shapes(shape, tokens):
+    """A dispatch of `xing4.0-29b-a4b-embed-windows` (eight rows of 1,024 or
+    2,048 tokens, four streams of 3,584): one kernel each under its own
+    name, nothing padded or copied in HBM (the pre-mix's only temporary is
+    phi turned, 1.4 MB), and the post-mix writing the new stream into the
+    old one's buffer."""
+    from sparkdl_tpu.ops import hyper_connection
+
+    constants, _ = _hyper()
+    f32 = jnp.float32
+    pre = (
+        jax.jit(lambda x, p, b, a: hyper_connection.hc_pre(constants, x, p, b, a, jnp.bfloat16))
+        .lower(shape((tokens, XING_WIDTH), f32), shape((XING_WIDTH, 24), f32),
+               shape((24,), f32), shape((3,), f32))
+        .compile()
+    )
+    text = pre.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"%hc_pre[.\w]* = ", text)) == 1
+    assert pre.memory_analysis().temp_size_in_bytes < XING_WIDTH * 24 * 4 + (1 << 20)
+    post = (
+        jax.jit(lambda x, f, hp, hr: hyper_connection.hc_post(constants, x, f, hp, hr),
+                donate_argnums=0)
+        .lower(shape((tokens, XING_WIDTH), f32), shape((tokens, 3584), f32),
+               shape((tokens, 4), f32), shape((tokens, 16), f32))
+        .compile()
+    )
+    text = post.as_text()
+    assert len(re.findall(r"%hc_post[.\w]* = ", text)) == 1
+    memory = post.memory_analysis()
+    assert memory.temp_size_in_bytes < (1 << 20)
+    assert memory.alias_size_in_bytes == tokens * XING_WIDTH * 4
+
+
+@pytest.mark.parametrize("length", [1024, 2048])
+def test_xing_programs_fit_the_chip(shape, length):
+    """The whole program of a bucket of the Xing4.0 cell (eight rows, five
+    layers at the published widths, all 64 experts held, weights as
+    arguments): ten pre-mixes and ten post-mixes, the latent kernel in
+    every layer, three grouped products and a combine an expert layer, and
+    no conditional. The weights and the larger bucket's temporaries lie
+    within the chip's 16 GiB, with room for the batches."""
+    from sparkdl_tpu.models import xing4_0
+    from sparkdl_tpu.models.jamba import _unflatten
+    from sparkdl_tpu.ops.flash_attention import flash_attention_latent
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    config, bf16 = xing4_0.xing4_0_29b_a4b(), jnp.bfloat16
+    leaves = {
+        p: shape(s, xing4_0._leaf_dtype(p, s, bf16))
+        for p, s in xing4_0.param_shapes(config).items()
+    }
+
+    def attention(q, kv, k_rope, dtype, lengths=None):
+        return flash_attention_latent(
+            q, kv, k_rope, None, lengths, num_heads=32, scale=config.softmax_scale,
+            block=1024,
+        ).astype(dtype)
+
+    attention.takes_lengths = True
+
+    def program(p, ids):
+        return xing4_0.forward(
+            config, p, ids, dtype=bf16, attention_fn=attention, experts_fn=grouped_matmul,
+            hyper=_hyper()[1], combine_fn=moe_combine.moe_combine,
+        )
+
+    compiled = (
+        jax.jit(program).lower(_unflatten(leaves), shape((ROWS, length), jnp.int32)).compile()
+    )
+    text = compiled.as_text()
+    assert len(re.findall(r"%hc_pre[.\w]* = ", text)) == 10
+    assert len(re.findall(r"%hc_post[.\w]* = ", text)) == 10
+    assert len(re.findall(r"%flash_attention[.\w]* = ", text)) == 5
+    assert len(re.findall(r"%moe_grouped_matmul[.\w]* = ", text)) == 12
+    assert len(re.findall(r"%moe_combine[.\w]* = ", text)) == 4
+    assert len(re.findall(r" conditional\(", text)) == 0
+    memory = compiled.memory_analysis()
+    weights = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves.values())
+    # the parameters in bfloat16, the mixes' 2.75 M in float32
+    assert weights == pytest.approx(7.16e9, rel=2e-3)
+    assert memory.argument_size_in_bytes - weights < (1 << 20)
+    print("temporaries", length, memory.temp_size_in_bytes)
+    assert weights + memory.temp_size_in_bytes < 0.9 * 16 * (1 << 30)

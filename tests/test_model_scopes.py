@@ -1,4 +1,4 @@
-"""The five text families name their parts on the device's timeline
+"""The six text families name their parts on the device's timeline
 (`sparkdl_tpu.utils.profiler.scope`): the compiled program of each tiny
 preset carries every scope of its family's vocabulary and no other, and
 is, its metadata aside, the program it is without them."""
@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from sparkdl_tpu.models import afmoe, bert, deepseek_v2, deepseek_v32, jamba
+from sparkdl_tpu.models import afmoe, bert, deepseek_v2, deepseek_v32, jamba, xing4_0
 from sparkdl_tpu.models.registry import get_model
 from sparkdl_tpu.utils import profiler
 
@@ -30,6 +30,8 @@ WINDOWED = {
     "embed", "attn.qkv", "attn.window", "attn.full", "attn.out", "mlp", "moe.route",
     "moe.routed", "moe.gather", "moe.experts", "moe.combine", "pool",
 }
+#: DeepSeek's parts around a residual of four streams; every expert held
+HYPER = (EXPERTS - {"moe.worst_case"}) | {"mhc.pre", "mhc.post"}
 
 #: family -> (its vocabulary (docs/OBSERVABILITY.md), a batch shape at
 #: which its program holds every part: both arms of the routed path's
@@ -40,8 +42,9 @@ FAMILIES = {
     "deepseek-v2-tiny": (EXPERTS, (8, 128)),
     "deepseek-v3.2-exp-tiny": (EXPERTS | INDEXER, (2, 64)),
     "trinity-mini-tiny": (WINDOWED, (2, 64)),
+    "xing4.0-tiny": (HYPER, (2, 64)),
 }
-MODULES = (bert, jamba, deepseek_v2, deepseek_v32, afmoe)
+MODULES = (bert, jamba, deepseek_v2, deepseek_v32, afmoe, xing4_0)
 
 
 @functools.lru_cache(maxsize=None)
