@@ -37,14 +37,16 @@ the output gate's sigmoid, the router (operands too, ``highest``), the
 routing weights and the combine are float32. Attention is
 ``ops/flash_attention.py:flash_attention``, the blocked causal kernel on
 the full layers and its window mode (``flash_attention_window``) on the
-sliding ones; the Pallas kernels on TPU, plain ``jax.numpy`` elsewhere,
-chosen at build time and reported as ``mf.attention`` and
+sliding ones, both handed each row's length so that no query block of
+padding alone runs; the Pallas kernels on TPU, plain ``jax.numpy``
+elsewhere, chosen at build time and reported as ``mf.attention`` and
 ``mf.window_attention``. Layers are unrolled into one program with every
 layer's weights an argument of its own (``weights_as_arguments``).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -253,10 +255,20 @@ def _output_gate(o, g):
     return o * jax.nn.sigmoid(g)
 
 
+def _with_lengths(attention_fn, real):
+    """``attention_fn`` handed its rows' lengths besides its operands
+    (``deepseek_v2.row_lengths`` over real [B, L] bool) where it takes
+    them, so that the kernel runs no query block of padding alone; as it
+    is where it does not (the dense fallback, a stand-in in a test)."""
+    by_length = v2.row_lengths(attention_fn, real)
+    return functools.partial(attention_fn, **by_length) if by_length else attention_fn
+
+
 def _attention(config: AfmoeConfig, p, u, tables, attention_fn):
     """u [B, L, hidden] in the compute dtype -> o W_o [B, L, hidden]
     float32, before the post-attention norm. ``tables``: the rotary
-    (cos, sin) of a sliding layer, None for a full one."""
+    (cos, sin) of a sliding layer, None for a full one. ``attention_fn``
+    carries the rows' lengths where it takes them (:func:`_with_lengths`)."""
     dtype, eps = u.dtype, config.rms_norm_eps
     rows, length, _ = u.shape
     d = config.head_dim
@@ -294,6 +306,8 @@ def forward(
         if config.mup_enabled:
             x = x * math.sqrt(config.hidden_size)
         tables = rope_tables(config, ids.shape[1])
+        attention_fn = _with_lengths(attention_fn, real)
+        window_attention_fn = _with_lengths(window_attention_fn, real)
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
     for i in range(config.num_hidden_layers):
@@ -351,7 +365,12 @@ def afmoe_model_function(
     ``moe.buffer_full``), which ``TextEmbedder`` strips. Per dispatched
     token it counts ``attn.window_tokens`` (once a sliding layer) and
     ``attn.full_tokens`` (once a full one), per real token
-    ``moe.slots_routed``."""
+    ``moe.slots_routed``; ``mf.batch_counters`` counts the attention
+    kernels' query blocks, ``attn.query_blocks`` and
+    ``attn.query_blocks_run``, over the five layers
+    (``deepseek_v2.query_block_counters``, each attention at its own
+    layers: nothing for one that says no block count, the dense
+    fallbacks)."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
     from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
@@ -396,10 +415,17 @@ def afmoe_model_function(
     mf.combine = getattr(combine_fn, "kind", "custom")
     mf.row_counters = ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
     sliding = config.sliding_layers
-    mf.dispatched_token_counters = {
-        "attn.window_tokens": sliding,
-        "attn.full_tokens": config.num_hidden_layers - sliding,
-    }
+    full = config.num_hidden_layers - sliding
+    mf.dispatched_token_counters = {"attn.window_tokens": sliding, "attn.full_tokens": full}
+
+    def batch_counters(ids, real) -> dict:
+        counted = {}
+        for fn, layers in ((window_attention_fn, sliding), (attention_fn, full)):
+            for name, count in v2.query_block_counters(fn, layers, ids, real, "attn").items():
+                counted[name] = counted.get(name, 0) + count
+        return counted
+
+    mf.batch_counters = batch_counters
     mf.real_token_counters = {
         "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
     }

@@ -635,6 +635,35 @@ def forward(
     return out, slots_held, sized
 
 
+def _counted_lengths(attention_fn, ids, real) -> list:
+    """Each row's length as the attention runs it: its last real position
+    + 1 (as ``row_lengths`` hands it over) where the attention takes
+    lengths, the bucket's edge where it takes none (every block runs)."""
+    rows, edge = ids.shape
+    if getattr(attention_fn, "takes_lengths", False):
+        return _last_real_position(real, np).tolist()
+    return [edge] * rows
+
+
+def query_block_counters(attention_fn, layers: int, ids, real, prefix: str = "mla") -> dict:
+    """The query blocks of a dispatched batch ``ids`` [B, L] whose real
+    tokens are ``real``, in ``layers`` layers of an attention that says
+    how many a row has (``.query_blocks``; nothing for one that does not):
+
+    - ``<prefix>.query_blocks``: rows x layers x the bucket's query blocks;
+    - ``<prefix>.query_blocks_run``: those of them that hold a real token,
+      every one where the attention takes no lengths."""
+    blocks = getattr(attention_fn, "query_blocks", None)
+    if blocks is None:
+        return {}
+    rows, edge = ids.shape
+    lengths = _counted_lengths(attention_fn, ids, real)
+    return {
+        f"{prefix}.query_blocks": rows * layers * blocks(edge),
+        f"{prefix}.query_blocks_run": layers * sum(blocks(n) for n in lengths),
+    }
+
+
 def attention_batch_counters(attention_fn, layers: int, ids, real) -> dict:
     """What ``mf.batch_counters`` counts of attention for a dispatched
     batch ``ids`` [B, L] whose real tokens are ``real``, where the
@@ -645,24 +674,17 @@ def attention_batch_counters(attention_fn, layers: int, ids, real) -> dict:
     - ``mla.pairs_computed``: layers x the (query, key) pairs a head's
       attention runs, summed over the rows: how much of the square was
       run, beside ``mla.attention_tokens``;
-    - ``mla.query_blocks``: rows x layers x the bucket's query blocks;
-    - ``mla.query_blocks_run``: those of them that hold a real token.
+    - ``mla.query_blocks`` and ``mla.query_blocks_run``
+      (:func:`query_block_counters`).
 
-    A row counts at its length (its last real position + 1, as
-    ``row_lengths`` hands it over) where the attention takes lengths, and
-    at the bucket's edge where it takes none: every block runs."""
+    A row counts at its length where the attention takes lengths, and at
+    the bucket's edge where it takes none (:func:`_counted_lengths`)."""
     pairs = getattr(attention_fn, "pairs_computed", None)
     if pairs is None:
         return {}
-    rows, edge = ids.shape
-    lengths = [edge] * rows
-    if getattr(attention_fn, "takes_lengths", False):
-        lengths = _last_real_position(real, np).tolist()
+    lengths = _counted_lengths(attention_fn, ids, real)
     counters = {"mla.pairs_computed": layers * sum(pairs(n) for n in lengths)}
-    blocks = getattr(attention_fn, "query_blocks", None)
-    if blocks is not None:
-        counters["mla.query_blocks"] = rows * layers * blocks(edge)
-        counters["mla.query_blocks_run"] = layers * sum(blocks(n) for n in lengths)
+    counters.update(query_block_counters(attention_fn, layers, ids, real))
     return counters
 
 

@@ -58,18 +58,20 @@ def _flash_kernel(
     l_ref,
     acc_ref,
     causal: bool = False,
+    live=None,
 ):
     """Grid = (B*H, num_q_blocks, num_k_blocks); the k dimension is
     sequential ('arbitrary'), so VMEM scratch carries the online softmax
     state across k-steps for each (bh, qi) tile. ``causal`` (square
     blocks): a key block above the diagonal is skipped, not masked after
-    the product, and the diagonal block is masked inside."""
+    the product, and the diagonal block is masked inside. ``live``: see
+    :func:`_when`."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(2)
     qi = pl.program_id(1) if causal else None
 
-    @pl.when(ki == 0)
+    @_when(ki == 0, live)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -99,13 +101,67 @@ def _flash_kernel(
         _softmax_step(s, v, m_ref, l_ref, acc_ref)
 
     if causal:
-        pl.when(ki <= qi)(_block)
+        _when(ki <= qi, live)(_block)
     else:
         _block()
 
-    @pl.when(ki == nk - 1)
+    @_when(ki == nk - 1, live)
     def _finalize():
         _write_result(o_ref, l_ref, acc_ref)
+
+
+def _when(step, live):
+    """``pl.when(step)`` (``step`` None: always), and in a call that knows
+    its rows' lengths (``live``: whether the step's query block is live,
+    a traced bool) in a live query block alone. Without lengths a step
+    that always runs is called as it is, outside any branch."""
+    from jax.experimental import pallas as pl
+
+    if live is None:
+        return (lambda f: f()) if step is None else pl.when(step)
+    return pl.when(live if step is None else jnp.logical_and(step, live))
+
+
+def _by_length(kernel, heads: int, last_step: int, live_ref, *refs):
+    """``kernel`` (the causal or the window kernel, over the refs q, k,
+    v, mask, o and its scratch) for a call that knows its rows' lengths:
+    ``live_ref`` is the prefetched [B] int32 of how many leading query
+    blocks of each row are *live*, hold a real token (a grid step's row
+    is its first index over ``heads``). A live query block runs the
+    kernel's steps as a call without lengths runs them, step for step
+    (the kernel ANDs ``live`` into each of its branches, :func:`_when`:
+    one branch around the whole kernel would not do, since the Pallas
+    interpreter reads no ``program_id`` inside a branch). A *dead* block,
+    padding alone, runs nothing at any key step and, at its last
+    (``last_step``), writes zeros itself."""
+    from jax.experimental import pallas as pl
+
+    live = pl.program_id(1) < live_ref[jax.lax.div(pl.program_id(0), heads)]
+    kernel(*refs, live=live)
+
+    @pl.when(jnp.logical_and(pl.program_id(2) == last_step, jnp.logical_not(live)))
+    def _dead():
+        o_ref = refs[4]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _resident(block, qi, live):
+    """The block that a step of query block ``qi`` names, where its row
+    has ``live`` live query blocks: ``block``, the one it names without
+    lengths, in a live query block; in a dead one the row's last live
+    query block (block 0 for a row of padding), which the last step of
+    that block named for its queries and its keys alike (the diagonal),
+    so that nothing is fetched for a dead block's steps."""
+    last = jnp.maximum(live, 1) - 1
+    return jnp.where(qi > last, last, block)
+
+
+def _named(heads: int, block, bh, qi, *live):
+    """The block an index map names at step (``bh``, ``qi``): ``block``;
+    in a call with lengths (``live``, the maps' prefetched last argument,
+    each row's count of live query blocks) a dead query block's names the
+    row's last live one (:func:`_resident`)."""
+    return _resident(block, qi, live[0][bh // heads]) if live else block
 
 
 def _window_key_block(qi, ki, steps: int):
@@ -118,14 +174,15 @@ def _window_key_block(qi, ki, steps: int):
 
 def _window_kernel(
     steps: int, scale: float, window: int, q_ref, k_ref, v_ref, mask_ref,
-    o_ref, m_ref, l_ref, acc_ref,
+    o_ref, m_ref, l_ref, acc_ref, live=None,
 ):
     """Grid = (B*H, num_q_blocks, steps): step ``ki`` of query block
     ``qi`` reads key block ``qi - (steps - 1) + ki``, the band of blocks
     that a query block's window reaches, the diagonal last. A step below
     block 0 runs nothing. The diagonal block is masked by ``j <= i``, the
     band's lowest by ``i - j < window`` where it can hold a key out of
-    the window, and the blocks between run unmasked."""
+    the window, and the blocks between run unmasked. ``live``: see
+    :func:`_when`."""
     from jax.experimental import pallas as pl
 
     qi, ki = pl.program_id(1), pl.program_id(2)
@@ -133,7 +190,7 @@ def _window_kernel(
     key_block = qi - last + ki
     bq, bk = q_ref.shape[1], k_ref.shape[1]
 
-    @pl.when(ki == 0)
+    @_when(ki == 0, live)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -161,16 +218,16 @@ def _window_kernel(
     # whether the band's lowest block can hold a key out of the window
     clipped = steps * bk > window
     if steps == 1:
-        _block(True, clipped)
+        _when(None, live)(lambda: _block(True, clipped))
     else:
-        live = key_block >= 0
-        pl.when(jnp.logical_and(ki == 0, live))(lambda: _block(False, clipped))
+        started = key_block >= 0
+        _when(jnp.logical_and(ki == 0, started), live)(lambda: _block(False, clipped))
         if steps > 2:
             between = jnp.logical_and(ki > 0, ki < last)
-            pl.when(jnp.logical_and(between, live))(lambda: _block(False, False))
-        pl.when(ki == last)(lambda: _block(True, False))
+            _when(jnp.logical_and(between, started), live)(lambda: _block(False, False))
+        _when(ki == last, live)(lambda: _block(True, False))
 
-    @pl.when(ki == last)
+    @_when(ki == last, live)
     def _finalize():
         _write_result(o_ref, l_ref, acc_ref)
 
@@ -222,6 +279,7 @@ def flash_attention(
     interpret: bool = False,
     causal: bool = False,
     window: Optional[int] = None,
+    lengths: Optional[jax.Array] = None,
 ):
     """Blockwise-online softmax attention.
 
@@ -246,6 +304,22 @@ def flash_attention(
             block 0 is neither run nor fetched. The call is named
             ``flash_attention_window``. None: the causal or plain kernel,
             as it was before the option existed.
+        lengths: with ``causal``, None or [B] int32: how many leading
+            positions of each row hold its real tokens, the rest right
+            padding (0 for a row of padding). A real query's answer needs
+            no length: right padding needs no mask under a causal one.
+            What a length saves is the padding's own queries: each row's
+            count of live query blocks (those that start before its
+            length) is a scalar-prefetch operand (same grid, blocks,
+            scratch and name). A live block, the one that holds the row's
+            last real token too, runs the steps it runs without lengths,
+            in their order, so a real query's answer is the same to the
+            bit; a dead block runs no step, every step of it names the
+            blocks the row's last live one left resident (block 0 for a
+            row of padding), so nothing is fetched for it, and its
+            positions of the result are zeros. None: every block runs,
+            through the call without the operand, as before the option
+            existed.
 
     Returns [B, H, L, Dh] in q's dtype.
     """
@@ -267,6 +341,13 @@ def flash_attention(
         raise ValueError(
             f"a window of {window} wants causal attention and a multiple "
             f"of the key block {block_k}"
+        )
+    if lengths is not None and (
+        not causal or lengths.shape != (B,) or lengths.dtype != jnp.int32
+    ):
+        raise ValueError(
+            f"lengths for q {q.shape} want causal attention and [B] int32, "
+            f"got {lengths.shape} {lengths.dtype}"
         )
     mask2d = _key_mask(mask, B, Lk)
 
@@ -304,14 +385,17 @@ def flash_attention(
 
     nq = Lq_p // block_q
     nk = Lk_p // block_k
+    live = None if lengths is None else (lengths + (block_q - 1)) // block_q
+    named = functools.partial(_named, H)
     if window is not None:
         out = _flash_window(
             qf, kf, vf, mask3d, H, Hkv, min(nk, window // block_k + 1), scale,
-            window, block_q, interpret,
+            window, block_q, interpret, live,
         )
         return out.reshape(B, H, Lq_p, Dh_p)[:, :, :L, :Dh]
 
     kernel = functools.partial(_flash_kernel, nk, scale)
+    q_block = lambda bh, qi, ki, *live: (bh, named(qi, bh, qi, *live), 0)  # noqa: E731
     kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
     mask_block = lambda bh, qi, ki, H=H: (bh // H, 0, ki)  # noqa: E731
     if causal or Hkv != H:
@@ -323,81 +407,119 @@ def flash_attention(
         def kv_head(bh):
             return (bh // H) * Hkv + (bh % H) // group
 
-        def key_block(qi, ki):
-            return jnp.minimum(ki, qi) if causal else ki
+        def key_block(bh, qi, ki, *live):
+            return named(jnp.minimum(ki, qi) if causal else ki, bh, qi, *live)
 
-        kv_block = lambda bh, qi, ki: (kv_head(bh), key_block(qi, ki), 0)  # noqa: E731
-        mask_block = lambda bh, qi, ki: (bh // H, 0, key_block(qi, ki))  # noqa: E731
-    out = pl.pallas_call(
-        kernel,
+        kv_block = lambda bh, qi, ki, *live: (  # noqa: E731
+            kv_head(bh), key_block(bh, qi, ki, *live), 0
+        )
+        mask_block = lambda bh, qi, ki, *live: (  # noqa: E731
+            bh // H, 0, key_block(bh, qi, ki, *live)
+        )
+    grid = dict(
         grid=(B * H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, Dh_p), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, Dh_p), q_block),
             pl.BlockSpec((1, block_k, Dh_p), kv_block),
             pl.BlockSpec((1, block_k, Dh_p), kv_block),
             pl.BlockSpec((1, 1, block_k), mask_block),
         ],
+        # the result's map alone keeps a dead block's own index: its zeros
         out_specs=pl.BlockSpec(
-            (1, block_q, Dh_p), lambda bh, qi, ki: (bh, qi, 0)
+            (1, block_q, Dh_p), lambda bh, qi, ki, *live: (bh, qi, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((B * H, Lq_p, Dh_p), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
             pltpu.VMEM((block_q, Dh_p), jnp.float32),  # output accumulator
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
+    )
+    out = _blocked_call(
+        kernel, grid, [qf, kf, vf, mask3d], (B * H, Lq_p, Dh_p), q.dtype,
         # a stable name for the kernel's events in a profiler trace
-        name="flash_attention",
-    )(qf, kf, vf, mask3d)
+        "flash_attention", interpret, live, H, nk - 1,
+    )
 
     out = out.reshape(B, H, Lq_p, Dh_p)
     return out[:, :, :L, :Dh]
 
 
-def _flash_window(qf, kf, vf, mask3d, H, Hkv, steps, scale, window, block, interpret):
+def _flash_window(
+    qf, kf, vf, mask3d, H, Hkv, steps, scale, window, block, interpret, live,
+):
     """The window kernel's call over :func:`flash_attention`'s flattened,
     padded operands: q [B*H, L, Dh], k and v [B*Hkv, L, Dh], the key
-    mask [B, 1, L]; grid (B*H, L / block, ``steps``)."""
+    mask [B, 1, L]; grid (B*H, L / block, ``steps``). ``live``: None, or
+    each row's count of live query blocks, prefetched (:func:`_named`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, L, Dh = qf.shape
     group = H // Hkv
+    named = functools.partial(_named, H)
 
-    def kv_block(bh, qi, ki):
+    def key_block(bh, qi, ki, *live):
+        return named(_window_key_block(qi, ki, steps), bh, qi, *live)
+
+    def kv_block(bh, qi, ki, *live):
         head = (bh // H) * Hkv + (bh % H) // group
-        return head, _window_key_block(qi, ki, steps), 0
+        return head, key_block(bh, qi, ki, *live), 0
 
-    return pl.pallas_call(
-        functools.partial(_window_kernel, steps, scale, window),
+    kernel = functools.partial(_window_kernel, steps, scale, window)
+    grid = dict(
         grid=(BH, L // block, steps),
         in_specs=[
-            pl.BlockSpec((1, block, Dh), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec(
+                (1, block, Dh),
+                lambda bh, qi, ki, *live: (bh, named(qi, bh, qi, *live), 0),
+            ),
             pl.BlockSpec((1, block, Dh), kv_block),
             pl.BlockSpec((1, block, Dh), kv_block),
             pl.BlockSpec(
                 (1, 1, block),
-                lambda bh, qi, ki: (bh // H, 0, _window_key_block(qi, ki, steps)),
+                lambda bh, qi, ki, *live: (bh // H, 0, key_block(bh, qi, ki, *live)),
             ),
         ],
-        out_specs=pl.BlockSpec((1, block, Dh), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, L, Dh), qf.dtype),
+        # the result's map alone keeps a dead block's own index: its zeros
+        out_specs=pl.BlockSpec((1, block, Dh), lambda bh, qi, ki, *live: (bh, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block, 128), jnp.float32),  # running max
             pltpu.VMEM((block, 128), jnp.float32),  # running sum
             pltpu.VMEM((block, Dh), jnp.float32),  # output accumulator
         ],
+    )
+    return _blocked_call(
+        kernel, grid, [qf, kf, vf, mask3d], (BH, L, Dh), qf.dtype,
+        # its own name: a trace tells the window layers from the full ones
+        "flash_attention_window", interpret, live, H, steps - 1,
+    )
+
+
+def _blocked_call(
+    kernel, grid, operands, shape, dtype, name, interpret, live, heads, last_step
+):
+    """The one ``pallas_call`` of the blocked kernel or its window mode:
+    ``grid`` its grid, blocks and scratch, the last axis sequential. With
+    ``live`` (each row's count of live query blocks) that count is the
+    first operand, scalar-prefetched, and :func:`_by_length` runs the
+    kernel; without, the call is the one made before lengths existed."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if live is not None:
+        kernel = functools.partial(_by_length, kernel, heads, last_step)
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **grid))
+        operands = [live, *operands]
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-        # its own name: a trace tells the window layers from the full ones
-        name="flash_attention_window",
-    )(qf, kf, vf, mask3d)
+        name=name,
+        **grid,
+    )(*operands)
 
 
 def packs(num_heads: Optional[int], head_dim: Optional[int]) -> bool:
@@ -1036,7 +1158,14 @@ def make_flash_attention_fn(
     none, gets the blocked kernel over [B, H, L, Dh] ('heads', what a
     function without ``.layout`` takes). ``window`` (with ``causal``):
     the sliding-window kernel, ``flash_attention_window``, and off the TPU
-    ``dense_causal_attention`` with that window."""
+    ``dense_causal_attention`` with that window.
+
+    The blocked kernel's causal and window functions also take
+    ``lengths=None`` ([B] int32, each row's leading real positions) and
+    run no query block of padding alone: their ``.takes_lengths`` is
+    True, and ``.query_blocks(length)`` is how many query blocks a row of
+    that length has. The dense fallbacks have neither attribute: a caller
+    hands lengths only to an attention that says it takes them."""
     if not interpret and jax.default_backend() != "tpu":
         if window is not None:
             windowed = functools.partial(dense_causal_attention, window=window)
@@ -1067,7 +1196,7 @@ def make_flash_attention_fn(
         packed_attention.layout = "packed"
         return packed_attention
 
-    def attention(q, k, v, mask, dtype):
+    def attention(q, k, v, mask, dtype, lengths=None):
         out = flash_attention(
             q,
             k,
@@ -1078,8 +1207,12 @@ def make_flash_attention_fn(
             interpret=interpret,
             causal=causal,
             window=window,
+            lengths=lengths,
         )
         return out.astype(dtype)
 
     attention.kind = "flash"
+    if causal:
+        attention.takes_lengths = True
+        attention.query_blocks = lambda length: -(-length // block_q)
     return attention

@@ -5,7 +5,9 @@ the tiny preset with seeded random weights, against the plain reference
 kernels (the window kernel on the sliding layers, the causal one on the
 full layer, the grouped product); five faults planted in the program and
 the float8 control, each caught; the gate by hand; one routed body and
-no conditional where every expert is held; and the counters.
+no conditional where every expert is held; the rows' lengths handed to
+the attentions that take them; and the counters, the query blocks at
+the cell's own traffic among them.
 
 Tolerances. In float32 the program and the reference at `highest` do the
 same arithmetic in another order: 3e-7 of the spread of the rows, held
@@ -18,6 +20,7 @@ median and every planted fault 0.137 or more, each over the bfloat16
 tolerance (tests/benchmarks/test_trinity_cell.py holds them against the
 cell's own limits)."""
 
+import json
 import os
 import sys
 
@@ -175,6 +178,11 @@ def test_embedder_matches_the_reference_row_by_row(tiny, corpus, want, dtype, pr
     # one buffer of every slot, no sized one: each live row counts 4 layers
     assert delta.get("moe.buffer_sized", 0) == 0
     assert delta["moe.buffer_full"] == 4 * len(corpus)
+    if interpret:  # query blocks of 16 in five layers, run up to each row's length
+        assert delta["attn.query_blocks"] == 5 * dispatched // 16
+        assert delta["attn.query_blocks_run"] == 5 * sum(-(-n // 16) for n in lengths)
+    else:  # the dense fallbacks say no block count
+        assert not delta.get("attn.query_blocks") and not delta.get("attn.query_blocks_run")
 
 
 @pytest.mark.parametrize("fault", afmoe_tiny.FAULTS)
@@ -244,3 +252,115 @@ def test_the_window_and_the_full_layer_use_their_own_kernels(monkeypatch):
     assert calls == ["window", "window", "full", "window", "window"]
     assert mf.dispatched_token_counters == {"attn.window_tokens": 4, "attn.full_tokens": 1}
     assert mf.row_counters == ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
+
+
+def _stand_in(**attributes):
+    """An attention that returns its values, one a query head, and says
+    what ``attributes`` say; it records the lengths it is handed."""
+    seen = []
+
+    def fn(q, k, v, mask, dtype, lengths=None):
+        seen.append(lengths)
+        return v.repeat(q.shape[1] // k.shape[1], 1).astype(dtype)
+
+    fn.__dict__.update(attributes, seen=seen)
+    return fn
+
+
+def test_each_attention_is_handed_its_rows_lengths_where_it_takes_them():
+    """The lengths (a row's last real position + 1, an id 0 inside a text
+    cutting nothing short, 0 for a row of padding) reach the sliding
+    layers' and the full layer's attention alike where it takes them, and
+    nothing reaches one that does not."""
+    window = _stand_in(takes_lengths=True)
+    full = _stand_in()
+    mf = program.afmoe_model_function(
+        "trinity-mini-tiny", attention_fn=full, window_attention_fn=window
+    )
+    ids = np.zeros((3, 64), np.int32)
+    ids[0, :10] = 5
+    ids[1, :] = 7
+    ids[1, 20] = 0
+    mf.fn(mf.params, jnp.asarray(ids))
+    assert len(window.seen) == 4 and full.seen == [None]
+    for lengths in window.seen:
+        assert np.asarray(lengths).tolist() == [10, 64, 0]
+    # both take them: each of the five layers gets the same lengths
+    full = _stand_in(takes_lengths=True)
+    mf = program.afmoe_model_function(
+        "trinity-mini-tiny", attention_fn=full, window_attention_fn=window
+    )
+    mf.fn(mf.params, jnp.asarray(ids))
+    assert [np.asarray(n).tolist() for n in full.seen] == [[10, 64, 0]]
+
+
+def _cell_dispatches():
+    """The dispatches of one job of `trinity-mini-embed-long-docs`, as its
+    traffic and its configuration's buckets make them: one row each, the
+    live rows' tokens (words + 2) filled with a word's id."""
+    from benchmarks.data import texts
+
+    with open(os.path.join(ROOT, "benchmarks", "traffic", "embed-long-docs.json")) as f:
+        data = json.load(f)["data"]
+    with open(os.path.join(ROOT, "benchmarks", "configs", "trinity-mini.json")) as f:
+        edges = [int(e) for e in json.load(f)["env"]["SPARKDL_TEXT_BUCKETS"].split(",")]
+    tokens = texts.word_counts(data["rows"] - data["null_rows"], data["word_counts"]) + 2
+    for n in tokens:
+        ids = np.zeros((1, min(e for e in edges if e >= n)), np.int32)
+        ids[0, :n] = 5
+        yield ids
+
+
+def _counted(mf, dispatches):
+    total = {}
+    for ids in dispatches:
+        for name, count in mf.batch_counters(ids, ids != 0).items():
+            total[name] = total.get(name, 0) + count
+    return total
+
+
+@pytest.mark.parametrize("takes_lengths", [True, False], ids=["lengths", "without"])
+def test_query_blocks_of_the_cells_job(takes_lengths):
+    """A job of the Trinity cell in blocks of 512 over the five layers:
+    its ten rows hold 240 query blocks a layer (five of 16 at 8,192 and
+    five of 32 at 16,384), 169 of them a real token. An attention that
+    takes no lengths runs every block."""
+    dispatches = list(_cell_dispatches())
+    assert sum(int((ids != 0).sum()) for ids in dispatches) == 84223
+    assert sum(ids.size for ids in dispatches) == 122880
+    blocks = lambda n: -(-n // 512)  # noqa: E731
+    attributes = dict(query_blocks=blocks)
+    if takes_lengths:
+        attributes["takes_lengths"] = True
+    mf = program.afmoe_model_function(
+        "trinity-mini-tiny", attention_fn=_stand_in(**attributes),
+        window_attention_fn=_stand_in(**attributes),
+    )
+    assert _counted(mf, dispatches) == {
+        "attn.query_blocks": 5 * 240,
+        "attn.query_blocks_run": 5 * (169 if takes_lengths else 240),
+    }
+
+
+def test_the_built_kernels_count_their_query_blocks_and_the_fallbacks_none():
+    """The builder's own choice: the interpreted kernels in blocks of 512
+    count the cell's 5 x 169 of 5 x 240; the dense fallbacks off the TPU
+    count nothing; a window kernel beside a dense full layer counts its
+    four layers alone."""
+    from sparkdl_tpu.ops.flash_attention import dense_causal_attention
+
+    def built(full, window):
+        return program.afmoe_model_function(
+            "trinity-mini-tiny", attention_fn=full, window_attention_fn=window
+        )
+
+    kernel = make_flash_attention_fn(512, 512, interpret=True, causal=True)
+    window = make_flash_attention_fn(512, 512, interpret=True, causal=True, window=16)
+    dispatches = list(_cell_dispatches())
+    assert _counted(built(kernel, window), dispatches) == {
+        "attn.query_blocks": 5 * 240, "attn.query_blocks_run": 5 * 169,
+    }
+    assert _counted(program.afmoe_model_function("trinity-mini-tiny"), dispatches) == {}
+    assert _counted(built(dense_causal_attention, window), dispatches) == {
+        "attn.query_blocks": 4 * 240, "attn.query_blocks_run": 4 * 169,
+    }

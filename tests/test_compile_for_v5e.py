@@ -453,14 +453,79 @@ def test_window_kernel_compiles_at_the_cells_shapes(shape, length):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+@pytest.mark.parametrize("window", [None, 2048], ids=["causal", "window"])
+@pytest.mark.parametrize("length", [8192, 16384])
+def test_the_blocked_kernels_with_lengths_compile_at_the_cells_shapes(shape, length, window):
+    """A full and a sliding layer of `trinity-mini-embed-long-docs` (one
+    row, 32 query heads over 4 of 128, blocks of 512) given the row's
+    length: one call each under its own name, each row's count of live
+    query blocks the one operand more (an int32, padded to 512 bytes),
+    nothing made in HBM."""
+    bf16 = jnp.bfloat16
+    q = shape((1, 32, length, HEAD_DIM), bf16)
+    kv = shape((1, 4, length, HEAD_DIM), bf16)
+    compiled = (
+        jax.jit(
+            lambda q, k, v, n: flash_attention(
+                q, k, v, block_q=512, block_k=512, causal=True, window=window, lengths=n
+            )
+        )
+        .lower(q, kv, kv, shape((1,), jnp.int32))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    name = "flash_attention_window" if window else r"flash_attention(?!_window)"
+    assert len(re.findall(rf"%{name}[.\w]* = ", text)) == 1
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < (1 << 20)
+    given = (q.size + 2 * kv.size) * 2
+    assert memory.argument_size_in_bytes - given == 512
+    assert memory.output_size_in_bytes == q.size * 2
+
+
+#: sha256 of the Mosaic module (debug locations aside) of Trinity's two
+#: kernels called without lengths at the cell's shapes, taken from the
+#: commit before lengths were added
+TRINITY_PINNED = {
+    (8192, None): "7fea0fff9391f310",
+    (16384, None): "8276668f45083bd8",
+    (8192, 2048): "de6ea12108effb45",
+    (16384, 2048): "7fac7fc6082ab9ee",
+}
+
+
+@pytest.mark.parametrize("length, window", sorted(TRINITY_PINNED, key=str))
+def test_trinitys_kernels_without_lengths_lower_as_the_parent_did(shape, length, window):
+    """Without lengths the causal and the window kernel at the Trinity
+    cell's shapes are the parent's Mosaic modules to the byte: the call
+    with lengths adds nothing to the call without them."""
+    import hashlib
+
+    q = shape((1, 32, length, HEAD_DIM), jnp.bfloat16)
+    kv = shape((1, 4, length, HEAD_DIM), jnp.bfloat16)
+    text = (
+        jax.jit(
+            lambda q, k, v: flash_attention(
+                q, k, v, block_q=512, block_k=512, causal=True, window=window
+            )
+        )
+        .lower(q, kv, kv)
+        .as_text()
+    )
+    digest = hashlib.sha256(_mosaic_text(text).encode()).hexdigest()[:16]
+    assert digest == TRINITY_PINNED[(length, window)]
+
+
 @pytest.mark.parametrize("length", [8192, 16384])
 def test_trinity_programs_fit_the_chip(shape, length):
     """The whole program of a bucket of the Trinity-Mini cell (one row,
     five layers at the published widths, every expert held, weights as
     arguments): the window kernel in the four sliding layers, the causal
-    kernel in the full one, three grouped products an expert layer, and
-    no conditional (every expert held: one slot buffer). The weights and
-    the larger bucket's temporaries lie well under the chip's 16 GiB."""
+    kernel in the full one, each handed the row's length, three grouped
+    products an expert layer, and no conditional (every expert held: one
+    slot buffer). The weights and the larger bucket's temporaries lie
+    well under the chip's 16 GiB."""
     from sparkdl_tpu.models import afmoe, deepseek_v2
     from sparkdl_tpu.models.jamba import _unflatten
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
@@ -472,11 +537,13 @@ def test_trinity_programs_fit_the_chip(shape, length):
     }
 
     def attention(window):
-        def fn(q, k, v, mask, dtype):
+        def fn(q, k, v, mask, dtype, lengths=None):
             return flash_attention(
-                q, k, v, mask, block_q=512, block_k=512, causal=True, window=window
+                q, k, v, mask, block_q=512, block_k=512, causal=True, window=window,
+                lengths=lengths,
             ).astype(dtype)
 
+        fn.takes_lengths = True
         return fn
 
     def program(p, ids):
@@ -492,6 +559,10 @@ def test_trinity_programs_fit_the_chip(shape, length):
     text = compiled.as_text()
     assert len(re.findall(r"%flash_attention_window[.\w]* = ", text)) == 4
     assert len(re.findall(r"%flash_attention(?:\.\d+)? = ", text)) == 1
+    # each of the five takes the row's live query blocks, its first operand
+    calls = re.findall(r"%flash_attention\S* = .*", text)
+    assert len(calls) == 5
+    assert all("operand_layout_constraints={s32[1]{0}, bf16[32," in c for c in calls)
     assert len(re.findall(r"%moe_grouped_matmul[.\w]* = ", text)) == 12
     combines = re.findall(r"%moe_combine[.\w]* = .*", text)
     assert len(combines) == 4

@@ -203,6 +203,96 @@ def test_existing_shapes_lower_to_what_they_were(shape):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[shape]
 
 
+# -- the causal kernel given its rows' lengths ----------------------------------
+
+
+@pytest.mark.parametrize(
+    "H, Hkv, L, block, lengths",
+    [
+        # a length inside a block, on a block's edge, the row's whole edge,
+        # and 0 (a row that only fills the batch)
+        (4, 1, 96, 16, (21, 32, 96, 0)),  # one shared head
+        (4, 2, 96, 32, (1, 64, 96, 0)),  # two groups of two
+        (2, 2, 128, 32, (100, 96, 128, 0)),  # heads equal
+        (4, 2, 90, 16, (90, 48, 17, 0)),  # a row off the block size, padded to 96
+    ],
+)
+def test_causal_with_lengths_matches_dense_at_every_real_position(H, Hkv, L, block, lengths):
+    """Every real position is `dense_causal_attention`'s; every position
+    of a live query block is the call without lengths' to the bit (the
+    same steps in the same order); every position of a dead block is
+    zero."""
+    q, k, v = qkv(L + H + block, len(lengths), H, Hkv, L, 32)
+    kw = dict(block_q=block, block_k=block, interpret=True, causal=True)
+    got = np.asarray(flash_attention(q, k, v, lengths=jnp.asarray(lengths, jnp.int32), **kw))
+    whole = np.asarray(flash_attention(q, k, v, **kw))
+    want = np.asarray(dense_causal_attention(q, k, v, None, jnp.float32))
+    assert got.shape == whole.shape and not np.isnan(got).any()
+    for row, n in enumerate(lengths):
+        live = min(-(-n // block) * block, L)
+        np.testing.assert_allclose(got[row, :, :n], want[row, :, :n], **TOL)
+        np.testing.assert_array_equal(got[row, :, :live], whole[row, :, :live])
+        assert not got[row, :, live:].any(), (row, n)
+    assert np.abs(got[0]).max() > 0.01  # the comparison is of something
+
+
+@pytest.mark.parametrize("live", [0, 1, 3, 6])
+def test_a_dead_causal_query_blocks_steps_fetch_nothing(live):
+    """The causal kernel's index maps over one row's head of 6 query
+    blocks, walked in the grid's order: a live block names its own query
+    block and the key blocks up to its diagonal (clamped there above
+    it); every step of a dead one names what the step before it named,
+    the last live block's diagonal, so nothing is copied for it."""
+    from sparkdl_tpu.ops.flash_attention import _resident
+
+    nq = 6
+    walked = [
+        (qi, ki, int(_resident(qi, qi, live)), int(_resident(min(ki, qi), qi, live)))
+        for qi in range(nq)
+        for ki in range(nq)
+    ]
+    for n, (qi, ki, q_block, k_block) in enumerate(walked):
+        if qi < live:
+            assert (q_block, k_block) == (qi, min(ki, qi))
+        elif n:
+            assert (q_block, k_block) == walked[n - 1][2:], (live, n)
+    assert walked[-1][2:] == (max(live, 1) - 1,) * 2
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_the_kernel_with_lengths_is_one_call_of_the_same_name(window):
+    """With lengths: the same one call and name, the live counts a
+    prefetched operand; the branches are the call without lengths' (three
+    in the causal kernel, as `test_bidirectional_equal_heads_kernel_is_untouched`
+    counts them, five in the window kernel), each taken in a live query
+    block alone (one `and` more a branch), and one more writes a dead
+    block's zeros."""
+    q, k, v = qkv(8, 2, 4, 2, 128, 32)
+
+    def call(*lengths):
+        return jax.make_jaxpr(
+            lambda q, k, v, *n: flash_attention(
+                q, k, v, block_q=32, block_k=32, causal=True, window=window,
+                lengths=n[0] if n else None,
+            )
+        )(q, k, v, *lengths)
+
+    def calls(jaxpr):
+        return [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+    (whole,) = calls(call())
+    (by_length,) = calls(call(jnp.zeros((2,), jnp.int32)))
+    assert by_length.params["name"] == whole.params["name"]
+    assert whole.params["name"] == ("flash_attention_window" if window else "flash_attention")
+    assert by_length.params["grid_mapping"].num_index_operands == 1
+    assert whole.params["grid_mapping"].num_index_operands == 0
+    def count(e, primitive):
+        return sum(x.primitive.name == primitive for x in e.params["jaxpr"].eqns)
+
+    assert count(by_length, "cond") == count(whole, "cond") + 1
+    assert count(by_length, "and") == count(whole, "and") + count(whole, "cond") + 1
+
+
 # -- latent attention over the projections' own arrays -------------------------
 
 

@@ -16,6 +16,7 @@ import pytest
 
 from sparkdl_tpu.ops.flash_attention import (
     NEG_INF,
+    _resident,
     _window_key_block,
     dense_causal_attention,
     flash_attention,
@@ -48,10 +49,10 @@ def windowed(q, k, v, window, mask=None):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v, precision="highest")
 
 
-def run(q, k, v, window=W, block=BLOCK, mask=None):
+def run(q, k, v, window=W, block=BLOCK, mask=None, lengths=None):
     return flash_attention(
         q, k, v, mask, block_q=block, block_k=block, interpret=True,
-        causal=True, window=window,
+        causal=True, window=window, lengths=lengths,
     )
 
 
@@ -193,3 +194,103 @@ def test_the_built_function_and_its_fallback():
     np.testing.assert_allclose(np.asarray(kernel(q, k, v, None, jnp.float32)), np.asarray(want), **TOL)
     # the causal fallback is the same function, without a window
     assert make_flash_attention_fn(causal=True) is dense_causal_attention
+
+
+# -- the window kernel given its rows' lengths ----------------------------------
+
+
+@pytest.mark.parametrize("H, Hkv", [(2, 2), (8, 2)], ids=["mha", "gqa4"])
+@pytest.mark.parametrize(
+    "window, L, lengths",
+    [
+        # in blocks of 16: a length inside a block, on a block's edge, the
+        # row's whole edge, and 0 (a row that only fills the batch)
+        (W, 96, (37, 48, 96, 0)),
+        (16, 96, (1, 16, 96, 0)),  # a band of two
+        (256, 96, (17, 80, 96, 0)),  # a window longer than the row
+        (W, 90, (90, 64, 33, 0)),  # a row off the block size, padded to 96
+    ],
+)
+def test_window_with_lengths_matches_dense_at_every_real_position(H, Hkv, window, L, lengths):
+    """Every real position is `dense_causal_attention`'s; every position
+    of a live query block is the call without lengths' to the bit (the
+    same steps in the same order); every position of a dead block is
+    zero."""
+    q, k, v = qkv(L + H + window, len(lengths), H, Hkv, L)
+    got = np.asarray(run(q, k, v, window, lengths=jnp.asarray(lengths, jnp.int32)))
+    whole = np.asarray(run(q, k, v, window))
+    want = np.asarray(dense_causal_attention(q, k, v, None, jnp.float32, window=window))
+    assert got.shape == whole.shape and not np.isnan(got).any()
+    for row, n in enumerate(lengths):
+        live = min(-(-n // BLOCK) * BLOCK, L)
+        np.testing.assert_allclose(got[row, :, :n], want[row, :, :n], **TOL)
+        np.testing.assert_array_equal(got[row, :, :live], whole[row, :, :live])
+        assert not got[row, :, live:].any(), (row, n)
+    assert np.abs(got[0]).max() > 0.01  # the comparison is of something
+
+
+def _walk(steps, nq, live, key_block):
+    """(query block, the query and key blocks its step names) of every step
+    of one row's head in the grid's order, where the row has ``live``
+    live query blocks: the index maps' rule (:func:`_resident`)."""
+    return [
+        (qi, int(_resident(qi, qi, live)), int(_resident(key_block(qi, ki), qi, live)))
+        for qi in range(nq)
+        for ki in range(steps)
+    ]
+
+
+@pytest.mark.parametrize("live", [0, 1, 3, 7, 8])
+@pytest.mark.parametrize("steps", [2, 3, 5])
+def test_a_dead_query_blocks_steps_fetch_nothing(steps, live):
+    """A live query block names what it names without lengths. Every step
+    of a dead one names the query and key blocks the step before it
+    named, the last live block's diagonal: nothing is copied for it. A
+    row of padding names block 0 throughout, fetched once a head."""
+    nq = 8
+    walked = _walk(steps, nq, live, lambda qi, ki: _window_key_block(qi, ki, steps))
+    for n, (qi, q_block, k_block) in enumerate(walked):
+        if qi < live:
+            assert (q_block, k_block) == (qi, int(_window_key_block(qi, n % steps, steps)))
+        elif n:
+            assert (q_block, k_block) == walked[n - 1][1:], (steps, live, n)
+    assert walked[-1][1:] == (max(live, 1) - 1,) * 2
+
+
+@pytest.mark.parametrize(
+    "kw, lengths, said",
+    [
+        (dict(causal=False), np.zeros((2,), np.int32), "want causal attention"),
+        (dict(causal=True, window=32), np.zeros((3,), np.int32), r"got \(3,\) int32"),
+        (dict(causal=True, window=32), np.zeros((2,), np.float32), r"got \(2,\) float32"),
+        (dict(causal=True), np.zeros((2, 1), np.int32), r"got \(2, 1\) int32"),
+    ],
+)
+def test_lengths_of_another_shape_or_type_or_without_causal_are_refused(kw, lengths, said):
+    q, k, v = qkv(0, 2, 2, 2, 64)
+    with pytest.raises(ValueError, match=said):
+        flash_attention(
+            q, k, v, block_q=16, block_k=16, interpret=True, lengths=jnp.asarray(lengths), **kw
+        )
+
+
+def test_the_built_kernels_say_they_take_lengths_and_the_fallbacks_do_not():
+    """The blocked kernel's causal and window functions take lengths and
+    count a row's query blocks; the dense fallbacks and the bidirectional
+    kernel have neither attribute, so a caller hands them none."""
+    for window in (None, W):
+        dense = make_flash_attention_fn(block_q=16, block_k=16, causal=True, window=window)
+        assert dense.kind == "dense"  # the tests run on the CPU
+        assert not hasattr(dense, "takes_lengths") and not hasattr(dense, "query_blocks")
+        kernel = make_flash_attention_fn(
+            block_q=16, block_k=16, causal=True, window=window, interpret=True
+        )
+        assert kernel.takes_lengths is True
+        assert [kernel.query_blocks(n) for n in (0, 1, 16, 17, 96)] == [0, 1, 1, 2, 6]
+        q, k, v = qkv(31, 2, 4, 2, 64)
+        whole = np.asarray(kernel(q, k, v, None, jnp.float32))
+        got = np.asarray(kernel(q, k, v, None, jnp.float32, lengths=jnp.asarray([20, 0], jnp.int32)))
+        np.testing.assert_array_equal(got[0, :, :32], whole[0, :, :32])
+        assert not got[0, :, 32:].any() and not got[1].any()
+    plain = make_flash_attention_fn(block_q=16, block_k=16, interpret=True)
+    assert not hasattr(plain, "takes_lengths") and not hasattr(plain, "query_blocks")
