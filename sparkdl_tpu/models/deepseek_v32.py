@@ -231,7 +231,9 @@ def forward(
     [B, hidden] float32, slots that fell on held experts [B] int32, how
     many expert layers worked on the sized slot buffer, an int32 scalar,
     the (query, key) pairs the layers' attention read for the row's real
-    queries [B] int32). A length within ``index_topk`` runs no indexer."""
+    queries [B] int32). A length within ``index_topk`` runs no indexer.
+    The attention and the indexer are each handed the rows' lengths where
+    they say they take them (``deepseek_v2.row_lengths``)."""
     eps = config.rms_norm_eps
     selects = ids.shape[1] > config.index_topk
     with scope("embed"):
@@ -239,6 +241,7 @@ def forward(
         tables = v2.rope_tables(config, ids.shape[1])
         x = params["embed"][ids].astype(jnp.float32)
         by_length = v2.row_lengths(attention_fn, real)
+        index_by_length = v2.row_lengths(indexer_fn, real) if selects else {}
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     pairs = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
@@ -254,7 +257,7 @@ def forward(
             with scope("dsa.index_inputs"):
                 operands = index_inputs(config, p["attn"]["indexer"], c_q, u, tables)
             with scope("dsa.select"):
-                selection = indexer_fn(*operands)
+                selection = indexer_fn(*operands, **index_by_length)
             with scope("mla.core"):
                 o = attention_fn(q, kv, k_pe, dtype, selection, **by_length)
             with scope("dsa.count"):
@@ -319,7 +322,11 @@ def deepseek_v32_model_function(
     ``mla.pairs_computed``, ``mla.query_blocks`` and
     ``mla.query_blocks_run`` (what the attention it was built with runs,
     at each row's length where it takes lengths:
-    ``deepseek_v2.attention_batch_counters``)."""
+    ``deepseek_v2.attention_batch_counters``), and where the bucket runs
+    the indexer ``dsa.query_blocks`` and ``dsa.query_blocks_run`` (the
+    index-scores kernel's query blocks, every one and those holding a
+    real token, where the indexer says how many a row has:
+    ``deepseek_v2.query_block_counters``)."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.ops.dsa_indexer import make_indexer_fn
     from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
@@ -367,10 +374,12 @@ def deepseek_v32_model_function(
 
     def batch_counters(ids, real) -> dict:
         n = real.sum(1).astype(np.int64)
+        selects = ids.shape[1] > top_k
         return {
-            "dsa.index_tokens": int(ids.size) * layers * (ids.shape[1] > top_k),
+            "dsa.index_tokens": int(ids.size) * layers * selects,
             "dsa.pairs_causal": int((n * (n + 1) // 2).sum()) * layers,
             **v2.attention_batch_counters(attention_fn, layers, ids, real),
+            **(v2.query_block_counters(indexer_fn, layers, ids, real, "dsa") if selects else {}),
         }
 
     mf = ModelFunction(

@@ -33,6 +33,17 @@ elsewhere, chosen at build time by :func:`make_indexer_fn` (``.kind``):
   of 16,384 with ``top_k`` 2,048 (PERF.md, PR 34): the scores 13.1 ms,
   the kernel's selection 7.2 ms, the plain form compiled by XLA 13.9,
   and ``lax.top_k`` over the masked rows 239.
+
+Given each row's length (``lengths`` [B] int32, its last real position
++ 1; the kernels' indexer says ``.takes_lengths``), both kernels run no
+query block of padding alone: each row's count of live query blocks,
+those that start before its length, is a scalar-prefetch operand, and a
+dead block's steps name blocks already resident. A live block, the one
+that holds the row's last real token too, runs as without lengths, so
+every real query's scores and selection are the same to the bit. Past
+the live blocks I is unspecified and never read, and each query of a
+dead selection block selects itself alone: no row of the selection is
+ever empty, whatever attention reads it.
 """
 
 from __future__ import annotations
@@ -44,6 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 _INT_MIN = np.int32(-(2**31))
+#: the index-scores kernel's query block: the unit of ``.query_blocks``
+SCORES_BLOCK_Q = 256
 #: what the select kernel may hold in VMEM: two buffers of a [bq, L]
 #: float32 block of scores, its int8 result, and the ordered integers
 _SELECT_VMEM_LIMIT_BYTES = 96 << 20
@@ -58,13 +71,18 @@ def index_scores(q, k, w, *, num_heads: int):
     return jnp.einsum("bhqk,bqh->bqk", jnp.maximum(s, 0.0), w.astype(jnp.float32))
 
 
-def _scores_kernel(heads, dim, q_ref, k_ref, w_ref, o_ref):
+def _scores_kernel(heads, dim, q_ref, k_ref, w_ref, o_ref, live=None):
+    """One step: the key block's scores for the query block, summed over
+    the heads; a block above the diagonal computes nothing, nor, in a
+    call with lengths (``live``: whether the query block is live, a
+    traced bool), does a dead query block."""
     from jax.experimental import pallas as pl
 
     qi, ki = pl.program_id(1), pl.program_id(2)
     bq, bk = o_ref.shape[1:]
+    needed = ki * bk < (qi + 1) * bq
 
-    @pl.when(ki * bk < (qi + 1) * bq)
+    @pl.when(needed if live is None else jnp.logical_and(needed, live))
     def _block():
         k = k_ref[0]  # [bk, D]
         w = w_ref[0]  # [bq, H] float32
@@ -78,12 +96,48 @@ def _scores_kernel(heads, dim, q_ref, k_ref, w_ref, o_ref):
         o_ref[0] = acc
 
 
+def _scores_by_length(heads, dim, live_ref, *refs):
+    """:func:`_scores_kernel` in a call that knows its rows' lengths:
+    ``live_ref`` the prefetched [B] int32 of each row's count of live
+    query blocks."""
+    from jax.experimental import pallas as pl
+
+    live = pl.program_id(1) < live_ref[pl.program_id(0)]
+    _scores_kernel(heads, dim, *refs, live=live)
+
+
+def _live_blocks(lengths, block: int):
+    """[B] int32 lengths -> each row's count of *live* query blocks of
+    ``block`` queries: those that start before its length."""
+    return (lengths + (block - 1)) // block
+
+
+def _last_live(live, b):
+    """Row ``b``'s last live query block (block 0 for a row of padding),
+    ``live`` the index maps' prefetched counts: a dead block's steps name
+    the blocks it left resident, so that nothing is fetched for them."""
+    return jnp.maximum(live[b], 1) - 1
+
+
+def _check_lengths(lengths, rows: int):
+    if lengths is not None and (lengths.shape != (rows,) or lengths.dtype != jnp.int32):
+        raise ValueError(
+            f"lengths for {rows} rows are [{rows}] int32, got "
+            f"{lengths.shape} {lengths.dtype}"
+        )
+
+
 def dsa_index_scores(
-    q, k, w, *, num_heads: int, block_q: int = 256, block_k: int = 512,
-    interpret: bool = False,
+    q, k, w, lengths=None, *, num_heads: int, block_q: int = SCORES_BLOCK_Q,
+    block_k: int = 512, interpret: bool = False,
 ):
     """I [B, L, L] float32, the per-head scores never in HBM. ``block_k``
-    a multiple of ``block_q`` or the reverse; L is padded to both."""
+    a multiple of ``block_q`` or the reverse; L is padded to both.
+
+    ``lengths`` [B] int32 (a row's last real position + 1, 0 for a row
+    of padding): each row's count of live query blocks is a
+    scalar-prefetch operand, a dead query block computes nothing and
+    fetches nothing, and what I holds in its rows is unspecified."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -94,6 +148,7 @@ def dsa_index_scores(
             f"index scores over {num_heads} heads want q [B, L, H*D], k "
             f"[B, L, D] and w [B, L, H]; got {q.shape}, {k.shape}, {w.shape}"
         )
+    _check_lengths(lengths, B)
     pad = -L % max(block_q, block_k)
     if pad:
         q, k, w = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (q, k, w))
@@ -104,22 +159,47 @@ def dsa_index_scores(
     def last_needed(qi):  # the key block that holds the query block's end
         return ((qi + 1) * block_q - 1) // block_k
 
-    out = pl.pallas_call(
-        functools.partial(_scores_kernel, num_heads, dim),
+    def step(b, qi, ki, *live):
+        """The query block and key step a grid step names: in a dead
+        query block the last step of the row's last live one."""
+        if not live:
+            return qi, ki
+        last = _last_live(live[0], b)
+        dead = qi > last
+        return jnp.where(dead, last, qi), jnp.where(dead, n // block_k - 1, ki)
+
+    def key_block(b, qi, ki, *live):
+        qi, ki = step(b, qi, ki, *live)
+        return jnp.minimum(ki, last_needed(qi))
+
+    def query_block(b, qi, ki, *live):
+        return step(b, qi, ki, *live)[0]
+
+    grid = dict(
         grid=(B, n // block_q, n // block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, wide), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec(
-                (1, block_k, dim),
-                lambda b, qi, ki: (b, jnp.minimum(ki, last_needed(qi)), 0),
+                (1, block_q, wide), lambda b, *at: (b, query_block(b, *at), 0)
             ),
-            pl.BlockSpec((1, block_q, num_heads), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, block_k, dim), lambda b, *at: (b, key_block(b, *at), 0)),
+            pl.BlockSpec(
+                (1, block_q, num_heads), lambda b, *at: (b, query_block(b, *at), 0)
+            ),
         ],
         # a skipped step names the block before it: nothing is written twice
         out_specs=pl.BlockSpec(
             (1, block_q, block_k),
-            lambda b, qi, ki: (b, qi, jnp.minimum(ki, last_needed(qi))),
+            lambda b, *at: (b, query_block(b, *at), key_block(b, *at)),
         ),
+    )
+    kernel = functools.partial(_scores_kernel, num_heads, dim)
+    operands = [q, k, w]
+    if lengths is not None:
+        kernel = functools.partial(_scores_by_length, num_heads, dim)
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **grid))
+        operands.insert(0, _live_blocks(lengths, block_q))
+    out = pl.pallas_call(
+        kernel,
         out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
@@ -127,7 +207,8 @@ def dsa_index_scores(
         interpret=interpret,
         # a stable name for the kernel's events in a profiler trace
         name="dsa_index_scores",
-    )(q, k, w)
+        **grid,
+    )(*operands)
     return out[:, :L, :L]
 
 
@@ -212,12 +293,46 @@ def select_keys(scores, *, top_k: int, block_q: int = 2048):
 
 
 def _select_kernel(top_k, chunk, s_ref, o_ref, key_ref):
-    """One block of queries: their scores [bq, L] become ordered integers
-    in VMEM once; every search step counts over the key chunks up to the
-    block's end."""
+    """One block of queries, the grid's second index:
+    :func:`_select_block`."""
+    from jax.experimental import pallas as pl
+
+    _select_block(top_k, chunk, pl.program_id(1), s_ref, o_ref, key_ref)
+
+
+def _select_by_length(top_k, chunk, live_ref, s_ref, o_ref, key_ref):
+    """:func:`_select_kernel` in a call that knows its rows' lengths
+    (``live_ref``: each row's count of live query blocks, prefetched). A
+    live block runs :func:`_select_block`; a dead one, padding alone,
+    reads no score, runs no search and writes for each of its queries
+    the query itself alone, so that no row of the selection is empty."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
+    live = qi < live_ref[pl.program_id(0)]
+    pl.when(live)(lambda: _select_block(top_k, chunk, qi, s_ref, o_ref, key_ref))
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        bq, length = key_ref.shape
+        t = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, chunk), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, chunk), 1)
+
+        def write(c, carry):
+            at = pl.multiple_of(c * chunk, chunk)
+            itself = jnp.where(at + lane == t, np.int32(1), np.int32(0))
+            o_ref[0, :, pl.ds(at, chunk)] = itself.astype(jnp.int8)
+            return carry
+
+        jax.lax.fori_loop(0, length // chunk, write, None)
+
+
+def _select_block(top_k, chunk, qi, s_ref, o_ref, key_ref):
+    """Query block ``qi``: its scores [bq, L] become ordered integers in
+    VMEM once; every search step counts over the key chunks up to the
+    block's end."""
+    from jax.experimental import pallas as pl
+
     bq, length = key_ref.shape
     # key chunks that hold a key any query of the block may see
     chunks = ((qi + 1) * bq + chunk - 1) // chunk
@@ -278,35 +393,57 @@ def _select_kernel(top_k, chunk, s_ref, o_ref, key_ref):
 
 
 def dsa_select(
-    scores, *, top_k: int, block_q: int = 64, chunk: int = 512,
+    scores, lengths=None, *, top_k: int, block_q: int = 64, chunk: int = 512,
     interpret: bool = False,
 ):
     """:func:`select_keys` as a kernel, grid (B, L / bq): the block's
-    scores are read from HBM once and searched in VMEM."""
+    scores are read from HBM once and searched in VMEM.
+
+    ``lengths`` [B] int32 (as :func:`dsa_index_scores` takes them): each
+    row's count of live query blocks is a scalar-prefetch operand; a live
+    block selects as without lengths, a dead one reads no score (its map
+    names the row's last live block, resident) and selects for each
+    query the query itself alone (:func:`_select_by_length`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, L, _ = scores.shape
+    _check_lengths(lengths, B)
     pad = -L % max(block_q, chunk)
     if pad:
         scores = jnp.pad(scores, ((0, 0), (0, pad), (0, pad)))
     n = L + pad
     if n % block_q or n % chunk:
         raise ValueError(f"a block of {block_q} and chunks of {chunk} do not tile {n}")
-    out = pl.pallas_call(
-        functools.partial(_select_kernel, top_k, chunk),
+
+    def queries(b, qi, *live):
+        return jnp.minimum(qi, _last_live(live[0], b)) if live else qi
+
+    grid = dict(
         grid=(B, n // block_q),
-        in_specs=[pl.BlockSpec((1, block_q, n), lambda b, qi: (b, qi, 0))],
-        out_specs=pl.BlockSpec((1, block_q, n), lambda b, qi: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.int8),
+        in_specs=[
+            pl.BlockSpec((1, block_q, n), lambda b, qi, *live: (b, queries(b, qi, *live), 0))
+        ],
+        out_specs=pl.BlockSpec((1, block_q, n), lambda b, qi, *live: (b, qi, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, n), jnp.int32)],
+    )
+    kernel = _select_kernel
+    operands = [scores]
+    if lengths is not None:
+        kernel = _select_by_length
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **grid))
+        operands.insert(0, _live_blocks(lengths, block_q))
+    out = pl.pallas_call(
+        functools.partial(kernel, top_k, chunk),
+        out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.int8),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_SELECT_VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
         name="dsa_select",
-    )(scores)
+        **grid,
+    )(*operands)
     return out[:, :L, :L]
 
 
@@ -314,7 +451,13 @@ def make_indexer_fn(num_heads: int, top_k: int, interpret: bool = False):
     """The indexer a model is BUILT with, ``fn(q, k, w) -> selection
     [B, L, L] int8``: the two Pallas kernels on the TPU (or interpreted
     when asked), ``jax.numpy`` elsewhere. ``.kind`` ('pallas' | 'jnp')
-    says which."""
+    says which.
+
+    The kernels' also takes ``lengths=None`` ([B] int32, each row's
+    last real position + 1) and runs no query block of padding alone:
+    its ``.takes_lengths`` is True, and ``.query_blocks(length)`` is how
+    many of the scores kernel's query blocks a row of that length has.
+    The plain one has neither attribute."""
     if not interpret and jax.default_backend() != "tpu":
 
         def plain(q, k, w):
@@ -323,9 +466,13 @@ def make_indexer_fn(num_heads: int, top_k: int, interpret: bool = False):
         plain.kind = "jnp"
         return plain
 
-    def indexer(q, k, w):
-        scores = dsa_index_scores(q, k, w, num_heads=num_heads, interpret=interpret)
-        return dsa_select(scores, top_k=top_k, interpret=interpret)
+    def indexer(q, k, w, lengths=None):
+        scores = dsa_index_scores(
+            q, k, w, lengths, num_heads=num_heads, interpret=interpret
+        )
+        return dsa_select(scores, lengths, top_k=top_k, interpret=interpret)
 
     indexer.kind = "pallas"
+    indexer.takes_lengths = True
+    indexer.query_blocks = lambda length: -(-length // SCORES_BLOCK_Q)
     return indexer

@@ -343,6 +343,70 @@ def test_the_indexers_two_kernels_compile_at_the_published_widths(shape, length)
     assert memory.temp_size_in_bytes < length * length * 4 + (64 << 20)
 
 
+@pytest.mark.parametrize("length", [8192, 16384])
+def test_the_indexers_two_kernels_compile_with_the_rows_lengths(shape, length):
+    """The same dispatch given the row's length: one call of each kernel
+    under its own name, each taking its count of live query blocks as a
+    scalar-prefetch operand, and what leaves them as without lengths."""
+    from sparkdl_tpu.ops import dsa_indexer
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def indexer(q, k, w, n):
+        scores = dsa_indexer.dsa_index_scores(q, k, w, n, num_heads=64)
+        return dsa_indexer.dsa_select(scores, n, top_k=2048)
+
+    compiled = (
+        jax.jit(indexer)
+        .lower(shape((1, length, 64 * 128), bf16), shape((1, length, 128), bf16),
+               shape((1, length, 64), f32), shape((1,), jnp.int32))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for kernel in ("dsa_index_scores", "dsa_select"):
+        calls = re.findall(rf"%{kernel}(?:\.\d+)? = .*", text)
+        assert len(calls) == 1
+        assert "operand_layout_constraints={s32[1]{0}, " in calls[0]
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == length * length  # int8
+    assert memory.temp_size_in_bytes < length * length * 4 + (64 << 20)
+
+
+#: sha256 of the Mosaic module (debug locations aside) of the indexer's two
+#: kernels called without lengths at the cell's shapes, taken from the
+#: commit before lengths were added
+DSA_PINNED = {
+    (8192, "dsa_index_scores"): "7155c3f707d81aca",
+    (8192, "dsa_select"): "0fabcaf5ac463461",
+    (16384, "dsa_index_scores"): "1e191fb00d4903e4",
+    (16384, "dsa_select"): "bb40ac1ee7a4a7a4",
+}
+
+
+@pytest.mark.parametrize("length, kernel", sorted(DSA_PINNED))
+def test_the_indexers_kernels_without_lengths_lower_as_the_parent_did(shape, length, kernel):
+    """Without lengths the index-scores and the selection kernel at the
+    V3.2 cell's shapes are the parent's Mosaic modules to the byte: the
+    call with lengths adds nothing to the call without them."""
+    import hashlib
+
+    from sparkdl_tpu.ops import dsa_indexer
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    if kernel == "dsa_index_scores":
+        lowered = jax.jit(
+            lambda q, k, w: dsa_indexer.dsa_index_scores(q, k, w, num_heads=64)
+        ).lower(shape((1, length, 64 * 128), bf16), shape((1, length, 128), bf16),
+                shape((1, length, 64), f32))
+    else:
+        lowered = jax.jit(lambda s: dsa_indexer.dsa_select(s, top_k=2048)).lower(
+            shape((1, length, length), f32)
+        )
+    digest = hashlib.sha256(_mosaic_text(lowered.as_text()).encode()).hexdigest()[:16]
+    assert digest == DSA_PINNED[(length, kernel)]
+
+
 @pytest.mark.parametrize("lengths", [False, True], ids=["whole", "lengths"])
 @pytest.mark.parametrize(
     "rows, length, selected",
@@ -384,6 +448,53 @@ def test_latent_flash_compiles_at_the_cells_shapes(shape, rows, length, selected
     given = 2 * wide.size * 2 + rows * length * 128 * 2 + selected * rows * length * length
     assert memory.argument_size_in_bytes - given in ((0, 512)[lengths],)
     assert memory.output_size_in_bytes == rows * length * 128 * 128 * 2
+
+
+def test_the_v32_program_holds_the_indexers_kernels_a_layer(shape):
+    """The whole program of the DeepSeek-V3.2 cell's larger bucket (one
+    row of 16,384, five layers at the published widths, weights as
+    arguments), its indexer and attention each handed the row's length:
+    one call of each indexer kernel a layer under its own name, the names
+    the trace's readers match, each taking the row's live query blocks as
+    its first operand."""
+    from sparkdl_tpu.models import deepseek_v2, deepseek_v32
+    from sparkdl_tpu.models.jamba import _unflatten
+    from sparkdl_tpu.ops import dsa_indexer
+    from sparkdl_tpu.ops.flash_attention import flash_attention_latent
+    from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    config, bf16 = deepseek_v32.deepseek_v32(), jnp.bfloat16
+    leaves = {
+        p: shape(s, deepseek_v2._leaf_dtype(p, s, bf16))
+        for p, s in deepseek_v32.param_shapes(config).items()
+    }
+
+    def attention(q, kv, k_rope, dtype, selection=None, lengths=None):
+        return flash_attention_latent(
+            q, kv, k_rope, selection, lengths, num_heads=config.num_heads,
+            scale=config.softmax_scale, block=1024,
+        ).astype(dtype)
+
+    def indexer(q, k, w, lengths=None):
+        scores = dsa_indexer.dsa_index_scores(q, k, w, lengths, num_heads=64)
+        return dsa_indexer.dsa_select(scores, lengths, top_k=config.index_topk)
+
+    attention.takes_lengths = indexer.takes_lengths = True
+
+    def program(p, ids):
+        return deepseek_v32.forward(
+            config, p, ids, dtype=bf16, attention_fn=attention, experts_fn=grouped_matmul,
+            indexer_fn=indexer, combine_fn=moe_combine.moe_combine,
+        )
+
+    text = (
+        jax.jit(program).lower(_unflatten(leaves), shape((1, 16384), jnp.int32))
+        .compile().as_text()
+    )
+    for kernel in ("dsa_index_scores", "dsa_select"):
+        calls = re.findall(rf"%{kernel}(?:\.\d+)? = .*", text)
+        assert len(calls) == config.num_layers
+        assert all("operand_layout_constraints={s32[1]{0}, " in c for c in calls)
 
 
 # -- Trinity-Mini: the window kernel and the whole program ----------------------

@@ -601,3 +601,103 @@ def test_the_models_batch_counters_hold_the_attentions(tiny):
     assert counted["mla.query_blocks_run"] == 3 * (0 + 1 + 2 + 3 + 4)
     assert counted["mla.pairs_computed"] == 3 * (0 + 1 + 3 + 6 + 10) * 64 * 64
     assert counted["dsa.index_tokens"] == 3 * 5 * 256
+
+
+# -- the indexer is handed its rows' lengths -------------------------------------
+
+
+def _indexer_without_lengths(indexer):
+    """The same indexer with `takes_lengths` taken away: what the parent
+    commit built."""
+
+    def blind(q, k, w):
+        return indexer(q, k, w)
+
+    blind.kind = indexer.kind
+    return blind
+
+
+@pytest.mark.parametrize("attention", ["with-lengths", "without-lengths"])
+def test_the_indexers_lengths_change_no_embedding_to_the_bit(tiny, attention):
+    """The tiny preset with the interpreted kernels, rows of 0, 10, 65,
+    300 and 512 tokens in a bucket of 512 (the index-scores kernel's
+    blocks of 256: 0, 1, 1, 2 and 2 of them live): with the indexer
+    handed the rows' lengths, every row's embedding and its counters
+    (`dsa.pairs_selected` too) are those without, to the bit, and every
+    layer's attention is finite at every position, the padding's too,
+    with an attention that runs every query (`without-lengths`) as with
+    one that skips the padding's blocks. The host counts the index
+    kernel's query blocks at each row's length."""
+    _, _, path = tiny
+    preset = program.deepseek_v32_tiny()
+    kernel = make_latent_attention_fn(
+        preset.num_heads, preset.softmax_scale, block=64, interpret=True
+    )
+    if attention == "without-lengths":
+        kernel = _without_lengths(kernel)
+    outputs = []
+
+    def attention_fn(*args, **by_length):
+        out = kernel(*args, **by_length)
+        outputs.append(np.asarray(out))
+        return out
+
+    for name in ("kind", "takes_lengths", "query_blocks", "pairs_computed"):
+        if hasattr(kernel, name):
+            setattr(attention_fn, name, getattr(kernel, name))
+    indexer = make_indexer_fn(preset.index_n_heads, preset.index_topk, interpret=True)
+
+    def built(indexer_fn):
+        return program.deepseek_v32_model_function(
+            "deepseek-v3.2-exp-tiny", dtype=jnp.bfloat16, weights_file=path,
+            attention_fn=attention_fn, indexer_fn=indexer_fn,
+        )
+
+    lengths = [0, 10, 65, 300, 512]
+    ids = np.zeros((len(lengths), 512), np.int32)
+    for row, n in enumerate(lengths):
+        ids[row, :n] = np.random.default_rng(row).integers(1, 512, n)
+    given, blind = built(indexer), built(_indexer_without_lengths(indexer))
+    got = np.asarray(given.fn(given.params, jnp.asarray(ids)))
+    assert len(outputs) == 3 and all(np.isfinite(o).all() for o in outputs)
+    want = np.asarray(blind.fn(blind.params, jnp.asarray(ids)))
+    np.testing.assert_array_equal(got, want)
+    assert got[1:, -2:].sum() > 0  # dsa.pairs_selected, in two columns
+    counted = given.batch_counters(ids, ids != 0)
+    assert counted["dsa.query_blocks"] == 3 * 5 * 2
+    assert counted["dsa.query_blocks_run"] == 3 * (0 + 1 + 1 + 2 + 2)
+    assert not any(name.startswith("dsa.query") for name in blind.batch_counters(ids, ids != 0))
+
+
+@pytest.mark.parametrize("takes_lengths", [True, False], ids=["lengths", "without"])
+def test_index_query_blocks_of_the_cells_job(tiny, takes_lengths):
+    """A job of the V3.2 cell (its traffic, its configuration's buckets,
+    one row a dispatch) in the index-scores kernel's blocks of 256: its
+    ten rows hold 480 query blocks a layer (five of 32 at 8,192 and five
+    of 64 at 16,384), 332 of them a real token; counted over the tiny
+    preset's three layers (the cell's five count 5 x 480 and 5 x 332).
+    An indexer that takes no lengths runs every block."""
+    import json
+
+    _, _, path = tiny
+    with open(os.path.join(ROOT, "benchmarks", "traffic", "embed-long-docs.json")) as f:
+        data = json.load(f)["data"]
+    with open(os.path.join(ROOT, "benchmarks", "configs", "deepseek-v3.2-exp.json")) as f:
+        edges = [int(e) for e in json.load(f)["env"]["SPARKDL_TEXT_BUCKETS"].split(",")]
+    tokens = texts.word_counts(data["rows"] - data["null_rows"], data["word_counts"]) + 2
+    indexer = built = make_indexer_fn(4, 16, interpret=True)
+    if not takes_lengths:
+        indexer = _indexer_without_lengths(built)
+        indexer.query_blocks = built.query_blocks
+    mf = program.deepseek_v32_model_function(
+        "deepseek-v3.2-exp-tiny", weights_file=path, indexer_fn=indexer
+    )
+    total = {}
+    for n in tokens:
+        ids = np.zeros((1, min(e for e in edges if e >= n)), np.int32)
+        ids[0, :n] = 5
+        for name, count in mf.batch_counters(ids, ids != 0).items():
+            total[name] = total.get(name, 0) + count
+    assert total["dsa.index_tokens"] == 3 * 122880 and sum(tokens) == 84223
+    assert total["dsa.query_blocks"] == 3 * 480
+    assert total["dsa.query_blocks_run"] == 3 * (332 if takes_lengths else 480)

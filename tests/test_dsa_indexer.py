@@ -222,3 +222,74 @@ def test_a_selection_of_every_causal_key_is_causal_attention(interpret):
         )
     else:
         assert (np.asarray(with_selection) == np.asarray(without)).all()
+
+
+# -- the rows' lengths ---------------------------------------------------------
+
+#: a bucket of 512 at the kernels' own blocks: 256 x 512 for the scores,
+#: 64 queries over chunks of 512 for the selection
+BUCKET, TOP_K = 512, 24
+
+
+@pytest.fixture(scope="module")
+def whole_bucket():
+    """Two rows' operands and the selection the kernels give without
+    lengths: what the parent commit gave."""
+    heads, dim = 4, 16
+    q, k, w = _operands(2, BUCKET, heads, dim, seed=7)
+    scores = dsa_indexer.dsa_index_scores(q, k, w, num_heads=heads, interpret=True)
+    blind = dsa_indexer.dsa_select(scores, top_k=TOP_K, interpret=True)
+    return (q, k, w), np.asarray(blind)
+
+
+@pytest.mark.parametrize(
+    "length",
+    [0, 1, 100, 128, 256, BUCKET],
+    ids=["padding", "one", "inside-a-block", "64-edge", "256-edge", "whole-bucket"],
+)
+def test_with_lengths_every_real_query_selects_as_without(whole_bucket, length):
+    """The built indexer handed the rows' lengths: a row of `length` real
+    tokens beside one of the whole bucket. Every query of a live 64-query
+    block (those before the length among them) selects what it selects
+    without lengths, to the bit; every query of a block past the length
+    selects itself alone, so that no row of the selection is empty; each
+    row runs its own count of blocks."""
+    (q, k, w), blind = whole_bucket
+    fn = dsa_indexer.make_indexer_fn(4, TOP_K, interpret=True)
+    assert fn.takes_lengths
+    assert fn.query_blocks(length) == -(-length // dsa_indexer.SCORES_BLOCK_Q)
+    lengths = jnp.asarray([length, BUCKET], jnp.int32)
+    got = np.asarray(fn(q, k, w, lengths=lengths))
+    assert got.dtype == np.int8 and got.shape == blind.shape
+    np.testing.assert_array_equal(got[1], blind[1])
+    live = -(-length // 64) * 64
+    np.testing.assert_array_equal(got[0, :live], blind[0, :live])
+    np.testing.assert_array_equal(got[0, live:], np.eye(BUCKET, dtype=np.int8)[live:])
+    assert (got.sum(-1) >= 1).all()
+    assert (np.triu(got, 1) == 0).all()
+
+
+def test_the_scores_of_a_live_block_are_the_parents_and_a_dead_one_is_not_read():
+    """The scores kernel handed lengths gives a live query block's causal
+    scores as without them, to the bit; the selection reads nothing of a
+    dead block's rows (NaN put there changes nothing), and a row of
+    padding alone selects the diagonal whatever its scores."""
+    heads, dim = 4, 16
+    q, k, w = _operands(3, BUCKET, heads, dim, seed=8)
+    lengths = jnp.asarray([0, 200, BUCKET], jnp.int32)
+    blind = np.asarray(dsa_indexer.dsa_index_scores(q, k, w, num_heads=heads, interpret=True))
+    got = np.asarray(
+        dsa_indexer.dsa_index_scores(q, k, w, lengths, num_heads=heads, interpret=True)
+    )
+    causal = np.tril(np.ones((BUCKET, BUCKET), bool))
+    for row, live in ((1, 256), (2, BUCKET)):
+        np.testing.assert_array_equal(got[row, :live][causal[:live]], blind[row, :live][causal[:live]])
+    poisoned = blind.copy()
+    poisoned[0] = np.nan
+    poisoned[1, 256:] = np.nan
+    want = dsa_indexer.dsa_select(jnp.asarray(blind), lengths, top_k=TOP_K, interpret=True)
+    got = dsa_indexer.dsa_select(jnp.asarray(poisoned), lengths, top_k=TOP_K, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got)[0], np.eye(BUCKET, dtype=np.int8))
+    with pytest.raises(ValueError, match=r"lengths for 1 rows are \[1\] int32"):
+        dsa_indexer.dsa_select(jnp.asarray(blind[:1]), lengths[:2], top_k=TOP_K)
