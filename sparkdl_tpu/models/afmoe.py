@@ -20,14 +20,14 @@ routed experts after them.
         s = sigmoid(W_r u) over all experts, w = route_scale * s_chosen / sum
 
 **Shared with DeepSeek, adapted and not copied**: the router is
-``deepseek_v2.route``'s second gate (``n_group`` 1: no group is dropped)
-and the routed experts ``deepseek_v2._routed`` over
-``ops/grouped_matmul.py``; each reads this family's keys through the
-properties of :class:`AfmoeConfig` that carry DeepSeek's names. A chip
-here holds every expert (``experts_held`` (0, 128)), so ``slot_capacity``
-is every slot and ``_routed`` builds the one body and no conditional.
-The norms, the dense products and the pooling are ``models/jamba.py``'s
-and ``models/deepseek_v2.py``'s.
+``models/moe.py:route``'s second gate (``n_group`` 1: no group is dropped)
+and the routed experts ``moe.routed`` over ``ops/grouped_matmul.py``;
+each reads this family's keys through the properties of
+:class:`AfmoeConfig` that carry DeepSeek's names. A chip here holds
+every expert (``experts_held`` (0, 128)), so ``moe.slot_capacity`` is
+every slot and ``moe.routed`` builds the one body and no conditional.
+The norms, the dense products and the pooling are ``models/layers.py``'s,
+the SwiGLU ``models/deepseek_v2.py``'s.
 
 ``embed`` is the mean, over a row's real tokens, of the final RMSNorm of
 their states, as both DeepSeek families have it. Precision: matrices and
@@ -56,7 +56,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from sparkdl_tpu.models import deepseek_v2 as v2
-from sparkdl_tpu.models.jamba import _dense, _rms, _unflatten, load_flat
+from sparkdl_tpu.models import moe
+from sparkdl_tpu.models.layers import (
+    dense,
+    leaf_dtype,
+    load_flat,
+    mean_real_state,
+    preset,
+    query_block_counters,
+    row_lengths,
+    turn,
+    unflatten,
+)
+from sparkdl_tpu.models.layers import rms as _rms  # a planted fault patches ``afmoe._rms``
 from sparkdl_tpu.ops.moe_combine import gather_combine
 from sparkdl_tpu.utils.profiler import scope
 
@@ -92,10 +104,10 @@ class AfmoeConfig:
     mup_enabled: bool = True
     #: [first, end) of the routed experts whose weights this chip holds
     experts_held: Tuple[int, int] = (0, 128)
-    #: ``deepseek_v2._routed``'s worst-case arm in one buffer
+    #: ``moe.routed``'s worst-case arm in one buffer
     worst_case_chunk_rows: Optional[int] = None
 
-    # -- the names ``deepseek_v2.route`` and ``_routed`` read ---------------
+    # -- the names ``moe.route`` and ``moe.routed`` read ---------------------
 
     @property
     def n_routed_experts(self) -> int:
@@ -229,8 +241,8 @@ def init_params(config: AfmoeConfig, seed: int, dtype) -> dict:
             v = rng.standard_normal(shape, dtype=np.float32) / math.sqrt(fan_in)
             if kind == "router":
                 v *= 2.0
-        flat[path] = jnp.asarray(v, v2._leaf_dtype(path, shape, dtype))
-    return _unflatten(flat)
+        flat[path] = jnp.asarray(v, leaf_dtype(path, shape, dtype))
+    return unflatten(flat)
 
 
 def rope_tables(config: AfmoeConfig, length: int):
@@ -247,7 +259,7 @@ def _rotate(t, tables):
     """t [B, L, heads, d] float32 turned by the rotary ``tables``, lane i
     paired with lane i + d / 2."""
     cos, sin = tables
-    return t * cos[:, None] + v2._turn(t) * sin[:, None]
+    return t * cos[:, None] + turn(t) * sin[:, None]
 
 
 def _output_gate(o, g):
@@ -257,10 +269,10 @@ def _output_gate(o, g):
 
 def _with_lengths(attention_fn, real):
     """``attention_fn`` handed its rows' lengths besides its operands
-    (``deepseek_v2.row_lengths`` over real [B, L] bool) where it takes
+    (``layers.row_lengths`` over real [B, L] bool) where it takes
     them, so that the kernel runs no query block of padding alone; as it
     is where it does not (the dense fallback, a stand-in in a test)."""
-    by_length = v2.row_lengths(attention_fn, real)
+    by_length = row_lengths(attention_fn, real)
     return functools.partial(attention_fn, **by_length) if by_length else attention_fn
 
 
@@ -274,7 +286,7 @@ def _attention(config: AfmoeConfig, p, u, tables, attention_fn):
     d = config.head_dim
 
     def heads(w, norm=None):  # [B, L, heads * d] -> [B, heads, L, d]
-        t = _dense(u, w).reshape(rows, length, -1, d)
+        t = dense(u, w).reshape(rows, length, -1, d)
         if norm is not None:
             t = _rms(t, norm, eps)
             if tables is not None:
@@ -284,12 +296,12 @@ def _attention(config: AfmoeConfig, p, u, tables, attention_fn):
     with scope("attn.qkv"):
         q, k = heads(p["q"], p["q_norm"]), heads(p["k"], p["k_norm"])
         v = heads(p["v"])
-        g = _dense(u, p["gate"])
+        g = dense(u, p["gate"])
     with scope("attn.window" if tables is not None else "attn.full"):
         o = attention_fn(q, k, v, None, dtype)
     with scope("attn.out"):
         o = o.transpose(0, 2, 1, 3).reshape(rows, length, -1)
-        return _dense(_output_gate(o, g).astype(dtype), p["o"])
+        return dense(_output_gate(o, g).astype(dtype), p["o"])
 
 
 def forward(
@@ -325,18 +337,19 @@ def forward(
             x = x + _rms(attended, p["norm_post_attn"], eps)
         with scope("mlp"):
             u = _rms(x, p["norm_pre_mlp"], eps)
+            # ``deepseek_v2._swiglu`` itself: a planted fault patches it there
             if i < config.num_dense_layers:
                 x = x + _rms(v2._swiglu(p["mlp"], u.astype(dtype)), p["norm_post_mlp"], eps)
                 continue
-        routed, count, fits = v2._routed(
-            config, p["moe"], u, real, experts_fn, combine_fn=combine_fn
+        routed, count, fits = moe.routed(
+            config, p["moe"], u, real, experts_fn, gate=moe.route, combine_fn=combine_fn
         )
         with scope("mlp"):
             shared = v2._swiglu(p["moe"]["shared"], u.astype(dtype))
             x = x + _rms(shared + routed, p["norm_post_mlp"], eps)
         slots_held, sized = slots_held + count, sized + fits
     with scope("pool"):
-        out = v2._mean_real_state(_rms(x, params["final_norm"], eps), real)
+        out = mean_real_state(_rms(x, params["final_norm"], eps), real)
     return out, slots_held, sized
 
 
@@ -351,82 +364,50 @@ def afmoe_model_function(
     combine_fn=None,
     name: Optional[str] = None,
 ):
-    """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
-    tuples, as TextEmbedder feeds them). ``attention_fn`` (the full
-    layers), ``window_attention_fn`` (the sliding ones), ``experts_fn``
-    and ``combine_fn`` default to the build-time choice of
-    ``make_flash_attention_fn(causal=True)``, the same with
-    ``window=sliding_window``, both in blocks of 512 as Jamba's,
-    ``make_grouped_matmul_fn()`` and ``make_moe_combine_fn()``: the
-    Pallas kernels on TPU.
+    """The ``embed`` ModelFunction (``moe.routed_model_function``: the
+    embedding and ``moe.ROW_COUNTERS`` a row). ``attention_fn`` (the full
+    layers) and ``window_attention_fn`` (the sliding ones) default to the
+    build-time choice of ``make_flash_attention_fn(causal=True)`` and the
+    same with ``window=sliding_window``, both in blocks of 512 as Jamba's:
+    the Pallas kernels on TPU.
 
-    The program's result is [B, hidden + 3]: the embedding and DeepSeek's
-    three row counters (``moe.slots_held``, ``moe.buffer_sized``,
-    ``moe.buffer_full``), which ``TextEmbedder`` strips. Per dispatched
-    token it counts ``attn.window_tokens`` (once a sliding layer) and
-    ``attn.full_tokens`` (once a full one), per real token
-    ``moe.slots_routed``; ``mf.batch_counters`` counts the attention
-    kernels' query blocks, ``attn.query_blocks`` and
+    Per dispatched token it counts ``attn.window_tokens`` (once a sliding
+    layer) and ``attn.full_tokens`` (once a full one); ``mf.batch_counters``
+    counts the attention kernels' query blocks, ``attn.query_blocks`` and
     ``attn.query_blocks_run``, over the five layers
-    (``deepseek_v2.query_block_counters``, each attention at its own
-    layers: nothing for one that says no block count, the dense
-    fallbacks)."""
-    from sparkdl_tpu.graph.function import ModelFunction
-    from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
-    from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
-    from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn
+    (``layers.query_block_counters``, each attention at its own layers:
+    nothing for one that says no block count, the dense fallbacks)."""
+    from sparkdl_tpu.ops import flash_attention
 
-    if size not in _SIZES:
-        raise ValueError(f"Unknown AFMoE size {size!r}; supported: {sorted(_SIZES)}")
-    config = _SIZES[size]()
+    config = preset(_SIZES, size, "AFMoE")
     if attention_fn is None:
-        attention_fn = make_flash_attention_fn(block_q=512, block_k=512, causal=True)
+        attention_fn = flash_attention.make_flash_attention_fn(
+            block_q=512, block_k=512, causal=True
+        )
     if window_attention_fn is None:
-        window_attention_fn = make_flash_attention_fn(
+        window_attention_fn = flash_attention.make_flash_attention_fn(
             block_q=512, block_k=512, causal=True, window=config.sliding_window
         )
-    if experts_fn is None:
-        experts_fn = make_grouped_matmul_fn()
-    if combine_fn is None:
-        combine_fn = make_moe_combine_fn()
     if weights_file:
-        params = load_flat(param_shapes(config), weights_file, dtype, v2._leaf_dtype)
+        params = load_flat(param_shapes(config), weights_file, dtype, leaf_dtype)
     else:
         params = init_params(config, seed, dtype)
-
-    def fn(p, x):
-        ids = x[0] if isinstance(x, (tuple, list)) else x
-        out, slots_held, sized = forward(
-            config, p, ids, dtype=dtype, attention_fn=attention_fn,
-            window_attention_fn=window_attention_fn, experts_fn=experts_fn,
-            combine_fn=combine_fn,
-        )
-        with scope("pool"):
-            sized = jnp.broadcast_to(sized, slots_held.shape)
-            counts = jnp.stack([slots_held, sized, config.expert_layers - sized], 1)
-            return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
-
-    mf = ModelFunction(fn, params, input_dtype=jnp.int32, name=name or f"{size}[embed]")
-    mf.weights_as_arguments = True
-    mf.vocab_size = config.vocab_size
-    mf.attention = getattr(attention_fn, "kind", "custom")
-    mf.window_attention = getattr(window_attention_fn, "kind", "custom")
-    mf.experts = getattr(experts_fn, "kind", "custom")
-    mf.combine = getattr(combine_fn, "kind", "custom")
-    mf.row_counters = ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
     sliding = config.sliding_layers
     full = config.num_hidden_layers - sliding
-    mf.dispatched_token_counters = {"attn.window_tokens": sliding, "attn.full_tokens": full}
 
     def batch_counters(ids, real) -> dict:
         counted = {}
         for fn, layers in ((window_attention_fn, sliding), (attention_fn, full)):
-            for name, count in v2.query_block_counters(fn, layers, ids, real, "attn").items():
+            for name, count in query_block_counters(fn, layers, ids, real, "attn").items():
                 counted[name] = counted.get(name, 0) + count
         return counted
 
-    mf.batch_counters = batch_counters
-    mf.real_token_counters = {
-        "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
+    kernels = {
+        "attention_fn": attention_fn, "window_attention_fn": window_attention_fn,
+        "experts_fn": experts_fn, "combine_fn": combine_fn,
     }
-    return mf
+    return moe.routed_model_function(
+        config, params, forward, kernels, dtype=dtype, name=name or f"{size}[embed]",
+        dispatched_token_counters={"attn.window_tokens": sliding, "attn.full_tokens": full},
+        batch_counters=batch_counters,
+    )

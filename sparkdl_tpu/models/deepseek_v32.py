@@ -17,18 +17,17 @@ pair and attention sees only the ``index_topk`` best keys of each query.
       S_t     = the min(index_topk, t + 1) keys s <= t of largest I[t,s],
                 of equal scores the lower s first
       Attn: softmax over s in S_t only, every head of token t the same S_t
-    Routed(u): ``models/deepseek_v2.py:route``'s second gate (sigmoid
-      scores, the choice on score + a learned correction bias, a group
-      ranked by the sum of its two best, weights renormalised and then
-      scaled), the sum over the k whose expert this chip holds.
+    Routed(u): ``models/moe.py:route``'s second gate (sigmoid scores, the
+      choice on score + a learned correction bias, a group ranked by the
+      sum of its two best, weights renormalised and then scaled), the sum
+      over the k whose expert this chip holds.
 
-**Shared with DeepSeek-V2, adapted and not copied**: ``_mla_inputs`` (the
+**Shared with DeepSeek-V2, adapted and not copied**: ``mla_inputs`` (the
 five projections, YaRN's tables and the rotary split), the latent flash
-kernel (given the selection), ``_routed`` with ``slot_capacity``, the
-``lax.cond`` on the load and ``_experts_and_combine``, ``_swiglu``,
-``_mean_real_state``, weights as arguments and the row counters: each
-takes this family's sizes through its configuration. What is this
-family's own: the indexer, the gate's second branch, the worst-case
+kernel (given the selection) and the SwiGLU from ``models/deepseek_v2.py``;
+the routed layer, its slot buffer and the row counters from
+``models/moe.py``; each takes this family's sizes through its
+configuration. What is this family's own: the indexer, the worst-case
 arm of the routed path in passes (``worst_case_chunk_rows``: its one
 buffer of tokens x k slot rows is 5.6 GB at 16,384 tokens of 7,168),
 and the indexer's rotary, which pairs lane i with i + rope / 2 (halves)
@@ -59,8 +58,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from sparkdl_tpu.models import deepseek_v2 as v2
+from sparkdl_tpu.models import moe
 from sparkdl_tpu.models.deepseek_v2 import DeepseekV2Config
-from sparkdl_tpu.models.jamba import _dense, _rms, _unflatten, load_flat
+from sparkdl_tpu.models.layers import (
+    attention_batch_counters,
+    dense,
+    leaf_dtype,
+    load_flat,
+    mean_real_state,
+    preset,
+    query_block_counters,
+    rms,
+    row_lengths,
+    turn,
+    unflatten,
+)
 from sparkdl_tpu.ops.moe_combine import gather_combine
 from sparkdl_tpu.utils.profiler import scope
 
@@ -178,8 +190,8 @@ def init_params(config: DeepseekV32Config, seed: int, dtype) -> dict:
             v = rng.standard_normal(shape, dtype=np.float32) / math.sqrt(shape[-2])
             if kind == "router":
                 v *= 2.0
-        flat[path] = jnp.asarray(v, v2._leaf_dtype(path, shape, dtype))
-    return _unflatten(flat)
+        flat[path] = jnp.asarray(v, leaf_dtype(path, shape, dtype))
+    return unflatten(flat)
 
 
 def _layer_norm(x, g, b, eps):
@@ -196,7 +208,7 @@ def _rotate_head(x, cos, sin):
     if x.ndim == 4:
         cos, sin = cos[:, None], sin[:, None]
     r = x[..., :rope]
-    return jnp.concatenate([r * cos + v2._turn(r) * sin, x[..., rope:]], -1)
+    return jnp.concatenate([r * cos + turn(r) * sin, x[..., rope:]], -1)
 
 
 def index_inputs(config: DeepseekV32Config, p, c_q, u, tables):
@@ -207,11 +219,11 @@ def index_inputs(config: DeepseekV32Config, p, c_q, u, tables):
     rows, length, _ = u.shape
     heads, dim = config.index_n_heads, config.index_head_dim
     cos, sin = tables
-    q = _dense(c_q, p["q_b"]).reshape(rows, length, heads, dim)
+    q = dense(c_q, p["q_b"]).reshape(rows, length, heads, dim)
     q = _rotate_head(q, cos, sin).reshape(rows, length, heads * dim).astype(dtype)
-    k = _layer_norm(_dense(u, p["k"]), p["k_norm"], p["k_norm_bias"], config.layer_norm_eps)
+    k = _layer_norm(dense(u, p["k"]), p["k_norm"], p["k_norm_bias"], config.layer_norm_eps)
     k = _rotate_head(k, cos, sin).astype(dtype)
-    w = _dense(u, p["weights"]) * (heads**-0.5 * dim**-0.5)
+    w = dense(u, p["weights"]) * (heads**-0.5 * dim**-0.5)
     return q, k, w
 
 
@@ -233,15 +245,15 @@ def forward(
     the (query, key) pairs the layers' attention read for the row's real
     queries [B] int32). A length within ``index_topk`` runs no indexer.
     The attention and the indexer are each handed the rows' lengths where
-    they say they take them (``deepseek_v2.row_lengths``)."""
+    they say they take them (``layers.row_lengths``)."""
     eps = config.rms_norm_eps
     selects = ids.shape[1] > config.index_topk
     with scope("embed"):
         real = ids != 0
         tables = v2.rope_tables(config, ids.shape[1])
         x = params["embed"][ids].astype(jnp.float32)
-        by_length = v2.row_lengths(attention_fn, real)
-        index_by_length = v2.row_lengths(indexer_fn, real) if selects else {}
+        by_length = row_lengths(attention_fn, real)
+        index_by_length = row_lengths(indexer_fn, real) if selects else {}
     slots_held = jnp.zeros((ids.shape[0],), jnp.int32)
     pairs = jnp.zeros((ids.shape[0],), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
@@ -251,8 +263,8 @@ def forward(
         # its operands, its two kernels (one call here), and the count of
         # what it selected
         with scope("mla.q"):
-            u = _rms(x, p["norm_in"], eps).astype(dtype)
-        c_q, q, kv, k_pe = v2._mla_inputs(config, p["attn"], u, tables)
+            u = rms(x, p["norm_in"], eps).astype(dtype)
+        c_q, q, kv, k_pe = v2.mla_inputs(config, p["attn"], u, tables)
         if selects:
             with scope("dsa.index_inputs"):
                 operands = index_inputs(config, p["attn"]["indexer"], c_q, u, tables)
@@ -270,14 +282,15 @@ def forward(
             with scope("dsa.count"):
                 pairs = pairs + causal_pairs(real)
         with scope("mla.out"):
-            x = x + _dense(o, p["attn"]["o"])
+            x = x + dense(o, p["attn"]["o"])
         with scope("mlp"):
-            u = _rms(x, p["norm_ff"], eps)
+            u = rms(x, p["norm_ff"], eps)
+            # ``deepseek_v2._swiglu`` itself: a planted fault patches it there
             if i < config.first_k_dense:
                 x = x + v2._swiglu(p["mlp"], u.astype(dtype))
                 continue
-        routed, count, fits = v2._routed(
-            config, p["moe"], u, real, experts_fn, combine_fn=combine_fn
+        routed, count, fits = moe.routed(
+            config, p["moe"], u, real, experts_fn, gate=moe.route, combine_fn=combine_fn
         )
         with scope("mlp"):
             x = x + v2._swiglu(p["moe"]["shared"], u.astype(dtype))
@@ -285,14 +298,8 @@ def forward(
             x = x + routed
         slots_held, sized = slots_held + count, sized + fits
     with scope("pool"):
-        out = v2._mean_real_state(_rms(x, params["final_norm"], eps), real)
+        out = mean_real_state(rms(x, params["final_norm"], eps), real)
     return out, slots_held, sized, pairs
-
-
-#: ``dsa.pairs_selected`` rides back in two float32 columns, the count's
-#: multiple of this and the rest: each is exact in float32, and so are
-#: their sums over a partition's rows (a row's count reaches 1.7e8)
-_PAIRS_SPLIT = 4096
 
 
 def deepseek_v32_model_function(
@@ -306,69 +313,37 @@ def deepseek_v32_model_function(
     combine_fn=None,
     name: Optional[str] = None,
 ):
-    """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
-    tuples), as ``deepseek_v2_model_function`` builds it. ``indexer_fn``
-    defaults to the build-time choice of ``make_indexer_fn(heads, top_k)``:
-    the Pallas kernels on TPU, reported as ``mf.indexer``.
+    """The ``embed`` ModelFunction, as ``deepseek_v2_model_function``
+    builds it. ``indexer_fn`` defaults to the build-time choice of
+    ``make_indexer_fn(heads, top_k)``: the Pallas kernels on TPU, reported
+    as ``mf.indexer``.
 
-    The program's result is [B, hidden + 5]: the embedding, DeepSeek-V2's
-    three row counters and, in two columns, ``dsa.pairs_selected``: the
-    (query, key) pairs the layers' attention read for the row's real
-    queries, counted on the device from the selection itself. On the
-    host, ``mf.batch_counters(ids, real)`` counts for every dispatched
-    batch ``dsa.index_tokens`` (rows x bucket edge x layers, where the
-    bucket runs the indexer), ``dsa.pairs_causal`` (what dense causal
-    attention would have read, from the real lengths) and
-    ``mla.pairs_computed``, ``mla.query_blocks`` and
-    ``mla.query_blocks_run`` (what the attention it was built with runs,
-    at each row's length where it takes lengths:
-    ``deepseek_v2.attention_batch_counters``), and where the bucket runs
-    the indexer ``dsa.query_blocks`` and ``dsa.query_blocks_run`` (the
-    index-scores kernel's query blocks, every one and those holding a
-    real token, where the indexer says how many a row has:
-    ``deepseek_v2.query_block_counters``)."""
-    from sparkdl_tpu.graph.function import ModelFunction
-    from sparkdl_tpu.ops.dsa_indexer import make_indexer_fn
-    from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
-    from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
-    from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn
+    Beside ``moe.ROW_COUNTERS`` a row carries ``dsa.pairs_selected``, a
+    wide count in two columns: the (query, key) pairs the layers'
+    attention read for the row's real queries, counted on the device from
+    the selection itself. On the host, ``mf.batch_counters(ids, real)``
+    counts for every dispatched batch ``dsa.index_tokens`` (rows x bucket
+    edge x layers, where the bucket runs the indexer), ``dsa.pairs_causal``
+    (what dense causal attention would have read, from the real lengths)
+    and ``mla.pairs_computed``, ``mla.query_blocks`` and
+    ``mla.query_blocks_run`` (``layers.attention_batch_counters``), and
+    where the bucket runs the indexer ``dsa.query_blocks`` and
+    ``dsa.query_blocks_run`` (the index-scores kernel's query blocks,
+    every one and those holding a real token, where the indexer says how
+    many a row has: ``layers.query_block_counters``)."""
+    from sparkdl_tpu.ops import dsa_indexer, flash_attention
 
-    if size not in _SIZES:
-        raise ValueError(
-            f"Unknown DeepSeek-V3.2 size {size!r}; supported: {sorted(_SIZES)}"
-        )
-    config = _SIZES[size]()
+    config = preset(_SIZES, size, "DeepSeek-V3.2")
     if attention_fn is None:
-        attention_fn = make_latent_attention_fn(
+        attention_fn = flash_attention.make_latent_attention_fn(
             config.num_heads, config.softmax_scale, block=1024
         )
-    if experts_fn is None:
-        experts_fn = make_grouped_matmul_fn()
     if indexer_fn is None:
-        indexer_fn = make_indexer_fn(config.index_n_heads, config.index_topk)
-    if combine_fn is None:
-        combine_fn = make_moe_combine_fn()
+        indexer_fn = dsa_indexer.make_indexer_fn(config.index_n_heads, config.index_topk)
     if weights_file:
-        params = load_flat(param_shapes(config), weights_file, dtype, v2._leaf_dtype)
+        params = load_flat(param_shapes(config), weights_file, dtype, leaf_dtype)
     else:
         params = init_params(config, seed, dtype)
-
-    def fn(p, x):
-        ids = x[0] if isinstance(x, (tuple, list)) else x
-        out, slots_held, sized, pairs = forward(
-            config, p, ids, dtype=dtype, attention_fn=attention_fn,
-            experts_fn=experts_fn, indexer_fn=indexer_fn, combine_fn=combine_fn,
-        )
-        with scope("pool"):
-            sized = jnp.broadcast_to(sized, slots_held.shape)
-            counts = jnp.stack(
-                [
-                    slots_held, sized, config.expert_layers - sized,
-                    pairs // _PAIRS_SPLIT * _PAIRS_SPLIT, pairs % _PAIRS_SPLIT,
-                ],
-                1,
-            )
-            return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
 
     layers, top_k = config.num_layers, config.index_topk
 
@@ -378,26 +353,16 @@ def deepseek_v32_model_function(
         return {
             "dsa.index_tokens": int(ids.size) * layers * selects,
             "dsa.pairs_causal": int((n * (n + 1) // 2).sum()) * layers,
-            **v2.attention_batch_counters(attention_fn, layers, ids, real),
-            **(v2.query_block_counters(indexer_fn, layers, ids, real, "dsa") if selects else {}),
+            **attention_batch_counters(attention_fn, layers, ids, real),
+            **(query_block_counters(indexer_fn, layers, ids, real, "dsa") if selects else {}),
         }
 
-    mf = ModelFunction(
-        fn, params, input_dtype=jnp.int32, name=name or f"{size}[embed]"
-    )
-    mf.weights_as_arguments = True
-    mf.vocab_size = config.vocab_size
-    mf.attention = getattr(attention_fn, "kind", "custom")
-    mf.experts = getattr(experts_fn, "kind", "custom")
-    mf.combine = getattr(combine_fn, "kind", "custom")
-    mf.indexer = getattr(indexer_fn, "kind", "custom")
-    mf.row_counters = (
-        "moe.slots_held", "moe.buffer_sized", "moe.buffer_full",
-        "dsa.pairs_selected", "dsa.pairs_selected",
-    )
-    mf.dispatched_token_counters = {"mla.attention_tokens": config.num_layers}
-    mf.real_token_counters = {
-        "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
+    kernels = {
+        "attention_fn": attention_fn, "experts_fn": experts_fn,
+        "indexer_fn": indexer_fn, "combine_fn": combine_fn,
     }
-    mf.batch_counters = batch_counters
-    return mf
+    return moe.routed_model_function(
+        config, params, forward, kernels, dtype=dtype, name=name or f"{size}[embed]",
+        dispatched_token_counters={"mla.attention_tokens": config.num_layers},
+        batch_counters=batch_counters, wide_counters=("dsa.pairs_selected",),
+    )

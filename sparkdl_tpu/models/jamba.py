@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sparkdl_tpu.models.layers import dense, load_flat, preset, rms, silu, unflatten
 from sparkdl_tpu.utils.profiler import scope
 
 
@@ -149,17 +150,6 @@ def param_shapes(config: JambaConfig) -> dict:
     return shapes
 
 
-def _unflatten(flat: dict) -> dict:
-    tree: dict = {}
-    for path, leaf in flat.items():
-        node = tree
-        *parents, last = path.split("/")
-        for p in parents:
-            node = node.setdefault(p, {})
-        node[last] = leaf
-    return tree
-
-
 def _leaf_dtype(path: str, shape: tuple, dtype) -> Any:
     """Matrices (and the embedding) are ``dtype``; vectors, the
     convolution and ``A_log`` feed float32 arithmetic and stay float32."""
@@ -193,51 +183,12 @@ def init_params(config: JambaConfig, seed: int, dtype) -> dict:
         else:
             v = rng.standard_normal(shape, dtype=np.float32) / math.sqrt(shape[0])
         flat[path] = jnp.asarray(v, _leaf_dtype(path, shape, dtype))
-    return _unflatten(flat)
-
-
-def load_flat(shapes: dict, weights_file: str, dtype, leaf_dtype) -> dict:
-    """The tree of a flat ``.npz`` that holds ``shapes``' paths, each
-    leaf as ``leaf_dtype(path, shape, dtype)``. A ``uint16`` leaf is the
-    bit pattern of bfloat16 values (numpy has no bfloat16 of its own to
-    store): 2 bytes a parameter on disk and on the way in."""
-    flat = {}
-    with np.load(weights_file, allow_pickle=False) as blob:
-        missing = sorted(set(shapes) - set(blob.files))
-        if missing:
-            raise ValueError(
-                f"{weights_file} lacks {len(missing)} leaves, the first "
-                f"{missing[0]!r}"
-            )
-        for path, shape in shapes.items():
-            leaf = blob[path]
-            if leaf.dtype == np.uint16:
-                leaf = leaf.view(jnp.bfloat16)
-            if leaf.shape != shape:
-                raise ValueError(
-                    f"{weights_file}: {path} is {leaf.shape}, not {shape}"
-                )
-            flat[path] = leaf.astype(leaf_dtype(path, shape, dtype), copy=False)
-    return _unflatten(flat)
+    return unflatten(flat)
 
 
 def load_params(config: JambaConfig, weights_file: str, dtype) -> dict:
     """A flat ``.npz`` of ``param_shapes``' paths."""
     return load_flat(param_shapes(config), weights_file, dtype, _leaf_dtype)
-
-
-def _rms(x, w, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _silu(x):
-    return x * jax.nn.sigmoid(x)
-
-
-def _dense(x, w):
-    """x [.., in] @ w [in, out], accumulated in float32."""
-    return jnp.einsum("...i,io->...o", x, w, preferred_element_type=jnp.float32)
 
 
 def _causal_conv(h, taps, bias):
@@ -261,22 +212,22 @@ def _mamba(config: JambaConfig, p, u, scan_fn):
     dtype, eps = u.dtype, config.rms_norm_eps
     n, r = config.d_state, config.dt_rank
     with scope("mamba.in_proj"):
-        h, z = jnp.split(_dense(u, p["in_proj"]), 2, -1)
+        h, z = jnp.split(dense(u, p["in_proj"]), 2, -1)
     with scope("mamba.conv"):
-        h = _silu(_causal_conv(h, p["conv_w"], p["conv_b"]))
+        h = silu(_causal_conv(h, p["conv_w"], p["conv_b"]))
     with scope("mamba.ssm_inputs"):
-        rbc = _dense(h.astype(dtype), p["x_proj"])
+        rbc = dense(h.astype(dtype), p["x_proj"])
         dt = jax.nn.softplus(
-            _dense(_rms(rbc[..., :r], p["dt_norm"], eps).astype(dtype), p["dt_proj"])
+            dense(rms(rbc[..., :r], p["dt_norm"], eps).astype(dtype), p["dt_proj"])
             + p["dt_bias"]
         )
-        b = _rms(rbc[..., r : r + n], p["b_norm"], eps)
-        c = _rms(rbc[..., r + n :], p["c_norm"], eps)
+        b = rms(rbc[..., r : r + n], p["b_norm"], eps)
+        c = rms(rbc[..., r + n :], p["c_norm"], eps)
         a = -jnp.exp(p["A_log"])
     with scope("mamba.scan"):
         gated = scan_fn(h, dt, b, c, z, a, p["D"])
     with scope("mamba.out_proj"):
-        return _dense(gated.astype(dtype), p["out_proj"])
+        return dense(gated.astype(dtype), p["out_proj"])
 
 
 def _attention(config: JambaConfig, p, u, attention_fn):
@@ -284,7 +235,7 @@ def _attention(config: JambaConfig, p, u, attention_fn):
     rows, length, _ = u.shape
 
     def heads(w):  # [B, L, heads * Dh] -> [B, heads, L, Dh]
-        t = _dense(u, w).astype(dtype)
+        t = dense(u, w).astype(dtype)
         return t.reshape(rows, length, -1, config.head_dim).transpose(0, 2, 1, 3)
 
     with scope("attn.qkv"):
@@ -292,7 +243,7 @@ def _attention(config: JambaConfig, p, u, attention_fn):
     with scope("attn.core"):
         o = attention_fn(q, k, v, None, dtype)
     with scope("attn.out"):
-        return _dense(o.transpose(0, 2, 1, 3).reshape(rows, length, -1), p["o"])
+        return dense(o.transpose(0, 2, 1, 3).reshape(rows, length, -1), p["o"])
 
 
 def forward(config: JambaConfig, params, ids, *, dtype, attention_fn, scan_fn):
@@ -306,7 +257,7 @@ def forward(config: JambaConfig, params, ids, *, dtype, attention_fn, scan_fn):
         # a norm is in the scope of the part it feeds, a residual sum in
         # that of the part it closes
         with scope("attn.qkv" if attends else "mamba.in_proj"):
-            u = _rms(x, p["norm_in"], eps).astype(dtype)
+            u = rms(x, p["norm_in"], eps).astype(dtype)
         if attends:
             mixed = _attention(config, p["attn"], u, attention_fn)
         else:
@@ -314,12 +265,12 @@ def forward(config: JambaConfig, params, ids, *, dtype, attention_fn, scan_fn):
         with scope("attn.out" if attends else "mamba.out_proj"):
             x = x + mixed
         with scope("mlp"):
-            u = _rms(x, p["norm_ff"], eps).astype(dtype)
+            u = rms(x, p["norm_ff"], eps).astype(dtype)
             mlp = p["mlp"]
-            gate = _silu(_dense(u, mlp["gate"]))
-            x = x + _dense((gate * _dense(u, mlp["up"])).astype(dtype), mlp["down"])
+            gate = silu(dense(u, mlp["gate"]))
+            x = x + dense((gate * dense(u, mlp["up"])).astype(dtype), mlp["down"])
     with scope("pool"):
-        return _rms(_last_real_state(x, ids), params["final_norm"], eps)
+        return rms(_last_real_state(x, ids), params["final_norm"], eps)
 
 
 def jamba_model_function(
@@ -340,11 +291,7 @@ def jamba_model_function(
     from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn
     from sparkdl_tpu.ops.selective_scan import make_selective_scan_fn
 
-    if size not in _SIZES:
-        raise ValueError(
-            f"Unknown Jamba size {size!r}; supported: {sorted(_SIZES)}"
-        )
-    config = _SIZES[size]()
+    config = preset(_SIZES, size, "Jamba")
     if attention_fn is None:
         # 512-token blocks: a 2,048-token row is 10 live blocks a head,
         # not 136 of 128

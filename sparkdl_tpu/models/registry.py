@@ -25,6 +25,7 @@ artifact store.
 
 from __future__ import annotations
 
+import importlib
 import pickle
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -603,19 +604,19 @@ _register(
 )
 
 
-def _jamba_text_builder(size: str):
-    """Builder over models/jamba.py presets: a causal hybrid of Mamba-1
-    and attention layers whose ``embed`` is the final state of a row's
-    last real token. The ModelFunction is marked ``weights_as_arguments``
-    and reports ``attention`` ('flash' | 'dense') and ``scan``
-    ('pallas' | 'jnp'), both chosen at build time."""
+def _text_builder(module: str, function: str, size: str):
+    """Builder over a weights-as-arguments family's presets:
+    ``function(size, ...)`` of ``sparkdl_tpu/models/<module>.py``, imported
+    when the model is built. Its ModelFunction reports the kernels it
+    chose at build time (``mf.attention``, ``mf.experts``, ..., each
+    'pallas' or 'flash' on TPU) and, for a routed family, the counts each
+    row carries (``mf.row_counters``)."""
 
     def build(
         spec: NamedTextModel, mode: str, dtype, weights_file, seed
     ) -> ModelFunction:
-        from sparkdl_tpu.models import jamba as jamba_mod
-
-        return jamba_mod.jamba_model_function(
+        family = importlib.import_module(f"sparkdl_tpu.models.{module}")
+        return getattr(family, function)(
             size,
             dtype=dtype,
             seed=seed,
@@ -626,188 +627,37 @@ def _jamba_text_builder(size: str):
     return build
 
 
-# AI21-Jamba2-3B at its published widths (no position table: the length
-# is the model's declared context), and the same family at test size.
-_register(
-    NamedTextModel(
-        "jamba2-3b", 262144, 2560, "jax", _jamba_text_builder("jamba2-3b"),
-        vocab_size=65536,
-    )
-)
-_register(
-    NamedTextModel(
-        "jamba-tiny", 4096, 64, "jax", _jamba_text_builder("jamba-tiny"),
-        vocab_size=512,
-    )
-)
-
-
-def _deepseek_v2_text_builder(size: str):
-    """Builder over models/deepseek_v2.py presets: latent attention and
-    shared plus routed experts, of which this chip holds a share;
-    ``embed`` is the mean final state of a row's real tokens (and a
-    column of counts, ``mf.row_counters``). The ModelFunction is marked
-    ``weights_as_arguments`` and reports
-    ``attention`` ('flash' | 'dense') and ``experts`` ('pallas' |
-    'ragged_dot'), both chosen at build time."""
-
-    def build(
-        spec: NamedTextModel, mode: str, dtype, weights_file, seed
-    ) -> ModelFunction:
-        from sparkdl_tpu.models import deepseek_v2 as deepseek_mod
-
-        return deepseek_mod.deepseek_v2_model_function(
-            size,
-            dtype=dtype,
-            seed=seed,
-            weights_file=weights_file,
-            name=f"{spec.name}[{mode}]",
+# Each family at the size of its benchmark cell (no position table: the
+# length is the model's declared context) and at test size; a registered
+# name is its family's size. Jamba: AI21-Jamba2-3B at its published
+# widths, 26 Mamba-1 and 2 attention layers. The routed families, one
+# chip's share each at published widths (the cuts of
+# benchmarks/configs/<name>.json): DeepSeek-V2 5 of 60 layers, experts
+# 0-39 of 160, a quarter of the vocabulary; DeepSeek-V3.2-Exp 5 of 61
+# layers, experts 0-7 of 256, an eighth of the vocabulary, a lightning
+# indexer in every layer; Trinity-Mini published layers 1-5 of 32, window
+# and full attention, all 128 experts; Xing4.0-29B-A4B 1 dense and 4
+# expert layers of 40, all 64 experts, four residual streams mixed by
+# hyper-connections.
+for _name, _length, _width, _vocab, _module, _function in (
+    ("jamba2-3b", 262144, 2560, 65536, "jamba", "jamba_model_function"),
+    ("jamba-tiny", 4096, 64, 512, "jamba", "jamba_model_function"),
+    ("deepseek-v2", 163840, 5120, 25600, "deepseek_v2", "deepseek_v2_model_function"),
+    ("deepseek-v2-tiny", 4096, 64, 512, "deepseek_v2", "deepseek_v2_model_function"),
+    ("deepseek-v3.2-exp", 163840, 7168, 16160, "deepseek_v32", "deepseek_v32_model_function"),
+    ("deepseek-v3.2-exp-tiny", 4096, 64, 512, "deepseek_v32", "deepseek_v32_model_function"),
+    ("trinity-mini", 131072, 2048, 200192, "afmoe", "afmoe_model_function"),
+    ("trinity-mini-tiny", 4096, 64, 512, "afmoe", "afmoe_model_function"),
+    ("xing4.0-29b-a4b", 262144, 3584, 131072, "xing4_0", "xing4_0_model_function"),
+    ("xing4.0-tiny", 4096, 64, 512, "xing4_0", "xing4_0_model_function"),
+):
+    _register(
+        NamedTextModel(
+            _name, _length, _width, "jax", _text_builder(_module, _function, _name),
+            vocab_size=_vocab,
         )
-
-    return build
-
-
-# One chip's share of DeepSeek-V2 at its published widths (5 of 60
-# layers, experts 0-39 of 160, a quarter of the vocabulary: the cut of
-# benchmarks/configs/deepseek-v2.json), and the family at test size.
-_register(
-    NamedTextModel(
-        "deepseek-v2", 163840, 5120, "jax",
-        _deepseek_v2_text_builder("deepseek-v2"), vocab_size=25600,
     )
-)
-_register(
-    NamedTextModel(
-        "deepseek-v2-tiny", 4096, 64, "jax",
-        _deepseek_v2_text_builder("deepseek-v2-tiny"), vocab_size=512,
-    )
-)
 
-
-def _deepseek_v32_text_builder(size: str):
-    """Builder over models/deepseek_v32.py presets: DeepSeek-V2's stack
-    with a lightning indexer in every layer that selects each query's
-    keys (``mf.indexer``: 'pallas' | 'jnp', chosen at build time like
-    ``attention`` and ``experts``) and the sigmoid gate with a correction
-    bias; two more row counters ride back with each row."""
-
-    def build(
-        spec: NamedTextModel, mode: str, dtype, weights_file, seed
-    ) -> ModelFunction:
-        from sparkdl_tpu.models import deepseek_v32 as deepseek_mod
-
-        return deepseek_mod.deepseek_v32_model_function(
-            size,
-            dtype=dtype,
-            seed=seed,
-            weights_file=weights_file,
-            name=f"{spec.name}[{mode}]",
-        )
-
-    return build
-
-
-# One chip's share of DeepSeek-V3.2-Exp at its published widths (5 of 61
-# layers, experts 0-7 of 256, an eighth of the vocabulary: the cut of
-# benchmarks/configs/deepseek-v3.2-exp.json), and the family at test size.
-_register(
-    NamedTextModel(
-        "deepseek-v3.2-exp", 163840, 7168, "jax",
-        _deepseek_v32_text_builder("deepseek-v3.2-exp"), vocab_size=16160,
-    )
-)
-_register(
-    NamedTextModel(
-        "deepseek-v3.2-exp-tiny", 4096, 64, "jax",
-        _deepseek_v32_text_builder("deepseek-v3.2-exp-tiny"), vocab_size=512,
-    )
-)
-
-
-
-def _afmoe_text_builder(size: str):
-    """Builder over models/afmoe.py presets: sliding-window and full
-    attention layers in one stack, gated GQA with QK-norm, and shared plus
-    routed experts, every expert held; ``embed`` is the mean final state
-    of a row's real tokens (and a column of counts, ``mf.row_counters``).
-    The ModelFunction is marked ``weights_as_arguments`` and reports
-    ``attention`` and ``window_attention`` ('flash' | 'dense') and
-    ``experts`` ('pallas' | 'ragged_dot'), all chosen at build time."""
-
-    def build(
-        spec: NamedTextModel, mode: str, dtype, weights_file, seed
-    ) -> ModelFunction:
-        from sparkdl_tpu.models import afmoe
-
-        return afmoe.afmoe_model_function(
-            size,
-            dtype=dtype,
-            seed=seed,
-            weights_file=weights_file,
-            name=f"{spec.name}[{mode}]",
-        )
-
-    return build
-
-
-# One chip's share of Trinity-Mini at its published widths (published
-# layers 1-5 of 32, all 128 experts, the whole vocabulary: the cut of
-# benchmarks/configs/trinity-mini.json), and the family at test size.
-_register(
-    NamedTextModel(
-        "trinity-mini", 131072, 2048, "jax",
-        _afmoe_text_builder("trinity-mini"), vocab_size=200192,
-    )
-)
-_register(
-    NamedTextModel(
-        "trinity-mini-tiny", 4096, 64, "jax",
-        _afmoe_text_builder("trinity-mini-tiny"), vocab_size=512,
-    )
-)
-
-
-def _xing4_0_text_builder(size: str):
-    """Builder over models/xing4_0.py presets: DeepSeek's latent attention
-    and shared plus routed experts, every expert held, with a residual of
-    four streams mixed by manifold-constrained hyper-connections; ``embed``
-    is the mean final state of a row's real tokens (and a column of
-    counts, ``mf.row_counters``). The ModelFunction is marked
-    ``weights_as_arguments`` and reports ``attention`` ('flash' | 'dense'),
-    ``experts`` ('pallas' | 'ragged_dot') and ``residual`` ('pallas' |
-    'xla'), all chosen at build time."""
-
-    def build(
-        spec: NamedTextModel, mode: str, dtype, weights_file, seed
-    ) -> ModelFunction:
-        from sparkdl_tpu.models import xing4_0
-
-        return xing4_0.xing4_0_model_function(
-            size,
-            dtype=dtype,
-            seed=seed,
-            weights_file=weights_file,
-            name=f"{spec.name}[{mode}]",
-        )
-
-    return build
-
-
-# Xing4.0-29B-A4B at its published widths (1 dense and 4 expert layers of
-# 40, all 64 experts, the whole vocabulary: the cut of
-# benchmarks/configs/xing4.0-29b-a4b.json), and the family at test size.
-_register(
-    NamedTextModel(
-        "xing4.0-29b-a4b", 262144, 3584, "jax",
-        _xing4_0_text_builder("xing4.0-29b-a4b"), vocab_size=131072,
-    )
-)
-_register(
-    NamedTextModel(
-        "xing4.0-tiny", 4096, 64, "jax",
-        _xing4_0_text_builder("xing4.0-tiny"), vocab_size=512,
-    )
-)
 
 def get_model(name: str):
     """The registered spec for ``name`` — a :class:`NamedImageModel` or

@@ -12,19 +12,20 @@ manifold-constrained hyper-connections (``ops/hyper_connection.py``).
       X = hc_post(X, F(u), H_post, H_res)         X'[j] = sum_i H_res[j, i] X[i] + H_post[j] F(u)
     F = MLA(rms(u; w_in)) or FFN(rms(u; w_ff)), as ``deepseek_v2.forward``
       computes them from its input; FFN_i>=first_k_dense = Shared + Routed,
-      the router ``deepseek_v2.route``'s sigmoid gate with a correction
+      the router ``moe.route``'s sigmoid gate with a correction
       bias (``noaux_tc``, one group: none dropped), top-k renormalised and
       scaled by ``routed_scaling_factor``
     h = sum_i X[i];  embed = the mean over a row's real tokens of rms(h; w_final)
 
 **Shared with DeepSeek, adapted and not copied**: the sublayers are
-``deepseek_v2``'s ``_mla`` (over ``flash_attention_latent``, handed each
-row's length), ``_swiglu``, ``route`` and ``_routed`` (every expert held:
-one slot buffer and no conditional, over ``ops/grouped_matmul.py`` and
-``ops/moe_combine.py``), with ``rope_tables``, ``row_lengths`` and
-``_mean_real_state``; :class:`Xing4Config` is ``DeepseekV2Config`` with this
-family's values and its hyper-connection keys. Only the loop around them
-is this module's.
+``deepseek_v2``'s ``mla`` (over ``flash_attention_latent``, handed each
+row's length) and SwiGLU, and ``models/moe.py``'s ``route`` and
+``routed`` (every expert held: one slot buffer and no conditional, over
+``ops/grouped_matmul.py`` and ``ops/moe_combine.py``), with
+``deepseek_v2.rope_tables`` and ``models/layers.py``'s norms, lengths
+and pooling; :class:`Xing4Config` is ``DeepseekV2Config`` with this
+family's values and its hyper-connection keys. Only the loop around
+them is this module's.
 
 Precision: matrices and the activations that feed them are ``dtype``,
 every product accumulates in float32; the n streams, every mHC coefficient
@@ -48,8 +49,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from sparkdl_tpu.models import deepseek_v2 as v2
+from sparkdl_tpu.models import layers, moe
 from sparkdl_tpu.models.deepseek_v2 import DeepseekV2Config
-from sparkdl_tpu.models.jamba import _rms, _unflatten, load_flat
+from sparkdl_tpu.models.layers import (
+    attention_batch_counters,
+    load_flat,
+    mean_real_state,
+    preset,
+    rms,
+    row_lengths,
+    unflatten,
+)
 from sparkdl_tpu.ops import hyper_connection
 from sparkdl_tpu.ops.moe_combine import gather_combine
 from sparkdl_tpu.utils.profiler import scope
@@ -157,10 +167,10 @@ def param_shapes(config: Xing4Config) -> dict:
 
 
 def _leaf_dtype(path: str, shape: tuple, dtype):
-    """``deepseek_v2``'s, and every hyper-connection leaf float32."""
+    """``layers.leaf_dtype``, and every hyper-connection leaf float32."""
     if "/hc_" in path:
         return jnp.float32
-    return v2._leaf_dtype(path, shape, dtype)
+    return layers.leaf_dtype(path, shape, dtype)
 
 
 def init_params(config: Xing4Config, seed: int, dtype) -> dict:
@@ -189,7 +199,7 @@ def init_params(config: Xing4Config, seed: int, dtype) -> dict:
             if kind == "router":
                 v *= 2.0
         flat[path] = jnp.asarray(np.asarray(v, np.float32), _leaf_dtype(path, shape, dtype))
-    return _unflatten(flat)
+    return unflatten(flat)
 
 
 def forward(
@@ -208,7 +218,7 @@ def forward(
         tables = v2.rope_tables(config, length)
         x = params["embed"][ids].astype(jnp.float32).reshape(tokens, hidden)
         stream = jnp.tile(x, (1, n))  # [T, n C]: every stream the embedding
-        by_length = v2.row_lengths(attention_fn, real)
+        by_length = row_lengths(attention_fn, real)
     slots_held = jnp.zeros((rows,), jnp.int32)
     sized = jnp.zeros((), jnp.int32)
 
@@ -227,18 +237,19 @@ def forward(
         u, h_post, h_res = pre(stream, p["hc_attn"])
         # a norm is in the scope of the first part it feeds
         with scope("mla.q"):
-            u = _rms(u, p["norm_in"], eps).astype(dtype)
-        attended = v2._mla(config, p["attn"], u, tables, attention_fn, by_length)
+            u = rms(u, p["norm_in"], eps).astype(dtype)
+        attended = v2.mla(config, p["attn"], u, tables, attention_fn, by_length)
         stream = post(stream, attended, h_post, h_res)
 
         u, h_post, h_res = pre(stream, p["hc_ffn"])
         with scope("mlp"):
-            u = _rms(u, p["norm_ff"], eps)
+            u = rms(u, p["norm_ff"], eps)
+            # ``deepseek_v2._swiglu`` itself: a planted fault patches it there
             if i < config.first_k_dense:
                 f = v2._swiglu(p["mlp"], u.astype(dtype))
         if i >= config.first_k_dense:
-            routed, count, fits = v2._routed(
-                config, p["moe"], u, real, experts_fn, combine_fn=combine_fn
+            routed, count, fits = moe.routed(
+                config, p["moe"], u, real, experts_fn, gate=moe.route, combine_fn=combine_fn
             )
             with scope("mlp"):
                 f = v2._swiglu(p["moe"]["shared"], u.astype(dtype))
@@ -251,7 +262,7 @@ def forward(
         h = streams[:, :, 0]
         for i in range(1, n):
             h = h + streams[:, :, i]
-        out = v2._mean_real_state(_rms(h, params["final_norm"], eps), real)
+        out = mean_real_state(rms(h, params["final_norm"], eps), real)
     return out, slots_held, sized
 
 
@@ -266,70 +277,41 @@ def xing4_0_model_function(
     hyper=None,
     name: Optional[str] = None,
 ):
-    """The ``embed`` ModelFunction over ids batches (or ``(ids, mask)``
-    tuples, as TextEmbedder feeds them). ``attention_fn``, ``experts_fn``,
-    ``combine_fn`` and ``hyper`` default to the build-time choice of
+    """The ``embed`` ModelFunction (``moe.routed_model_function``: the
+    embedding and ``moe.ROW_COUNTERS`` a row). ``attention_fn`` and
+    ``hyper`` default to the build-time choice of
     ``make_latent_attention_fn(heads, scale)`` in blocks of 1,024 as
-    DeepSeek-V2's, ``make_grouped_matmul_fn()``, ``make_moe_combine_fn()``
-    and ``make_hyper_connection_fn(...)``: the Pallas kernels on TPU.
+    DeepSeek-V2's and ``make_hyper_connection_fn(...)``: the Pallas kernels
+    on TPU.
 
-    The program's result is [B, hidden + 3]: the embedding and DeepSeek's
-    three row counters (``moe.slots_held``, ``moe.buffer_sized``,
-    ``moe.buffer_full``), which ``TextEmbedder`` strips. Per dispatched
-    token it counts ``mla.attention_tokens`` (once a layer) and
-    ``mhc.tokens`` (twice a layer: the mixes' tokens), per real token
-    ``moe.slots_routed``; ``mf.batch_counters`` counts attention's pairs
-    and query blocks as ``deepseek_v2.attention_batch_counters`` does."""
-    from sparkdl_tpu.graph.function import ModelFunction
-    from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn
-    from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn
-    from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn
+    Per dispatched token it counts ``mla.attention_tokens`` (once a layer)
+    and ``mhc.tokens`` (twice a layer: the mixes' tokens);
+    ``mf.batch_counters`` counts attention's pairs and query blocks
+    (``layers.attention_batch_counters``)."""
+    from sparkdl_tpu.ops import flash_attention
 
-    if size not in _SIZES:
-        raise ValueError(f"Unknown Xing4.0 size {size!r}; supported: {sorted(_SIZES)}")
-    config = _SIZES[size]()
+    config = preset(_SIZES, size, "Xing4.0")
     if attention_fn is None:
-        attention_fn = make_latent_attention_fn(
+        attention_fn = flash_attention.make_latent_attention_fn(
             config.num_heads, config.softmax_scale, block=1024
         )
-    if experts_fn is None:
-        experts_fn = make_grouped_matmul_fn()
-    if combine_fn is None:
-        combine_fn = make_moe_combine_fn()
     if hyper is None:
         hyper = hyper_connection.make_hyper_connection_fn(config.hyper_constants)
     if weights_file:
         params = load_flat(param_shapes(config), weights_file, dtype, _leaf_dtype)
     else:
         params = init_params(config, seed, dtype)
-
-    def fn(p, x):
-        ids = x[0] if isinstance(x, (tuple, list)) else x
-        out, slots_held, sized = forward(
-            config, p, ids, dtype=dtype, attention_fn=attention_fn,
-            experts_fn=experts_fn, hyper=hyper, combine_fn=combine_fn,
-        )
-        with scope("pool"):
-            sized = jnp.broadcast_to(sized, slots_held.shape)
-            counts = jnp.stack([slots_held, sized, config.expert_layers - sized], 1)
-            return jnp.concatenate([out, counts.astype(jnp.float32)], 1)
-
-    mf = ModelFunction(fn, params, input_dtype=jnp.int32, name=name or f"{size}[embed]")
-    mf.weights_as_arguments = True
-    mf.vocab_size = config.vocab_size
-    mf.attention = getattr(attention_fn, "kind", "custom")
-    mf.experts = getattr(experts_fn, "kind", "custom")
-    mf.combine = getattr(combine_fn, "kind", "custom")
-    mf.residual = getattr(hyper, "kind", "custom")
-    mf.row_counters = ("moe.slots_held", "moe.buffer_sized", "moe.buffer_full")
-    mf.dispatched_token_counters = {
-        "mla.attention_tokens": config.num_layers,
-        "mhc.tokens": len(SUBLAYERS) * config.num_layers,
+    kernels = {
+        "attention_fn": attention_fn, "experts_fn": experts_fn,
+        "combine_fn": combine_fn, "hyper": hyper,
     }
-    mf.real_token_counters = {
-        "moe.slots_routed": config.num_experts_per_tok * config.expert_layers
-    }
-    mf.batch_counters = functools.partial(
-        v2.attention_batch_counters, attention_fn, config.num_layers
+    return moe.routed_model_function(
+        config, params, forward, kernels, dtype=dtype, name=name or f"{size}[embed]",
+        dispatched_token_counters={
+            "mla.attention_tokens": config.num_layers,
+            "mhc.tokens": len(SUBLAYERS) * config.num_layers,
+        },
+        batch_counters=functools.partial(
+            attention_batch_counters, attention_fn, config.num_layers
+        ),
     )
-    return mf

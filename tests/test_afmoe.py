@@ -41,7 +41,7 @@ from benchmarks.data import texts  # noqa: E402
 from benchmarks.reference import afmoe as reference  # noqa: E402
 from sparkdl_tpu.dataframe import DataFrame  # noqa: E402
 from sparkdl_tpu.models import afmoe as program  # noqa: E402
-from sparkdl_tpu.models import deepseek_v2  # noqa: E402
+from sparkdl_tpu.models import moe as moe_layer  # noqa: E402
 from sparkdl_tpu.models import get_model  # noqa: E402
 from sparkdl_tpu.ops.flash_attention import make_flash_attention_fn  # noqa: E402
 from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn  # noqa: E402
@@ -117,7 +117,7 @@ def test_tiny_preset_is_the_family(tiny):
     assert reference.weight_shapes(config) == program.param_shapes(preset)
     assert [preset.is_sliding(i) for i in range(5)] == [True, True, False, True, True]
     assert (preset.sliding_layers, preset.expert_layers, preset.num_dense_layers) == (4, 4, 1)
-    # DeepSeek's names, read by `route` and `_routed`, carry this family's keys
+    # DeepSeek's names, read by `moe.route` and `moe.routed`, carry this family's keys
     assert (preset.n_routed_experts, preset.routed_scaling_factor) == (16, 2.826)
     assert (preset.norm_topk_prob, preset.scoring_func, preset.n_group) == (True, "sigmoid", 1)
 
@@ -145,7 +145,7 @@ def test_published_preset_is_the_configuration_file():
     assert (spec.feature_dim, spec.vocab_size, spec.max_length) == (2048, 200192, 131072)
     assert get_model("trinity-mini-tiny").feature_dim == 64
     # every expert held: the slot buffer is every slot, one body
-    assert deepseek_v2.slot_capacity(preset, 16384) == 16384 * 8
+    assert moe_layer.slot_capacity(preset, 16384) == 16384 * 8
 
 
 @pytest.mark.parametrize(
@@ -206,7 +206,7 @@ def test_the_gate_by_hand():
     u = r.normal(size=(5, 64)).astype(np.float32)
     router = r.normal(size=(64, 16)).astype(np.float32) / 4
     bias = r.uniform(-0.05, 0.05, 16).astype(np.float32)
-    experts, weights = deepseek_v2.route(preset, jnp.asarray(u), jnp.asarray(router), jnp.asarray(bias))
+    experts, weights = moe_layer.route(preset, jnp.asarray(u), jnp.asarray(router), jnp.asarray(bias))
     scores = 1 / (1 + np.exp(-(u.astype(np.float64) @ router)))
     chosen = np.argsort(-(scores + bias), -1)[:, :4]
     assert np.array_equal(np.sort(np.asarray(experts), -1), np.sort(chosen, -1))
@@ -217,7 +217,7 @@ def test_the_gate_by_hand():
 
 
 def test_the_routed_layer_holds_one_body_and_no_conditional():
-    """Every expert held: `_routed` builds the one buffer of every slot
+    """Every expert held: `moe.routed` builds the one buffer of every slot
     and no `lax.cond` on the load."""
     preset = program.trinity_mini_tiny()
     shapes = program.layer_shapes(preset, 1)
@@ -227,7 +227,9 @@ def test_the_routed_layer_holds_one_body_and_no_conditional():
         "experts": {k: f32(*shapes[f"moe/experts/{k}"]) for k in ("gate", "up", "down")},
     }
     text = str(jax.make_jaxpr(
-        lambda p, u, real: deepseek_v2._routed(preset, p, u, real, make_grouped_matmul_fn())
+        lambda p, u, real: moe_layer.routed(
+            preset, p, u, real, make_grouped_matmul_fn(), gate=moe_layer.route
+        )
     )(moe, f32(2, 64, 64), jax.ShapeDtypeStruct((2, 64), jnp.bool_)))
     assert "cond[" not in text
 

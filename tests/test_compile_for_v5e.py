@@ -149,7 +149,7 @@ def test_packed_flash_compiles_at_bert_base_widths(shape, rows, length):
 
 @pytest.mark.parametrize("length", [1024, 2048])
 def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
-    """One expert layer of `deepseek-v2` as `models/deepseek_v2.py:_routed`
+    """One expert layer of `deepseek-v2` as `models/moe.py:routed`
     builds it for a chip that holds 40 of the 160 experts: the sized slot
     buffer and the worst-case one are the arms of one conditional. The
     arms never live together, so the layer's temporaries are the larger
@@ -159,6 +159,7 @@ def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
     product's rows where they lie, through a bitcast, so that its event
     names no product kernel."""
     from sparkdl_tpu.models import deepseek_v2
+    from sparkdl_tpu.models import moe as moe_layer
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
     config = deepseek_v2.deepseek_v2()
@@ -172,12 +173,13 @@ def test_the_expert_layers_two_buffers_share_and_copy_no_expert(shape, length):
     }
     tokens, hidden = ROWS * length, config.hidden_size
     slots = tokens * config.num_experts_per_tok
-    capacity = deepseek_v2.slot_capacity(config, tokens)
+    capacity = moe_layer.slot_capacity(config, tokens)
     assert capacity == 15 * length == 1.25 * slots / 4
 
     def layer(p, u, real):
-        return deepseek_v2._routed(
-            config, p, u, real, grouped_matmul, combine_fn=moe_combine.moe_combine
+        return moe_layer.routed(
+            config, p, u, real, grouped_matmul, gate=moe_layer.route,
+            combine_fn=moe_combine.moe_combine,
         )
 
     compiled = (
@@ -269,7 +271,7 @@ def test_a_buckets_expert_layers_lower_one_combine_an_arm(shape, monkeypatch):
     at eight sites (two arms each), of two shapes: the kernel's body is
     traced and lowered twice, once an arm, since the call sites of one
     shape share one `pallas_call`."""
-    from sparkdl_tpu.models import deepseek_v2
+    from sparkdl_tpu.models import deepseek_v2, moe
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
     traced = []
@@ -293,8 +295,9 @@ def test_a_buckets_expert_layers_lower_one_combine_an_arm(shape, monkeypatch):
 
     def bucket(layers, u, real):
         for p in layers:
-            routed, _, _ = deepseek_v2._routed(
-                config, p, u, real, grouped_matmul, combine_fn=moe_combine.moe_combine
+            routed, _, _ = moe.routed(
+                config, p, u, real, grouped_matmul, gate=moe.route,
+                combine_fn=moe_combine.moe_combine,
             )
             u = u + routed
         return u
@@ -457,15 +460,15 @@ def test_the_v32_program_holds_the_indexers_kernels_a_layer(shape):
     one call of each indexer kernel a layer under its own name, the names
     the trace's readers match, each taking the row's live query blocks as
     its first operand."""
-    from sparkdl_tpu.models import deepseek_v2, deepseek_v32
-    from sparkdl_tpu.models.jamba import _unflatten
+    from sparkdl_tpu.models import deepseek_v32
+    from sparkdl_tpu.models.layers import leaf_dtype, unflatten
     from sparkdl_tpu.ops import dsa_indexer
     from sparkdl_tpu.ops.flash_attention import flash_attention_latent
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
     config, bf16 = deepseek_v32.deepseek_v32(), jnp.bfloat16
     leaves = {
-        p: shape(s, deepseek_v2._leaf_dtype(p, s, bf16))
+        p: shape(s, leaf_dtype(p, s, bf16))
         for p, s in deepseek_v32.param_shapes(config).items()
     }
 
@@ -488,7 +491,7 @@ def test_the_v32_program_holds_the_indexers_kernels_a_layer(shape):
         )
 
     text = (
-        jax.jit(program).lower(_unflatten(leaves), shape((1, 16384), jnp.int32))
+        jax.jit(program).lower(unflatten(leaves), shape((1, 16384), jnp.int32))
         .compile().as_text()
     )
     for kernel in ("dsa_index_scores", "dsa_select"):
@@ -637,13 +640,13 @@ def test_trinity_programs_fit_the_chip(shape, length):
     products an expert layer, and no conditional (every expert held: one
     slot buffer). The weights and the larger bucket's temporaries lie
     well under the chip's 16 GiB."""
-    from sparkdl_tpu.models import afmoe, deepseek_v2
-    from sparkdl_tpu.models.jamba import _unflatten
+    from sparkdl_tpu.models import afmoe
+    from sparkdl_tpu.models.layers import leaf_dtype, unflatten
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
     config, bf16 = afmoe.trinity_mini(), jnp.bfloat16
     leaves = {
-        p: shape(s, deepseek_v2._leaf_dtype(p, s, bf16))
+        p: shape(s, leaf_dtype(p, s, bf16))
         for p, s in afmoe.param_shapes(config).items()
     }
 
@@ -665,7 +668,7 @@ def test_trinity_programs_fit_the_chip(shape, length):
         )
 
     compiled = (
-        jax.jit(program).lower(_unflatten(leaves), shape((1, length), jnp.int32)).compile()
+        jax.jit(program).lower(unflatten(leaves), shape((1, length), jnp.int32)).compile()
     )
     text = compiled.as_text()
     assert len(re.findall(r"%flash_attention_window[.\w]* = ", text)) == 4
@@ -746,7 +749,7 @@ def test_xing_programs_fit_the_chip(shape, length):
     no conditional. The weights and the larger bucket's temporaries lie
     within the chip's 16 GiB, with room for the batches."""
     from sparkdl_tpu.models import xing4_0
-    from sparkdl_tpu.models.jamba import _unflatten
+    from sparkdl_tpu.models.layers import unflatten
     from sparkdl_tpu.ops.flash_attention import flash_attention_latent
     from sparkdl_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -771,7 +774,7 @@ def test_xing_programs_fit_the_chip(shape, length):
         )
 
     compiled = (
-        jax.jit(program).lower(_unflatten(leaves), shape((ROWS, length), jnp.int32)).compile()
+        jax.jit(program).lower(unflatten(leaves), shape((ROWS, length), jnp.int32)).compile()
     )
     text = compiled.as_text()
     assert len(re.findall(r"%hc_pre[.\w]* = ", text)) == 10

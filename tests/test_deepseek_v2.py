@@ -8,7 +8,7 @@ Tolerances. In float32 the program and the reference at `highest` do the
 same arithmetic in another order: 1e-6 of the spread of the rows, held to
 1e-5. In bfloat16 both round the operands of every matrix product to
 bfloat16, at other points: the program rounds a rotary query's two terms,
-x cos and turn(x) sin, apart (models/deepseek_v2.py:_mla) and the
+x cos and turn(x) sin, apart (models/deepseek_v2.py:mla_inputs) and the
 reference their sum, and a token whose last chosen expert swaps with the
 next (routing is discrete) moves its row by its share of the row's mean,
 most in a row of ten tokens: 0.008 at the median and 0.09 at the widest
@@ -36,7 +36,8 @@ from benchmarks.data import texts  # noqa: E402
 from benchmarks.reference import deepseek_v2 as reference  # noqa: E402
 from sparkdl_tpu.dataframe import DataFrame  # noqa: E402
 from sparkdl_tpu.models import deepseek_v2 as program  # noqa: E402
-from sparkdl_tpu.models import get_model  # noqa: E402
+from sparkdl_tpu.models import get_model, layers  # noqa: E402
+from sparkdl_tpu.models import moe as moe_layer  # noqa: E402
 from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn  # noqa: E402
 from sparkdl_tpu.ops.grouped_matmul import make_grouped_matmul_fn  # noqa: E402
 from sparkdl_tpu.ops.moe_combine import make_moe_combine_fn  # noqa: E402
@@ -228,7 +229,9 @@ def test_four_shares_and_the_shared_experts_once_are_the_uncut_layer():
                 k: moe[f"experts/{k}"][first : first + 4] for k in ("gate", "up", "down")
             },
         }
-        part, count, _ = program._routed(preset, p, u, real, make_grouped_matmul_fn())
+        part, count, _ = moe_layer.routed(
+            preset, p, u, real, make_grouped_matmul_fn(), gate=moe_layer.route
+        )
         total, slots = total + part, slots + int(count.sum())
     assert slots == 2 * 24 * 3  # every slot is held by exactly one share
     p_shared = {k: moe[f"shared/{k}"] for k in ("gate", "up", "down")}
@@ -261,8 +264,8 @@ def _share(first, end):
     ],
 )
 def test_the_buffer_is_sized_by_the_held_share(preset, tokens, capacity):
-    assert program.slot_capacity(preset, tokens) == capacity
-    assert 1.25 <= program._CAPACITY_MARGIN <= 1.5
+    assert moe_layer.slot_capacity(preset, tokens) == capacity
+    assert 1.25 <= moe_layer._CAPACITY_MARGIN <= 1.5
 
 
 def _layer(preset, seed=0):
@@ -309,11 +312,11 @@ def test_both_arms_are_the_oracle(interpret, forced):
     real = np.ones((4, 128), bool)
     real[3, 70:] = False
     real = jnp.asarray(real)
-    part, count, fits = program._routed(
+    part, count, fits = moe_layer.routed(
         preset, moe, u, real, make_grouped_matmul_fn(interpret=interpret),
-        combine_fn=make_moe_combine_fn(interpret=interpret),
+        combine_fn=make_moe_combine_fn(interpret=interpret), gate=moe_layer.route,
     )
-    assert program.slot_capacity(preset, 4 * 128) == 512 < 4 * 128 * 3
+    assert moe_layer.slot_capacity(preset, 4 * 128) == 512 < 4 * 128 * 3
     assert bool(fits) is not forced
     held = int(count.sum())
     if forced:
@@ -334,14 +337,14 @@ def test_the_two_arms_are_bit_equal_on_a_load_both_hold(monkeypatch, interpret):
     real, moe = jnp.ones((4, 128), bool), _layer(preset)
     experts_fn = make_grouped_matmul_fn(interpret=interpret)
     combine_fn = make_moe_combine_fn(interpret=interpret)
-    sized, count, fits = program._routed(
-        preset, moe, u, real, experts_fn, combine_fn=combine_fn
+    sized, count, fits = moe_layer.routed(
+        preset, moe, u, real, experts_fn, combine_fn=combine_fn, gate=moe_layer.route
     )
     assert bool(fits) and int(count.sum()) > 256
-    monkeypatch.setattr(program, "_CAPACITY_MARGIN", 0.01)
-    assert program.slot_capacity(preset, 4 * 128) == 256
-    full, count_full, fits = program._routed(
-        preset, moe, u, real, experts_fn, combine_fn=combine_fn
+    monkeypatch.setattr(moe_layer, "_CAPACITY_MARGIN", 0.01)
+    assert moe_layer.slot_capacity(preset, 4 * 128) == 256
+    full, count_full, fits = moe_layer.routed(
+        preset, moe, u, real, experts_fn, combine_fn=combine_fn, gate=moe_layer.route
     )
     assert not bool(fits) and count_full.tolist() == count.tolist()
     assert np.asarray(sized).tobytes() == np.asarray(full).tobytes()
@@ -356,10 +359,10 @@ def test_a_load_of_exactly_the_buffer_and_of_one_more(over):
     rng = np.random.default_rng(6)
     u = jnp.asarray(rng.standard_normal((4, 1024, 64)), jnp.float32)
     moe, u = _to_the_held_group(_layer(preset), u, experts=1)
-    capacity = program.slot_capacity(preset, 4 * 1024)
+    capacity = moe_layer.slot_capacity(preset, 4 * 1024)
     real = (np.arange(4 * 1024) < capacity + over).reshape(4, 1024)
-    part, count, fits = program._routed(
-        preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn()
+    part, count, fits = moe_layer.routed(
+        preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn(), gate=moe_layer.route
     )
     assert int(count.sum()) == capacity + over == 3840 + over
     assert bool(fits) is (over == 0)
@@ -393,7 +396,9 @@ def test_a_chip_that_holds_every_expert_has_no_conditional(
     u = jax.ShapeDtypeStruct((rows, length, preset.hidden_size), jnp.float32)
     real = jax.ShapeDtypeStruct((rows, length), bool)
     text = str(jax.make_jaxpr(
-        lambda p, u, real: program._routed(preset, p, u, real, make_grouped_matmul_fn())
+        lambda p, u, real: moe_layer.routed(
+            preset, p, u, real, make_grouped_matmul_fn(), gate=moe_layer.route
+        )
     )(moe, u, real))
     assert (text.count("cond["), text.count("optimization_barrier")) == (
         conditionals, barriers
@@ -456,7 +461,7 @@ def test_routing_by_hand():
     u = np.zeros((2, 64), np.float32)
     u[0, :16] = logits
     u[1, :16] = logits[::-1]
-    experts, weights = program.route(preset, jnp.asarray(u), router)
+    experts, weights = moe_layer.route(preset, jnp.asarray(u), router)
     chosen, by_hand = _route_by_hand(logits)
     assert chosen == [1, 9, 2]
     assert 12 not in chosen and 13 not in chosen
@@ -479,16 +484,16 @@ def test_a_pad_token_takes_no_slot():
     real = np.ones((2, 16), bool)
     real[0, 5:] = False  # a row of five tokens
     real[1, :] = False  # a row that only fills the batch
-    part, count, _ = program._routed(
-        preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn()
+    part, count, _ = moe_layer.routed(
+        preset, moe, u, jnp.asarray(real), make_grouped_matmul_fn(), gate=moe_layer.route
     )
-    experts, _ = program.route(preset, u.reshape(-1, 64), moe["router"])
+    experts, _ = moe_layer.route(preset, u.reshape(-1, 64), moe["router"])
     held = np.asarray((experts >= 0) & (experts < 4)).reshape(2, 16, 3)
     assert count.tolist() == [int(held[0, :5].sum()), 0]
     assert not np.asarray(part[0, 5:]).any() and not np.asarray(part[1]).any()
     # the real tokens' part is what it is with every token real
-    whole, _, _ = program._routed(
-        preset, moe, u, jnp.ones((2, 16), bool), make_grouped_matmul_fn()
+    whole, _, _ = moe_layer.routed(
+        preset, moe, u, jnp.ones((2, 16), bool), make_grouped_matmul_fn(), gate=moe_layer.route
     )
     np.testing.assert_allclose(np.asarray(part[0, :5]), np.asarray(whole[0, :5]), atol=1e-6)
 
@@ -555,7 +560,7 @@ def test_the_programs_pairing_gives_the_sources_scores():
     # the program leaves a query as [x cos | turn(x) sin] and writes the
     # rotated key twice: the product over both halves is rope(x) . k
     x = q[..., perm]
-    split = jnp.concatenate([x * tables[0], program._turn(x) * tables[1]], -1)
+    split = jnp.concatenate([x * tables[0], layers.turn(x) * tables[1]], -1)
     twice = jnp.concatenate([ours_k, ours_k], -1)
     np.testing.assert_allclose(scores(split, twice), scores(source_q, source_k), atol=1e-4)
     # and the projection writes turn(x) itself: x @ turn(w) == turn(x @ w)
@@ -568,7 +573,7 @@ def test_the_programs_pairing_gives_the_sources_scores():
     r = plain[..., 16:][..., program._deinterleave(8)]
     np.testing.assert_allclose(np.asarray(got[..., :16]), np.asarray(plain[..., :16]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(got[..., 16:24]), np.asarray(r), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(got[..., 24:]), np.asarray(program._turn(r)), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[..., 24:]), np.asarray(layers.turn(r)), atol=1e-5)
 
 
 def test_weights_file_is_read_strictly(tmp_path, tiny):
@@ -607,7 +612,7 @@ def test_the_first_gate_is_bit_for_bit_what_it_was(normalise):
     rng = np.random.default_rng(7)
     u = jnp.asarray(rng.standard_normal((5, 64)), jnp.float32)
     router = jnp.asarray(rng.standard_normal((64, 16)) * 0.25, jnp.float32)
-    experts, weights = program.route(preset, u, router)
+    experts, weights = moe_layer.route(preset, u, router)
     logits = jnp.einsum("ti,io->to", u, router, precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.softmax(logits, -1)
     best = scores.reshape(-1, 4, 4).max(-1)
@@ -625,7 +630,7 @@ def test_the_first_gate_is_bit_for_bit_what_it_was(normalise):
     assert np.allclose(total, 1.0, atol=1e-6) == normalise
     # the family's own key takes this branch, with or without the bias argument
     assert preset.scoring_func == "softmax" and preset.worst_case_chunk_rows is None
-    again = program.route(preset, u, router, None)
+    again = moe_layer.route(preset, u, router, None)
     assert (np.asarray(again[1]) == np.asarray(weights)).all()
 
 
@@ -650,8 +655,9 @@ PARENT_JAXPR = {
 
 @pytest.mark.parametrize("dtype, shape", sorted(PARENT_JAXPR), ids=str)
 def test_the_programs_jaxpr_is_the_parents(dtype, shape):
-    """`_mla` was split for the family that selects its keys, `route` and
-    `_routed` learned its gate and its worst-case arm in passes: this
+    """MLA was split for the family that selects its keys, `route` and
+    the routed layer learned its gate and its worst-case arm in passes,
+    and the routed layer moved to `models/moe.py`: this
     family's program is to the letter the one it was (no indexer, no
     second gate, no scan of passes)."""
     import hashlib
@@ -688,7 +694,7 @@ def test_the_embeddings_are_the_parents_combines_to_the_bit(tiny, monkeypatch, d
     row's embedding and counters alike, to the bit."""
     _, _, path = tiny
     if forced:
-        monkeypatch.setattr(program, "_CAPACITY_MARGIN", 0.01)
+        monkeypatch.setattr(moe_layer, "_CAPACITY_MARGIN", 0.01)
 
     def built(combine_fn=None):
         return program.deepseek_v2_model_function(
@@ -773,9 +779,9 @@ def test_a_row_is_as_long_as_its_last_real_position():
     def takes(*a, **k):
         raise AssertionError("not called")
 
-    assert program.row_lengths(takes, real) == {}
+    assert layers.row_lengths(takes, real) == {}
     takes.takes_lengths = True
-    (name, lengths), = program.row_lengths(takes, real).items()
+    (name, lengths), = layers.row_lengths(takes, real).items()
     assert name == "lengths" and lengths.dtype == jnp.int32
     assert lengths.tolist() == [4, 0, 6, 3]
 
@@ -831,7 +837,7 @@ def test_attention_counters_of_the_claimed_cells_job_by_hand(takes_lengths):
             ids = np.zeros((1, edge), np.int32)
             ids[0, :n] = 7
             ids[0, n // 2] = 0  # an id 0 inside the text
-            counted = program.attention_batch_counters(kernel, 5, ids, ids != 0)
+            counted = layers.attention_batch_counters(kernel, 5, ids, ids != 0)
             for name, count in counted.items():
                 total[name] = total.get(name, 0) + count
     steps = {True: 70.625 + 408.75, False: 5 * 33 + 5 * 130}[takes_lengths]
@@ -850,13 +856,13 @@ def test_attention_counters_of_a_batch_with_rows_of_padding():
     ids = np.zeros((8, 2048), np.int32)
     for row, n in enumerate((2048, 1025, 1500, 2048, 1026)):
         ids[row, :n] = 3
-    counted = program.attention_batch_counters(kernel, 5, ids, ids != 0)
+    counted = layers.attention_batch_counters(kernel, 5, ids, ids != 0)
     assert counted == {
         "mla.pairs_computed": 5 * 5 * int(2.25 * 1024 * 1024),
         "mla.query_blocks": 5 * 8 * 2,
         "mla.query_blocks_run": 5 * 5 * 2,
     }
     dense = make_latent_attention_fn(128, 0.1147)
-    assert program.attention_batch_counters(dense, 5, ids, ids != 0) == {
+    assert layers.attention_batch_counters(dense, 5, ids, ids != 0) == {
         "mla.pairs_computed": 5 * 8 * 2048 * 2048
     }

@@ -34,6 +34,7 @@ from benchmarks.data import texts  # noqa: E402
 from benchmarks.reference import deepseek_v32 as reference  # noqa: E402
 from sparkdl_tpu.dataframe import DataFrame  # noqa: E402
 from sparkdl_tpu.models import deepseek_v2  # noqa: E402
+from sparkdl_tpu.models import moe as moe_layer  # noqa: E402
 from sparkdl_tpu.models import deepseek_v32 as program  # noqa: E402
 from sparkdl_tpu.models import get_model  # noqa: E402
 from sparkdl_tpu.ops.dsa_indexer import make_indexer_fn  # noqa: E402
@@ -141,8 +142,8 @@ def test_published_preset_is_the_configuration_file():
     assert spec.feature_dim == 7168 and spec.vocab_size == 16160
     assert get_model("deepseek-v3.2-exp-tiny").feature_dim == 64
     # the slot buffer of the cell's two dispatches, and the worst case in passes
-    assert deepseek_v2.slot_capacity(preset, 16384) == 5120
-    assert deepseek_v2.slot_capacity(preset, 8192) == 2560
+    assert moe_layer.slot_capacity(preset, 16384) == 5120
+    assert moe_layer.slot_capacity(preset, 8192) == 2560
     assert (16384 * 8) % preset.worst_case_chunk_rows == 0
 
 
@@ -200,7 +201,7 @@ def test_embedder_matches_the_reference_row_by_row(
 def test_a_large_count_of_pairs_rides_back_exactly():
     """A row of the cell's size has 1.6e8 selected pairs: float32 holds
     neither that nor a partition's sum of it, the two columns do."""
-    split = program._PAIRS_SPLIT
+    split = moe_layer.WIDE_SPLIT
     pairs = np.array([5 * (2048 * 2049 // 2 + 14336 * 2048), 123456789], np.int64)
     high = (pairs // split * split).astype(np.float32)
     low = (pairs % split).astype(np.float32)
@@ -353,7 +354,7 @@ def test_the_gate_by_hand():
     router = jnp.asarray(np.eye(64, 16, dtype=np.float32))
     u = np.zeros((2, 64), np.float32)
     u[0, :16] = u[1, :16] = logits
-    experts, weights = deepseek_v2.route(preset, jnp.asarray(u), router, jnp.asarray(bias))
+    experts, weights = moe_layer.route(preset, jnp.asarray(u), router, jnp.asarray(bias))
     chosen, by_hand = _gate_by_hand(logits, bias)
     assert chosen == [0, 9, 1] and 5 not in chosen
     assert np.asarray(experts[0]).tolist() == chosen
@@ -361,7 +362,7 @@ def test_the_gate_by_hand():
     assert float(weights[0].sum()) == pytest.approx(2.5, rel=1e-6)
     # the bias chooses: expert 8 over expert 1 ...
     bias[8] = 0.1
-    experts, lifted = deepseek_v2.route(preset, jnp.asarray(u), router, jnp.asarray(bias))
+    experts, lifted = moe_layer.route(preset, jnp.asarray(u), router, jnp.asarray(bias))
     chosen, by_hand = _gate_by_hand(logits, bias)
     assert chosen == [0, 9, 8]
     assert np.asarray(experts[0]).tolist() == chosen
@@ -390,7 +391,7 @@ def test_a_negative_choice_in_a_kept_group_beats_a_dropped_groups_best():
     router = jnp.asarray(np.eye(64, 16, dtype=np.float32))
     u = np.zeros((1, 64), np.float32)
     u[0, :16] = logits
-    experts, _ = deepseek_v2.route(preset, jnp.asarray(u), router, jnp.asarray(bias))
+    experts, _ = moe_layer.route(preset, jnp.asarray(u), router, jnp.asarray(bias))
     assert sorted(np.asarray(experts[0]).tolist()) == [0, 1, 2]
 
 
@@ -434,8 +435,9 @@ def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
                 k: moe[f"experts/{k}"][first : first + 4] for k in ("gate", "up", "down")
             },
         }
-        part, count, _ = deepseek_v2._routed(
-            _share(first, first + 4), p, u, real, make_grouped_matmul_fn()
+        part, count, _ = moe_layer.routed(
+            _share(first, first + 4), p, u, real, make_grouped_matmul_fn(),
+            gate=moe_layer.route,
         )
         total, slots = total + part, slots + int(count.sum())
     assert slots == 2 * 24 * 3  # every slot is held by exactly one share
@@ -456,7 +458,7 @@ def _layer(preset, seed=0):
 @pytest.mark.parametrize("chunk", [64, 128, 384])
 @pytest.mark.parametrize("load", ["spread", "all_on_the_held_group", "none_held"])
 def test_the_worst_case_arm_in_passes_is_the_one_buffer(interpret, chunk, load):
-    """`_routed` with `worst_case_chunk_rows`: the same sum as the one
+    """`moe.routed` with `worst_case_chunk_rows`: the same sum as the one
     buffer of every slot, at a load the sized buffer holds (the `cond`
     takes the sized arm in both) and at one it does not (every slot on
     the held group: the worst-case arm, in passes against whole)."""
@@ -475,8 +477,9 @@ def test_the_worst_case_arm_in_passes_is_the_one_buffer(interpret, chunk, load):
     real = np.ones((2, 64), bool)
     real[1, 50:] = False
     experts_fn = make_grouped_matmul_fn(interpret=interpret)
-    want, held, fits = deepseek_v2._routed(whole, moe, jnp.asarray(u), jnp.asarray(real), experts_fn)
-    got, held_p, fits_p = deepseek_v2._routed(passes, moe, jnp.asarray(u), jnp.asarray(real), experts_fn)
+    u, real = jnp.asarray(u), jnp.asarray(real)
+    want, held, fits = moe_layer.routed(whole, moe, u, real, experts_fn, gate=moe_layer.route)
+    got, held_p, fits_p = moe_layer.routed(passes, moe, u, real, experts_fn, gate=moe_layer.route)
     assert bool(fits) == bool(fits_p) == (load != "all_on_the_held_group")
     assert np.asarray(held).tolist() == np.asarray(held_p).tolist()
     if load == "all_on_the_held_group":
@@ -502,7 +505,9 @@ def test_the_published_preset_works_its_worst_case_in_passes():
     u = jax.ShapeDtypeStruct((1, 16384, preset.hidden_size), jnp.float32)
     real = jax.ShapeDtypeStruct((1, 16384), bool)
     text = str(jax.make_jaxpr(
-        lambda p, u, real: deepseek_v2._routed(preset, p, u, real, make_grouped_matmul_fn())
+        lambda p, u, real: moe_layer.routed(
+            preset, p, u, real, make_grouped_matmul_fn(), gate=moe_layer.route
+        )
     )(moe, u, real))
     assert "scan[" in text and "length=8" in text
     assert "f32[131072,7168]" not in text and "f32[16384,7168]" in text

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from sparkdl_tpu.models import afmoe, bert, deepseek_v2, deepseek_v32, jamba, xing4_0
+from sparkdl_tpu.models import afmoe, bert, deepseek_v2, deepseek_v32, jamba, moe, xing4_0
 from sparkdl_tpu.models.registry import get_model
 from sparkdl_tpu.utils import profiler
 
@@ -44,7 +44,8 @@ FAMILIES = {
     "trinity-mini-tiny": (WINDOWED, (2, 64)),
     "xing4.0-tiny": (HYPER, (2, 64)),
 }
-MODULES = (bert, jamba, deepseek_v2, deepseek_v32, afmoe, xing4_0)
+#: every module that names a scope: the families and the routed layer
+MODULES = (bert, jamba, deepseek_v2, deepseek_v32, afmoe, xing4_0, moe)
 
 
 @functools.lru_cache(maxsize=None)

@@ -40,7 +40,7 @@ from benchmarks.data import texts  # noqa: E402
 from benchmarks.reference import xing4_0 as reference  # noqa: E402
 from benchmarks.reference.jamba import from_bits, tokenize  # noqa: E402
 from sparkdl_tpu.dataframe import DataFrame  # noqa: E402
-from sparkdl_tpu.models import deepseek_v2, get_model  # noqa: E402
+from sparkdl_tpu.models import get_model, moe  # noqa: E402
 from sparkdl_tpu.models import xing4_0 as program  # noqa: E402
 from sparkdl_tpu.ops import hyper_connection as hc  # noqa: E402
 from sparkdl_tpu.ops.flash_attention import make_latent_attention_fn  # noqa: E402
@@ -116,7 +116,7 @@ def test_tiny_preset_is_the_family(tiny):
     preset = program.xing4_0_tiny()
     assert reference.weight_shapes(config) == program.param_shapes(preset)
     assert (preset.expert_layers, preset.first_k_dense, preset.hc_mult) == (2, 1, 4)
-    # DeepSeek's names, read by `route` and `_routed`, carry this family's keys
+    # DeepSeek's names, read by `moe.route` and `moe.routed`, carry this family's keys
     assert (preset.scoring_func, preset.n_group, preset.norm_topk_prob) == ("sigmoid", 1, True)
     assert preset.hyper_constants == hc.Constants(4, 20, 1e-6, (-30.0, 30.0), 1e-6)
 
@@ -157,7 +157,7 @@ def test_published_preset_is_the_configuration_file():
     assert (spec.feature_dim, spec.vocab_size, spec.max_length) == (3584, 131072, 262144)
     assert get_model("xing4.0-tiny").feature_dim == 64
     # every expert held: the slot buffer is every slot, one body
-    assert deepseek_v2.slot_capacity(preset, 16384) == 16384 * 4
+    assert moe.slot_capacity(preset, 16384) == 16384 * 4
 
 
 @pytest.mark.parametrize(
